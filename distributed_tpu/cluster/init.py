@@ -231,14 +231,11 @@ def initialize(
     # with no args uses the TPU metadata. This is the documented default
     # when pod markers are present; DTPU_AUTO_INIT=0 opts out.
     if _should_auto_init() and not _initialized:
-        try:
-            jax.distributed.initialize()
-            _initialized = True
-        except RuntimeError as e:
-            # Best-effort: jax.distributed must run before any backend use;
-            # initialize() called late in a single-host flow should degrade
-            # to local semantics, not crash the program.
-            dlog.warning(f"pod auto-init skipped: {e}")
+        # Pod markers say this host is one of several: failing to join is
+        # an error (initialize() must run before any backend use), never a
+        # quiet single-process run on a fraction of the slice.
+        jax.distributed.initialize()
+        _initialized = True
     if jax.process_count() > 1:
         # Multi-process for real — whether our auto-init did it or the user
         # called jax.distributed.initialize() themselves. The returned spec

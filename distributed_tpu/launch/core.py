@@ -19,6 +19,7 @@ included as data (never a hang).
 from __future__ import annotations
 
 import dataclasses
+import glob
 import json
 import os
 import shlex
@@ -137,10 +138,61 @@ def _tail(path: Path, max_bytes: int = 4096) -> str:
         return ""
 
 
+# One-chip-per-process layouts of a TPU host, by chips on the host: libtpu's
+# TPU_PROCESS_BOUNDS for that many single-chip processes. Only what PR 21
+# ran on the chip is listed (a v5e 2x2 host).
+_TPU_PROCESS_BOUNDS = {4: "2,2,1"}
+
+
+def _tpu_chip_count() -> int:
+    """TPU chips on this host, found WITHOUT jax (a launcher that touched
+    the backend would hold every chip its workers need): the accelerator
+    device nodes libtpu itself opens."""
+    return len(glob.glob("/dev/accel[0-9]*")) or len(
+        glob.glob("/dev/vfio/[0-9]*")
+    )
+
+
+def _tpu_worker_env(index: int, num_workers: int, chips: int,
+                    ports: Sequence[int]) -> Dict[str, str]:
+    """libtpu's per-process visibility/bounds environment pinning local
+    worker ``index`` to chip ``index``. A chip belongs to one process at a
+    time, so N unpinned local workers would all claim every chip and all
+    but one die at the libtpu lock; pinned, each sees one local device and
+    ``cluster.initialize()`` joins them into one N-device mesh."""
+    if num_workers != chips or chips not in _TPU_PROCESS_BOUNDS:
+        raise ValueError(
+            f"LocalLauncher: cannot give {num_workers} local workers their "
+            f"own chip on a host with {chips} TPU chip(s) (supported: one "
+            f"worker per chip on {sorted(_TPU_PROCESS_BOUNDS)}-chip hosts). "
+            "One process drives every chip of a host: run the script "
+            "directly under dtpu.DataParallel() instead."
+        )
+    addresses = ",".join(f"localhost:{p}" for p in ports)
+    # Both generations of libtpu's names: a TPU VM image may export the
+    # older *_HOST_BOUNDS spelling for the whole host, which must not
+    # survive into a one-chip worker.
+    return {
+        "TPU_VISIBLE_CHIPS": str(index),
+        "TPU_VISIBLE_DEVICES": str(index),
+        "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+        "TPU_CHIPS_PER_HOST_BOUNDS": "1,1,1",
+        "TPU_PROCESS_BOUNDS": _TPU_PROCESS_BOUNDS[chips],
+        "TPU_HOST_BOUNDS": _TPU_PROCESS_BOUNDS[chips],
+        "TPU_PROCESS_ADDRESSES": addresses,
+        "TPU_PROCESS_PORT": str(ports[index]),
+        "CLOUD_TPU_TASK_ID": str(index),
+        "TPU_WORKER_ID": str(index),
+    }
+
+
 class LocalLauncher:
-    """Spawn N worker processes on this machine (CPU-sim CI and single-host
-    multi-chip runs). Gang semantics: all start together; on any worker's
-    crash the rest are killed after `grace` rather than hanging at the next
+    """Spawn N worker processes on this machine: the CPU-sim gangs of CI,
+    and on a TPU host one worker per chip (each pinned to its own chip
+    through libtpu's per-process environment — see ``_tpu_worker_env``;
+    worker counts it cannot pin are refused, never left to fight over the
+    chips). Gang semantics: all start together; on any worker's crash the
+    rest are killed after `grace` rather than hanging at the next
     collective — the failure surfaces as that worker's result row."""
 
     def __init__(self, env_extra: Optional[Dict[str, str]] = None):
@@ -173,13 +225,24 @@ class LocalLauncher:
         else:
             ports = net.free_ports(num_workers)
         workers = [f"127.0.0.1:{p}" for p in ports]
+        base_env = {**os.environ, **self.env_extra}
+        # Workers held to the CPU (the sim gangs) never open a chip.
+        chips = (
+            _tpu_chip_count()
+            if num_workers > 1 and base_env.get("JAX_PLATFORMS") != "cpu"
+            else 0
+        )
+        tpu_ports = net.free_ports(num_workers) if chips else []
+        chip_envs = [
+            _tpu_worker_env(i, num_workers, chips, tpu_ports) if chips else {}
+            for i in range(num_workers)
+        ]  # refuses here, before anything is spawned
         tmp = Path(tempfile.mkdtemp(prefix="dtpu_launch_"))
         procs = []
         hb_paths = [tmp / f"heartbeat-{i}" for i in range(num_workers)]
         for i in range(num_workers):
             spec = config_lib.ClusterSpec(workers=workers, index=i)
-            env = dict(os.environ)
-            env.update(self.env_extra)
+            env = {**base_env, **chip_envs[i]}
             env[config_lib.ENV_VAR] = spec.to_json()
             env[RESULT_ENV] = str(tmp / f"result-{i}.json")
             env[HEARTBEAT_ENV] = str(hb_paths[i])

@@ -42,26 +42,10 @@ from typing import Callable, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import PartitionSpec
 
 from .core import Layer, Shape
-
-try:  # modern location (jax>=0.8)
-    from jax import shard_map
-except ImportError:  # pragma: no cover — older jax
-    from jax.experimental.shard_map import shard_map
-
-import inspect
-
-_sig = inspect.signature(shard_map).parameters
-if "check_vma" in _sig:
-    _CHECK_KWARGS = {"check_vma": False}
-elif "check_rep" in _sig:  # pragma: no cover — older jax
-    _CHECK_KWARGS = {"check_rep": False}
-else:  # pragma: no cover
-    _CHECK_KWARGS = {}
-del _sig
 
 
 # Trace-time record of the most recent pipelined apply on this thread:
@@ -403,7 +387,7 @@ class PipelinedBlocks(Layer):
                 mesh=mesh,
                 in_specs=tuple(in_specs),
                 out_specs=x_spec,
-                **_CHECK_KWARGS,
+                check_vma=False,
             )(*args)
         return out, {}
 
@@ -545,6 +529,6 @@ class PipelinedBlocks(Layer):
             mesh=mesh,
             in_specs=(p_specs, c_specs, x_spec, PartitionSpec()),
             out_specs=(x_spec, c_specs),
-            **_CHECK_KWARGS,
+            check_vma=False,
         )(stacked, cache["blocks"], x, jnp.asarray(pos))
         return out, {"blocks": new_blocks}

@@ -14,10 +14,20 @@ import jax
 
 
 def interpret() -> bool:
-    """True when pallas_call must run in interpreter mode: Mosaic lowering
-    exists only for real TPUs; everywhere else (CPU CI, the 8-device sim)
-    the interpreter runs the same kernel semantics."""
-    return jax.default_backend() != "tpu"
+    """True when pallas_call must run in interpreter mode: Mosaic compiles
+    the kernels on TPU; the CPU backend (tier-1, the 8-device sim) runs the
+    same kernel semantics in the interpreter. Any other backend is an
+    error — no kernel here has run on one, and an interpreted kernel on an
+    accelerator would hide that."""
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(
+        f"distributed_tpu's Pallas kernels run on TPU (Mosaic) or CPU "
+        f"(interpreter); backend {backend!r} is not supported"
+    )
 
 
 def round_up(v: int, m: int) -> int:

@@ -186,6 +186,7 @@ def _fwd_pallas(q, k, v, scale, causal, block_q, block_k):
             pltpu.VMEM((block_q, 128), jnp.float32),
             pltpu.VMEM((block_q, d_pad), jnp.float32),
         ],
+        name="dtpu_flash_fwd",
         interpret=_interpret(),
     )(qp, kp, vp)
     # Residual stats are sliced to one value per row: the lane-replicated
@@ -327,6 +328,7 @@ def _bwd_pallas(res, g, *, scale, causal, block_q, block_k):
         out_specs=pl.BlockSpec((1, block_q, d_pad), lambda b, i, j: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, t_pad, d_pad), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d_pad), jnp.float32)],
+        name="dtpu_flash_dq",
         interpret=_interpret(),
     )(qp, kp, vp, dop, m_b, l_b, dl_b)
 
@@ -356,6 +358,7 @@ def _bwd_pallas(res, g, *, scale, causal, block_q, block_k):
             pltpu.VMEM((block_k, d_pad), jnp.float32),
             pltpu.VMEM((block_k, d_pad), jnp.float32),
         ],
+        name="dtpu_flash_dkv",
         interpret=_interpret(),
     )(qp, kp, vp, dop, m_b, l_b, dl_b)
     return dq[:, :t, :d], dk[:, :t, :d], dv[:, :t, :d]
@@ -570,6 +573,7 @@ def _fwd_pallas_packed(qf, kf, vf, h, d, scale, causal, block_q, block_k):
             pltpu.VMEM((block_q, _LANES), jnp.float32),
             pltpu.VMEM((block_q, _LANES), jnp.float32),
         ],
+        name="dtpu_flash_fwd_packed",
         interpret=_interpret(),
     )(qp, kp, vp)
     if t_pad >= _COMPACT_STATS_MIN_T:
@@ -626,6 +630,7 @@ def _bwd_pallas_packed(h, d, causal, block_q, block_k, res, g):
         out_specs=lane_q,
         out_shape=jax.ShapeDtypeStruct((b, t_pad, h * d), qf.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, _LANES), jnp.float32)],
+        name="dtpu_flash_dq_packed",
         interpret=_interpret(),
     )(qp, kp, vp, dop, m_out, l_out, delta)
 
@@ -652,6 +657,7 @@ def _bwd_pallas_packed(h, d, causal, block_q, block_k, res, g):
             pltpu.VMEM((block_k, _LANES), jnp.float32),
             pltpu.VMEM((block_k, _LANES), jnp.float32),
         ],
+        name="dtpu_flash_dkv_packed",
         interpret=_interpret(),
     )(qp, kp, vp, dop, m_out, l_out, delta)
     return dq[:, :t], dk[:, :t], dv[:, :t]
@@ -711,14 +717,11 @@ def _flash_bwd(causal, block_q, block_k, res, g):
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
-_warned_backend = False
-
-
 def dense_attention(q, k, v, causal: bool):
     """Stock-XLA attention over (B, T, H, D) tensors — THE dense softmax
-    path, shared by MultiHeadAttention's short-T branch, the Ulysses
-    non-flash branch, and the no-Mosaic backend fallback below, so mask/
-    scale/dtype policy lives in exactly one place."""
+    path, shared by MultiHeadAttention's short-T branch and the Ulysses
+    non-flash branch, so mask/scale/dtype policy lives in exactly one
+    place."""
     hd = q.shape[-1]
     s = jnp.einsum(
         "bqhd,bkhd->bhqk", q, k, preferred_element_type=jnp.float32
@@ -731,14 +734,6 @@ def dense_attention(q, k, v, causal: bool):
     return jnp.einsum("bhqk,bkhd->bqhd", a, v)
 
 
-def _dense_fallback(q, k, v, causal):
-    """Backends with no Mosaic lowering: Pallas interpret mode inside jit is
-    orders of magnitude slower than the dense einsums, so non-TPU
-    accelerators (GPU) take the dense path with a warning (CPU keeps
-    interpret mode — that's the test configuration)."""
-    return dense_attention(q, k, v, causal)
-
-
 def flash_attention(
     q, k, v, *, causal: bool = False,
     block_q: Optional[int] = None, block_k: int = 1024,
@@ -747,9 +742,8 @@ def flash_attention(
 
     q, k, v: (B, T, H, D) — same layout MultiHeadAttention produces.
     Returns (B, T, H, D) in q's dtype. Scores/softmax compute in float32.
-    On backends with neither a Mosaic lowering nor a test rationale for
-    interpret mode (anything but TPU/CPU), falls back to dense XLA attention
-    with a one-time warning.
+    Mosaic on TPU, the Pallas interpreter on CPU (the test configuration);
+    any other backend is an error (``_pallas_common.interpret``).
 
     ``block_q=None`` (default) resolves to the swept 1024, scoped-VMEM-
     clamped to 512 for float32 inputs (any length) and for bf16 above
@@ -757,18 +751,6 @@ def flash_attention(
     as passed — sweeps on chips with different VMEM budgets must measure
     what they ask for.
     """
-    backend = jax.default_backend()
-    if backend not in ("tpu", "cpu"):
-        global _warned_backend
-        if not _warned_backend:
-            from ..utils import logging as dlog
-
-            dlog.warning(
-                f"flash_attention: no Mosaic lowering on backend "
-                f"{backend!r}; using dense XLA attention"
-            )
-            _warned_backend = True
-        return _dense_fallback(q, k, v, causal)
     b, t, h, d = q.shape
     rt = _round_up(t, 8)
     if block_q is None:

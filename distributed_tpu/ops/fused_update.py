@@ -147,6 +147,7 @@ def _segment_update(hyper, flat_p, flat_g, flat_m, flat_v):
         ] + [pl.BlockSpec((bm, _LANES), lambda i: (i, 0))] * 4,
         out_specs=[pl.BlockSpec((bm, _LANES), lambda i: (i, 0))] * 3,
         out_shape=[shape, shape, shape],
+        name="dtpu_fused_adam",
         interpret=_interpret(),
     )(hyper, p2, g2, m2, v2)
     return (

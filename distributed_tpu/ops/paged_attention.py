@@ -278,6 +278,7 @@ def paged_attention(q, k_pool, v_pool, block_tables, positions):
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((s, kw, h * hd), q.dtype),
+        name="dtpu_paged_attention_int8" if quant else "dtpu_paged_attention",
         interpret=_interpret(),
     )(tables, pos, *inputs)
     return out.reshape(s, kw, h, hd)

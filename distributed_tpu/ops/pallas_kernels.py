@@ -107,6 +107,7 @@ def _xent_forward(logits, labels):
         ],
         out_specs=pl.BlockSpec((bm, 1), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n_pad, 1), jnp.float32),
+        name="dtpu_xent_fwd",
         interpret=_interpret(),
     )(lp, yp)
     return loss[:n, 0]
@@ -129,6 +130,7 @@ def _xent_backward(logits, labels, g):
         ],
         out_specs=pl.BlockSpec((bm, c_pad), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n_pad, c_pad), logits.dtype),
+        name="dtpu_xent_bwd",
         interpret=_interpret(),
     )(lp, yp, gp)
     return dl[:n, :c]
@@ -165,21 +167,24 @@ def _vjp_bwd(res, g):
 fused_softmax_xent.defvjp(_vjp_fwd, _vjp_bwd)
 
 
-_warned_fallback = False
+def loss_path(c: int) -> str:
+    """Which implementation the registry-level pallas loss runs for a
+    ``c``-class head: ``"fused"`` (the Pallas kernel) or ``"stock"`` (the
+    XLA loss, above ``MAX_FUSED_CLASSES``)."""
+    return "fused" if c <= MAX_FUSED_CLASSES else "stock"
 
 
 def _stock_fallback(c: int) -> bool:
-    global _warned_fallback
-    if c <= MAX_FUSED_CLASSES:
+    if loss_path(c) == "fused":
         return False
-    if not _warned_fallback:
-        from ..utils import logging as dlog
+    from ..utils import logging as dlog
 
-        dlog.warning(
-            f"pallas loss: {c} classes exceeds the fused ceiling "
-            f"({MAX_FUSED_CLASSES}); using the stock XLA loss"
-        )
-        _warned_fallback = True
+    # Trace-time, so once per compiled program — never once per process:
+    # every program that swaps losses says so.
+    dlog.warning(
+        f"pallas loss: {c} classes exceeds the fused ceiling "
+        f"({MAX_FUSED_CLASSES}); this program uses the stock XLA loss"
+    )
     return True
 
 

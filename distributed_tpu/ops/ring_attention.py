@@ -35,26 +35,8 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec
-
-try:  # modern location (jax>=0.8)
-    from jax import shard_map
-except ImportError:  # pragma: no cover — older jax
-    from jax.experimental.shard_map import shard_map
-
-import inspect
-
-# Replication checking was renamed check_rep -> check_vma in jax 0.8;
-# resolve the kwarg once at import, not per call.
-_sig = inspect.signature(shard_map).parameters
-if "check_vma" in _sig:
-    _CHECK_KWARGS = {"check_vma": False}
-elif "check_rep" in _sig:  # pragma: no cover — older jax
-    _CHECK_KWARGS = {"check_rep": False}
-else:  # pragma: no cover
-    _CHECK_KWARGS = {}
-del _sig
 
 
 def _online_fold(m, l, acc, qf, kc, vc, scale, mask):
@@ -175,7 +157,7 @@ def ring_attention(
         mesh=mesh,
         in_specs=(spec, spec, spec),
         out_specs=spec,
-        **_CHECK_KWARGS,
+        check_vma=False,
     )(q, k, v)
 
 
@@ -314,5 +296,5 @@ def _ring_attention_zigzag(q, k, v, *, mesh, seq_axis, batch_axis):
         mesh=mesh,
         in_specs=(spec, spec, spec),
         out_specs=spec,
-        **_CHECK_KWARGS,
+        check_vma=False,
     )(q, k, v)

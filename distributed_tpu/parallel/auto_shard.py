@@ -51,9 +51,11 @@ Two jobs live here:
      machinery);
    - every array dim sharded by a spec divides evenly.
 
-   Otherwise the plain call runs (GSPMD replication on multi-device, which
-   is still correct — and free on a single device, where there is nothing
-   to replicate).
+   Otherwise the plain call runs and a warning says why: GSPMD
+   replication is still correct, but on real chips it is an all-gather in
+   front of an opaque custom call. Inside a shard_map body
+   (PipelinedBlocks) the operands are already local and the plain call is
+   the per-shard call — no warning.
 """
 
 from __future__ import annotations
@@ -63,24 +65,8 @@ from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
-
-try:  # modern location (jax>=0.8)
-    from jax import shard_map
-except ImportError:  # pragma: no cover - older jax
-    from jax.experimental.shard_map import shard_map
-
-import inspect
-
-# Replication checking was renamed check_rep -> check_vma in jax 0.8.
-_sig = inspect.signature(shard_map).parameters
-if "check_vma" in _sig:
-    _CHECK_KWARGS = {"check_vma": False}
-elif "check_rep" in _sig:  # pragma: no cover - older jax
-    _CHECK_KWARGS = {"check_rep": False}
-else:  # pragma: no cover
-    _CHECK_KWARGS = {}
-del _sig
 
 
 def ambient_mesh() -> Tuple[Optional[Mesh], Optional[str], Optional[str]]:
@@ -105,13 +91,17 @@ def ambient_mesh() -> Tuple[Optional[Mesh], Optional[str], Optional[str]]:
 def shard_rows(fn, arrays: Sequence, in_specs: Sequence[PartitionSpec],
                out_spec: PartitionSpec, *, allowed_axes=None):
     """Apply fn(*arrays) under shard_map over the ambient mesh when safe
-    (see module docstring), else call it plainly.
+    (see module docstring), else call it plainly — with a warning, because
+    on real chips the plain call puts an all-gather in front of an opaque
+    custom call.
 
     ``allowed_axes``: override the default {batch, model} axis allowlist —
     for callers that deliberately shard over another axis (e.g. Ulysses
     attention sharding heads over 'seq') and have already validated it."""
     mesh, batch_axis, model_axis = ambient_mesh()
-    if mesh is None:
+    if mesh is None or jax.sharding.get_abstract_mesh().manual_axes:
+        # Off-mesh, or already inside a shard_map body (PipelinedBlocks):
+        # the operands are local, the plain call IS the per-shard call.
         return fn(*arrays)
     if allowed_axes is not None:
         allowed = set(allowed_axes) | {None}
@@ -119,8 +109,11 @@ def shard_rows(fn, arrays: Sequence, in_specs: Sequence[PartitionSpec],
         allowed = {batch_axis, model_axis, None}
     for name in mesh.axis_names:
         if int(mesh.shape[name]) > 1 and name not in allowed:
-            return fn(*arrays)
-    # Divisibility of every sharded dim, or fall back.
+            return _gathered_call(
+                fn, arrays,
+                f"mesh axis {name!r} (size {int(mesh.shape[name])}) is not "
+                f"one shard_rows may shard over ({sorted(map(str, allowed))})",
+            )
     for arr, spec in zip(arrays, in_specs):
         for dim, axis in enumerate(spec):
             if axis is None:
@@ -128,11 +121,27 @@ def shard_rows(fn, arrays: Sequence, in_specs: Sequence[PartitionSpec],
             if int(mesh.shape[axis]) > 1 and arr.shape[dim] % int(
                 mesh.shape[axis]
             ):
-                return fn(*arrays)
+                return _gathered_call(
+                    fn, arrays,
+                    f"dim {dim} of shape {tuple(arr.shape)} does not divide "
+                    f"over mesh axis {axis!r} (size {int(mesh.shape[axis])})",
+                )
     return shard_map(
         fn, mesh=mesh, in_specs=tuple(in_specs), out_specs=out_spec,
-        **_CHECK_KWARGS,
+        check_vma=False,
     )(*arrays)
+
+
+def _gathered_call(fn, arrays, why: str):
+    from ..utils import logging as dlog
+
+    name = getattr(getattr(fn, "func", fn), "__name__", repr(fn))
+    dlog.warning(
+        f"shard_rows: {name} runs UNSHARDED on a multi-device mesh — {why}; "
+        "GSPMD gathers its operands and every device computes the global "
+        "problem"
+    )
+    return fn(*arrays)
 
 
 # ===========================================================================
@@ -146,14 +155,14 @@ def shard_rows(fn, arrays: Sequence, in_specs: Sequence[PartitionSpec],
 TIE_REL_TOL = 0.05
 
 #: Analytic per-device peak FLOP/s and per-device collective bandwidth by
-#: backend. Order-of-magnitude on purpose: the cost model ranks candidates
-#: for ONE model on ONE backend, so only the relative weight of compute vs
-#: comm vs dispatch matters, not the absolute seconds.
-_BACKEND_CONSTANTS = {
-    "tpu": {"peak_flops": 2.0e14, "comm_bw": 9.0e10, "dispatch_s": 5e-4,
-            "reduced_speedup": 2.0},
-    "gpu": {"peak_flops": 1.0e14, "comm_bw": 5.0e10, "dispatch_s": 8e-4,
-            "reduced_speedup": 2.0},
+#: ``device_kind``. Order-of-magnitude on purpose: the cost model ranks
+#: candidates for ONE model on ONE device kind, so only the relative weight
+#: of compute vs comm vs dispatch matters, not the absolute seconds. A
+#: device kind that is not listed is an error, never priced as another's.
+_DEVICE_CONSTANTS = {
+    # TPU v5e: 197 TFLOP/s bf16 (Google Cloud "TPU v5e").
+    "TPU v5 lite": {"peak_flops": 2.0e14, "comm_bw": 9.0e10,
+                    "dispatch_s": 5e-4, "reduced_speedup": 2.0},
     # XLA:CPU EMULATES bf16 (BENCH_precision measured mixed at 0.83x f32),
     # so reduced precision gets a PENALTY there, not a speedup — the
     # planner must not recommend a policy the backend runs slower.
@@ -166,9 +175,15 @@ _STRATEGY_RANK = {  # simplicity order for tie-breaking (lower = simpler)
 }
 
 
-def _backend_constants(backend: Optional[str] = None) -> dict:
-    backend = backend or jax.default_backend()
-    return _BACKEND_CONSTANTS.get(backend, _BACKEND_CONSTANTS["tpu"])
+def _device_constants(device) -> dict:
+    try:
+        return _DEVICE_CONSTANTS[device.device_kind]
+    except KeyError:
+        raise ValueError(
+            f"auto-shard planner has no cost constants for device kind "
+            f"{device.device_kind!r} (platform {device.platform!r}); known: "
+            f"{sorted(_DEVICE_CONSTANTS)}"
+        ) from None
 
 
 @dataclasses.dataclass(frozen=True)
@@ -663,13 +678,12 @@ def plan_sharding(
 
     devices = list(devices) if devices is not None else list(jax.devices())
     backend = devices[0].platform
-    consts = _backend_constants(backend)
+    consts = _device_constants(devices[0])
     if tx is None:
         tx = optim.get(optimizer)
     if precisions is None:
         precisions = (
-            (None, "mixed_bfloat16") if backend in ("tpu", "gpu")
-            else (None,)
+            (None, "mixed_bfloat16") if backend == "tpu" else (None,)
         )
     if grad_accums is None:
         grad_accums = (1, 2, 4)

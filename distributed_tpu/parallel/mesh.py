@@ -106,27 +106,29 @@ def make_mesh(
                 f"dcn_axis {dcn_axis!r} not among mesh axes {names}"
             )
         if slice_ids is None:
-            # Real multi-slice hardware: let jax's hybrid topology helper
-            # optimize within-slice ordering; fall back to the plain
-            # slice-major arrangement when it can't.
-            try:
-                dcn_shape = [1] * len(sizes)
-                dcn_i = names.index(dcn_axis)
-                n_slices = len(set(ids))
-                per = list(sizes)
-                per[dcn_i] = sizes[dcn_i] // n_slices
-                dcn_shape[dcn_i] = n_slices
-                dev_array = mesh_utils.create_hybrid_device_mesh(
-                    per, dcn_shape, devices=devices
-                )
-                return Mesh(dev_array, axis_names=tuple(names))
-            except Exception:
-                pass
+            # Real multi-slice hardware: jax's hybrid topology helper
+            # orders devices within each slice; its failure is an error,
+            # not a reason to guess an ordering.
+            dcn_shape = [1] * len(sizes)
+            dcn_i = names.index(dcn_axis)
+            n_slices = len(set(ids))
+            per = list(sizes)
+            per[dcn_i] = sizes[dcn_i] // n_slices
+            dcn_shape[dcn_i] = n_slices
+            dev_array = mesh_utils.create_hybrid_device_mesh(
+                per, dcn_shape, devices=devices
+            )
+            return Mesh(dev_array, axis_names=tuple(names))
         dev_array = _hybrid_device_array(devices, names, sizes, dcn_axis, ids)
         return Mesh(dev_array, axis_names=tuple(names))
-    try:
+    if len(devices) == jax.device_count():
+        # The whole topology: the helper picks an ICI-friendly order on TPU
+        # (a plain reshape elsewhere) and raises on a topology it cannot
+        # lay out.
         dev_array = mesh_utils.create_device_mesh(sizes, devices=devices)
-    except Exception:
+    else:
+        # A caller-chosen subset (planner measurements, tests) is not a
+        # topology the helper knows; it keeps the order it was given.
         dev_array = np.array(devices).reshape(sizes)
     return Mesh(dev_array, axis_names=tuple(names))
 

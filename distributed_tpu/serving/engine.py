@@ -528,11 +528,13 @@ class Engine:
             self._draft_decode_fn = self._with_kernel(
                 draft_model._scoped(self._draft_decode_jit)
             )
+        from ..ops._pallas_common import interpret as pallas_interpret
+
         events_lib.emit(
             evs.DECODE_KERNEL_SELECTED,
             kernel=self.decode_kernel,
             backend=jax.default_backend(),
-            interpret=bool(jax.default_backend() != "tpu"),
+            interpret=pallas_interpret(),
         )
         self.last_run_telemetry = None
         self._sched: Optional[Scheduler] = None  # live during run()

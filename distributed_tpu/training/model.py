@@ -613,6 +613,23 @@ class Model:
         )
         return self._train_step
 
+    def lower_train_step(self, x, y):
+        """AOT-lower the single-step train program for one host batch
+        ``(x, y)`` under this model's strategy and precision scopes — the
+        body ``fit`` jits, on the batch placement ``fit`` uses. The
+        result's ``.as_text()`` is the StableHLO the step asks for (each
+        Pallas kernel is a ``tpu_custom_call`` carrying its ``kernel_name``
+        and per-shard operand shapes); ``.compile()`` is served from the
+        persistent compile cache once ``fit`` has compiled the same step."""
+        batch = self.strategy.put_batch(
+            {"x": np.asarray(x), "y": np.asarray(y)}
+        )
+        jitted = jax.jit(self._train_step_body(), donate_argnums=(0, 1, 2))
+        return self._scoped(jitted.lower)(
+            self.params, self.state, self.opt_state, batch["x"], batch["y"],
+            self._step_rng(),
+        )
+
     def _train_step_body(self):
         """The uncompiled single-step train body (plain or chunked-head):
         ``(params, state, opt_state, x, y, rng) -> (params, state,

@@ -1,177 +1,68 @@
-"""Persistent XLA compilation cache (jax_compilation_cache_dir) setup.
+"""Persistent XLA compilation cache: one directory, placed from outside.
 
-Compilation dominates two wall-clock budgets this repo cares about:
+Compiling is a large part of every cold start this repo pays for — the
+136M LM's train step, each serving dispatch, every tier-1 jit — and JAX's
+persistent cache turns a repeat compile into a disk read. The cache key
+includes the cache directory's own path, so a directory that moves never
+hits; the rule here is therefore:
 
-- **CI**: the tier-1 suite on the 1-core box measured 869s against the
-  870s kill at PR 5, and most of that is jit compiles repeated identically
-  run after run.
-- **Production restarts**: the resilience supervisor's
-  restart-to-first-step latency (``bench.py resilience``) is process spawn
-  + imports + checkpoint restore + *jit recompile* — the recompile is the
-  dominant term for real models, and a warm persistent cache removes it
-  (measured 1.8x faster restart-to-first-step, BENCH_compile_cache.json).
+- where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX itself reads it and this
+  module sets nothing (child processes inherit the variable);
+- where it is not, the cache is ``<checkout>/.jax_cache`` — one fixed,
+  git-ignored path, never built from a hostname, pid, time or temp name.
 
-:func:`enable` points JAX's persistent compilation cache at a directory
-keyed per box + JAX version + Python version, so serialized executables
-are never shared across incompatible toolchains (a cache dir on shared
-storage would otherwise mix them), and makes cache-entry writes atomic
-(kill-safe). Callers: ``tests/conftest.py`` (every pytest process) and
-any production launcher that wants cheap restarts. Subprocess workers
-are deliberately NOT pointed at the shared cache by env var — see
-:func:`enable`, which also documents why the cache is OFF by default on
-the XLA:CPU backend (this jaxlib's CPU executable serializer corrupts
-the heap for some programs — tier-1's budget rescue on the CPU box
-therefore comes from the whale triage, and the cache pays off on
-accelerator backends).
+:func:`enable` is the only place in the repo that updates
+``jax_compilation_cache_dir``. Callers: ``chip_smoke.py`` and ``bench.py``.
+Entry thresholds stay at JAX's defaults (compiles under one second are not
+cached). Tier-1 runs without it: on XLA:CPU every cache hit logs a
+multi-kilobyte ``cpu_aot_loader`` machine-feature message to stderr.
 
-``DTPU_COMPILE_CACHE``: ``0`` never, ``1`` always (including CPU —
-measure at your own risk), unset = accelerator backends only. Relocate
-with ``DTPU_COMPILE_CACHE_DIR=/path`` (or JAX's own
-``JAX_COMPILATION_CACHE_DIR``, which wins because it reaches the config
-before we do).
+Installed-version notes (jax/jaxlib 0.9.0, re-tried in PR 21): the XLA:CPU
+executable serializer no longer corrupts the heap (the jaxlib-0.4.37 crash
+that kept the cache off on CPU does not reproduce: tests/test_chunked_head.py
+passes cold and warm, thresholds at zero included), and a cache entry
+truncated by a kill is now a logged read error followed by a recompile, not
+a crash — so the CPU skip, its ``DTPU_COMPILE_CACHE`` knob and the
+atomic-write patch of ``jax._src.lru_cache`` are gone.
 """
 
 from __future__ import annotations
 
 import os
-import platform
-import sys
-from typing import Optional
+
+ENV_VAR = "JAX_COMPILATION_CACHE_DIR"
+
+_CHECKOUT = os.path.dirname(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+)
 
 
-def default_cache_dir() -> str:
-    """Per-box, per-toolchain cache directory: serialized XLA executables
-    are only valid for the exact jax/jaxlib build (and, conservatively,
-    the box) that wrote them, so the key includes hostname + jax version +
-    python minor version."""
+def cache_dir() -> str:
+    """The directory the persistent cache lives in: ``$JAX_COMPILATION_CACHE_DIR``
+    when set, else the fixed in-checkout path."""
+    return os.environ.get(ENV_VAR) or os.path.join(_CHECKOUT, ".jax_cache")
+
+
+def enable() -> str:
+    """Turn on the persistent compilation cache for this process and return
+    its directory. Safe to call before or after the first compile — JAX
+    consults the config per compilation."""
     import jax
 
-    tag = (
-        f"{platform.node() or 'localhost'}"
-        f"-jax{jax.__version__}"
-        f"-py{sys.version_info.major}.{sys.version_info.minor}"
-    )
-    base = os.environ.get(
-        "DTPU_COMPILE_CACHE_DIR",
-        os.path.join(
-            os.path.expanduser("~"), ".cache", "dtpu", "jax-compile-cache"
-        ),
-    )
-    return os.path.join(base, tag)
+    path = cache_dir()
+    if not os.environ.get(ENV_VAR):
+        jax.config.update("jax_compilation_cache_dir", path)
+    # A Pallas kernel's MLIR locations are serialized INTO its custom call
+    # (the Mosaic body), which the cache key hashes. By default they carry
+    # ten frames of the Python call stack of the trace, so the same train
+    # step traced from two call sites got two keys (measured on the chip,
+    # PR 21: fit's step vs Model.lower_train_step). One frame per location
+    # — the kernel's own source line — keeps the key a function of the
+    # program only, and keeps the kernel's name in the HLO op_name (turning
+    # jax_include_full_tracebacks_in_locations off would drop it, and with
+    # it the names a device trace is read by).
+    jax.config.update("jax_traceback_in_locations_limit", 1)
+    return path
 
 
-def _patch_atomic_cache_writes() -> bool:
-    """Make jax's disk-cache writes ATOMIC (temp file + os.replace).
-
-    ``LRUCache.put`` writes entries with a bare ``write_bytes`` and never
-    rewrites an existing path — so a process killed mid-write (the tier-1
-    runner's 870s ``timeout -k 10``, a preempted worker, the resilience
-    suite's kill injection) leaves a PERMANENTLY truncated entry, and
-    deserializing it crashes every later reader with SIGSEGV/SIGABRT (a
-    C++ executable-deserialize failure, observed while building
-    ``bench.py compile_cache``). A shared per-box cache must survive
-    kills, so the write is replaced with write-to-temp + rename, both for
-    the entry and its atime stamp. Best-effort: returns False (and the
-    cache still works, minus kill-safety) if jax's internals moved."""
-    try:
-        import tempfile
-        import time
-
-        from jax._src import lru_cache as _lru
-
-        if getattr(_lru.LRUCache, "_dtpu_atomic_put", False):
-            return True
-        cache_sfx = _lru._CACHE_SUFFIX
-        atime_sfx = _lru._ATIME_SUFFIX
-
-        def _write_atomic(path, data):
-            fd, tmp = tempfile.mkstemp(
-                dir=str(path.parent), prefix=f".{path.name}.tmp"
-            )
-            try:
-                with os.fdopen(fd, "wb") as f:
-                    f.write(data)
-                os.replace(tmp, path)
-            except BaseException:
-                try:
-                    os.unlink(tmp)
-                except OSError:
-                    pass
-                raise
-
-        def put(self, key, val):
-            # Same contract as LRUCache.put (first write wins, eviction
-            # under the lock), with atomic file creation.
-            if not key:
-                raise ValueError("key cannot be empty")
-            if self.eviction_enabled and len(val) > self.max_size:
-                return
-            cache_path = self.path / f"{key}{cache_sfx}"
-            atime_path = self.path / f"{key}{atime_sfx}"
-            if self.eviction_enabled:
-                self.lock.acquire(timeout=self.lock_timeout_secs)
-            try:
-                if cache_path.exists():
-                    return
-                self._evict_if_needed(additional_size=len(val))
-                _write_atomic(cache_path, val)
-                _write_atomic(
-                    atime_path, time.time_ns().to_bytes(8, "little")
-                )
-            finally:
-                if self.eviction_enabled:
-                    self.lock.release()
-
-        _lru.LRUCache.put = put
-        _lru.LRUCache._dtpu_atomic_put = True
-        return True
-    except Exception:
-        return False
-
-
-def enable(cache_dir: Optional[str] = None,
-           force: bool = False) -> Optional[str]:
-    """Turn on the persistent compilation cache; returns the directory in
-    use (None when disabled or skipped). Safe to call any time before (or
-    after) the first compile — JAX consults the config per compilation. A
-    dir already set (env ``JAX_COMPILATION_CACHE_DIR`` or a prior call)
-    is respected.
-
-    ``DTPU_COMPILE_CACHE`` modes: ``0`` never, ``1`` always, unset/auto
-    = **accelerator backends only**. The CPU skip is a measured
-    necessity, not caution: on this jaxlib (0.4.37), serializing certain
-    XLA:CPU executables (observed with the ``jax.checkpoint``-rematerialized
-    chunked-head scan, under donation) corrupts the process heap —
-    `pytest tests/test_chunked_head.py` with the STOCK jax cache (no
-    wrapper code at all) aborts/segfaults 5/5 runs and passes 3/3 with
-    the cache off. On TPU/GPU the persistent cache is the battle-tested
-    standard path, and the restart-latency win is real (`bench.py
-    compile_cache`, BENCH_compile_cache.json).
-
-    NOTE this enables the cache for THIS process only (jax config, not
-    env), on purpose: a subprocess that inherited only the env var would
-    write entries WITHOUT the atomic-write patch below, and a kill
-    mid-write would poison the shared cache for every later run."""
-    mode = os.environ.get("DTPU_COMPILE_CACHE", "auto")
-    if mode == "0":
-        return None
-    import jax
-
-    if mode != "1" and not force and jax.default_backend() == "cpu":
-        return None
-    current = jax.config.jax_compilation_cache_dir
-    if current:
-        cache_dir = current
-    else:
-        cache_dir = cache_dir or default_cache_dir()
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-    # Thresholds stay at the JAX defaults (min_compile_time 1s): caching
-    # every tiny eager-op executable multiplies the serialize traffic for
-    # no meaningful warm-start win — the >=1s compiles are where the
-    # wall time lives.
-    _patch_atomic_cache_writes()
-    os.makedirs(cache_dir, exist_ok=True)
-    return cache_dir
-
-
-__all__ = ["enable", "default_cache_dir"]
+__all__ = ["ENV_VAR", "cache_dir", "enable"]

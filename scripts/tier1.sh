@@ -23,19 +23,6 @@
 set -o pipefail
 cd "$(dirname "$0")/.." || exit 1
 
-# Persistent XLA compile cache (ROADMAP item 0): tests/conftest.py points
-# the PYTEST process at a per-box/per-jax-version cache dir with
-# kill-safe atomic writes (utils/compile_cache.py) — on ACCELERATOR
-# backends. On this XLA:CPU box the cache stays OFF: jax's CPU
-# executable serializer corrupts the heap for some programs (the suite
-# aborts 5/5 with it on — see utils/compile_cache.py), so the 870s time
-# budget is held by the @slow whale triage instead. DTPU_COMPILE_CACHE=1
-# forces the cache on to re-measure; =0 disables everywhere. Deliberately
-# never exported as JAX_COMPILATION_CACHE_DIR: subprocess workers would
-# write through jax's NON-atomic default path, and a kill mid-write
-# poisons the shared cache permanently.
-echo "compile cache: auto (accelerator backends only; DTPU_COMPILE_CACHE=1/0 to force)"
-
 # TIER1_PRECISION_SMOKE=1: pre-push fast path for mixed-precision work —
 # runs ONLY tests/test_precision.py (~50 s vs the full ~800 s suite) so a
 # policy/step-body/strategy-cast change can iterate without paying for

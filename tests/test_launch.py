@@ -456,3 +456,43 @@ def test_spark_barrier_flow_end_to_end(tmp_path):
     assert "conv2d" in params and "dense" in params
     # Rank 1's row is a parseable accuracy in [0, 1] (README.md:226-232).
     assert 0.0 <= float(by_rank[1].value["row"]) <= 1.0
+
+
+def test_tpu_host_workers_get_one_chip_each_or_are_refused(
+        tmp_path, monkeypatch):
+    """On a TPU host every local worker is pinned to its own chip through
+    libtpu's per-process environment (ran on a v5e 2x2 host in PR 21);
+    a worker count the launcher cannot pin is refused before anything is
+    spawned — never N workers fighting over the chips."""
+    from distributed_tpu.launch import core
+
+    script = tmp_path / "worker.py"  # no package import: 8 quick spawns
+    script.write_text(textwrap.dedent(
+        """
+        import json, os
+        keys = ("TPU_VISIBLE_CHIPS", "TPU_PROCESS_BOUNDS",
+                "TPU_CHIPS_PER_PROCESS_BOUNDS", "TPU_PROCESS_ADDRESSES",
+                "TPU_PROCESS_PORT", "CLOUD_TPU_TASK_ID")
+        with open(os.environ["DTPU_RESULT_FILE"], "w") as f:
+            json.dump({"value": {k: os.environ.get(k) for k in keys}}, f)
+        """
+    ))
+    script = str(script)
+    monkeypatch.delenv("JAX_PLATFORMS")  # workers not held to the CPU
+    monkeypatch.setattr(core, "_tpu_chip_count", lambda: 4)
+    results = LocalLauncher().run([sys.executable, script], 4, timeout=60)
+    assert all(r.ok for r in results)
+    addresses = {r.value["TPU_PROCESS_ADDRESSES"] for r in results}
+    assert len(addresses) == 1 and len(addresses.pop().split(",")) == 4
+    for i, r in enumerate(results):
+        assert r.value["TPU_VISIBLE_CHIPS"] == r.value["CLOUD_TPU_TASK_ID"] == str(i)
+        assert r.value["TPU_PROCESS_BOUNDS"] == "2,2,1"
+        assert r.value["TPU_CHIPS_PER_PROCESS_BOUNDS"] == "1,1,1"
+    assert len({r.value["TPU_PROCESS_PORT"] for r in results}) == 4
+
+    with pytest.raises(ValueError, match=r"DataParallel\(\)"):
+        LocalLauncher().run([sys.executable, script], 2, timeout=60)
+    # CPU-held gangs (every other launcher test) are never restricted.
+    results = LocalLauncher(env_extra={"JAX_PLATFORMS": "cpu"}).run(
+        [sys.executable, script], 2, timeout=60)
+    assert all(r.ok and r.value["TPU_VISIBLE_CHIPS"] is None for r in results)
