@@ -220,6 +220,25 @@ def current_sample_weights():
     return getattr(_AMBIENT_WEIGHTS, "value", None)
 
 
+def child_scope(key: str):
+    """The device scope a container opens around one child's ``apply``:
+    ``with child_scope(key): child.apply(...)``, ``key`` being the child's
+    key in the container's params tree. The ONE place a layer's scope comes
+    from: every container applies its children under it, so the scope path
+    an operation carries on the device
+    (``residual_6/main/multi_head_attention``, read in XProf and by
+    ``benchmarks/scopes.py``) IS its parameter path, which is already a
+    checkpoint format. Names only: a scope adds to the operation's
+    ``op_name`` metadata and changes no instruction. A transparent wrapper
+    (``Remat``) opens none: it shares its inner layer's name and path.
+
+    A context manager and not a function that calls ``apply``: a Python
+    frame between a container and its child made tracing the 24-layer
+    benchmark step 3.7 s (55%) slower on the chip's host, all of it inside
+    the forward Pallas kernels' tracing (measured, PR 24; root PERF.md)."""
+    return jax.named_scope(key)
+
+
 def apply_layers(layers, params, state, x, *, train=False, rng=None):
     """Apply a sequence of layers with Sequential's rng-split and state-
     collection discipline. The SINGLE implementation of that discipline:
@@ -231,13 +250,14 @@ def apply_layers(layers, params, state, x, *, train=False, rng=None):
     rngs = iter(jax.random.split(rng, n_rng)) if (rng is not None and n_rng) else iter(())
     for layer in layers:
         layer_rng = next(rngs, None) if getattr(layer, "needs_rng", False) else None
-        x, s = layer.apply(
-            params.get(layer.name, {}),
-            state.get(layer.name, {}),
-            x,
-            train=train,
-            rng=layer_rng,
-        )
+        with child_scope(layer.name):
+            x, s = layer.apply(
+                params.get(layer.name, {}),
+                state.get(layer.name, {}),
+                x,
+                train=train,
+                rng=layer_rng,
+            )
         if s:
             new_state[layer.name] = s
     return x, new_state
@@ -483,16 +503,18 @@ class Residual(Layer):
             jax.random.split(rng, 2) if rng is not None else (None, None)
         )
         main_rng = rngs[0] if getattr(self.main, "needs_rng", False) else None
-        y, sm = self.main.apply(
-            params.get("main", {}), state.get("main", {}), x,
-            train=train, rng=main_rng,
-        )
+        with child_scope("main"):
+            y, sm = self.main.apply(
+                params.get("main", {}), state.get("main", {}), x,
+                train=train, rng=main_rng,
+            )
         if self.shortcut is not None:
             sc_rng = rngs[1] if getattr(self.shortcut, "needs_rng", False) else None
-            sc, ss = self.shortcut.apply(
-                params.get("shortcut", {}), state.get("shortcut", {}), x,
-                train=train, rng=sc_rng,
-            )
+            with child_scope("shortcut"):
+                sc, ss = self.shortcut.apply(
+                    params.get("shortcut", {}), state.get("shortcut", {}),
+                    x, train=train, rng=sc_rng,
+                )
         else:
             sc, ss = x, {}
         new_state = {}

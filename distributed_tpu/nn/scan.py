@@ -27,7 +27,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from .core import Layer, Shape
+from .core import Layer, Shape, child_scope
 
 # Trace-time record of the most recent ScannedBlocks.apply on this thread:
 # whether the gather overlap engaged and over how many layers. Model.fit's
@@ -109,7 +109,8 @@ def scan_stacked(block, stacked_p, stacked_s, x, *, train, rngs,
 
     def body(h, per_iter):
         p, s, r = per_iter
-        y, new_s = block.apply(p, s, h, train=train, rng=r)
+        with child_scope("blocks"):
+            y, new_s = block.apply(p, s, h, train=train, rng=r)
         # Carry dtype must be stable across iterations (a bf16-compute
         # block in an f32 stream behaves like any mixed-precision layer).
         return y.astype(h.dtype), new_s
@@ -125,7 +126,8 @@ def scan_stacked(block, stacked_p, stacked_s, x, *, train, rngs,
             h, g = carry
             p_next, s, r = per_iter
             g_next = overlap_gather(p_next)
-            y, new_s = block.apply(g, s, h, train=train, rng=r)
+            with child_scope("blocks"):
+                y, new_s = block.apply(g, s, h, train=train, rng=r)
             return (y.astype(h.dtype), g_next), new_s
 
         if rngs is None:

@@ -37,6 +37,7 @@ from .. import optim
 from .. import precision as precision_lib
 from ..nn.core import (
     Layer,
+    child_scope as _child_scope,
     apply_layers as _apply_layers,
     eval_sample_weights as _eval_sample_weights,
 )
@@ -91,10 +92,11 @@ def _cast_for_compute(policy, params, dtype_hints):
     so FSDP-family all-gathers move compute-dtype bytes."""
     if policy is None or not policy.needs_compute_cast:
         return params
-    cast = policy.cast_to_compute(params, dtype_hints)
-    strat = current_strategy()
-    if strat is not None:
-        cast = strat.constrain_compute_params(cast)
+    with jax.named_scope("cast"):
+        cast = policy.cast_to_compute(params, dtype_hints)
+        strat = current_strategy()
+        if strat is not None:
+            cast = strat.constrain_compute_params(cast)
     return cast
 
 
@@ -639,6 +641,12 @@ class Model:
         grad_eval = self._grad_eval_body()
         tx = self.tx
 
+        # Device scopes of a step (``op_name`` metadata only, no instruction
+        # changes; docs/OBSERVABILITY.md): ``cast``, ``loss``, ``metrics``
+        # and ``optimizer`` in this file, the parameter path of each layer
+        # from ``nn.core.child_scope``; forward and backward are JAX's own
+        # ``jvp(...)`` and ``transpose(jvp(...))`` wrappers. Opened inline,
+        # never through a helper that calls on: see ``child_scope``.
         def step(params, state, opt_state, x, y, rng):
             # Under mixed_float16 the live loss scale rides in the
             # (outermost) optimizer state; the loss is scaled before
@@ -647,9 +655,12 @@ class Model:
             loss, new_state, grads, mvals = grad_eval(
                 params, state, x, y, rng, scale
             )
-            updates, new_opt = tx.update(grads, opt_state, params)
-            new_params = optax.apply_updates(params, updates)
-            new_params, new_opt = _constrain_step_outputs(new_params, new_opt)
+            with jax.named_scope("optimizer"):
+                updates, new_opt = tx.update(grads, opt_state, params)
+                new_params = optax.apply_updates(params, updates)
+                new_params, new_opt = _constrain_step_outputs(
+                    new_params, new_opt
+                )
             return new_params, new_state, new_opt, loss, mvals
 
         return step
@@ -674,12 +685,13 @@ class Model:
                 logits, new_state = module.apply(
                     pc, state, x, train=True, rng=rng
                 )
-                if policy is not None:
-                    logits = policy.cast_output(logits)
                 # Layers may report auxiliary objectives (e.g. MoE router
                 # load-balance loss) through state keys named "aux_loss";
                 # they join the objective so their gradients flow.
-                loss = loss_fn(logits, y) + _aux_loss_sum(new_state)
+                with jax.named_scope("loss"):
+                    if policy is not None:
+                        logits = policy.cast_output(logits)
+                    loss = loss_fn(logits, y) + _aux_loss_sum(new_state)
                 # Loss scaling (mixed_float16): autodiff sees scale*loss;
                 # the reported loss stays unscaled via the aux output.
                 scaled = loss if scale is None else loss * scale
@@ -688,7 +700,8 @@ class Model:
             (_, (loss, new_state, logits)), grads = jax.value_and_grad(
                 loss_f, has_aux=True
             )(params)
-            mvals = {name: fn(logits, y) for name, fn in metric_fns}
+            with jax.named_scope("metrics"):
+                mvals = {name: fn(logits, y) for name, fn in metric_fns}
             return loss, new_state, grads, mvals
 
         return grad_eval
@@ -737,28 +750,35 @@ class Model:
 
         def chunk(carry, hyw):
             h_i, y_i, w_i = hyw
-            logits_i, _ = head.apply(head_params, {}, h_i, train=train)
-            if per_ex is not None:
-                elems = per_ex(logits_i, y_i)
-                lsum = jnp.sum(elems * w_i.astype(elems.dtype))
-            else:
-                # Custom loss without a per-example form: whole-chunk mean
-                # weighted by the chunk's valid count (exact when unpadded).
-                lsum = loss_fn(logits_i, y_i) * jnp.sum(w_i)
-            msums = []
-            for name, fn in metric_fns:
-                scores = metrics_lib.per_example(fn)
-                if scores is not None:
-                    s_elems = scores(logits_i, y_i)
-                    msums.append((jnp.sum(s_elems * w_i.astype(s_elems.dtype)),
-                                  jnp.sum(w_i)))
+            with _child_scope(head.name):
+                logits_i, _ = head.apply(head_params, {}, h_i, train=train)
+            with jax.named_scope("loss"):
+                if per_ex is not None:
+                    elems = per_ex(logits_i, y_i)
+                    lsum = jnp.sum(elems * w_i.astype(elems.dtype))
                 else:
-                    # No per-example form: rescale the chunk's (sum, count)
-                    # by its valid-token weight, mirroring the plain eval
-                    # step's mask treatment (exact when unpadded).
-                    s, c = fn(logits_i, y_i)
-                    w_sum = jnp.sum(w_i)
-                    msums.append((s * w_sum / jnp.maximum(c, 1.0), w_sum))
+                    # Custom loss without a per-example form: whole-chunk
+                    # mean weighted by the chunk's valid count (exact when
+                    # unpadded).
+                    lsum = loss_fn(logits_i, y_i) * jnp.sum(w_i)
+            msums = []
+            with jax.named_scope("metrics"):
+                for name, fn in metric_fns:
+                    scores = metrics_lib.per_example(fn)
+                    if scores is not None:
+                        s_elems = scores(logits_i, y_i)
+                        msums.append(
+                            (jnp.sum(s_elems * w_i.astype(s_elems.dtype)),
+                             jnp.sum(w_i)))
+                    else:
+                        # No per-example form: rescale the chunk's (sum,
+                        # count) by its valid-token weight, mirroring the
+                        # plain eval step's mask treatment (exact when
+                        # unpadded).
+                        s, c = fn(logits_i, y_i)
+                        w_sum = jnp.sum(w_i)
+                        msums.append(
+                            (s * w_sum / jnp.maximum(c, 1.0), w_sum))
             loss_c, m_c = carry
             m_new = tuple(
                 (a + jnp.float32(s), b + jnp.float32(c))
@@ -791,7 +811,8 @@ class Model:
                 loss_sum, n_tok, mvals = self._chunked_head_scan(
                     pc, state, h, y, None, train=True
                 )
-                loss = loss_sum / n_tok + _aux_loss_sum(new_state)
+                with jax.named_scope("loss"):
+                    loss = loss_sum / n_tok + _aux_loss_sum(new_state)
                 scaled = loss if scale is None else loss * scale
                 return scaled, (loss, new_state, mvals)
 
@@ -865,9 +886,12 @@ class Model:
             grads = precision_lib.cast_like(
                 jax.tree_util.tree_map(lambda a: a / m, gsum), params
             )
-            updates, new_opt = tx.update(grads, opt_state, params)
-            new_params = optax.apply_updates(params, updates)
-            new_params, new_opt = _constrain_step_outputs(new_params, new_opt)
+            with jax.named_scope("optimizer"):
+                updates, new_opt = tx.update(grads, opt_state, params)
+                new_params = optax.apply_updates(params, updates)
+                new_params, new_opt = _constrain_step_outputs(
+                    new_params, new_opt
+                )
             mvals = {n: p for n, p in zip(metric_names, msums)}
             return new_params, new_state, new_opt, loss_sum / m, mvals
 

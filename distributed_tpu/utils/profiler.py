@@ -35,11 +35,6 @@ def trace(logdir: str, *, chief_only: bool = True):
             jax.profiler.stop_trace()
 
 
-def annotate(name: str):
-    """Named region that shows up on the trace timeline (host + device)."""
-    return jax.profiler.TraceAnnotation(name)
-
-
 def device_memory_stats(device=None):
     """Allocator stats of one device as ``{bytes_in_use, peak_bytes_in_use,
     bytes_limit}`` — the numbers a ZeRO/FSDP run watches to know how close

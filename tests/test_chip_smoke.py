@@ -61,10 +61,14 @@ def restore_cache_config():
     runs without a persistent cache."""
     names = ("jax_compilation_cache_dir",
              "jax_traceback_in_locations_limit")
+    from jax._src import cache_key
+
     before = {n: getattr(jax.config, n) for n in names}
+    hook = cache_key.custom_hook
     yield before
     for n, v in before.items():
         jax.config.update(n, v)
+    cache_key.custom_hook = hook
 
 
 def test_cache_dir_is_env_or_one_fixed_in_checkout_path(
@@ -111,6 +115,35 @@ def test_cache_key_does_not_depend_on_the_call_site(
     compile_cache.enable()
     assert lower() == another_site()
     fa._packed_cached.cache_clear()
+
+
+def test_cache_key_carries_the_scope_scheme(monkeypatch,
+                                            restore_cache_config):
+    """The key is taken with metadata stripped, so the device scopes' names
+    do not move it: a cache warmed before the scopes would serve an
+    executable without them. enable() puts a constant into every key, where
+    the environment places the directory too."""
+    import jax.numpy as jnp
+    import numpy as np
+    from jax._src import cache_key, compiler
+
+    monkeypatch.setenv(compile_cache.ENV_VAR, "/somewhere/else")
+
+    def key():
+        lowered = jax.jit(lambda x: x + 1).lower(jnp.zeros(4))
+        return cache_key.get(
+            lowered.compiler_ir(), np.array(jax.devices()[:1]),
+            compiler.get_compile_options(num_replicas=1, num_partitions=1),
+            jax.devices()[0].client)
+
+    plain = key()
+    compile_cache.enable()
+    assert cache_key.custom_hook() == compile_cache.SCOPE_SCHEME
+    assert jax.config.jax_compilation_cache_dir is None
+    scoped = key()
+    assert scoped != plain
+    monkeypatch.setattr(compile_cache, "SCOPE_SCHEME", "another-scheme")
+    assert key() not in (plain, scoped)
 
 
 def test_imports_initialise_no_backend():

@@ -1,0 +1,122 @@
+"""Device scopes of the train step (docs/OBSERVABILITY.md, "Device scopes").
+
+Every operation of a compiled train step carries, in its ``op_name``
+metadata, the name of the layer and the phase it belongs to: a layer's scope
+path is its parameter path (``nn.core.child_scope``), the phases outside the
+layers are ``cast``, ``loss``, ``metrics`` and ``optimizer``
+(``training/model.py``), and forward and backward are JAX's own ``jvp(...)``
+and ``transpose(jvp(...))`` wrappers. The names are an interface (XProf, and
+``benchmarks/scopes.py`` reads them from a device trace), so they are pinned
+here as strings, from the tiny LM's step lowered and compiled on the CPU.
+"""
+
+import re
+
+import numpy as np
+import pytest
+
+import jax
+import distributed_tpu as dtpu
+
+CASES = {
+    "plain": ({}, {}),
+    "chunked_head": ({}, {"head_chunks": 2}),
+    "remat": ({"remat": True}, {}),
+    "scan": ({"scan": True}, {}),
+}
+# What JAX puts between a transform wrapper and the program's scopes: loop
+# and checkpoint bodies. Matched loosely: these are JAX's names, not ours.
+JAX = r"(?:[\w()]+/)*?"
+
+
+@pytest.fixture(scope="module")
+def compiled():
+    cache = {}
+
+    def get(case):
+        if case not in cache:
+            lm_kw, compile_kw = CASES[case]
+            m = dtpu.Model(dtpu.models.transformer_lm(
+                64, num_layers=2, d_model=16, num_heads=2, max_len=16,
+                **lm_kw))
+            m.compile(optimizer=dtpu.optim.Adam(1e-3),
+                      loss="sparse_categorical_crossentropy",
+                      metrics=["accuracy"], precision="mixed_bfloat16",
+                      **compile_kw)
+            m.build((16,), seed=0)
+            x = np.zeros((4, 16), np.int32)
+            text = m.lower_train_step(x, x).compile().as_text()
+            cache[case] = (m, sorted(set(
+                re.findall(r'op_name="(jit\(step\)[^"]*)"', text))))
+        return cache[case]
+
+    return get
+
+
+def has(names, pattern):
+    rx = re.compile(pattern)
+    return any(rx.match(n) for n in names)
+
+
+def layer_paths(params):
+    """The container path of every parameter leaf: ``residual_1/main/dense``."""
+    return sorted({
+        tuple(k.key for k in path[:-1])
+        for path, _ in jax.tree_util.tree_flatten_with_path(params)[0]})
+
+
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_phases_carry_their_scopes(compiled, case):
+    _, names = compiled(case)
+    assert has(names, r"jit\(step\)/jvp\(cast\)/convert_element_type$")
+    assert has(names, r"jit\(step\)/transpose\(jvp\(cast\)\)/")
+    assert has(names, r"jit\(step\)/optimizer/")
+    if case == "chunked_head":
+        # head, loss and metrics run per chunk, in a checkpointed scan body
+        assert has(names, rf"jit\(step\)/jvp\(\)/while/body/{JAX}loss/")
+        assert has(names, rf"jit\(step\)/transpose\(jvp\(\)\)/while/body/"
+                          rf"{JAX}loss/")
+        assert has(names, rf"jit\(step\)/jvp\(\)/while/body/{JAX}metrics/")
+    else:
+        assert has(names, r"jit\(step\)/jvp\(loss\)/")
+        assert has(names, r"jit\(step\)/transpose\(jvp\(loss\)\)/")
+        assert has(names, r"jit\(step\)/metrics/")
+    # nothing of the optimizer or the metrics is differentiated
+    assert not has(names, r"jit\(step\)/(transpose\()?jvp\((optimizer|"
+                          r"metrics)\)")
+
+
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_a_layers_scope_path_is_its_parameter_path(compiled, case):
+    model, names = compiled(case)
+    paths = layer_paths(model.params)
+    assert ("residual_1", "main", "dense_1") in paths or case == "scan"
+    for path in paths:
+        top, rest = path[0], "/".join(path[1:])
+        if case == "scan" and top == "scanned_blocks":
+            # .../jvp(scanned_blocks)/while/body/.../blocks/residual/main/..
+            fwd = (rf"jit\(step\)/jvp\({top}\)/while/body/{JAX}{rest}/")
+            bwd = (rf"jit\(step\)/transpose\(jvp\({top}\)\)/while/body/"
+                   rf"{JAX}{rest}/")
+        elif case == "chunked_head" and path == ("dense",):
+            fwd = rf"jit\(step\)/jvp\(\)/while/body/{JAX}dense/dot_general$"
+            bwd = rf"jit\(step\)/transpose\(jvp\(\)\)/while/body/{JAX}dense/"
+        else:
+            tail = f"/{rest}/" if rest else "/"
+            fwd = rf"jit\(step\)/jvp\({top}\){tail}"
+            # under Remat the backward pass re-enters the name stack:
+            # transpose(jvp(a))/jvp(a)/checkpoint/main/dense_1/...
+            bwd = rf"jit\(step\)/transpose\(jvp\({top}\)\)/{JAX}{tail[1:]}"
+        assert has(names, fwd), (path, fwd)
+        assert has(names, bwd), (path, bwd)
+
+
+def test_every_operation_of_the_plain_step_is_under_a_scope(compiled):
+    model, names = compiled("plain")
+    tops = {p[0] for p in layer_paths(model.params)} | {
+        "cast", "loss", "metrics", "optimizer"}
+    for name in names:
+        if name == "jit(step)":
+            continue
+        m = re.match(r"jit\(step\)/(?:transpose\()?(?:jvp\()?(\w*)", name)
+        assert m and m.group(1) in tops, name
