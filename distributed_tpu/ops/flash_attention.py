@@ -9,11 +9,50 @@ scratch, so HBM traffic is O(T*D) instead of O(T^2).
 
 The backward pass is a pair of Pallas kernels (dq with the kv dimension
 innermost; dk/dv with the q dimension innermost) that recompute the
-probabilities in VMEM from the saved per-row statistics (m, l) —
-flash-style rematerialization; HBM traffic stays O(T*D) and no (T, T)
-matrix ever exists. (The first implementation was a plain-JAX blockwise
-scan; on the TPU it ran at ~12% MFU per layer because XLA serialized the
-kv-block loop as a while op — the kernels keep the MXU busy instead.)
+probabilities in VMEM from the saved per-row statistics — flash-style
+rematerialization; HBM traffic stays O(T*D) and no (T, T) matrix ever
+exists. (The first implementation was a plain-JAX blockwise scan; on the
+TPU it ran at ~12% MFU per layer because XLA serialized the kv-block loop
+as a while op — the kernels keep the MXU busy instead.)
+
+Two families. The *folded* kernels take (B*H, T, D) and serve any head
+size; they skip causal work at the grain of a grid block and mask every
+block they compute. The *lane-packed* kernels (64- and 128-wide heads:
+every benchmark cell) read (B, T, H*D) as projected and hold a
+(block_q, block_k) tile of 128//D heads in VMEM a grid step; at T <= 1024
+that is the whole sequence in one step, so the grid has nothing to skip.
+They walk the tile in square sub-tiles of side ``_SUBTILE`` and treat each
+by where it lies (``_tile_class``), statically for a shape:
+
+- above the diagonal (or wholly in the padding, when not causal): not
+  computed, no product, no exp, no mask;
+- wholly at or below the diagonal and real: computed with no mask, and
+  merged with its neighbours of the same kind into one product a head;
+- crossed by the diagonal (or the padding edge): the only ones that build
+  ``_valid_mask``.
+
+At T = 1024 and side 128 that is 36 of 64 sub-tiles computed, 8 of them
+masked (``subtile_counts``; published as the gauges
+``flash.subtiles_square`` / ``_computed`` / ``_masked``). ``causal=False``
+runs the same walk with every sub-tile of the second kind. What else the
+walk changed, same mathematics in the same precision (f32 scores,
+statistics and accumulators, bf16 MXU operands, exact divide):
+
+- one row statistic, lse = m + log l, is saved for the backward, whose
+  kernels compute p = exp(s - lse) with no per-score divide;
+- 1/sqrt(D) is folded into q where that is exact in q's dtype (a power of
+  two: D = 64), and applied to every f32 score where not (D = 128);
+- a head's operand is not sliced out of the 128 lanes (a rotate per use)
+  but has the other heads' lanes zeroed, contracts over all 128 and
+  produces all 128, of which the head's own span is kept: the same MXU
+  passes, no XLU work, dense stores;
+- dk/dv compute the score tile column-major (k q^T), so p^T and ds^T are
+  left operands as they stand, and read their row statistics lane-major.
+
+The walk is unrolled at trace time (the LLO scheduler packs straight-line
+code best; an in-kernel ``fori_loop`` over sub-tiles cannot merge runs),
+so the custom_vjp's two halves are jitted: a model's layers share one
+trace and one lowering of each kernel. Chip numbers: root PERF.md.
 
 The reference has no attention anywhere (SURVEY.md §2c); this is part of the
 long-context tier the framework adds (with ops.ring_attention for the
@@ -27,6 +66,7 @@ to Mosaic.
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional
 
 import jax
@@ -36,6 +76,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
+from ..obs.registry import default_registry
 from ._pallas_common import (
     LANES as _LANES,
     NEG as _NEG,
@@ -46,18 +87,18 @@ from ._pallas_common import (
 
 
 # -------------------------------------------------- shared kernel helpers --
-def _valid_mask(qi, ki, block_q, block_k, t_actual, causal):
-    """(block_q, block_k) mask: real columns, and under causality the
-    lower-triangular band for this (qi, ki) block pair. The single source
-    of truth for masking across all six kernels (folded + packed)."""
-    col = ki * block_k + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 1
-    )
+def _valid_mask(row0, col0, rows, cols, t_actual, causal, kv_major=False):
+    """(rows, cols) mask of the score tile whose first row and column are
+    ``row0`` and ``col0``: real columns, and under causality the lower-
+    triangular band. ``kv_major``: of the transposed tile, (cols, rows). The
+    single source of truth for masking across all six kernels (folded: a
+    grid block; packed: a run of sub-tiles of one)."""
+    shape, r_ax, c_ax = ((cols, rows), 1, 0) if kv_major else (
+        (rows, cols), 0, 1)
+    col = col0 + jax.lax.broadcasted_iota(jnp.int32, shape, c_ax)
     valid = col < t_actual
     if causal:
-        row = qi * block_q + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 0
-        )
+        row = row0 + jax.lax.broadcasted_iota(jnp.int32, shape, r_ax)
         valid = jnp.logical_and(valid, col <= row)
     return valid
 
@@ -107,7 +148,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, m_out_ref, l_out_ref,
             preferred_element_type=jnp.float32,
         ) * scale  # (block_q, block_k) f32
 
-        valid = _valid_mask(qi, ki, block_q, block_k, t_actual, causal)
+        valid = _valid_mask(qi * block_q, ki * block_k, block_q, block_k,
+                            t_actual, causal)
         s = jnp.where(valid, s, _NEG)
 
         m_prev = m_ref[...]  # (block_q, 128), all lanes equal
@@ -213,7 +255,8 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, m_ref, l_ref, dl_ref, dq_ref,
 
     def compute():
         k = k_ref[0]
-        valid = _valid_mask(qi, ki, block_q, block_k, t_actual, causal)
+        valid = _valid_mask(qi * block_q, ki * block_k, block_q, block_k,
+                            t_actual, causal)
         _, ds = _p_ds(
             q_ref[0], k, v_ref[0], do_ref[0],
             m_ref[0][:, :1], l_ref[0][:, :1], dl_ref[0][:, :1],
@@ -253,7 +296,8 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, m_ref, l_ref, dl_ref,
     def compute():
         q = q_ref[0]
         do = do_ref[0]
-        valid = _valid_mask(qi, ki, block_q, block_k, t_actual, causal)
+        valid = _valid_mask(qi * block_q, ki * block_k, block_q, block_k,
+                            t_actual, causal)
         p, ds = _p_ds(
             q, k_ref[0], v_ref[0], do,
             m_ref[0][:, :1], l_ref[0][:, :1], dl_ref[0][:, :1],
@@ -375,280 +419,545 @@ def _bwd_pallas(res, g, *, scale, causal, block_q, block_k):
 # slices lanes in VMEM. No HBM transpose exists in either direction.
 # Requires 128 % D == 0 and H % (128//D) == 0 (covers head_dim 64/128);
 # other shapes fall back to the folded path.
+#
+# Inside a grid step the (block_q, block_k) tile is walked in sub-tiles
+# (module docstring): block_q/block_k size the DMA, _SUBTILE the compute.
 
-# Sequence length (padded) above which the packed kernels save their row
-# stats compactly ((b, nh, t_pad, heads_per_block)) and re-expand in the
-# backward: the lane-replicated form reads fastest under Mosaic but costs
-# 128/heads_per_block x the residual memory, which only matters once T is
-# long enough for stats to rival the activations themselves.
-_COMPACT_STATS_MIN_T = 2048
+# Side of the square sub-tile the packed kernels compute at a time; 128, a
+# lane tile, is the floor. Chosen on the v5e from the three kernels' device
+# time a call at the cells' shapes, bf16 causal (scripts/flash_kernel_times.py;
+# PR 26), fwd + dq + dkv in ms:
+#   (8, 1024, 16, 64): whole tile 1.994, 512: 1.302, 256: 1.094, 128: 1.028
+#   (4, 1024, 20, 64):                             256: 0.697, 128: 0.655
+# A smaller side skips more of the triangle (3/4, 10/16, 36/64 computed)
+# and pays more, smaller products; dq and dkv run at 90% MXU occupancy at
+# 128 and 256 alike (LLO bundle count), so there the smaller area wins.
+# At (1, 4096, 16, 64), block_q 512 (no cell), 256 is 3% ahead: 2.041, 2.114.
+_SUBTILE = 128
 
 
-def _fwd_kernel_packed(q_ref, k_ref, v_ref, o_ref, m_out_ref, l_out_ref,
-                       m_ref, l_ref, acc_ref,
-                       *, scale, hd, block_q, block_k, t_actual, causal, nk):
+def _subtile(block: int) -> int:
+    """Sub-tile side along a block of ``block`` rows or columns:
+    ``_SUBTILE`` where it divides the block, else the whole block (a block
+    that is smaller, or ragged against it, is one sub-tile)."""
+    return _SUBTILE if block % _SUBTILE == 0 else block
+
+
+# How a sub-tile lies against what its rows may see.
+_SKIPPED, _MASKED, _UNMASKED = range(3)
+
+
+def _tile_class(r0, rows, c0, cols, diag, edge):
+    """Class of the score sub-tile of ``rows`` x ``cols`` at (r0, c0). Row r
+    sees the columns below lim(r): r + diag + 1 under causality (``diag``
+    is the first row's distance below the first column's diagonal), else
+    ``edge``, the number of real columns (None: all). A sub-tile no row
+    sees any of is skipped; one every row sees all of needs no mask."""
+    if diag is not None:
+        lo, hi = r0 + diag + 1, r0 + rows + diag
+    elif edge is not None:
+        lo = hi = edge
+    else:
+        return _UNMASKED
+    if c0 >= hi:
+        return _SKIPPED
+    return _UNMASKED if c0 + cols <= lo else _MASKED
+
+
+def _segments(classes, size):
+    """[(start, stop, masked)] over a row of sub-tile classes: neighbours of
+    one class are merged, so that each run is one matrix product."""
+    segs = []
+    for i, c in enumerate(classes):
+        if c == _SKIPPED:
+            continue
+        masked = c == _MASKED
+        if segs and segs[-1][1] == i * size and segs[-1][2] == masked:
+            segs[-1] = (segs[-1][0], (i + 1) * size, masked)
+        else:
+            segs.append((i * size, (i + 1) * size, masked))
+    return segs
+
+
+@functools.lru_cache(maxsize=64)
+def subtile_counts(t: int, block_q: int, block_k: int, causal: bool):
+    """(square, computed, masked): the sub-tiles of the padded T x T score
+    square for these blocks, those the packed kernels compute (the rest lie
+    wholly above the diagonal, or wholly in the padding when not causal)
+    and, of the computed, those that build a mask (the diagonal crosses
+    them, or the padding edge when not causal). Static for a shape."""
+    sub_q, sub_k = _subtile(block_q), _subtile(block_k)
+    t_pad = _round_up(t, max(block_q, block_k))
+    diag, edge = (0, None) if causal else (None, t)
+    classes = [
+        _tile_class(r0, sub_q, c0, sub_k, diag, edge)
+        for r0 in range(0, t_pad, sub_q) for c0 in range(0, t_pad, sub_k)
+    ]
+    return (len(classes), len(classes) - classes.count(_SKIPPED),
+            classes.count(_MASKED))
+
+
+def _block_views(nq, nk, block_q, block_k, t_actual, causal):
+    """The distinct ways a grid block (qi, ki) lies against the diagonal
+    (causal) or the padding edge (not causal), static for a shape:
+    [((diag, edge), in_view)] with ``_tile_class``'s ``diag`` / ``edge`` in
+    the block's own coordinates and ``in_view(qi, ki)`` true in exactly
+    those blocks. Blocks nothing is seen of appear in no view. A block
+    wholly below the diagonal, or wholly real, is (None, None)."""
+    views = {}
+    for qi in range(nq):
+        for ki in range(nk):
+            if causal:
+                d = qi * block_q - ki * block_k
+                if d <= -block_q:
+                    continue
+                if d >= block_k - 1:
+                    views[None, None] = (
+                        lambda qi, ki:
+                        qi * block_q - ki * block_k >= block_k - 1)
+                else:
+                    views[d, None] = functools.partial(
+                        lambda d, qi, ki:
+                        qi * block_q - ki * block_k == d, d)
+            else:
+                e = min(t_actual - ki * block_k, block_k)
+                if e <= 0:
+                    continue
+                if e == block_k:
+                    views[None, None] = (
+                        lambda qi, ki: (ki + 1) * block_k <= t_actual)
+                else:
+                    views[None, e] = (
+                        lambda qi, ki: ki == t_actual // block_k)
+    return list(views.items())
+
+
+def _scale_folds(scale: float) -> bool:
+    """True when multiplying by ``scale`` is exact in every float dtype (a
+    power of two: 1/sqrt(64)), so it can be applied once to a (rows, hd)
+    operand or result instead of to every score."""
+    return math.frexp(scale)[0] == 0.5
+
+
+def _head_lanes(hd):
+    return [slice(hx * hd, (hx + 1) * hd) for hx in range(_LANES // hd)]
+
+
+def _head_groups(lanes):
+    """Heads whose score tiles are alive together: two (every head of a
+    64- or 128-wide block), so that narrower heads do not multiply the VMEM
+    the walk needs."""
+    return [lanes[i:i + 2] for i in range(0, len(lanes), 2)]
+
+
+def _own_lanes(x, sl):
+    """``x`` (rows, 128) with the lanes of every head but ``sl`` zeroed: a
+    contraction over all 128 lanes is then that head's alone, at the MXU
+    cost of the 64-wide one and without the lane slice's rotate to lane 0."""
+    if sl.stop - sl.start == _LANES:
+        return x
+    lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    own = jnp.logical_and(lane >= sl.start, lane < sl.stop)
+    return jnp.where(own, x, jnp.zeros_like(x))
+
+
+def _by_head(lanes, per_head):
+    """One (rows, 128) array that holds, in each head's lane span, that
+    head's entry of ``per_head`` ((rows, 128) or (rows, 1) each). A product
+    with a whole (.., 128) lane block on its right is right in the lanes of
+    the head whose probabilities were on its left, and only there."""
+    out = per_head[0]
+    for sl, x in zip(lanes[1:], per_head[1:]):
+        shape = (x.shape[0], _LANES)
+        lane = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+        out = jnp.where(lane >= sl.start, jnp.broadcast_to(x, shape),
+                        jnp.broadcast_to(out, shape))
+    return jnp.broadcast_to(out, (out.shape[0], _LANES))
+
+
+def _stat_columns(stat, lanes):
+    """A row statistic as the q-major kernels use it, (rows, 128) with each
+    head's value across its lane span, from how it is stored, lane-major
+    (heads, rows): one XLU transpose, no HBM traffic."""
+    rows = stat.shape[-1]
+    return jnp.concatenate([
+        jnp.broadcast_to(stat[hx:hx + 1, :], (sl.stop - sl.start, rows))
+        for hx, sl in enumerate(lanes)], axis=0).T
+
+
+def _nt(a, b):
+    """a @ b.T on the MXU, f32 out."""
+    return jax.lax.dot_general(
+        a, b, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+
+
+def _dot(a, b):
+    return jnp.dot(a.astype(b.dtype), b, preferred_element_type=jnp.float32)
+
+
+def _p_ds_lse(a, b, c, d, lse, delta, valid, scale):
+    """The packed backward kernels' segment math: p = exp(a b^T - lse) from
+    the one saved row statistic, ds = p * (c d^T - delta). Row-major (dq):
+    a, b, c, d = q, k, dO, v and lse, delta are columns; column-major (dkv):
+    k, q, v, dO and rows. ``valid`` is None on a segment that needs no
+    mask; ``scale`` is None when it is already folded into q."""
+    s = _nt(a, b)
+    if scale is not None:
+        s = s * scale
+    if valid is not None:
+        s = jnp.where(valid, s, _NEG)
+    p = jnp.exp(s - lse)
+    ds = p * (_nt(c, d) - delta)
+    if scale is not None:
+        ds = ds * scale
+    return p, ds
+
+
+def _walk_blocks(kernel_walk, one_block, views, qi, ki):
+    """Run ``kernel_walk(diag, edge)`` for the view this grid block lies in:
+    statically in a grid of one block, else under one ``pl.when`` a view."""
+    if one_block:
+        return kernel_walk(*views[0][0])
+    for view, in_view in views:
+        pl.when(in_view(qi, ki))(functools.partial(kernel_walk, *view))
+
+
+def _fwd_kernel_packed(q_ref, k_ref, v_ref, o_ref, lse_out_ref,
+                       m_ref, l_ref, acc_ref, *, scale, hd, block_q,
+                       block_k, t_actual, causal, nq, nk):
     """One (b, hblk, qi, ki) grid step on (1, block, 128) lane-packed tiles;
-    the 128 lanes hold 128//hd heads. Scratch m/l keep each head's running
-    stat replicated across that head's lane span."""
+    the 128 lanes hold 128//hd heads. Each q sub-tile takes the kv columns
+    its rows see in at most two matrix products a head, an unmasked run
+    and a masked one, under one row maximum. Scratch carries m, l and acc,
+    each head's replicated across its lane span, between the sequential ki
+    steps; a grid of one block needs neither scratch nor ``pl.when``."""
     qi = pl.program_id(2)
     ki = pl.program_id(3)
+    sub_q, sub_k = _subtile(block_q), _subtile(block_k)
+    lanes = _head_lanes(hd)
+    fold = _scale_folds(scale)
+    one_block = nq == 1 and nk == 1
 
-    @pl.when(ki == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, _NEG)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+    def finish(rows, m, l, acc):
+        l = jnp.maximum(l, 1e-30)
+        o_ref[0, rows, :] = (acc / l).astype(o_ref.dtype)
+        lse = (m + jnp.log(l)).T  # (128, rows): a head's value, lane-major
+        for hx, sl in enumerate(lanes):
+            lse_out_ref[0, 0, hx:hx + 1, rows] = lse[sl.start:sl.start + 1]
 
-    def compute():
-        q = q_ref[0]  # (block_q, 128)
-        k = k_ref[0]  # (block_k, 128)
-        v = v_ref[0]
-        valid = _valid_mask(qi, ki, block_q, block_k, t_actual, causal)
-        for hx in range(_LANES // hd):
-            sl = slice(hx * hd, (hx + 1) * hd)
-            s = jax.lax.dot_general(
-                q[:, sl], k[:, sl], (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            ) * scale  # (block_q, block_k)
-            s = jnp.where(valid, s, _NEG)
-            m_prev = m_ref[:, sl]  # (block_q, hd), lanes equal
-            l_prev = l_ref[:, sl]
-            m_cur = jnp.max(s, axis=-1, keepdims=True)
-            m_new = jnp.maximum(
-                m_prev, jnp.broadcast_to(m_cur, m_prev.shape)
-            )
-            alpha = jnp.exp(m_prev[:, :1] - m_new[:, :1])
-            p = jnp.exp(s - m_new[:, :1])
-            l_new = l_prev * alpha + jnp.broadcast_to(
-                jnp.sum(p, axis=-1, keepdims=True), l_prev.shape
-            )
-            acc_ref[:, sl] = acc_ref[:, sl] * alpha + jnp.dot(
-                p.astype(v.dtype), v[:, sl],
-                preferred_element_type=jnp.float32,
-            )
-            m_ref[:, sl] = m_new
-            l_ref[:, sl] = l_new
+    def walk(diag, edge):
+        for r0 in reversed(range(0, block_q, sub_q)):
+            segs = _segments([
+                _tile_class(r0, sub_q, c0, sub_k, diag, edge)
+                for c0 in range(0, block_k, sub_k)], sub_k)
+            if not segs:
+                continue
+            rows = slice(r0, r0 + sub_q)
+            q = q_ref[0, rows, :]
+            if fold:
+                q = q * scale
+            ks = [k_ref[0, c0:c1, :] for c0, c1, _ in segs]
+            vs = [v_ref[0, c0:c1, :] for c0, c1, _ in segs]
+            valid = [
+                _valid_mask(qi * block_q + r0, ki * block_k + c0, sub_q,
+                            c1 - c0, t_actual, causal) if masked else None
+                for c0, c1, masked in segs]
+            # Both heads' scores, then both softmaxes, then both P V: the
+            # order the bundle scheduler packs best.
+            done = []
+            for group in _head_groups(lanes):
+                scores = []
+                for sl in group:
+                    qh = _own_lanes(q, sl)
+                    ss = [_nt(qh, k) for k in ks]  # (sub_q, c1 - c0) f32
+                    if not fold:
+                        ss = [s * scale for s in ss]
+                    scores.append([
+                        s if ok is None else jnp.where(ok, s, _NEG)
+                        for s, ok in zip(ss, valid)])
+                stats = []
+                for sl, ss in zip(group, scores):
+                    m = functools.reduce(jnp.maximum, [
+                        jnp.max(s, axis=-1, keepdims=True) for s in ss])
+                    if not one_block:
+                        m = jnp.maximum(
+                            m_ref[rows, sl.start:sl.start + 1], m)
+                    ps = [jnp.exp(s - m) for s in ss]
+                    l = sum(jnp.sum(p, axis=-1, keepdims=True) for p in ps)
+                    stats.append((m, l, ps))
+                done += [(m, l, sum(_dot(p, v) for p, v in zip(ps, vs)))
+                         for m, l, ps in stats]
+            m, l, acc = (_by_head(lanes, x) for x in zip(*done))
+            if one_block:
+                finish(rows, m, l, acc)
+                continue
+            alpha = jnp.exp(m_ref[rows, :] - m)
+            m_ref[rows, :] = m
+            l_ref[rows, :] = l + alpha * l_ref[rows, :]
+            acc_ref[rows, :] = acc + alpha * acc_ref[rows, :]
 
-    if causal:
-        @pl.when(ki * block_k <= qi * block_q + block_q - 1)
-        def _():
-            compute()
-    else:
-        compute()
+    if not one_block:
+        @pl.when(ki == 0)
+        def _init():
+            m_ref[...] = jnp.full_like(m_ref, _NEG)
+            l_ref[...] = jnp.zeros_like(l_ref)
+            acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    @pl.when(ki == nk - 1)
-    def _finish():
-        for hx in range(_LANES // hd):
-            sl = slice(hx * hd, (hx + 1) * hd)
-            o_ref[0, :, sl] = (
-                acc_ref[:, sl]
-                / jnp.maximum(l_ref[:, hx * hd : hx * hd + 1], 1e-30)
-            ).astype(o_ref.dtype)
-        m_out_ref[0, 0] = m_ref[...]
-        l_out_ref[0, 0] = l_ref[...]
+    _walk_blocks(
+        walk, one_block,
+        _block_views(nq, nk, block_q, block_k, t_actual, causal), qi, ki)
+
+    if not one_block:
+        @pl.when(ki == nk - 1)
+        def _finish():
+            finish(slice(None), m_ref[...], l_ref[...], acc_ref[...])
 
 
-def _dq_kernel_packed(q_ref, k_ref, v_ref, do_ref, m_ref, l_ref, dl_ref,
-                      dq_ref, acc_ref,
-                      *, scale, hd, block_q, block_k, t_actual, causal, nk):
+def _dq_kernel_packed(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref,
+                      dq_ref, acc_ref, *, scale, hd, block_q, block_k,
+                      t_actual, causal, nq, nk):
+    """dq for one (b, hblk, qi, ki) grid step, walked like the forward: per
+    q sub-tile, dq += ds K over the kv columns its rows see."""
     qi = pl.program_id(2)
     ki = pl.program_id(3)
+    sub_q, sub_k = _subtile(block_q), _subtile(block_k)
+    lanes = _head_lanes(hd)
+    fold = _scale_folds(scale)
+    one_block = nq == 1 and nk == 1
 
-    @pl.when(ki == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+    def finish(rows, acc):
+        if fold:
+            acc = acc * scale
+        dq_ref[0, rows, :] = acc.astype(dq_ref.dtype)
 
-    def compute():
-        q = q_ref[0]
-        k = k_ref[0]
-        v = v_ref[0]
-        do = do_ref[0]
-        valid = _valid_mask(qi, ki, block_q, block_k, t_actual, causal)
-        for hx in range(_LANES // hd):
-            sl = slice(hx * hd, (hx + 1) * hd)
-            _, ds = _p_ds(
-                q[:, sl], k[:, sl], v[:, sl], do[:, sl],
-                m_ref[0, 0, :, hx * hd : hx * hd + 1],
-                l_ref[0, 0, :, hx * hd : hx * hd + 1],
-                dl_ref[0, 0, :, hx * hd : hx * hd + 1],
-                valid, scale,
-            )
-            acc_ref[:, sl] += jax.lax.dot_general(
-                ds.astype(k.dtype), k[:, sl], (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
+    def walk(diag, edge):
+        lses = _stat_columns(lse_ref[0, 0], lanes)
+        deltas = _stat_columns(dl_ref[0, 0], lanes)
+        for r0 in range(0, block_q, sub_q):
+            segs = _segments([
+                _tile_class(r0, sub_q, c0, sub_k, diag, edge)
+                for c0 in range(0, block_k, sub_k)], sub_k)
+            if not segs:
+                continue
+            rows = slice(r0, r0 + sub_q)
+            q = q_ref[0, rows, :]
+            if fold:
+                q = q * scale
+            do = do_ref[0, rows, :]
+            ks = [k_ref[0, c0:c1, :] for c0, c1, _ in segs]
+            vs = [v_ref[0, c0:c1, :] for c0, c1, _ in segs]
+            valid = [
+                _valid_mask(qi * block_q + r0, ki * block_k + c0, sub_q,
+                            c1 - c0, t_actual, causal) if masked else None
+                for c0, c1, masked in segs]
+            accs = []
+            for group in _head_groups(lanes):
+                dss = []
+                for sl in group:
+                    qh, doh = _own_lanes(q, sl), _own_lanes(do, sl)
+                    lse = lses[rows, sl.start:sl.start + 1]
+                    delta = deltas[rows, sl.start:sl.start + 1]
+                    dss.append([
+                        _p_ds_lse(qh, k, doh, v, lse, delta, ok,
+                                  None if fold else scale)[1]
+                        for k, v, ok in zip(ks, vs, valid)])
+                accs += [sum(_dot(ds, k) for ds, k in zip(ds_h, ks))
+                         for ds_h in dss]
+            acc = _by_head(lanes, accs)
+            if one_block:
+                finish(rows, acc)
+            else:
+                acc_ref[rows, :] += acc
 
-    if causal:
-        @pl.when(ki * block_k <= qi * block_q + block_q - 1)
-        def _():
-            compute()
-    else:
-        compute()
+    if not one_block:
+        @pl.when(ki == 0)
+        def _init():
+            acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    @pl.when(ki == nk - 1)
-    def _finish():
-        dq_ref[0] = acc_ref[...].astype(dq_ref.dtype)
+    _walk_blocks(
+        walk, one_block,
+        _block_views(nq, nk, block_q, block_k, t_actual, causal), qi, ki)
+
+    if not one_block:
+        @pl.when(ki == nk - 1)
+        def _finish():
+            finish(slice(None), acc_ref[...])
 
 
-def _dkv_kernel_packed(q_ref, k_ref, v_ref, do_ref, m_ref, l_ref, dl_ref,
-                       dk_ref, dv_ref, acc_dk, acc_dv,
-                       *, scale, hd, block_q, block_k, t_actual, causal, nq):
+def _dkv_kernel_packed(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref,
+                       dk_ref, dv_ref, acc_dk, acc_dv, *, scale, hd,
+                       block_q, block_k, t_actual, causal, nq, nk):
+    """dk/dv for one (b, hblk, ki, qi) grid step, the transpose of the dq
+    walk: per kv sub-tile, dv += p^T dO and dk += ds^T q over the q rows
+    that see its columns. The score tile is computed column-major (k q^T),
+    so that p^T and ds^T are the products' left operands as they stand;
+    the row statistics come lane-major, (heads, rows)."""
     ki = pl.program_id(2)
     qi = pl.program_id(3)
+    sub_q, sub_k = _subtile(block_q), _subtile(block_k)
+    lanes = _head_lanes(hd)
+    fold = _scale_folds(scale)
+    one_block = nq == 1 and nk == 1
 
-    @pl.when(qi == 0)
-    def _init():
-        acc_dk[...] = jnp.zeros_like(acc_dk)
-        acc_dv[...] = jnp.zeros_like(acc_dv)
+    def walk(diag, edge):
+        for c0 in range(0, block_k, sub_k):
+            cols = slice(c0, c0 + sub_k)
+            segs = _segments([
+                _tile_class(r0, sub_q, c0, sub_k, diag, edge)
+                for r0 in range(0, block_q, sub_q)], sub_q)
+            if not segs:
+                if one_block:  # padding columns: nothing sees them
+                    dk_ref[0, cols, :] = jnp.zeros_like(dk_ref[0, cols, :])
+                    dv_ref[0, cols, :] = jnp.zeros_like(dv_ref[0, cols, :])
+                continue
+            k = k_ref[0, cols, :]
+            v = v_ref[0, cols, :]
+            qs = [q_ref[0, r0:r1, :] for r0, r1, _ in segs]
+            if fold:
+                qs = [q * scale for q in qs]
+            dos = [do_ref[0, r0:r1, :] for r0, r1, _ in segs]
+            valid = [
+                _valid_mask(qi * block_q + r0, ki * block_k + c0, r1 - r0,
+                            sub_k, t_actual, causal, kv_major=True)
+                if masked else None
+                for r0, r1, masked in segs]
+            dvs, dks = [], []
+            for group in _head_groups(lanes):
+                p_ds = []
+                for sl in group:
+                    hx = sl.start // hd
+                    kh, vh = _own_lanes(k, sl), _own_lanes(v, sl)
+                    p_ds.append([
+                        _p_ds_lse(
+                            kh, q, vh, do, lse_ref[0, 0, hx:hx + 1, r0:r1],
+                            dl_ref[0, 0, hx:hx + 1, r0:r1], ok,
+                            None if fold else scale)
+                        for (r0, r1, _), q, do, ok
+                        in zip(segs, qs, dos, valid)])
+                dvs += [sum(_dot(p, do) for (p, _), do in zip(h, dos))
+                        for h in p_ds]
+                dks += [sum(_dot(ds, q) for (_, ds), q in zip(h, qs))
+                        for h in p_ds]
+            dv, dk = _by_head(lanes, dvs), _by_head(lanes, dks)
+            if one_block:
+                dk_ref[0, cols, :] = dk.astype(dk_ref.dtype)
+                dv_ref[0, cols, :] = dv.astype(dv_ref.dtype)
+            else:
+                acc_dk[cols, :] += dk
+                acc_dv[cols, :] += dv
 
-    def compute():
-        q = q_ref[0]
-        k = k_ref[0]
-        v = v_ref[0]
-        do = do_ref[0]
-        valid = _valid_mask(qi, ki, block_q, block_k, t_actual, causal)
-        for hx in range(_LANES // hd):
-            sl = slice(hx * hd, (hx + 1) * hd)
-            p, ds = _p_ds(
-                q[:, sl], k[:, sl], v[:, sl], do[:, sl],
-                m_ref[0, 0, :, hx * hd : hx * hd + 1],
-                l_ref[0, 0, :, hx * hd : hx * hd + 1],
-                dl_ref[0, 0, :, hx * hd : hx * hd + 1],
-                valid, scale,
-            )
-            acc_dv[:, sl] += jax.lax.dot_general(
-                p.astype(do.dtype), do[:, sl], (((0,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
-            acc_dk[:, sl] += jax.lax.dot_general(
-                ds.astype(q.dtype), q[:, sl], (((0,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
+    if not one_block:
+        @pl.when(qi == 0)
+        def _init():
+            acc_dk[...] = jnp.zeros_like(acc_dk)
+            acc_dv[...] = jnp.zeros_like(acc_dv)
 
-    if causal:
-        @pl.when(qi * block_q + block_q - 1 >= ki * block_k)
-        def _():
-            compute()
-    else:
-        compute()
+    _walk_blocks(
+        walk, one_block,
+        _block_views(nq, nk, block_q, block_k, t_actual, causal), qi, ki)
 
-    @pl.when(qi == nq - 1)
-    def _finish():
-        dk_ref[0] = acc_dk[...].astype(dk_ref.dtype)
-        dv_ref[0] = acc_dv[...].astype(dv_ref.dtype)
+    if not one_block:
+        @pl.when(qi == nq - 1)
+        def _finish():
+            dk_ref[0] = acc_dk[...].astype(dk_ref.dtype)
+            dv_ref[0] = acc_dv[...].astype(dv_ref.dtype)
+
+
+def _packed_specs(t, h, d, block_q, block_k):
+    """What the three packed pallas_calls share: (t_pad, nh, nq, nk) and
+    the block specs for the grid (b, head block, i, j), by which of i / j
+    walks the q blocks. Tensors are (B, T, H*D) blocks of 128 lanes; a row
+    statistic is (B, nh, heads a block, t_pad), lane-major."""
+    t_pad = _round_up(t, max(block_q, block_k))
+    hpb = _LANES // d
+    nq, nk = t_pad // block_q, t_pad // block_k
+    if not _interpret() and nq > 1 and block_q % _LANES:
+        raise ValueError(
+            f"block_q={block_q}: the packed kernels keep their row "
+            "statistics lane-major, so a q block that is not the whole "
+            f"sequence has to be a multiple of {_LANES} rows")
+
+    def specs(q_axis):
+        kv_axis = 5 - q_axis  # grid axes 2 and 3
+        at = lambda axis: lambda *g: (g[0], g[axis], g[1])
+        return (
+            pl.BlockSpec((1, block_q, _LANES), at(q_axis)),
+            pl.BlockSpec((1, block_k, _LANES), at(kv_axis)),
+            pl.BlockSpec((1, 1, hpb, block_q),
+                         lambda *g: (g[0], g[1], 0, g[q_axis])))
+
+    return t_pad, h // hpb, nq, nk, specs
 
 
 def _fwd_pallas_packed(qf, kf, vf, h, d, scale, causal, block_q, block_k):
-    """qf,kf,vf: (B, T, H*D) lane-packed. Returns (out, m, l) with out in
-    the same layout and m/l: (B, H//hpb, t_pad, 128)."""
+    """qf,kf,vf: (B, T, H*D) lane-packed. Returns (out, lse) with out in
+    the same layout and the one row statistic the backward needs, lse = m +
+    log l, as (B, H//hpb, hpb, t_pad): 4 bytes a row and head."""
     b, t, _ = qf.shape
-    t_pad = _round_up(t, max(block_q, block_k))
+    t_pad, nh, nq, nk, specs = _packed_specs(t, h, d, block_q, block_k)
     pad = lambda x: jnp.pad(x, ((0, 0), (0, t_pad - t), (0, 0)))
-    qp, kp, vp = pad(qf), pad(kf), pad(vf)
-    hpb = _LANES // d
-    nh = h // hpb
-    nq = t_pad // block_q
-    nk = t_pad // block_k
-
-    kernel = functools.partial(
-        _fwd_kernel_packed, scale=scale, hd=d, block_q=block_q,
-        block_k=block_k, t_actual=t, causal=causal, nk=nk,
-    )
-    lane_q = pl.BlockSpec((1, block_q, _LANES), lambda b, h, i, j: (b, i, h))
-    lane_k = pl.BlockSpec((1, block_k, _LANES), lambda b, h, i, j: (b, j, h))
-    stat = pl.BlockSpec((1, 1, block_q, _LANES),
-                        lambda b, h, i, j: (b, h, i, 0))
-    out, m_out, l_out = pl.pallas_call(
-        kernel,
+    lane_q, lane_k, stat = specs(q_axis=2)
+    out, lse = pl.pallas_call(
+        functools.partial(
+            _fwd_kernel_packed, scale=scale, hd=d, block_q=block_q,
+            block_k=block_k, t_actual=t, causal=causal, nq=nq, nk=nk,
+        ),
         grid=(b, nh, nq, nk),
         in_specs=[lane_q, lane_k, lane_k],
-        out_specs=[lane_q, stat, stat],
+        out_specs=[lane_q, stat],
         out_shape=[
             jax.ShapeDtypeStruct((b, t_pad, h * d), qf.dtype),
-            jax.ShapeDtypeStruct((b, nh, t_pad, _LANES), jnp.float32),
-            jax.ShapeDtypeStruct((b, nh, t_pad, _LANES), jnp.float32),
+            jax.ShapeDtypeStruct((b, nh, _LANES // d, t_pad), jnp.float32),
         ],
         scratch_shapes=[
+            # m, l, acc between the sequential ki steps.
             pltpu.VMEM((block_q, _LANES), jnp.float32),
             pltpu.VMEM((block_q, _LANES), jnp.float32),
             pltpu.VMEM((block_q, _LANES), jnp.float32),
         ],
         name="dtpu_flash_fwd_packed",
         interpret=_interpret(),
-    )(qp, kp, vp)
-    if t_pad >= _COMPACT_STATS_MIN_T:
-        # Long context: slice to one value per head per row (the 128-lane
-        # block holds 128//d heads, each replicated over its d-lane span)
-        # so the residual is 1/d the size; the backward re-expands.
-        return out[:, :t], m_out[..., ::d], l_out[..., ::d]
-    return out[:, :t], m_out, l_out
+    )(pad(qf), pad(kf), pad(vf))
+    return out[:, :t], lse
 
 
-def _bwd_pallas_packed(h, d, causal, block_q, block_k, res, g):
-    qf, kf, vf, out, m_rows, l_rows = res  # m/l: (b, nh, t_pad)
+def _bwd_pallas_packed(h, d, scale, causal, block_q, block_k, res, g):
+    qf, kf, vf, out, lse = res
     b, t, _ = qf.shape
-    scale = 1.0 / np.sqrt(d)
-    t_pad = _round_up(t, max(block_q, block_k))
+    t_pad, nh, nq, nk, specs = _packed_specs(t, h, d, block_q, block_k)
     pad = lambda x: jnp.pad(x, ((0, 0), (0, t_pad - t), (0, 0)))
     qp, kp, vp = pad(qf), pad(kf), pad(vf)
     dop = pad(g.astype(qf.dtype))
-    hpb = _LANES // d
-    nh = h // hpb
-    nq = t_pad // block_q
-    nk = t_pad // block_k
 
-    # Short-T residuals arrive lane-replicated (fastest Mosaic reads);
-    # long-T residuals arrive compact and are re-expanded transiently.
-    if m_rows.shape[-1] == _LANES:
-        m_out, l_out = m_rows, l_rows
-    else:
-        m_out = jnp.repeat(m_rows, d, axis=-1)  # (b, nh, t_pad, 128)
-        l_out = jnp.repeat(l_rows, d, axis=-1)
+    # delta_i = sum_j dO_ij O_ij per row and head, laid out like lse: XLA
+    # reduces over a 64-wide minor dimension by making T minor first, so
+    # this layout is the one it reaches with no copy after the reduce.
+    gf = g.astype(jnp.float32).reshape(b, t, nh, _LANES // d, d)
+    of = out.astype(jnp.float32).reshape(b, t, nh, _LANES // d, d)
+    delta = jnp.transpose(jnp.sum(gf * of, axis=-1), (0, 2, 3, 1))
+    delta = jnp.pad(delta, ((0, 0), (0, 0), (0, 0), (0, t_pad - t)))
 
-    # delta per (b, t, head) -> the (b, nh, t_pad, 128) stat layout with
-    # each head's value replicated across its lane span.
-    gf = g.astype(jnp.float32).reshape(b, t, h, d)
-    of = out.astype(jnp.float32).reshape(b, t, h, d)
-    delta = jnp.sum(gf * of, axis=-1)  # (b, t, h)
-    delta = jnp.repeat(
-        delta.reshape(b, t, nh, hpb), d, axis=-1
-    )  # (b, t, nh, 128)
-    delta = jnp.moveaxis(delta, 2, 1)  # (b, nh, t, 128) — small tensor
-    delta = jnp.pad(delta, ((0, 0), (0, 0), (0, t_pad - t), (0, 0)))
-
-    lane_q = pl.BlockSpec((1, block_q, _LANES), lambda b, h, i, j: (b, i, h))
-    lane_k = pl.BlockSpec((1, block_k, _LANES), lambda b, h, i, j: (b, j, h))
-    stat_q = pl.BlockSpec((1, 1, block_q, _LANES),
-                          lambda b, h, i, j: (b, h, i, 0))
+    kernel_args = dict(
+        scale=scale, hd=d, block_q=block_q, block_k=block_k, t_actual=t,
+        causal=causal, nq=nq, nk=nk)
+    lane_q, lane_k, stat = specs(q_axis=2)
     dq = pl.pallas_call(
-        functools.partial(
-            _dq_kernel_packed, scale=scale, hd=d, block_q=block_q,
-            block_k=block_k, t_actual=t, causal=causal, nk=nk,
-        ),
+        functools.partial(_dq_kernel_packed, **kernel_args),
         grid=(b, nh, nq, nk),
-        in_specs=[lane_q, lane_k, lane_k, lane_q, stat_q, stat_q, stat_q],
+        in_specs=[lane_q, lane_k, lane_k, lane_q, stat, stat],
         out_specs=lane_q,
         out_shape=jax.ShapeDtypeStruct((b, t_pad, h * d), qf.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, _LANES), jnp.float32)],
         name="dtpu_flash_dq_packed",
         interpret=_interpret(),
-    )(qp, kp, vp, dop, m_out, l_out, delta)
+    )(qp, kp, vp, dop, lse, delta)
 
-    lane_q_kv = pl.BlockSpec((1, block_q, _LANES),
-                             lambda b, h, i, j: (b, j, h))
-    lane_k_kv = pl.BlockSpec((1, block_k, _LANES),
-                             lambda b, h, i, j: (b, i, h))
-    stat_kv = pl.BlockSpec((1, 1, block_q, _LANES),
-                           lambda b, h, i, j: (b, h, j, 0))
+    lane_q, lane_k, stat = specs(q_axis=3)
     dk, dv = pl.pallas_call(
-        functools.partial(
-            _dkv_kernel_packed, scale=scale, hd=d, block_q=block_q,
-            block_k=block_k, t_actual=t, causal=causal, nq=nq,
-        ),
+        functools.partial(_dkv_kernel_packed, **kernel_args),
         grid=(b, nh, nk, nq),
-        in_specs=[lane_q_kv, lane_k_kv, lane_k_kv, lane_q_kv,
-                  stat_kv, stat_kv, stat_kv],
-        out_specs=[lane_k_kv, lane_k_kv],
+        in_specs=[lane_q, lane_k, lane_k, lane_q, stat, stat],
+        out_specs=[lane_k, lane_k],
         out_shape=[
             jax.ShapeDtypeStruct((b, t_pad, h * d), kf.dtype),
             jax.ShapeDtypeStruct((b, t_pad, h * d), vf.dtype),
@@ -659,31 +968,39 @@ def _bwd_pallas_packed(h, d, causal, block_q, block_k, res, g):
         ],
         name="dtpu_flash_dkv_packed",
         interpret=_interpret(),
-    )(qp, kp, vp, dop, m_out, l_out, delta)
+    )(qp, kp, vp, dop, lse, delta)
     return dq[:, :t], dk[:, :t], dv[:, :t]
 
 
 def _make_packed(h, d, causal, block_q, block_k):
-    """custom_vjp fn over (B, T, H*D) arrays for this static config."""
+    """custom_vjp fn over (B, T, H*D) arrays for this static config. Its
+    two halves are jitted: every layer of a model calls this one function
+    at one shape, so the kernels are traced and lowered once a program and
+    not once a layer (the walk is unrolled at trace time, and tracing is
+    set-up time)."""
+    # A Python float: a NumPy scalar is no weak type, and q * scale would
+    # promote the MXU's bf16 operand to f32.
+    scale = 1.0 / math.sqrt(d)
+
+    @jax.jit
+    def flash_fwd(qf, kf, vf):
+        return _fwd_pallas_packed(
+            qf, kf, vf, h, d, scale, causal, block_q, block_k)
+
+    @jax.jit
+    def flash_bwd(res, g):
+        return _bwd_pallas_packed(
+            h, d, scale, causal, block_q, block_k, res, g)
 
     @jax.custom_vjp
     def packed(qf, kf, vf):
-        scale = 1.0 / np.sqrt(d)
-        out, _, _ = _fwd_pallas_packed(
-            qf, kf, vf, h, d, scale, causal, block_q, block_k
-        )
-        return out
+        return flash_fwd(qf, kf, vf)[0]
 
     def fwd(qf, kf, vf):
-        scale = 1.0 / np.sqrt(d)
-        out, m_out, l_out = _fwd_pallas_packed(
-            qf, kf, vf, h, d, scale, causal, block_q, block_k
-        )
-        return out, (qf, kf, vf, out, m_out, l_out)
+        out, lse = flash_fwd(qf, kf, vf)
+        return out, (qf, kf, vf, out, lse)
 
-    packed.defvjp(fwd, functools.partial(
-        _bwd_pallas_packed, h, d, causal, block_q, block_k
-    ))
+    packed.defvjp(fwd, flash_bwd)
     return packed
 
 
@@ -745,16 +1062,25 @@ def flash_attention(
     Mosaic on TPU, the Pallas interpreter on CPU (the test configuration);
     any other backend is an error (``_pallas_common.interpret``).
 
-    ``block_q=None`` (default) resolves to the swept 1024, scoped-VMEM-
-    clamped to 512 for float32 inputs (any length) and for bf16 above
+    ``block_q`` / ``block_k`` are the DMA blocks: what one grid step holds
+    in VMEM. ``block_q=None`` (default) resolves to the swept 1024, scoped-
+    VMEM-clamped to 512 for float32 inputs (any length) and for bf16 above
     T=2048 (see the comment at the clamp). An EXPLICIT block_q is honored
     as passed — sweeps on chips with different VMEM budgets must measure
-    what they ask for.
+    what they ask for. Inside a block the packed kernels compute by
+    sub-tiles of side ``_SUBTILE`` (module docstring), which is no argument:
+    what is skipped and what is masked follows from ``causal``, T and the
+    blocks.
     """
     b, t, h, d = q.shape
     rt = _round_up(t, 8)
     if block_q is None:
-        # Swept default with scoped-VMEM clamps (16MB limit on v5e):
+        # Swept default with scoped-VMEM clamps (16MB limit on v5e). The
+        # sweeps (docs/PERF.md rounds 3-5) moved these grid blocks, and a
+        # smaller one lost: it skips more of the causal triangle but pays
+        # a grid step, a DMA and a scratch round trip a tile. The skipping
+        # now happens inside the block at no such cost (_SUBTILE), so the
+        # block stays as large as VMEM allows:
         # - float32 inputs double every resident block (measured compile
         #   failure at T>=2048 with 1024);
         # - bf16 at long sequence: the full-model BACKWARD kernel's stack
@@ -778,6 +1104,12 @@ def flash_attention(
     if _packed_supported(h, d):
         # Lane-packed path: kernels read heads straight from the (B, T,
         # H*D) projection layout — the reshape is free, no transposes.
+        # How far the sub-tile walk engages is static for a shape, so it is
+        # published here, at trace time, as counts.
+        gauge = default_registry().gauge
+        for name, n in zip(("square", "computed", "masked"),
+                           subtile_counts(t, bq, bk, causal)):
+            gauge(f"flash.subtiles_{name}", n)
         packed = _packed_cached(h, d, causal, bq, bk)
         return packed(
             q.reshape(b, t, h * d), k.reshape(b, t, h * d),

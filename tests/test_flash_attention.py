@@ -177,27 +177,63 @@ def test_fused_xent_sharded_no_allgather(devices):
     assert abs(got - want) < 1e-5
 
 
+# (shape, block_q, block_k, sub-tile side): the packed kernels' sub-tile walk.
+# The side is a module constant sized for the chip (a lane tile at least);
+# here it is patched down so that every sub-tile class (skipped, unmasked,
+# masked by the diagonal or by the padding edge) occurs at interpret-mode
+# sizes, in a grid of one block and in a grid of several.
+PACKED_CASES = [
+    ((2, 64, 2, 64), 32, 32, None),    # head_dim 64: two heads per lane block
+    ((1, 100, 1, 128), 32, 32, None),  # head_dim 128: one head, ragged T
+    ((2, 72, 4, 64), 32, 32, None),    # multiple head blocks, ragged T
+    ((1, 128, 2, 64), 128, 128, 32),   # one block of 4 x 4 sub-tiles
+    ((1, 120, 2, 64), 128, 128, 32),   # ... whose last column the edge crosses
+    ((2, 90, 1, 128), 128, 128, 32),   # ... and whose last lies in the padding
+    ((1, 256, 2, 64), 64, 128, 32),    # block_q != block_k, several blocks
+    ((1, 250, 4, 64), 128, 64, 32),    # block_q > block_k, ragged T
+    ((1, 192, 2, 64), 64, 64, 16),     # square blocks: diagonal and below
+]
+
+
+@pytest.fixture
+def subtile(monkeypatch):
+    """``subtile(side)`` sets the packed kernels' sub-tile side for one test
+    (None: the module's own) and returns the module. The side is static
+    configuration, so the cached custom_vjp functions go with it."""
+    from distributed_tpu.ops import flash_attention as fa
+
+    def clear():
+        fa._packed_cached.cache_clear()
+        fa.subtile_counts.cache_clear()
+
+    def patch(side):
+        if side is not None:
+            monkeypatch.setattr(fa, "_SUBTILE", side)
+        clear()
+        return fa
+
+    yield patch
+    clear()
+
+
 @pytest.mark.parametrize("causal", [False, True])
-@pytest.mark.parametrize("shape", [
-    (2, 64, 2, 64),    # head_dim 64: two heads per 128-lane block
-    (1, 100, 1, 128),  # head_dim 128: one head per block, ragged T
-    (2, 72, 4, 64),    # multiple head blocks, ragged T
-])
-def test_packed_layout_matches_dense_values_and_grads(shape, causal):
+@pytest.mark.parametrize("shape,block_q,block_k,side", PACKED_CASES)
+def test_packed_layout_matches_dense_values_and_grads(
+        shape, block_q, block_k, side, causal, subtile):
     """The lane-packed (B,T,H*D) kernels (head_dim 64/128 — no transposes)
     must match dense attention in values AND all three gradients."""
-    from distributed_tpu.ops.flash_attention import _packed_supported
-
-    assert _packed_supported(shape[2], shape[3])
+    fa = subtile(side)
+    assert fa._packed_supported(shape[2], shape[3])
     q, k, v = _qkv(shape, seed=3)
-    out = flash_attention(q, k, v, causal=causal, block_q=32, block_k=32)
+    flash = lambda q, k, v: flash_attention(
+        q, k, v, causal=causal, block_q=block_q, block_k=block_k)
+    out = flash(q, k, v)
     want = dense_attention(q, k, v, causal)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want),
                                rtol=2e-5, atol=2e-5)
 
     def loss_flash(q, k, v):
-        o = flash_attention(q, k, v, causal=causal, block_q=32, block_k=32)
-        return jnp.sum(jnp.sin(o))
+        return jnp.sum(jnp.sin(flash(q, k, v)))
 
     def loss_dense(q, k, v):
         return jnp.sum(jnp.sin(dense_attention(q, k, v, causal)))
@@ -209,25 +245,87 @@ def test_packed_layout_matches_dense_values_and_grads(shape, causal):
                                    rtol=3e-4, atol=3e-4)
 
 
-def test_packed_compact_stats_branch_matches(monkeypatch):
-    """Long-context residual policy: above _COMPACT_STATS_MIN_T the packed
-    path saves compact per-head stats and re-expands in backward — values
-    and grads must be identical to the short-T (lane-replicated) branch."""
-    from distributed_tpu.ops import flash_attention as fa
+@pytest.mark.parametrize("t,blocks,causal,want", [
+    (1024, (1024, 1024), True, (64, 36, 8)),    # both benchmark cells
+    (1024, (1024, 1024), False, (64, 64, 0)),
+    (1000, (1024, 1024), False, (64, 64, 8)),   # the padding edge
+    (700, (1024, 1024), False, (64, 48, 8)),   # two columns in the padding
+    (4096, (512, 1024), True, (1024, 528, 32)),  # the bf16 clamp shape
+    (600, (600, 600), True, (1, 1, 1)),         # ragged block: one sub-tile
+])
+def test_subtile_counts(t, blocks, causal, want):
+    """The static count of the sub-tile walk, at the module's own side."""
+    from distributed_tpu.ops.flash_attention import _SUBTILE, subtile_counts
 
-    q, k, v = _qkv((1, 96, 2, 64), seed=5)
+    assert _SUBTILE == 128
+    assert subtile_counts(t, *blocks, causal) == want
+
+
+def test_subtile_gauges_published():
+    """flash_attention publishes the counts at trace time, as gauges."""
+    from distributed_tpu import obs
+
+    reg = obs.default_registry()
+    q = jax.ShapeDtypeStruct((1, 1024, 2, 64), jnp.bfloat16)
+    jax.eval_shape(
+        lambda q, k, v: flash_attention(q, k, v, causal=True), q, q, q)
+    assert [reg.gauge_value(f"flash.subtiles_{n}")
+            for n in ("square", "computed", "masked")] == [64.0, 36.0, 8.0]
+
+
+def _dot_operand_dtypes(jaxpr, found):
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "dot_general":
+            found.add(tuple(str(v.aval.dtype) for v in eqn.invars))
+        for param in eqn.params.values():
+            for sub in param if isinstance(param, (list, tuple)) else [param]:
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    _dot_operand_dtypes(sub, found)
+    return found
+
+
+@pytest.mark.parametrize("head_dim", [64, 128])
+def test_packed_kernels_feed_the_mxu_bf16(head_dim):
+    """Every matrix product of the three packed kernels takes bf16 operands
+    from bf16 inputs: the scale folded into q (head_dim 64: a power of two)
+    must not promote it (a NumPy scalar is no weak type), and head_dim 128
+    keeps its f32 multiply on the scores."""
+    q = jax.ShapeDtypeStruct((1, 512, 128 // head_dim, head_dim),
+                             jnp.bfloat16)
 
     def loss(q, k, v):
-        o = flash_attention(q, k, v, causal=True, block_q=32, block_k=32)
-        return jnp.sum(jnp.sin(o))
+        return jnp.sum(flash_attention(q, k, v, causal=True)
+                       .astype(jnp.float32))
 
-    g_fast = jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
-    fa._packed_cached.cache_clear()  # static config changed: force retrace
-    monkeypatch.setattr(fa, "_COMPACT_STATS_MIN_T", 32)
-    try:
-        g_compact = jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
-    finally:
-        fa._packed_cached.cache_clear()
-    for a, b in zip(g_fast, g_compact):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                   rtol=1e-6, atol=1e-6)
+    jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, q, q)
+    assert _dot_operand_dtypes(jaxpr.jaxpr, set()) == {
+        ("bfloat16", "bfloat16")}
+
+
+@pytest.mark.parametrize("causal", [False, True])
+@pytest.mark.parametrize("shape,blocks,side", [
+    ((1, 96, 2, 64), (32, 32), None),     # several blocks: from the scratch
+    ((1, 120, 2, 64), (128, 128), 32),    # one block, walked in sub-tiles
+    ((2, 100, 1, 128), (64, 64), 32),     # one head a lane block, ragged T
+])
+def test_packed_residual_statistic(shape, blocks, side, causal, subtile):
+    """What the forward saves for the backward beside its inputs and output
+    is ONE row statistic, lse = m + log l, 4 bytes a row and head, laid out
+    lane-major (B, head blocks, heads a block, t_pad): it must be the
+    log-sum-exp of the dense scores on every real row."""
+    fa = subtile(side)
+    b, t, h, d = shape
+    q, k, v = _qkv(shape, seed=5)
+    flat = lambda x: x.reshape(b, t, h * d)
+    _, lse = fa._fwd_pallas_packed(
+        flat(q), flat(k), flat(v), h, d, 1.0 / np.sqrt(d), causal, *blocks)
+    hpb = 128 // d
+    assert lse.shape[:3] == (b, h // hpb, hpb) and lse.dtype == jnp.float32
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k) / np.sqrt(d)
+    if causal:
+        s = jnp.where(jnp.tril(jnp.ones((t, t), bool)), s, -jnp.inf)
+    want = jax.scipy.special.logsumexp(s, axis=-1)  # (b, h, t)
+    np.testing.assert_allclose(
+        np.asarray(lse[..., :t]).reshape(b, h, t), np.asarray(want),
+        rtol=2e-5, atol=2e-5)

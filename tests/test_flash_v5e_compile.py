@@ -1,0 +1,67 @@
+"""The packed flash kernels, forward and backward, compiled by the TPU's
+own compiler for a described (not attached) v5e at the benchmark cells'
+shapes and at the scoped-VMEM clamp shape. Nothing runs: this guards the
+16 MB scoped-VMEM limit and the lane / sublane alignment of the in-kernel
+sub-tile slices, which interpret mode cannot see, at no chip time
+(on-chip-measurement guide, third rehearsal; the whole step programs are
+``benchmarks/rehearsal/compile_v5e.py``'s).
+
+Kept in ONE file: the worker that runs it loads libtpu and keeps its lock.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from distributed_tpu.ops import flash_attention as fa
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def mosaic(monkeypatch):
+    """Lower to Mosaic although the default backend is the CPU, with the
+    persistent compile cache off (an entry for a described device cannot be
+    read back) and no cached custom_vjp from an interpret-mode test."""
+    from jax.experimental.compilation_cache import compilation_cache
+    monkeypatch.setattr(fa, "_interpret", lambda: False)
+    fa._packed_cached.cache_clear()
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+    fa._packed_cached.cache_clear()
+
+
+@pytest.mark.parametrize("shape", [
+    (8, 1024, 16, 64),   # gpt2-medium.train.1chip
+    (4, 1024, 20, 64),   # gpt2-large.train.fsdp4, rows of one chip
+    (1, 4096, 16, 64),   # the bf16 clamp: block_q 512, block_k 1024
+])
+def test_packed_flash_grad_compiles_for_v5e(shape, one_chip, mosaic):
+    x = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+
+    def loss(q, k, v):
+        out = fa.flash_attention(q, k, v, causal=True)
+        return jnp.sum(out.astype(jnp.float32))
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        x, x, x).compile().as_text()
+    for name in ("dtpu_flash_fwd_packed", "dtpu_flash_dq_packed",
+                 "dtpu_flash_dkv_packed"):
+        assert name in text
