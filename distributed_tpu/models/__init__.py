@@ -1,6 +1,6 @@
 from .cnn import cifar_cnn, mnist_cnn
 from .resnet import resnet, resnet18, resnet34, resnet50
-from .transformer import transformer_block, transformer_lm
+from .transformer import deepseek_v3_lm, transformer_block, transformer_lm
 from .vit import vit, vit_base, vit_large, vit_small, vit_tiny
 
 __all__ = [
@@ -12,6 +12,7 @@ __all__ = [
     "resnet50",
     "transformer_lm",
     "transformer_block",
+    "deepseek_v3_lm",
     "vit",
     "vit_tiny",
     "vit_small",

@@ -148,3 +148,70 @@ def transformer_lm(
             layers += block
     layers += [nn.LayerNorm(), nn.Dense(vocab_size, dtype=dtype)]
     return nn.Sequential(layers, name="transformer_lm")
+
+
+def deepseek_v3_lm(
+    vocab_size: int,
+    *,
+    num_layers: int,
+    d_model: int,
+    num_heads: int,
+    kv_rank: int,
+    nope_dim: int,
+    rope_dim: int,
+    v_dim: int,
+    d_ff: int,
+    num_experts: int,
+    top_k: int,
+    moe_hidden: int,
+    shared_experts: int = 0,
+    first_dense: int = 1,
+    experts_held: Optional[int] = None,
+    expert_offset: int = 0,
+    routed_scaling: float = 1.0,
+    bias_update_rate: float = 1e-3,
+    record_choice: bool = False,
+    rope_theta: float = 10000.0,
+    epsilon: float = 1e-6,
+    flash="auto",
+    dtype=None,
+) -> nn.Sequential:
+    """The DeepSeek-V3 block as a token-in, logits-out LM (kanana-2-30b-a3b
+    is one; ``model_type: deepseek_v3``, no query compression): pre-RMSNorm
+    residual blocks of ``nn.LatentAttention`` and a gated SiLU MLP. The
+    first ``first_dense`` layers' MLP is dense of width ``d_ff``; the rest
+    are ``nn.DroplessMoE`` over ``num_experts`` experts of ``moe_hidden``,
+    ``top_k`` a token, with one shared gated MLP of ``shared_experts *
+    moe_hidden``. ``experts_held`` and ``expert_offset`` give this chip's
+    share of every expert layer under expert parallelism: the experts it
+    holds and computes, while routing stays over all ``num_experts``.
+    ``bias_update_rate`` is the step of noaux_tc's balancing of the selection
+    bias (0 freezes it) and ``record_choice`` keeps each expert layer's last
+    choices in its state (``nn.DroplessMoE``). A final RMSNorm and an
+    untied, bias-free head; no position table (RoPE is inside the
+    attention)."""
+    layers = [nn.Embedding(vocab_size, d_model, dtype=dtype)]
+    for i in range(num_layers):
+        attn = nn.LatentAttention(
+            num_heads, kv_rank=kv_rank, nope_dim=nope_dim, rope_dim=rope_dim,
+            v_dim=v_dim, rope_theta=rope_theta, epsilon=epsilon, flash=flash,
+            dtype=dtype)
+        if i < first_dense:
+            ffn = nn.GatedMLP(d_ff, dtype=dtype)
+        else:
+            ffn = nn.DroplessMoE(
+                num_experts, moe_hidden, top_k=top_k,
+                experts_held=experts_held, expert_offset=expert_offset,
+                shared_hidden_dim=shared_experts * moe_hidden,
+                routed_scaling=routed_scaling,
+                bias_update_rate=bias_update_rate,
+                record_choice=record_choice, dtype=dtype)
+        layers += [
+            nn.Residual(nn.Sequential([nn.RMSNorm(epsilon), attn],
+                                      name="main")),
+            nn.Residual(nn.Sequential([nn.RMSNorm(epsilon), ffn],
+                                      name="main")),
+        ]
+    layers += [nn.RMSNorm(epsilon),
+               nn.Dense(vocab_size, use_bias=False, dtype=dtype)]
+    return nn.Sequential(layers, name="deepseek_v3_lm")

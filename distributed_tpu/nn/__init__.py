@@ -1,6 +1,10 @@
-from .attention import MultiHeadAttention, PositionalEmbedding
+from .attention import (
+    LatentAttention,
+    MultiHeadAttention,
+    PositionalEmbedding,
+)
 from .augment import RandomCrop, RandomFlip
-from .moe import MoE
+from .moe import DroplessMoE, MoE
 from .pipeline import PipelinedBlocks
 from .scan import ScannedBlocks
 from .remat import Remat
@@ -14,9 +18,11 @@ from .layers import (
     Dropout,
     Embedding,
     Flatten,
+    GatedMLP,
     GlobalAvgPool2D,
     LayerNorm,
     MaxPool2D,
+    RMSNorm,
     SpaceToDepth,
 )
 
@@ -40,7 +46,11 @@ __all__ = [
     "RandomFlip",
     "RandomCrop",
     "MultiHeadAttention",
+    "LatentAttention",
     "MoE",
+    "DroplessMoE",
+    "RMSNorm",
+    "GatedMLP",
     "PipelinedBlocks",
     "ScannedBlocks",
     "PositionalEmbedding",

@@ -23,7 +23,7 @@ import jax
 import jax.numpy as jnp
 
 from . import initializers
-from .core import Layer, Shape
+from .core import Layer, Shape, child_scope
 from ..precision import resolve_dtype
 from ..quant import _QMAX, QKEY, SKEY, dequantize, maybe_dequantize, shape_of
 
@@ -582,6 +582,123 @@ class MultiHeadAttention(Layer):
         if self.use_bias:
             out = out + params["bo"].astype(out.dtype)
         return out, {}
+
+
+def rope_interleaved(x, theta: float):
+    """Rotary position embedding on adjacent pairs (``rope_interleave``):
+    dimensions (2i, 2i+1) of ``x`` (B, T, H, d) at position t turn by the
+    angle t * theta^(-2i/d), from position 0. Float32 inside, ``x``'s dtype
+    out. The pair swap (x[2i], x[2i+1]) -> (-x[2i+1], x[2i]) is a product
+    with a signed permutation matrix, exact in any dtype, so the MXU does
+    it and no lane-strided reshape exists."""
+    t, d = x.shape[1], x.shape[-1]
+    inv_freq = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
+    angle = jnp.arange(t, dtype=jnp.float32)[:, None] * inv_freq[None]
+    cos = jnp.repeat(jnp.cos(angle), 2, axis=-1)[None, :, None, :]
+    sin = jnp.repeat(jnp.sin(angle), 2, axis=-1)[None, :, None, :]
+    even = jnp.arange(0, d, 2)
+    swap = jnp.zeros((d, d), x.dtype)
+    swap = swap.at[even + 1, even].set(-1).at[even, even + 1].set(1)
+    rotated = x.astype(jnp.float32) * cos + jnp.dot(x, swap).astype(
+        jnp.float32) * sin
+    return rotated.astype(x.dtype)
+
+
+class LatentAttention(Layer):
+    """Multi-head latent attention (DeepSeek-V2/V3's MLA, no query
+    compression) over (B, T, D) inputs, for training and full forward
+    passes:
+
+        q = x Wq                       (T, H, nope + rope)
+        [c | k_rope] = x Wkv_a         latent of ``kv_rank``, one rope key
+        [k_nope | v] = RMSNorm(c) Wkv_b    (T, H, nope + v_dim)
+        k = [k_nope | rope(k_rope)]    the one rope key shared by all heads
+        out = softmax(q k^T / sqrt(nope + rope), causal) v  Wo
+
+    with interleaved RoPE on the rope part of q and on k_rope and no
+    position table. Queries and keys are wider than values (192 and 128 at
+    the published sizes): the flash kernels take both widths as they are
+    (``ops.flash_attention``). The scope, and the parameter key, starts with
+    ``multi_head_attention``: ``benchmarks/scopes.py`` sorts attention by
+    that prefix. No cached decode: a latent paged cache does not exist yet
+    (ROADMAP.md)."""
+
+    decode_safe = False
+
+    def __init__(self, num_heads: int, *, kv_rank: int, nope_dim: int,
+                 rope_dim: int, v_dim: int, rope_theta: float = 10000.0,
+                 epsilon: float = 1e-6, causal: bool = True, dtype=None,
+                 flash="auto", name: Optional[str] = None):
+        super().__init__(name)
+        self.num_heads = int(num_heads)
+        self.kv_rank = int(kv_rank)
+        self.nope_dim = int(nope_dim)
+        self.rope_dim = int(rope_dim)
+        self.v_dim = int(v_dim)
+        self.rope_theta = float(rope_theta)
+        self.epsilon = float(epsilon)
+        self.causal = bool(causal)
+        self.dtype = dtype
+        self.flash = flash
+
+    def default_name(self) -> str:
+        return "multi_head_attention_latent"
+
+    def init(self, key, input_shape: Shape):
+        d, h = input_shape[-1], self.num_heads
+        keys = jax.random.split(key, 4)
+        init = initializers.get("glorot_uniform")
+        params = {
+            "wq": init(keys[0], (d, h * (self.nope_dim + self.rope_dim)),
+                       jnp.float32),
+            "wkv_a": init(keys[1], (d, self.kv_rank + self.rope_dim),
+                          jnp.float32),
+            "kv_norm": {"scale": jnp.ones((self.kv_rank,), jnp.float32)},
+            "wkv_b": init(keys[2],
+                          (self.kv_rank, h * (self.nope_dim + self.v_dim)),
+                          jnp.float32),
+            "wo": init(keys[3], (h * self.v_dim, d), jnp.float32),
+        }
+        return params, {}, tuple(input_shape)
+
+    def sharding_hints(self):
+        return {"wq": "col", "wkv_b": "col", "wo": "row"}
+
+    _use_flash = MultiHeadAttention._use_flash
+    _flash_call = MultiHeadAttention._flash_call
+
+    def apply(self, params, state, x, *, train=False, rng=None):
+        dt = resolve_dtype(self.dtype)
+        if dt is not None:
+            x = x.astype(dt)
+        b, t, _ = x.shape
+        h, nope = self.num_heads, self.nope_dim
+        proj = lambda a, w: jnp.dot(
+            a, maybe_dequantize(params[w]).astype(a.dtype))
+        q = proj(x, "wq").reshape(b, t, h, nope + self.rope_dim)
+        latent = proj(x, "wkv_a")
+        c, k_rope = latent[..., :self.kv_rank], latent[..., self.kv_rank:]
+        with child_scope("kv_norm"):
+            cf = c.astype(jnp.float32)
+            ms = jnp.mean(jnp.square(cf), axis=-1, keepdims=True)
+            c = (cf * jax.lax.rsqrt(ms + self.epsilon)
+                 * params["kv_norm"]["scale"]).astype(c.dtype)
+        kv = proj(c, "wkv_b").reshape(b, t, h, nope + self.v_dim)
+        k_rope = rope_interleaved(k_rope[:, :, None, :], self.rope_theta)
+        q = jnp.concatenate(
+            [q[..., :nope], rope_interleaved(q[..., nope:], self.rope_theta)],
+            axis=-1)
+        k = jnp.concatenate(
+            [kv[..., :nope],
+             jnp.broadcast_to(k_rope, (b, t, h, self.rope_dim))], axis=-1)
+        v = kv[..., nope:]
+        if self._use_flash(t):
+            ctx = self._flash_call(q, k, v)
+        else:
+            from ..ops.flash_attention import dense_attention
+
+            ctx = dense_attention(q, k, v, self.causal)
+        return proj(ctx.reshape(b, t, h * self.v_dim), "wo"), {}
 
 
 class PositionalEmbedding(Layer):

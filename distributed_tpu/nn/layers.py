@@ -23,7 +23,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from . import activations, initializers
-from .core import Layer, Shape
+from .core import Layer, Shape, child_scope
 from ..precision import resolve_dtype
 from ..quant import is_quantized_leaf, maybe_dequantize
 
@@ -487,6 +487,74 @@ class LayerNorm(Layer):
         y = (xf - mean) * lax.rsqrt(var + self.epsilon)
         y = y * params["scale"] + params["bias"]
         return y.astype(x.dtype), {}
+
+
+class RMSNorm(Layer):
+    """``x / sqrt(mean(x^2) + epsilon) * scale`` over the last axis, in
+    float32, returned in ``x``'s dtype. No mean subtraction and no bias
+    (Zhang & Sennrich 2019; the norm of the DeepSeek-V3 block)."""
+
+    def __init__(self, epsilon: float = 1e-6, name=None):
+        super().__init__(name)
+        self.epsilon = float(epsilon)
+
+    def default_name(self) -> str:
+        return "rms_norm"  # the camel-case splitter would produce "rmsnorm"
+
+    def init(self, key, input_shape: Shape):
+        d = input_shape[-1]
+        return {"scale": jnp.ones((d,), jnp.float32)}, {}, tuple(input_shape)
+
+    def apply(self, params, state, x, *, train=False, rng=None):
+        xf = x.astype(jnp.float32)
+        ms = jnp.mean(jnp.square(xf), axis=-1, keepdims=True)
+        y = xf * lax.rsqrt(ms + self.epsilon) * params["scale"]
+        return y.astype(x.dtype), {}
+
+
+class GatedMLP(Layer):
+    """Bias-free gated MLP, ``down(act(gate(x)) * up(x))``, on the trailing
+    axis (SwiGLU with ``activation="silu"``). Its three kernels sit under
+    the keys ``dense`` (gate), ``dense_1`` (up) and ``dense_2`` (down), so
+    the parameter path, which is the device scope, reads ``dense*`` as the
+    plain MLP's does; gate and up are column-sharded, down row-sharded."""
+
+    def __init__(self, hidden_dim: int, activation="silu", dtype=None,
+                 kernel_initializer="glorot_uniform", name=None):
+        super().__init__(name)
+        self.hidden_dim = int(hidden_dim)
+        self.activation = activations.get(activation)
+        self.kernel_initializer = kernel_initializer
+        self.dtype = dtype
+
+    def init(self, key, input_shape: Shape):
+        d, h = input_shape[-1], self.hidden_dim
+        init = initializers.get(self.kernel_initializer)
+        keys = jax.random.split(key, 3)
+        params = {
+            name: {"kernel": init(k, shape, jnp.float32)}
+            for name, k, shape in zip(
+                ("dense", "dense_1", "dense_2"), keys,
+                ((d, h), (d, h), (h, d)))
+        }
+        return params, {}, tuple(input_shape)
+
+    def sharding_hints(self):
+        return {"dense": {"kernel": "col"}, "dense_1": {"kernel": "col"},
+                "dense_2": {"kernel": "row"}}
+
+    def apply(self, params, state, x, *, train=False, rng=None):
+        dt = resolve_dtype(self.dtype)
+        if dt is not None:
+            x = x.astype(dt)
+
+        def linear(name, h):
+            kernel = maybe_dequantize(params[name]["kernel"])
+            with child_scope(name):
+                return jnp.dot(h, kernel.astype(h.dtype))
+
+        hidden = self.activation(linear("dense", x)) * linear("dense_1", x)
+        return linear("dense_2", hidden), {}
 
 
 class Embedding(Layer):

@@ -18,6 +18,13 @@ TPU-first design choices:
 - Router computes in float32; a load-balancing auxiliary loss (Switch
   Transformer's fraction*probability form) is returned in state under
   ``"aux_loss"`` so training can add it to the objective.
+
+``DroplessMoE`` below is the other formulation, DeepSeek-V3's: sigmoid
+scores, no capacity and no dropped pair, the (token, choice) pairs sorted by
+expert into one buffer and multiplied by ``ops.grouped_matmul``, and a layer
+that holds a share of the experts (``experts_held``) while routing over all
+of them. ``MoE`` stays for what it alone does: the GSPMD all-to-all over the
+'expert' mesh axis, cached decode, the auxiliary loss.
 """
 
 from __future__ import annotations
@@ -28,7 +35,9 @@ import jax
 import jax.numpy as jnp
 
 from . import core, initializers
-from .core import Layer, Shape
+from .core import Layer, Shape, child_scope
+from .layers import GatedMLP
+from ..ops import grouped_matmul as gmm
 from ..quant import maybe_dequantize
 from ..precision import resolve_dtype
 
@@ -274,3 +283,282 @@ class MoE(Layer):
             "ne,ned->nd", weight.astype(compute_dtype), out_e
         )
         return out.reshape(b, t, d).astype(x.dtype), cache
+
+
+# ------------------------------------------------------ dropless experts --
+# Pairs are laid out choice-major, pair j * n + i being token i's j-th choice:
+# n * k gathered rows then split as (k, n, d) along the major axis, which is
+# no data movement, where (n, k, d) would pad k to a sublane tile and copy.
+def _gather_pairs(buf, dest):
+    """Rows ``dest`` (k, n) of ``buf`` (M, d) as (k, n, d); ``dest`` is in
+    range by construction."""
+    k, n = dest.shape
+    rows = jnp.take(buf, dest.reshape(-1), axis=0, mode="clip")
+    return rows.reshape(k, n, buf.shape[-1])
+
+
+@jax.custom_vjp
+def _dispatch(flat, src_token, row_valid, dest, held):
+    """Rows of ``flat`` (n, d) gathered into the experts' buffer (M, d):
+    row r holds token ``src_token[r]`` where ``row_valid[r]``, zeros
+    elsewhere. Its transpose is a gather too: token i's gradient is the sum
+    of the buffer rows ``dest[j, i]`` of its held pairs, so no scatter-add
+    of M rows runs in either direction."""
+    rows = jnp.take(flat, src_token, axis=0, mode="clip")
+    return jnp.where(row_valid[:, None], rows, jnp.zeros((), flat.dtype))
+
+
+def _dispatch_fwd(flat, src_token, row_valid, dest, held):
+    return _dispatch(flat, src_token, row_valid, dest, held), (dest, held)
+
+
+def _dispatch_bwd(res, d_buf):
+    dest, held = res
+    rows = _gather_pairs(d_buf, dest)
+    d_flat = jnp.sum(jnp.where(held[:, :, None], rows.astype(jnp.float32),
+                               0.0), axis=0)
+    return d_flat.astype(d_buf.dtype), None, None, None, None
+
+
+_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
+@jax.custom_vjp
+def _combine(out_buf, gates, dest, src_pair, row_valid):
+    """``y[i] = sum_j gates[j, i] * out_buf[dest[j, i]]``: a gather of n*k
+    buffer rows and a weighted sum over k. ``gates`` (k, n) float32, zero
+    where the pair's expert is not held. Backward: each buffer row takes
+    its token's dy times its gate (a gather of M rows), and a gate's
+    gradient is its row's dot with dy (the rows gathered again, not kept)."""
+    rows = _gather_pairs(out_buf, dest)
+    y = jnp.sum(gates[:, :, None] * rows.astype(jnp.float32), axis=0)
+    return y.astype(out_buf.dtype)
+
+
+def _combine_fwd(out_buf, gates, dest, src_pair, row_valid):
+    y = _combine(out_buf, gates, dest, src_pair, row_valid)
+    return y, (out_buf, gates, dest, src_pair, row_valid)
+
+
+def _combine_bwd(res, dy):
+    out_buf, gates, dest, src_pair, row_valid = res
+    k, n = dest.shape
+    row_gate = jnp.where(row_valid, gates.reshape(-1)[src_pair], 0.0)
+    d_out = (jnp.take(dy, src_pair % n, axis=0, mode="clip").astype(
+        jnp.float32) * row_gate[:, None]).astype(out_buf.dtype)
+    rows = _gather_pairs(out_buf, dest)
+    d_gates = jnp.sum(rows.astype(jnp.float32)
+                      * dy.astype(jnp.float32)[None], axis=-1)
+    return d_out, jnp.where(gates != 0, d_gates, 0.0), None, None, None
+
+
+_combine.defvjp(_combine_fwd, _combine_bwd)
+
+# Names of a DroplessMoE's counters in its state (``counters``).
+_COUNTERS = ("steps", "pairs", "held_rows", "load_max_sum")
+
+
+class DroplessMoE(Layer):
+    """DeepSeek-V3's expert layer over (..., D) inputs, for the share of the
+    experts this chip holds.
+
+    Routing is over all ``num_experts``: scores ``s = sigmoid(x Wr)`` in
+    float32; a token's ``top_k`` experts are the largest of ``s + b``; their
+    gates are ``s`` (without ``b``) over the sum of the ``top_k``, times
+    ``routed_scaling``. ``b`` is noaux_tc's per-expert selection bias, a
+    buffer in the layer's state under ``router_bias`` with no gradient:
+    the balancing of the family (DeepSeek-V3, arXiv:2412.19437, section
+    2.1.2). Every train step ends by moving it ``bias_update_rate`` down for
+    each expert that took more pairs than the mean expert in that step's
+    batch and as much up for each that took fewer (the paper trains with
+    0.001, the default here; 0 freezes it). No auxiliary loss.
+
+    The layer holds experts ``[expert_offset, expert_offset +
+    experts_held)`` (all of them by default) and returns the part of the
+    result they give,
+
+        y = sum over a token's chosen experts held here of gate * expert(x)
+            + shared(x)
+
+    ``shared`` one gated MLP of ``shared_hidden_dim`` on every token (none
+    at 0). What the experts held elsewhere add is left out; nothing stands
+    in for them, and on one chip no exchange runs. Experts are bias-free
+    gated SiLU MLPs (``GatedMLP``'s mathematics) of ``hidden_dim``.
+
+    No capacity and no dropped pair: the (token, choice) pairs of held
+    experts are sorted by expert (a counting sort: a pair's rank in its
+    expert's group is a running count) into one buffer of static shape,
+    sized for every pair landing here, each group starting on a tile
+    boundary (``ops.grouped_matmul.group_layout``). Three grouped matmuls run
+    over the groups' tiles in use, and the rows go back weighted by their
+    gates. Dispatch, combine and both their transposes are gathers, the
+    pairs laid out choice-major so that no gathered block is copied to be
+    reshaped.
+
+    Device scopes under the layer's own (``moe``): ``route`` (router, top-k,
+    sort, gather, weighted sum back), ``experts`` (the grouped matmuls and
+    the activation between them) and ``shared``. Counters, cumulative over
+    train steps, in the layer's state and so read with no device sync
+    inside a step loop (``counters``): ``steps``, ``pairs`` (tokens x
+    ``top_k``), ``held_rows`` (pairs whose expert is held here) and
+    ``load_max_sum`` (the busiest of all ``num_experts`` experts' pairs,
+    summed over steps). ``record_choice`` adds an output for whoever compares
+    the layer with another implementation: ``choice`` in the state, the
+    experts the last train step chose for the first example of its batch,
+    (..., top_k) in the shape of one example. Routing is discrete, so such a
+    comparison has to start from the same experts before it can see rounding.
+    """
+
+    def __init__(self, num_experts: int, hidden_dim: int, *, top_k: int,
+                 experts_held: Optional[int] = None, expert_offset: int = 0,
+                 shared_hidden_dim: int = 0, routed_scaling: float = 1.0,
+                 bias_update_rate: float = 1e-3, record_choice: bool = False,
+                 dtype=None, name: Optional[str] = None):
+        super().__init__(name)
+        self.num_experts = int(num_experts)
+        self.hidden_dim = int(hidden_dim)
+        self.top_k = int(top_k)
+        self.experts_held = int(
+            num_experts if experts_held is None else experts_held)
+        self.expert_offset = int(expert_offset)
+        if not 1 <= self.top_k <= self.num_experts:
+            raise ValueError(
+                f"top_k must be in [1, num_experts={num_experts}], got {top_k}")
+        if (self.experts_held < 1 or self.expert_offset < 0
+                or self.expert_offset + self.experts_held > self.num_experts):
+            raise ValueError(
+                f"experts [{expert_offset}, {expert_offset} + {experts_held})"
+                f" are not among the {num_experts} routed over")
+        self.routed_scaling = float(routed_scaling)
+        self.bias_update_rate = float(bias_update_rate)
+        self.record_choice = bool(record_choice)
+        self.dtype = dtype
+        self.shared = (GatedMLP(shared_hidden_dim, dtype=dtype)
+                       if shared_hidden_dim else None)
+
+    def default_name(self) -> str:
+        return "moe"
+
+    def init(self, key, input_shape: Shape):
+        d = input_shape[-1]
+        g, h = self.experts_held, self.hidden_dim
+        k_router, k_gate, k_up, k_down, k_shared = jax.random.split(key, 5)
+        glorot = initializers.get("glorot_uniform")
+        params = {
+            "router": glorot(k_router, (d, self.num_experts), jnp.float32),
+            "w_gate": glorot(k_gate, (g, d, h), jnp.float32),
+            "w_up": glorot(k_up, (g, d, h), jnp.float32),
+            "w_down": glorot(k_down, (g, h, d), jnp.float32),
+        }
+        if self.shared is not None:
+            params["shared"] = self.shared.init(k_shared, input_shape)[0]
+        state = {"router_bias": jnp.zeros((self.num_experts,), jnp.float32)}
+        state.update({c: jnp.float32(0.0) for c in _COUNTERS})
+        if self.record_choice:
+            state["choice"] = jnp.zeros(
+                tuple(input_shape[:-1]) + (self.top_k,), jnp.int32)
+        return params, state, tuple(input_shape)
+
+    def sharding_hints(self):
+        hints = {"w_gate": "expert", "w_up": "expert", "w_down": "expert"}
+        if self.shared is not None:
+            hints["shared"] = self.shared.sharding_hints()
+        return hints
+
+    def route(self, tokens_f32, router, bias):
+        """``(expert index, gate)``, each (n, top_k): see the class."""
+        logits = jnp.dot(tokens_f32, router,
+                         precision=jax.lax.Precision.HIGHEST)
+        scores = jax.nn.sigmoid(logits)
+        _, idx = jax.lax.top_k(scores + bias, self.top_k)
+        chosen = jnp.take_along_axis(scores, idx, axis=-1)
+        gates = chosen / (jnp.sum(chosen, axis=-1, keepdims=True) + 1e-20)
+        return idx, gates * self.routed_scaling
+
+    def apply(self, params, state, x, *, train=False, rng=None):
+        dt = resolve_dtype(self.dtype) or x.dtype
+        d = x.shape[-1]
+        flat = x.reshape(-1, d).astype(dt)
+        n, k, g = flat.shape[0], self.top_k, self.experts_held
+        rows = gmm.buffer_rows(n * k, g)
+        with jax.named_scope("route"):
+            idx, gates = self.route(
+                flat.astype(jnp.float32), maybe_dequantize(params["router"]),
+                state["router_bias"])
+            # (k, n), choice-major: see _gather_pairs.
+            local = idx.T - self.expert_offset
+            held = jnp.logical_and(local >= 0, local < g)
+            group = jnp.where(held, local, g).reshape(-1)
+            # Counting sort by expert: a pair's rank is how many earlier
+            # pairs chose its expert.
+            onehot = (group[:, None] == jnp.arange(g)[None]).astype(jnp.int32)
+            rank = jnp.sum((jnp.cumsum(onehot, axis=0) - onehot) * onehot,
+                           axis=1)
+            sizes = jnp.sum(onehot, axis=0)
+            row_starts, tile_group, tiles_used = gmm.group_layout(
+                sizes, rows // gmm.TILE_M)
+            start = jnp.take(row_starts, jnp.minimum(group, g - 1))
+            dest = jnp.where(held.reshape(-1), start + rank, rows)
+            # The inverse, pair of a buffer row: one scatter of n*k ints
+            # (pairs not held fall outside the buffer and are dropped).
+            src_pair = jnp.full((rows,), n * k, jnp.int32).at[dest].set(
+                jnp.arange(n * k, dtype=jnp.int32), mode="drop")
+            row_valid = src_pair < n * k
+            src_pair = jnp.minimum(src_pair, n * k - 1)
+            dest = jnp.where(held, dest.reshape(k, n), 0)
+            buf = _dispatch(flat, src_pair % n, row_valid, dest, held)
+        with jax.named_scope("experts"):
+            weight = lambda name: maybe_dequantize(params[name]).astype(dt)
+            hidden = jax.nn.silu(gmm.grouped_matmul(
+                buf, weight("w_gate"), tile_group, tiles_used)
+            ) * gmm.grouped_matmul(buf, weight("w_up"), tile_group, tiles_used)
+            out_buf = gmm.grouped_matmul(
+                hidden, weight("w_down"), tile_group, tiles_used)
+        with jax.named_scope("route"):
+            y = _combine(out_buf, jnp.where(held, gates.T, 0.0), dest,
+                         src_pair, row_valid)
+        if self.shared is not None:
+            with child_scope("shared"):
+                y = y + self.shared.apply(params["shared"], {}, flat)[0]
+        y = y.reshape(x.shape).astype(x.dtype)
+        if not train:
+            return y, {}
+        with jax.named_scope("route"):
+            loads = jnp.sum(
+                idx.reshape(-1)[:, None] == jnp.arange(self.num_experts)[None],
+                axis=0)
+            # noaux_tc: overloaded experts down a notch, underloaded up.
+            bias = state["router_bias"] + self.bias_update_rate * jnp.sign(
+                n * k / self.num_experts - loads.astype(jnp.float32))
+            new_state = dict(
+                state, router_bias=bias,
+                steps=state["steps"] + 1.0,
+                pairs=state["pairs"] + float(n * k),
+                held_rows=state["held_rows"] + jnp.sum(sizes).astype(
+                    jnp.float32),
+                load_max_sum=state["load_max_sum"] + jnp.max(loads).astype(
+                    jnp.float32),
+            )
+            if self.record_choice:
+                new_state["choice"] = idx.reshape(x.shape[:-1] + (k,))[0]
+        return y, new_state
+
+
+def counters(state) -> dict:
+    """``{layer path: {counter: value}}`` of every ``DroplessMoE`` in a
+    model's ``state`` tree, fetched from the device: call it outside a step
+    loop (``Model.fit`` does, once, when a fit ends)."""
+    out = {}
+
+    def walk(tree, path):
+        if not isinstance(tree, dict):
+            return
+        if all(c in tree for c in _COUNTERS):
+            values = jax.device_get({c: tree[c] for c in _COUNTERS})
+            out["/".join(path)] = {c: float(v) for c, v in values.items()}
+            return
+        for key, sub in tree.items():
+            walk(sub, path + (key,))
+
+    walk(state, ())
+    return out

@@ -120,6 +120,16 @@ def _p_ds(q, k, v, do, m, l, delta, valid, scale):
     return p, ds
 
 
+def _lane_pad(d: int) -> int:
+    return _round_up(max(d, _LANES), _LANES)
+
+
+def _pad_rows_lanes(x, t_pad: int, d_pad: int):
+    """(BH, T, D) zero-padded to (BH, t_pad, d_pad)."""
+    return jnp.pad(
+        x, ((0, 0), (0, t_pad - x.shape[1]), (0, d_pad - x.shape[2])))
+
+
 # ------------------------------------------------------------------ forward
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, m_out_ref, l_out_ref,
                 m_ref, l_ref, acc_ref,
@@ -184,19 +194,20 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, m_out_ref, l_out_ref,
 
 
 def _fwd_pallas(q, k, v, scale, causal, block_q, block_k):
-    """q,k,v: (BH, T, D). Returns (out, m_rows, l_rows) with m/l: (BH, T)."""
+    """q,k: (BH, T, D); v: (BH, T, Dv), Dv its own width (latent attention:
+    192-wide scores, 128-wide values). Returns (out (BH, T, Dv), m_rows,
+    l_rows) with m/l: (BH, T)."""
     bh, t, d = q.shape
+    dv = v.shape[-1]
     if max(block_q, block_k) % min(block_q, block_k):
         raise ValueError(
             f"block_q={block_q} and block_k={block_k} must divide each "
             "other, or trailing rows would fall outside the grid"
         )
     t_pad = _round_up(t, max(block_q, block_k))
-    d_pad = _round_up(max(d, 128), 128)
-    pad = lambda x: jnp.pad(
-        x, ((0, 0), (0, t_pad - t), (0, d_pad - d))
-    )
-    qp, kp, vp = pad(q), pad(k), pad(v)
+    d_pad, dv_pad = _lane_pad(d), _lane_pad(dv)
+    qp, kp, vp = (_pad_rows_lanes(x, t_pad, w) for x, w in (
+        (q, d_pad), (k, d_pad), (v, dv_pad)))
     nq = t_pad // block_q
     nk = t_pad // block_k
 
@@ -210,15 +221,15 @@ def _fwd_pallas(q, k, v, scale, causal, block_q, block_k):
         in_specs=[
             pl.BlockSpec((1, block_q, d_pad), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, block_k, d_pad), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, block_k, d_pad), lambda b, i, j: (b, j, 0)),
+            pl.BlockSpec((1, block_k, dv_pad), lambda b, i, j: (b, j, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, block_q, d_pad), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, block_q, dv_pad), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, block_q, 128), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, block_q, 128), lambda b, i, j: (b, i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, t_pad, d_pad), q.dtype),
+            jax.ShapeDtypeStruct((bh, t_pad, dv_pad), q.dtype),
             jax.ShapeDtypeStruct((bh, t_pad, 128), jnp.float32),
             jax.ShapeDtypeStruct((bh, t_pad, 128), jnp.float32),
         ],
@@ -226,7 +237,7 @@ def _fwd_pallas(q, k, v, scale, causal, block_q, block_k):
             # m, l, acc live across the sequential ki dimension.
             pltpu.VMEM((block_q, 128), jnp.float32),
             pltpu.VMEM((block_q, 128), jnp.float32),
-            pltpu.VMEM((block_q, d_pad), jnp.float32),
+            pltpu.VMEM((block_q, dv_pad), jnp.float32),
         ],
         name="dtpu_flash_fwd",
         interpret=_interpret(),
@@ -235,7 +246,7 @@ def _fwd_pallas(q, k, v, scale, causal, block_q, block_k):
     # (bh, t_pad, 128) kernel form is 128x larger and would dominate
     # forward->backward residual memory at long T; the backward
     # re-broadcasts transiently instead.
-    return out[:, :t, :d], m_out[:, :t, 0], l_out[:, :t, 0]
+    return out[:, :t, :dv], m_out[:, :t, 0], l_out[:, :t, 0]
 
 
 # ----------------------------------------------------------------- backward
@@ -332,11 +343,11 @@ def _bwd_pallas(res, g, *, scale, causal, block_q, block_k):
     innermost; dk/dv with q innermost), each O(T*D) HBM traffic."""
     q, k, v, out, m_rows, l_rows = res  # m/l: (bh, t)
     bh, t, d = q.shape
+    dv = v.shape[-1]
     t_pad = _round_up(t, max(block_q, block_k))
-    d_pad = _round_up(max(d, 128), 128)
-    pad = lambda x: jnp.pad(x, ((0, 0), (0, t_pad - t), (0, d_pad - d)))
-    qp, kp, vp = pad(q), pad(k), pad(v)
-    dop = pad(g.astype(q.dtype))
+    d_pad, dv_pad = _lane_pad(d), _lane_pad(dv)
+    qp, kp, vp, dop = (_pad_rows_lanes(x, t_pad, w) for x, w in (
+        (q, d_pad), (k, d_pad), (v, dv_pad), (g.astype(q.dtype), dv_pad)))
     nq = t_pad // block_q
     nk = t_pad // block_k
 
@@ -365,8 +376,8 @@ def _bwd_pallas(res, g, *, scale, causal, block_q, block_k):
         in_specs=[
             pl.BlockSpec((1, block_q, d_pad), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, block_k, d_pad), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, block_k, d_pad), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, block_q, d_pad), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, block_k, dv_pad), lambda b, i, j: (b, j, 0)),
+            pl.BlockSpec((1, block_q, dv_pad), lambda b, i, j: (b, i, 0)),
             row_spec, row_spec, row_spec,
         ],
         out_specs=pl.BlockSpec((1, block_q, d_pad), lambda b, i, j: (b, i, 0)),
@@ -377,7 +388,7 @@ def _bwd_pallas(res, g, *, scale, causal, block_q, block_k):
     )(qp, kp, vp, dop, m_b, l_b, dl_b)
 
     row_spec_kv = pl.BlockSpec((1, block_q, 128), lambda b, i, j: (b, j, 0))
-    dk, dv = pl.pallas_call(
+    dk, dv_out = pl.pallas_call(
         functools.partial(
             _dkv_kernel, scale=scale, block_q=block_q, block_k=block_k,
             t_actual=t, causal=causal, nq=nq,
@@ -386,26 +397,26 @@ def _bwd_pallas(res, g, *, scale, causal, block_q, block_k):
         in_specs=[
             pl.BlockSpec((1, block_q, d_pad), lambda b, i, j: (b, j, 0)),
             pl.BlockSpec((1, block_k, d_pad), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_k, d_pad), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_q, d_pad), lambda b, i, j: (b, j, 0)),
+            pl.BlockSpec((1, block_k, dv_pad), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, block_q, dv_pad), lambda b, i, j: (b, j, 0)),
             row_spec_kv, row_spec_kv, row_spec_kv,
         ],
         out_specs=[
             pl.BlockSpec((1, block_k, d_pad), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_k, d_pad), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, block_k, dv_pad), lambda b, i, j: (b, i, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((bh, t_pad, d_pad), k.dtype),
-            jax.ShapeDtypeStruct((bh, t_pad, d_pad), v.dtype),
+            jax.ShapeDtypeStruct((bh, t_pad, dv_pad), v.dtype),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_k, d_pad), jnp.float32),
-            pltpu.VMEM((block_k, d_pad), jnp.float32),
+            pltpu.VMEM((block_k, dv_pad), jnp.float32),
         ],
         name="dtpu_flash_dkv",
         interpret=_interpret(),
     )(qp, kp, vp, dop, m_b, l_b, dl_b)
-    return dq[:, :t, :d], dk[:, :t, :d], dv[:, :t, :d]
+    return dq[:, :t, :d], dk[:, :t, :d], dv_out[:, :t, :dv]
 
 
 # ------------------------------------------------- lane-packed (B,T,H*D) --
@@ -1057,8 +1068,11 @@ def flash_attention(
 ):
     """softmax(Q K^T / sqrt(d)) V without materializing the (T, T) scores.
 
-    q, k, v: (B, T, H, D) — same layout MultiHeadAttention produces.
-    Returns (B, T, H, D) in q's dtype. Scores/softmax compute in float32.
+    q, k: (B, T, H, D); v: (B, T, H, Dv) — the layout MultiHeadAttention
+    produces. Dv is D everywhere but under latent attention, whose keys
+    carry the rope part and are wider than its values (192 and 128): the
+    folded kernels take that as it is, each width padded to whole lanes.
+    Returns (B, T, H, Dv) in q's dtype. Scores/softmax compute in float32.
     Mosaic on TPU, the Pallas interpreter on CPU (the test configuration);
     any other backend is an error (``_pallas_common.interpret``).
 
@@ -1101,7 +1115,8 @@ def flash_attention(
     bk = min(block_k, _round_up(t, bq))
     if max(bq, bk) % min(bq, bk):  # clamping broke divisibility
         bq = bk = min(bq, bk)
-    if _packed_supported(h, d):
+    dv = v.shape[-1]
+    if dv == d and _packed_supported(h, d):
         # Lane-packed path: kernels read heads straight from the (B, T,
         # H*D) projection layout — the reshape is free, no transposes.
         # How far the sub-tile walk engages is static for a shape, so it is
@@ -1115,6 +1130,6 @@ def flash_attention(
             q.reshape(b, t, h * d), k.reshape(b, t, h * d),
             v.reshape(b, t, h * d),
         ).reshape(b, t, h, d)
-    fold = lambda x: jnp.moveaxis(x, 2, 1).reshape(b * h, t, d)
+    fold = lambda x: jnp.moveaxis(x, 2, 1).reshape(b * h, t, x.shape[-1])
     out = _flash(fold(q), fold(k), fold(v), causal, bq, bk)
-    return jnp.moveaxis(out.reshape(b, h, t, d), 1, 2)
+    return jnp.moveaxis(out.reshape(b, h, t, dv), 1, 2)

@@ -41,6 +41,7 @@ from ..nn.core import (
     apply_layers as _apply_layers,
     eval_sample_weights as _eval_sample_weights,
 )
+from ..nn import moe as moe_lib
 from ..ops import losses as losses_lib
 from ..ops import metrics as metrics_lib
 from ..parallel.strategy import SingleDevice, Strategy, current_strategy
@@ -1760,6 +1761,14 @@ class Model:
         # candidates' rationale (docs/PERF.md "Autotuned sharding").
         if self.last_plan is not None:
             report["plan"] = self.last_plan.summary()
+        # Dropless expert layers count in their state (no sync in the step
+        # loop); read once here: per layer in the report, the sums as gauges.
+        moe_counters = moe_lib.counters(self.state)
+        if moe_counters:
+            report["moe"] = moe_counters
+            for name in ("pairs", "held_rows"):
+                obs_reg.gauge(f"moe.{name}", sum(
+                    c[name] for c in moe_counters.values()))
         # The legacy dict is a VIEW stored in the metrics registry
         # (key-for-key identical — pinned by the obs parity test): one
         # telemetry surface, backward-compatible reader.

@@ -120,3 +120,65 @@ def test_every_operation_of_the_plain_step_is_under_a_scope(compiled):
             continue
         m = re.match(r"jit\(step\)/(?:transpose\()?(?:jvp\()?(\w*)", name)
         assert m and m.group(1) in tops, name
+
+
+# ------------------------------------------------- the DeepSeek-V3 block --
+@pytest.fixture(scope="module")
+def deepseek():
+    m = dtpu.Model(dtpu.models.deepseek_v3_lm(
+        64, num_layers=2, d_model=16, num_heads=2, kv_rank=8, nope_dim=8,
+        rope_dim=4, v_dim=8, d_ff=24, num_experts=8, experts_held=4,
+        expert_offset=2, top_k=2, moe_hidden=8, shared_experts=2))
+    m.compile(optimizer=dtpu.optim.Adam(1e-3),
+              loss="sparse_categorical_crossentropy", metrics=())
+    m.build((16,), seed=0)
+    x = np.zeros((4, 16), np.int32)
+    text = m.lower_train_step(x, x).compile().as_text()
+    return m, sorted(set(re.findall(r'op_name="(jit\(step\)[^"]*)"', text)))
+
+
+def test_deepseek_layers_scope_paths_are_their_parameter_paths(deepseek):
+    model, names = deepseek
+    paths = layer_paths(model.params)
+    for want in (("residual", "main", "multi_head_attention_latent"),
+                 ("residual", "main", "multi_head_attention_latent",
+                  "kv_norm"),
+                 ("residual_1", "main", "gated_mlp", "dense_2"),
+                 ("residual_3", "main", "moe"),
+                 ("residual_3", "main", "moe", "shared", "dense_1")):
+        assert want in paths
+    for path in paths:
+        top, rest = path[0], "/".join(path[1:])
+        tail = f"/{rest}/" if rest else "/"
+        assert has(names, rf"jit\(step\)/jvp\({top}\){tail}"), path
+        assert has(names, rf"jit\(step\)/transpose\(jvp\({top}\)\)/{JAX}"
+                          rf"{tail[1:]}"), path
+
+
+@pytest.mark.parametrize("inner,primitive", [
+    ("route", "top_k"), ("route", "gather"), ("route", "scatter"),
+    ("experts", "dtpu_gmm"), ("shared/dense", "dot_general"),
+])
+def test_the_expert_layer_names_its_routing_its_experts_and_its_shared(
+        deepseek, inner, primitive):
+    """``moe*/route`` holds the router, the top-k, the sort and the gathers
+    both ways; ``moe*/experts`` the grouped matmuls; ``moe*/shared`` the
+    shared gated MLP: ``benchmarks/scopes_moe.py`` splits the expert layers'
+    device time by them."""
+    _, names = deepseek
+    assert has(names, rf"jit\(step\)/jvp\(residual_3\)/main/moe/{inner}/"
+                      rf"{JAX}\w*{primitive}")
+    if primitive not in ("top_k", "scatter"):  # indices have no gradient
+        assert has(names, rf"jit\(step\)/transpose\(jvp\(residual_3\)\)/"
+                          rf"{JAX}main/moe/{inner}/")
+
+
+def test_every_operation_of_the_deepseek_step_is_under_a_scope(deepseek):
+    model, names = deepseek
+    tops = {p[0] for p in layer_paths(model.params)} | {
+        "cast", "loss", "metrics", "optimizer"}
+    for name in names:
+        if name == "jit(step)":
+            continue
+        m = re.match(r"jit\(step\)/(?:transpose\()?(?:jvp\()?(\w*)", name)
+        assert m and m.group(1) in tops, name

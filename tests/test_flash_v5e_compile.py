@@ -1,6 +1,8 @@
 """The packed flash kernels, forward and backward, compiled by the TPU's
 own compiler for a described (not attached) v5e at the benchmark cells'
-shapes and at the scoped-VMEM clamp shape. Nothing runs: this guards the
+shapes and at the scoped-VMEM clamp shape; and, for the latent-attention
+cell, the folded flash kernels at 192-wide keys and 128-wide values and the
+grouped-matmul kernels at the held experts' shapes. Nothing runs: this guards the
 16 MB scoped-VMEM limit and the lane / sublane alignment of the in-kernel
 sub-tile slices, which interpret mode cannot see, at no chip time
 (on-chip-measurement guide, third rehearsal; the whole step programs are
@@ -17,6 +19,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from distributed_tpu.ops import flash_attention as fa
+from distributed_tpu.ops import grouped_matmul as gm
 
 
 @pytest.fixture(scope="module")
@@ -38,6 +41,7 @@ def mosaic(monkeypatch):
     read back) and no cached custom_vjp from an interpret-mode test."""
     from jax.experimental.compilation_cache import compilation_cache
     monkeypatch.setattr(fa, "_interpret", lambda: False)
+    monkeypatch.setattr(gm, "_interpret", lambda: False)
     fa._packed_cached.cache_clear()
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
@@ -64,4 +68,47 @@ def test_packed_flash_grad_compiles_for_v5e(shape, one_chip, mosaic):
         x, x, x).compile().as_text()
     for name in ("dtpu_flash_fwd_packed", "dtpu_flash_dq_packed",
                  "dtpu_flash_dkv_packed"):
+        assert name in text
+
+
+def test_folded_flash_grad_at_latent_attentions_widths_compiles_for_v5e(
+        one_chip, mosaic):
+    """kanana2-30b.train.ep8share: (1, 4096, 32) heads, q and k 192 wide (in
+    256 lanes), v 128 wide; block_q 512, block_k 1024."""
+    qk = jax.ShapeDtypeStruct((1, 4096, 32, 192), jnp.bfloat16,
+                              sharding=one_chip)
+    v = jax.ShapeDtypeStruct((1, 4096, 32, 128), jnp.bfloat16,
+                             sharding=one_chip)
+
+    def loss(q, k, v):
+        out = fa.flash_attention(q, k, v, causal=True)
+        return jnp.sum(out.astype(jnp.float32))
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        qk, qk, v).compile()
+    text = compiled.as_text()
+    for name in ("dtpu_flash_fwd", "dtpu_flash_dq", "dtpu_flash_dkv"):
+        assert name in text
+    assert "_packed" not in text
+
+
+@pytest.mark.parametrize("k,n", [(2048, 768), (768, 2048)])
+def test_grouped_matmul_grad_compiles_for_v5e(k, n, one_chip, mosaic):
+    """The three kernels at the cell's shapes: 16 held experts, a buffer for
+    all 24,576 pairs of a step, gate/up (2048 -> 768) and down (768 ->
+    2048): the weight block, the f32 d rhs block and the row tiles fit the
+    16 MB a kernel may use."""
+    rows = gm.buffer_rows(4096 * 6, 16)
+    spec = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                     sharding=one_chip)
+
+    def loss(lhs, rhs, tile_group, used):
+        out = gm.grouped_matmul(lhs, rhs, tile_group, used)
+        return jnp.sum(out.astype(jnp.float32))
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        spec((rows, k), jnp.bfloat16), spec((16, k, n), jnp.bfloat16),
+        spec((rows // gm.TILE_M,), jnp.int32), spec((1,), jnp.int32)
+    ).compile().as_text()
+    for name in ("dtpu_gmm_nt", "dtpu_gmm_tn"):
         assert name in text
