@@ -3,49 +3,61 @@
 The dense attention path materializes the (B, H, T, T) score tensor in HBM —
 at T=8k and 12 heads that is the whole memory budget. This kernel computes
 softmax(QK^T)V with the online-softmax recurrence entirely in VMEM: the
-grid walks (batch*heads, q_blocks, kv_blocks) with the kv dimension
+grid walks (batch, head blocks, q_blocks, kv_blocks) with the kv dimension
 innermost and sequential, carrying the running max/sum/accumulator in
 scratch, so HBM traffic is O(T*D) instead of O(T^2).
 
 The backward pass is a pair of Pallas kernels (dq with the kv dimension
 innermost; dk/dv with the q dimension innermost) that recompute the
-probabilities in VMEM from the saved per-row statistics — flash-style
+probabilities in VMEM from the saved per-row statistic — flash-style
 rematerialization; HBM traffic stays O(T*D) and no (T, T) matrix ever
 exists. (The first implementation was a plain-JAX blockwise scan; on the
 TPU it ran at ~12% MFU per layer because XLA serialized the kv-block loop
 as a while op — the kernels keep the MXU busy instead.)
 
-Two families. The *folded* kernels take (B*H, T, D) and serve any head
-size; they skip causal work at the grain of a grid block and mask every
-block they compute. The *lane-packed* kernels (64- and 128-wide heads:
-every benchmark cell) read (B, T, H*D) as projected and hold a
-(block_q, block_k) tile of 128//D heads in VMEM a grid step; at T <= 1024
-that is the whole sequence in one step, so the grid has nothing to skip.
-They walk the tile in square sub-tiles of side ``_SUBTILE`` and treat each
-by where it lies (``_tile_class``), statically for a shape:
+One set of kernels, two layouts, told apart by what the kernels read from
+their refs' shapes (lanes a head block, heads that share it, a value width
+of its own). *Lane-packed* (64- and 128-wide heads: the GPT-2 cells):
+(B, T, H*D) as projected, a (block_q, block_k) tile of 128//D heads in
+VMEM a grid step. *Folded*, (B*H, T, D): every other shape, one head a
+block, q and k padded to whole lanes and v to its own (latent attention:
+192-wide keys in 256 lanes, 128-wide values), behind a transpose. A grid
+block is as large as VMEM allows (at T <= 1024 the whole sequence, at 4096
+8 x 4 blocks a head); the kernels walk it in square sub-tiles (``_subtile``:
+side ``_SUBTILE`` a 128 lanes of head block) and treat each by where it
+lies (``_tile_class``), statically for a shape:
 
 - above the diagonal (or wholly in the padding, when not causal): not
   computed, no product, no exp, no mask;
 - wholly at or below the diagonal and real: computed with no mask, and
-  merged with its neighbours of the same kind into one product a head;
+  merged with its neighbours of the same kind into one product a head,
+  along a row of sub-tiles and, where rows come out alike, across them
+  (``_passes``): a grid block the diagonal does not touch is one product;
 - crossed by the diagonal (or the padding edge): the only ones that build
   ``_valid_mask``.
 
 At T = 1024 and side 128 that is 36 of 64 sub-tiles computed, 8 of them
-masked (``subtile_counts``; published as the gauges
-``flash.subtiles_square`` / ``_computed`` / ``_masked``). ``causal=False``
-runs the same walk with every sub-tile of the second kind. What else the
-walk changed, same mathematics in the same precision (f32 scores,
-statistics and accumulators, bf16 MXU operands, exact divide):
+masked; at T = 4096, 528 of 1024 and 32, or 136 of 256 and 16 where the
+head block is 256 lanes and the side with it (``_subtile``,
+``subtile_counts``; published as the gauges ``flash.subtiles_square`` /
+``_computed`` / ``_masked``). How a grid block lies against the diagonal is
+one of a few static *views* (``_block_views``), each an unrolled walk under
+its own ``pl.when``; blocks above the diagonal run none. The walk is
+set-up time as well as kernel time: what it unrolls is traced and lowered. ``causal=False`` runs the same walk with every
+sub-tile of the second kind. What else the walk rests on, the mathematics
+in one precision throughout (f32 scores, statistics and accumulators, bf16
+MXU operands, exact divide, once a row):
 
-- one row statistic, lse = m + log l, is saved for the backward, whose
-  kernels compute p = exp(s - lse) with no per-score divide;
+- one row statistic, lse = m + log l, is saved for the backward, lane-major
+  (4 bytes a row and head), whose kernels compute p = exp(s - lse) with no
+  per-score divide;
 - 1/sqrt(D) is folded into q where that is exact in q's dtype (a power of
-  two: D = 64), and applied to every f32 score where not (D = 128);
-- a head's operand is not sliced out of the 128 lanes (a rotate per use)
-  but has the other heads' lanes zeroed, contracts over all 128 and
-  produces all 128, of which the head's own span is kept: the same MXU
-  passes, no XLU work, dense stores;
+  two: D = 64), and applied to every f32 score where not (D = 128, 192);
+- a head's operand is not sliced out of a shared lane block (a rotate per
+  use) but has the other heads' lanes zeroed, contracts over the whole block
+  and produces the whole block, of which the head's own span is kept: the
+  same MXU passes, no XLU work, dense stores; a head that has the block to
+  itself is taken as it stands;
 - dk/dv compute the score tile column-major (k q^T), so p^T and ds^T are
   left operands as they stand, and read their row statistics lane-major.
 
@@ -71,7 +83,6 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -91,8 +102,8 @@ def _valid_mask(row0, col0, rows, cols, t_actual, causal, kv_major=False):
     """(rows, cols) mask of the score tile whose first row and column are
     ``row0`` and ``col0``: real columns, and under causality the lower-
     triangular band. ``kv_major``: of the transposed tile, (cols, rows). The
-    single source of truth for masking across all six kernels (folded: a
-    grid block; packed: a run of sub-tiles of one)."""
+    single source of truth for masking: the three kernels build it over a
+    run of sub-tiles the diagonal or the padding edge crosses."""
     shape, r_ax, c_ax = ((cols, rows), 1, 0) if kv_major else (
         (rows, cols), 0, 1)
     col = col0 + jax.lax.broadcasted_iota(jnp.int32, shape, c_ax)
@@ -103,355 +114,63 @@ def _valid_mask(row0, col0, rows, cols, t_actual, causal, kv_major=False):
     return valid
 
 
-def _p_ds(q, k, v, do, m, l, delta, valid, scale):
-    """Backward-pass block math shared by all dq/dk/dv kernels: recompute
-    p from the saved row stats (flash-style), then ds = p*(dO V^T -
-    delta)*scale. q/do: (bq, d); k/v: (bk, d); m/l/delta: (bq, 1)."""
-    s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    ) * scale
-    p = jnp.where(valid, jnp.exp(s - m) / jnp.maximum(l, 1e-30), 0.0)
-    dp = jax.lax.dot_general(
-        do, v, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
-    ds = p * (dp - delta) * scale
-    return p, ds
-
-
 def _lane_pad(d: int) -> int:
     return _round_up(max(d, _LANES), _LANES)
 
 
-def _pad_rows_lanes(x, t_pad: int, d_pad: int):
-    """(BH, T, D) zero-padded to (BH, t_pad, d_pad)."""
+def _pad(x, t_pad: int, width: int):
+    """(b, T, n) zero-padded to (b, t_pad, width)."""
     return jnp.pad(
-        x, ((0, 0), (0, t_pad - x.shape[1]), (0, d_pad - x.shape[2])))
+        x, ((0, 0), (0, t_pad - x.shape[1]), (0, width - x.shape[2])))
 
 
-# ------------------------------------------------------------------ forward
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, m_out_ref, l_out_ref,
-                m_ref, l_ref, acc_ref,
-                *, scale, block_q, block_k, t_actual, causal, nk):
-    """One (bh, qi, ki) grid step. Scratch carries the online-softmax state
-    across the sequential ki dimension."""
-    qi = pl.program_id(1)
-    ki = pl.program_id(2)
-
-    @pl.when(ki == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, _NEG)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    # Causal: a kv block strictly above the diagonal band contributes
-    # nothing — skip its matmuls entirely (the scratch carries through).
-    def compute():
-        # Keep inputs in their storage dtype for the MXU (bf16 matmul with
-        # f32 accumulate); only the softmax recurrence runs in f32.
-        q = q_ref[0]  # (block_q, d_pad)
-        k = k_ref[0]  # (block_k, d_pad)
-        v = v_ref[0]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale  # (block_q, block_k) f32
-
-        valid = _valid_mask(qi * block_q, ki * block_k, block_q, block_k,
-                            t_actual, causal)
-        s = jnp.where(valid, s, _NEG)
-
-        m_prev = m_ref[...]  # (block_q, 128), all lanes equal
-        l_prev = l_ref[...]
-        m_cur = jnp.max(s, axis=-1, keepdims=True)  # (block_q, 1)
-        m_new = jnp.maximum(m_prev, jnp.broadcast_to(m_cur, m_prev.shape))
-        alpha = jnp.exp(m_prev[:, :1] - m_new[:, :1])  # (block_q, 1)
-        p = jnp.exp(s - m_new[:, :1])  # (block_q, block_k)
-        l_new = l_prev * alpha + jnp.broadcast_to(
-            jnp.sum(p, axis=-1, keepdims=True), l_prev.shape
-        )
-        acc_ref[...] = acc_ref[...] * alpha + jnp.dot(
-            p.astype(v.dtype), v, preferred_element_type=jnp.float32
-        )
-        m_ref[...] = m_new
-        l_ref[...] = l_new
-
-    if causal:
-        # Not taken only when the whole block is above the diagonal.
-        @pl.when(ki * block_k <= qi * block_q + block_q - 1)
-        def _():
-            compute()
-    else:
-        compute()
-
-    @pl.when(ki == nk - 1)
-    def _finish():
-        l = l_ref[:, :1]
-        o_ref[0] = (acc_ref[...] / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
-        m_out_ref[0] = m_ref[...]
-        l_out_ref[0] = l_ref[...]
-
-
-def _fwd_pallas(q, k, v, scale, causal, block_q, block_k):
-    """q,k: (BH, T, D); v: (BH, T, Dv), Dv its own width (latent attention:
-    192-wide scores, 128-wide values). Returns (out (BH, T, Dv), m_rows,
-    l_rows) with m/l: (BH, T)."""
-    bh, t, d = q.shape
-    dv = v.shape[-1]
-    if max(block_q, block_k) % min(block_q, block_k):
-        raise ValueError(
-            f"block_q={block_q} and block_k={block_k} must divide each "
-            "other, or trailing rows would fall outside the grid"
-        )
-    t_pad = _round_up(t, max(block_q, block_k))
-    d_pad, dv_pad = _lane_pad(d), _lane_pad(dv)
-    qp, kp, vp = (_pad_rows_lanes(x, t_pad, w) for x, w in (
-        (q, d_pad), (k, d_pad), (v, dv_pad)))
-    nq = t_pad // block_q
-    nk = t_pad // block_k
-
-    kernel = functools.partial(
-        _fwd_kernel, scale=scale, block_q=block_q, block_k=block_k,
-        t_actual=t, causal=causal, nk=nk,
-    )
-    out, m_out, l_out = pl.pallas_call(
-        kernel,
-        grid=(bh, nq, nk),
-        in_specs=[
-            pl.BlockSpec((1, block_q, d_pad), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_k, d_pad), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, block_k, dv_pad), lambda b, i, j: (b, j, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_q, dv_pad), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_q, 128), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_q, 128), lambda b, i, j: (b, i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((bh, t_pad, dv_pad), q.dtype),
-            jax.ShapeDtypeStruct((bh, t_pad, 128), jnp.float32),
-            jax.ShapeDtypeStruct((bh, t_pad, 128), jnp.float32),
-        ],
-        scratch_shapes=[
-            # m, l, acc live across the sequential ki dimension.
-            pltpu.VMEM((block_q, 128), jnp.float32),
-            pltpu.VMEM((block_q, 128), jnp.float32),
-            pltpu.VMEM((block_q, dv_pad), jnp.float32),
-        ],
-        name="dtpu_flash_fwd",
-        interpret=_interpret(),
-    )(qp, kp, vp)
-    # Residual stats are sliced to one value per row: the lane-replicated
-    # (bh, t_pad, 128) kernel form is 128x larger and would dominate
-    # forward->backward residual memory at long T; the backward
-    # re-broadcasts transiently instead.
-    return out[:, :t, :dv], m_out[:, :t, 0], l_out[:, :t, 0]
-
-
-# ----------------------------------------------------------------- backward
-def _dq_kernel(q_ref, k_ref, v_ref, do_ref, m_ref, l_ref, dl_ref, dq_ref,
-               acc_ref, *, scale, block_q, block_k, t_actual, causal, nk):
-    """dq for one (bh, qi, ki) grid step; ki sequential, acc in scratch.
-
-    p is recomputed from the saved row statistics (m, l) flash-style —
-    never a (T, T) tensor in HBM; ds = p * (dO V^T - delta) * scale;
-    dq += ds K."""
-    qi = pl.program_id(1)
-    ki = pl.program_id(2)
-
-    @pl.when(ki == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    def compute():
-        k = k_ref[0]
-        valid = _valid_mask(qi * block_q, ki * block_k, block_q, block_k,
-                            t_actual, causal)
-        _, ds = _p_ds(
-            q_ref[0], k, v_ref[0], do_ref[0],
-            m_ref[0][:, :1], l_ref[0][:, :1], dl_ref[0][:, :1],
-            valid, scale,
-        )
-        acc_ref[...] += jax.lax.dot_general(
-            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-
-    if causal:
-        @pl.when(ki * block_k <= qi * block_q + block_q - 1)
-        def _():
-            compute()
-    else:
-        compute()
-
-    @pl.when(ki == nk - 1)
-    def _finish():
-        dq_ref[0] = acc_ref[...].astype(dq_ref.dtype)
-
-
-def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, m_ref, l_ref, dl_ref,
-                dk_ref, dv_ref, acc_dk, acc_dv,
-                *, scale, block_q, block_k, t_actual, causal, nq):
-    """dk/dv for one (bh, ki, qi) grid step; qi sequential, accs in scratch.
-
-    dv += p^T dO; dk += ds^T q — both contractions over the q-block rows."""
-    ki = pl.program_id(1)
-    qi = pl.program_id(2)
-
-    @pl.when(qi == 0)
-    def _init():
-        acc_dk[...] = jnp.zeros_like(acc_dk)
-        acc_dv[...] = jnp.zeros_like(acc_dv)
-
-    def compute():
-        q = q_ref[0]
-        do = do_ref[0]
-        valid = _valid_mask(qi * block_q, ki * block_k, block_q, block_k,
-                            t_actual, causal)
-        p, ds = _p_ds(
-            q, k_ref[0], v_ref[0], do,
-            m_ref[0][:, :1], l_ref[0][:, :1], dl_ref[0][:, :1],
-            valid, scale,
-        )
-        acc_dv[...] += jax.lax.dot_general(
-            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )  # (bk, d)
-        acc_dk[...] += jax.lax.dot_general(
-            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )  # (bk, d)
-
-    if causal:
-        # Skip q blocks entirely above the diagonal band (no row of this
-        # q block can see any column of this kv block).
-        @pl.when(qi * block_q + block_q - 1 >= ki * block_k)
-        def _():
-            compute()
-    else:
-        compute()
-
-    @pl.when(qi == nq - 1)
-    def _finish():
-        dk_ref[0] = acc_dk[...].astype(dk_ref.dtype)
-        dv_ref[0] = acc_dv[...].astype(dv_ref.dtype)
-
-
-def _bwd_pallas(res, g, *, scale, causal, block_q, block_k):
-    """Pallas dq/dk/dv from the saved row stats: two kernels (dq with kv
-    innermost; dk/dv with q innermost), each O(T*D) HBM traffic."""
-    q, k, v, out, m_rows, l_rows = res  # m/l: (bh, t)
-    bh, t, d = q.shape
-    dv = v.shape[-1]
-    t_pad = _round_up(t, max(block_q, block_k))
-    d_pad, dv_pad = _lane_pad(d), _lane_pad(dv)
-    qp, kp, vp, dop = (_pad_rows_lanes(x, t_pad, w) for x, w in (
-        (q, d_pad), (k, d_pad), (v, dv_pad), (g.astype(q.dtype), dv_pad)))
-    nq = t_pad // block_q
-    nk = t_pad // block_k
-
-    # delta_i = sum_j dO_ij O_ij; m/l/delta broadcast across lanes into
-    # the kernels' (1, block_q, 128) row-stat form (transient buffers —
-    # only the (bh, t) stats are held as residuals from the forward).
-    delta = jnp.sum(
-        g.astype(jnp.float32) * out.astype(jnp.float32), axis=-1
-    )  # (bh, t)
-
-    def rowstat(x):
-        return jnp.broadcast_to(
-            jnp.pad(x, ((0, 0), (0, t_pad - t)))[..., None],
-            (bh, t_pad, 128),
-        )
-
-    m_b, l_b, dl_b = rowstat(m_rows), rowstat(l_rows), rowstat(delta)
-
-    row_spec = pl.BlockSpec((1, block_q, 128), lambda b, i, j: (b, i, 0))
-    dq = pl.pallas_call(
-        functools.partial(
-            _dq_kernel, scale=scale, block_q=block_q, block_k=block_k,
-            t_actual=t, causal=causal, nk=nk,
-        ),
-        grid=(bh, nq, nk),
-        in_specs=[
-            pl.BlockSpec((1, block_q, d_pad), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_k, d_pad), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, block_k, dv_pad), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, block_q, dv_pad), lambda b, i, j: (b, i, 0)),
-            row_spec, row_spec, row_spec,
-        ],
-        out_specs=pl.BlockSpec((1, block_q, d_pad), lambda b, i, j: (b, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((bh, t_pad, d_pad), q.dtype),
-        scratch_shapes=[pltpu.VMEM((block_q, d_pad), jnp.float32)],
-        name="dtpu_flash_dq",
-        interpret=_interpret(),
-    )(qp, kp, vp, dop, m_b, l_b, dl_b)
-
-    row_spec_kv = pl.BlockSpec((1, block_q, 128), lambda b, i, j: (b, j, 0))
-    dk, dv_out = pl.pallas_call(
-        functools.partial(
-            _dkv_kernel, scale=scale, block_q=block_q, block_k=block_k,
-            t_actual=t, causal=causal, nq=nq,
-        ),
-        grid=(bh, nk, nq),
-        in_specs=[
-            pl.BlockSpec((1, block_q, d_pad), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, block_k, d_pad), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_k, dv_pad), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_q, dv_pad), lambda b, i, j: (b, j, 0)),
-            row_spec_kv, row_spec_kv, row_spec_kv,
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_k, d_pad), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_k, dv_pad), lambda b, i, j: (b, i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((bh, t_pad, d_pad), k.dtype),
-            jax.ShapeDtypeStruct((bh, t_pad, dv_pad), v.dtype),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((block_k, d_pad), jnp.float32),
-            pltpu.VMEM((block_k, dv_pad), jnp.float32),
-        ],
-        name="dtpu_flash_dkv",
-        interpret=_interpret(),
-    )(qp, kp, vp, dop, m_b, l_b, dl_b)
-    return dq[:, :t, :d], dk[:, :t, :d], dv_out[:, :t, :dv]
-
-
-# ------------------------------------------------- lane-packed (B,T,H*D) --
-# The folded kernels above take (B*H, T, D) and therefore need a
-# (B,T,H,D) -> (B,H,T,D) transpose around every call — profiled at
-# 25-30% of a GPT-2-small training step on v5e (the transposes run at
-# ~150 GB/s and there are ~8 per layer). The kernels below read the
-# attention heads straight out of the projection layout (B, T, H*D):
-# each 128-lane block holds 128//D whole heads side by side, the grid
-# walks (batch, head-block, q-block, kv-block), and the per-head math
-# slices lanes in VMEM. No HBM transpose exists in either direction.
-# Requires 128 % D == 0 and H % (128//D) == 0 (covers head_dim 64/128);
-# other shapes fall back to the folded path.
+# ------------------------------------------------------ the sub-tile walk --
+# One set of kernels serves both layouts: they read how many lanes a head
+# block has from their refs, and how many heads share it from ``heads``.
+# Lane-packed, (B, T, H*D) as projected: each 128-lane block holds 128//D
+# whole heads side by side and the per-head math masks lanes in VMEM, so no
+# HBM transpose exists in either direction (the fold's ran at ~150 GB/s,
+# 25-30% of a GPT-2-small step). Requires 128 % D == 0 and H % (128//D) == 0
+# (head_dim 64/128). Folded, (B*H, T, D): any other shape; one head a block,
+# q and k padded to whole lanes and v to its own, behind a (B,T,H,D) ->
+# (B,H,T,D) transpose around the call. The grid walks (batch, head block,
+# q block, kv block) in both.
 #
 # Inside a grid step the (block_q, block_k) tile is walked in sub-tiles
 # (module docstring): block_q/block_k size the DMA, _SUBTILE the compute.
 
-# Side of the square sub-tile the packed kernels compute at a time; 128, a
-# lane tile, is the floor. Chosen on the v5e from the three kernels' device
-# time a call at the cells' shapes, bf16 causal (scripts/flash_kernel_times.py;
-# PR 26), fwd + dq + dkv in ms:
+# Side of the square sub-tile the kernels class and compute by, under a head
+# block of 128 lanes; 128, a lane tile, is the floor. Chosen on the v5e from
+# the three kernels' device time a call at the cells' shapes, bf16 causal
+# (scripts/flash_kernel_times.py; PR 26), fwd + dq + dkv in ms:
 #   (8, 1024, 16, 64): whole tile 1.994, 512: 1.302, 256: 1.094, 128: 1.028
 #   (4, 1024, 20, 64):                             256: 0.697, 128: 0.655
 # A smaller side skips more of the triangle (3/4, 10/16, 36/64 computed)
 # and pays more, smaller products; dq and dkv run at 90% MXU occupancy at
 # 128 and 256 alike (LLO bundle count), so there the smaller area wins.
 # At (1, 4096, 16, 64), block_q 512 (no cell), 256 is 3% ahead: 2.041, 2.114.
+# Folded at (1, 4096, 32, 192) with 128-wide values, q and k in 256 lanes
+# (PR 29; parent's whole (512, 1024) blocks 2.264 + 3.372 + 3.623 = 9.259):
+#   every row of sub-tiles a pass:  128: 7.831, 256: 7.587, 512: 7.701
+#   like rows merged (_passes):     128: 7.540, 256: 7.503
+# The forward alone read 2.453 / 2.186 / 2.062: a pass of 128 rows pays the
+# products' set-up and the statistics' round trip four times a block. With
+# merged passes the side matters on the blocks the diagonal crosses only;
+# at 256 lanes, side 256 runs as fast on 544/528 of the area, unrolls half
+# the passes (the cell's warm set-up: 24.8-25.4 s against 27.0, the parent's
+# 23.7-24.0: side 128 is past setup_s's bound) and measured 0.1% ahead in the
+# cell: hence _subtile's rule.
 _SUBTILE = 128
 
 
-def _subtile(block: int) -> int:
-    """Sub-tile side along a block of ``block`` rows or columns:
-    ``_SUBTILE`` where it divides the block, else the whole block (a block
-    that is smaller, or ragged against it, is one sub-tile)."""
-    return _SUBTILE if block % _SUBTILE == 0 else block
+def _subtile(block: int, lanes: int = _LANES) -> int:
+    """Sub-tile side along a block of ``block`` rows or columns, under a
+    head block of ``lanes`` lanes: ``_SUBTILE`` a lane tile of the head block
+    (a contraction twice as deep takes a tile twice the side) where that
+    divides the block, else the whole block (a block that is smaller, or
+    ragged against it, is one sub-tile)."""
+    side = _SUBTILE * (lanes // _LANES)
+    return side if block % side == 0 else block
 
 
 # How a sub-tile lies against what its rows may see.
@@ -490,14 +209,48 @@ def _segments(classes, size):
     return segs
 
 
+# Scores one pass of a kernel may hold, the heads alive together counted: a
+# (1024, 1024) block of one head, the largest the default blocks make. Twice
+# that (two 64-wide heads) does not fit the v5e's 16 MB of scoped VMEM (v5e
+# compile, PR 29: dkv at (8, 1024, 16, 64), not causal).
+_PASS_SCORES = 1024 * 1024
+
+
+def _passes(blocks, subs, heads, diag, edge, kv_major=False):
+    """[(start, stop, segs)] down a grid block of ``blocks`` = (block_q,
+    block_k) in sub-tiles of ``subs`` = (sub_q, sub_k): for each row of
+    sub-tiles its ``_segments`` along the columns (``kv_major``: for each
+    column, along the rows), with neighbouring rows whose segments are alike
+    merged into one pass while ``_PASS_SCORES`` holds it with ``heads`` heads
+    a block: a grid block the diagonal does not touch is one product as tall
+    as it is wide. ``segs`` is empty where nothing is to compute."""
+    (block, lim), (sub, sub_lim) = (
+        (blocks[::-1], subs[::-1]) if kv_major else (blocks, subs))
+    most = _PASS_SCORES // (lim * min(heads, 2))  # alive: _head_groups
+    passes = []
+    for i in range(0, block, sub):
+        segs = _segments([
+            _tile_class(*((j, subs[0], i) if kv_major else (i, subs[0], j)),
+                        subs[1], diag, edge)
+            for j in range(0, lim, sub_lim)], sub_lim)
+        if passes and passes[-1][1:] == (i, segs) and (
+                i + sub - passes[-1][0] <= most):
+            passes[-1] = (passes[-1][0], i + sub, segs)
+        else:
+            passes.append((i, i + sub, segs))
+    return passes
+
+
 @functools.lru_cache(maxsize=64)
-def subtile_counts(t: int, block_q: int, block_k: int, causal: bool):
+def subtile_counts(t: int, block_q: int, block_k: int, causal: bool,
+                   lanes: int = _LANES):
     """(square, computed, masked): the sub-tiles of the padded T x T score
-    square for these blocks, those the packed kernels compute (the rest lie
+    square for these blocks and a head block of ``lanes`` lanes (q's and
+    k's), those the kernels compute (the rest lie
     wholly above the diagonal, or wholly in the padding when not causal)
     and, of the computed, those that build a mask (the diagonal crosses
     them, or the padding edge when not causal). Static for a shape."""
-    sub_q, sub_k = _subtile(block_q), _subtile(block_k)
+    sub_q, sub_k = _subtile(block_q, lanes), _subtile(block_k, lanes)
     t_pad = _round_up(t, max(block_q, block_k))
     diag, edge = (0, None) if causal else (None, t)
     classes = [
@@ -550,22 +303,25 @@ def _scale_folds(scale: float) -> bool:
     return math.frexp(scale)[0] == 0.5
 
 
-def _head_lanes(hd):
-    return [slice(hx * hd, (hx + 1) * hd) for hx in range(_LANES // hd)]
+def _head_lanes(ref, heads):
+    """Each head's lane span in a block of ``heads`` heads side by side."""
+    hd = ref.shape[-1] // heads
+    return [slice(hx * hd, (hx + 1) * hd) for hx in range(heads)]
 
 
 def _head_groups(lanes):
     """Heads whose score tiles are alive together: two (every head of a
-    64- or 128-wide block), so that narrower heads do not multiply the VMEM
-    the walk needs."""
+    block there is), so that narrower heads would not multiply the VMEM the
+    walk needs."""
     return [lanes[i:i + 2] for i in range(0, len(lanes), 2)]
 
 
 def _own_lanes(x, sl):
-    """``x`` (rows, 128) with the lanes of every head but ``sl`` zeroed: a
-    contraction over all 128 lanes is then that head's alone, at the MXU
-    cost of the 64-wide one and without the lane slice's rotate to lane 0."""
-    if sl.stop - sl.start == _LANES:
+    """``x`` (rows, lanes) with the lanes of every head but ``sl`` zeroed: a
+    contraction over all the lanes is then that head's alone, at the MXU
+    cost of the 64-wide one and without the lane slice's rotate to lane 0.
+    A head that has the block to itself is ``x``."""
+    if sl.stop - sl.start == x.shape[-1]:
         return x
     lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
     own = jnp.logical_and(lane >= sl.start, lane < sl.stop)
@@ -573,21 +329,22 @@ def _own_lanes(x, sl):
 
 
 def _by_head(lanes, per_head):
-    """One (rows, 128) array that holds, in each head's lane span, that
-    head's entry of ``per_head`` ((rows, 128) or (rows, 1) each). A product
-    with a whole (.., 128) lane block on its right is right in the lanes of
-    the head whose probabilities were on its left, and only there."""
+    """One (rows, lanes) array that holds, in each head's lane span, that
+    head's entry of ``per_head`` ((rows, lanes) or (rows, 1) each). A product
+    with a whole lane block on its right is right in the lanes of the head
+    whose probabilities were on its left, and only there."""
+    width = lanes[-1].stop
     out = per_head[0]
     for sl, x in zip(lanes[1:], per_head[1:]):
-        shape = (x.shape[0], _LANES)
+        shape = (x.shape[0], width)
         lane = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
         out = jnp.where(lane >= sl.start, jnp.broadcast_to(x, shape),
                         jnp.broadcast_to(out, shape))
-    return jnp.broadcast_to(out, (out.shape[0], _LANES))
+    return jnp.broadcast_to(out, (out.shape[0], width))
 
 
 def _stat_columns(stat, lanes):
-    """A row statistic as the q-major kernels use it, (rows, 128) with each
+    """A row statistic as the q-major kernels use it, (rows, lanes) with each
     head's value across its lane span, from how it is stored, lane-major
     (heads, rows): one XLU transpose, no HBM traffic."""
     rows = stat.shape[-1]
@@ -607,7 +364,7 @@ def _dot(a, b):
 
 
 def _p_ds_lse(a, b, c, d, lse, delta, valid, scale):
-    """The packed backward kernels' segment math: p = exp(a b^T - lse) from
+    """The backward kernels' segment math: p = exp(a b^T - lse) from
     the one saved row statistic, ds = p * (c d^T - delta). Row-major (dq):
     a, b, c, d = q, k, dO, v and lse, delta are columns; column-major (dkv):
     k, q, v, dO and rows. ``valid`` is None on a segment that needs no
@@ -633,44 +390,45 @@ def _walk_blocks(kernel_walk, one_block, views, qi, ki):
         pl.when(in_view(qi, ki))(functools.partial(kernel_walk, *view))
 
 
-def _fwd_kernel_packed(q_ref, k_ref, v_ref, o_ref, lse_out_ref,
-                       m_ref, l_ref, acc_ref, *, scale, hd, block_q,
-                       block_k, t_actual, causal, nq, nk):
-    """One (b, hblk, qi, ki) grid step on (1, block, 128) lane-packed tiles;
-    the 128 lanes hold 128//hd heads. Each q sub-tile takes the kv columns
-    its rows see in at most two matrix products a head, an unmasked run
-    and a masked one, under one row maximum. Scratch carries m, l and acc,
-    each head's replicated across its lane span, between the sequential ki
-    steps; a grid of one block needs neither scratch nor ``pl.when``."""
+def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_out_ref, m_ref, l_ref,
+                acc_ref, *, scale, heads, block_q, block_k, t_actual,
+                causal, nq, nk):
+    """One (b, hblk, qi, ki) grid step on (1, block, lanes) tiles of
+    ``heads`` heads side by side: q and k as wide as each other, v, and with
+    it the output and the statistics, as wide as itself. Each q sub-tile
+    takes the kv columns its rows see in at most two matrix products a
+    head, an unmasked run and a masked one, under one row maximum. Scratch
+    carries m, l and acc, each head's replicated across its span of v's
+    lanes, between the sequential ki steps; a grid of one block needs
+    neither scratch nor ``pl.when``."""
     qi = pl.program_id(2)
     ki = pl.program_id(3)
-    sub_q, sub_k = _subtile(block_q), _subtile(block_k)
-    lanes = _head_lanes(hd)
+    subs = [_subtile(x, q_ref.shape[-1]) for x in (block_q, block_k)]
+    v_lanes = _head_lanes(v_ref, heads)
+    lanes = list(zip(_head_lanes(q_ref, heads), v_lanes))
     fold = _scale_folds(scale)
     one_block = nq == 1 and nk == 1
 
     def finish(rows, m, l, acc):
         l = jnp.maximum(l, 1e-30)
         o_ref[0, rows, :] = (acc / l).astype(o_ref.dtype)
-        lse = (m + jnp.log(l)).T  # (128, rows): a head's value, lane-major
-        for hx, sl in enumerate(lanes):
+        lse = (m + jnp.log(l)).T  # (lanes, rows): a head's value, lane-major
+        for hx, sl in enumerate(v_lanes):
             lse_out_ref[0, 0, hx:hx + 1, rows] = lse[sl.start:sl.start + 1]
 
     def walk(diag, edge):
-        for r0 in reversed(range(0, block_q, sub_q)):
-            segs = _segments([
-                _tile_class(r0, sub_q, c0, sub_k, diag, edge)
-                for c0 in range(0, block_k, sub_k)], sub_k)
+        for r0, r1, segs in reversed(_passes(
+                (block_q, block_k), subs, heads, diag, edge)):
             if not segs:
                 continue
-            rows = slice(r0, r0 + sub_q)
+            rows = slice(r0, r1)
             q = q_ref[0, rows, :]
             if fold:
                 q = q * scale
             ks = [k_ref[0, c0:c1, :] for c0, c1, _ in segs]
             vs = [v_ref[0, c0:c1, :] for c0, c1, _ in segs]
             valid = [
-                _valid_mask(qi * block_q + r0, ki * block_k + c0, sub_q,
+                _valid_mask(qi * block_q + r0, ki * block_k + c0, r1 - r0,
                             c1 - c0, t_actual, causal) if masked else None
                 for c0, c1, masked in segs]
             # Both heads' scores, then both softmaxes, then both P V: the
@@ -678,8 +436,8 @@ def _fwd_kernel_packed(q_ref, k_ref, v_ref, o_ref, lse_out_ref,
             done = []
             for group in _head_groups(lanes):
                 scores = []
-                for sl in group:
-                    qh = _own_lanes(q, sl)
+                for slq, _ in group:
+                    qh = _own_lanes(q, slq)
                     ss = [_nt(qh, k) for k in ks]  # (sub_q, c1 - c0) f32
                     if not fold:
                         ss = [s * scale for s in ss]
@@ -687,18 +445,18 @@ def _fwd_kernel_packed(q_ref, k_ref, v_ref, o_ref, lse_out_ref,
                         s if ok is None else jnp.where(ok, s, _NEG)
                         for s, ok in zip(ss, valid)])
                 stats = []
-                for sl, ss in zip(group, scores):
+                for (_, slv), ss in zip(group, scores):
                     m = functools.reduce(jnp.maximum, [
                         jnp.max(s, axis=-1, keepdims=True) for s in ss])
                     if not one_block:
                         m = jnp.maximum(
-                            m_ref[rows, sl.start:sl.start + 1], m)
+                            m_ref[rows, slv.start:slv.start + 1], m)
                     ps = [jnp.exp(s - m) for s in ss]
                     l = sum(jnp.sum(p, axis=-1, keepdims=True) for p in ps)
                     stats.append((m, l, ps))
                 done += [(m, l, sum(_dot(p, v) for p, v in zip(ps, vs)))
                          for m, l, ps in stats]
-            m, l, acc = (_by_head(lanes, x) for x in zip(*done))
+            m, l, acc = (_by_head(v_lanes, x) for x in zip(*done))
             if one_block:
                 finish(rows, m, l, acc)
                 continue
@@ -724,15 +482,16 @@ def _fwd_kernel_packed(q_ref, k_ref, v_ref, o_ref, lse_out_ref,
             finish(slice(None), m_ref[...], l_ref[...], acc_ref[...])
 
 
-def _dq_kernel_packed(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref,
-                      dq_ref, acc_ref, *, scale, hd, block_q, block_k,
-                      t_actual, causal, nq, nk):
+def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, dq_ref,
+               acc_ref, *, scale, heads, block_q, block_k, t_actual, causal,
+               nq, nk):
     """dq for one (b, hblk, qi, ki) grid step, walked like the forward: per
     q sub-tile, dq += ds K over the kv columns its rows see."""
     qi = pl.program_id(2)
     ki = pl.program_id(3)
-    sub_q, sub_k = _subtile(block_q), _subtile(block_k)
-    lanes = _head_lanes(hd)
+    subs = [_subtile(x, q_ref.shape[-1]) for x in (block_q, block_k)]
+    q_lanes, v_lanes = _head_lanes(q_ref, heads), _head_lanes(v_ref, heads)
+    lanes = list(zip(q_lanes, v_lanes))
     fold = _scale_folds(scale)
     one_block = nq == 1 and nk == 1
 
@@ -742,15 +501,13 @@ def _dq_kernel_packed(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref,
         dq_ref[0, rows, :] = acc.astype(dq_ref.dtype)
 
     def walk(diag, edge):
-        lses = _stat_columns(lse_ref[0, 0], lanes)
-        deltas = _stat_columns(dl_ref[0, 0], lanes)
-        for r0 in range(0, block_q, sub_q):
-            segs = _segments([
-                _tile_class(r0, sub_q, c0, sub_k, diag, edge)
-                for c0 in range(0, block_k, sub_k)], sub_k)
+        lses = _stat_columns(lse_ref[0, 0], v_lanes)
+        deltas = _stat_columns(dl_ref[0, 0], v_lanes)
+        for r0, r1, segs in _passes(
+                (block_q, block_k), subs, heads, diag, edge):
             if not segs:
                 continue
-            rows = slice(r0, r0 + sub_q)
+            rows = slice(r0, r1)
             q = q_ref[0, rows, :]
             if fold:
                 q = q * scale
@@ -758,23 +515,23 @@ def _dq_kernel_packed(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref,
             ks = [k_ref[0, c0:c1, :] for c0, c1, _ in segs]
             vs = [v_ref[0, c0:c1, :] for c0, c1, _ in segs]
             valid = [
-                _valid_mask(qi * block_q + r0, ki * block_k + c0, sub_q,
+                _valid_mask(qi * block_q + r0, ki * block_k + c0, r1 - r0,
                             c1 - c0, t_actual, causal) if masked else None
                 for c0, c1, masked in segs]
             accs = []
             for group in _head_groups(lanes):
                 dss = []
-                for sl in group:
-                    qh, doh = _own_lanes(q, sl), _own_lanes(do, sl)
-                    lse = lses[rows, sl.start:sl.start + 1]
-                    delta = deltas[rows, sl.start:sl.start + 1]
+                for slq, slv in group:
+                    qh, doh = _own_lanes(q, slq), _own_lanes(do, slv)
+                    lse = lses[rows, slv.start:slv.start + 1]
+                    delta = deltas[rows, slv.start:slv.start + 1]
                     dss.append([
                         _p_ds_lse(qh, k, doh, v, lse, delta, ok,
                                   None if fold else scale)[1]
                         for k, v, ok in zip(ks, vs, valid)])
                 accs += [sum(_dot(ds, k) for ds, k in zip(ds_h, ks))
                          for ds_h in dss]
-            acc = _by_head(lanes, accs)
+            acc = _by_head(q_lanes, accs)
             if one_block:
                 finish(rows, acc)
             else:
@@ -795,9 +552,9 @@ def _dq_kernel_packed(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref,
             finish(slice(None), acc_ref[...])
 
 
-def _dkv_kernel_packed(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref,
-                       dk_ref, dv_ref, acc_dk, acc_dv, *, scale, hd,
-                       block_q, block_k, t_actual, causal, nq, nk):
+def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, dk_ref,
+                dv_ref, acc_dk, acc_dv, *, scale, heads, block_q, block_k,
+                t_actual, causal, nq, nk):
     """dk/dv for one (b, hblk, ki, qi) grid step, the transpose of the dq
     walk: per kv sub-tile, dv += p^T dO and dk += ds^T q over the q rows
     that see its columns. The score tile is computed column-major (k q^T),
@@ -805,17 +562,16 @@ def _dkv_kernel_packed(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref,
     the row statistics come lane-major, (heads, rows)."""
     ki = pl.program_id(2)
     qi = pl.program_id(3)
-    sub_q, sub_k = _subtile(block_q), _subtile(block_k)
-    lanes = _head_lanes(hd)
+    subs = [_subtile(x, q_ref.shape[-1]) for x in (block_q, block_k)]
+    q_lanes, v_lanes = _head_lanes(q_ref, heads), _head_lanes(v_ref, heads)
+    lanes = list(enumerate(zip(q_lanes, v_lanes)))
     fold = _scale_folds(scale)
     one_block = nq == 1 and nk == 1
 
     def walk(diag, edge):
-        for c0 in range(0, block_k, sub_k):
-            cols = slice(c0, c0 + sub_k)
-            segs = _segments([
-                _tile_class(r0, sub_q, c0, sub_k, diag, edge)
-                for r0 in range(0, block_q, sub_q)], sub_q)
+        for c0, c1, segs in _passes(
+                (block_q, block_k), subs, heads, diag, edge, kv_major=True):
+            cols = slice(c0, c1)
             if not segs:
                 if one_block:  # padding columns: nothing sees them
                     dk_ref[0, cols, :] = jnp.zeros_like(dk_ref[0, cols, :])
@@ -829,15 +585,14 @@ def _dkv_kernel_packed(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref,
             dos = [do_ref[0, r0:r1, :] for r0, r1, _ in segs]
             valid = [
                 _valid_mask(qi * block_q + r0, ki * block_k + c0, r1 - r0,
-                            sub_k, t_actual, causal, kv_major=True)
+                            c1 - c0, t_actual, causal, kv_major=True)
                 if masked else None
                 for r0, r1, masked in segs]
             dvs, dks = [], []
             for group in _head_groups(lanes):
                 p_ds = []
-                for sl in group:
-                    hx = sl.start // hd
-                    kh, vh = _own_lanes(k, sl), _own_lanes(v, sl)
+                for hx, (slq, slv) in group:
+                    kh, vh = _own_lanes(k, slq), _own_lanes(v, slv)
                     p_ds.append([
                         _p_ds_lse(
                             kh, q, vh, do, lse_ref[0, 0, hx:hx + 1, r0:r1],
@@ -849,7 +604,7 @@ def _dkv_kernel_packed(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref,
                         for h in p_ds]
                 dks += [sum(_dot(ds, q) for (_, ds), q in zip(h, qs))
                         for h in p_ds]
-            dv, dk = _by_head(lanes, dvs), _by_head(lanes, dks)
+            dv, dk = _by_head(v_lanes, dvs), _by_head(q_lanes, dks)
             if one_block:
                 dk_ref[0, cols, :] = dk.astype(dk_ref.dtype)
                 dv_ref[0, cols, :] = dv.astype(dv_ref.dtype)
@@ -874,177 +629,163 @@ def _dkv_kernel_packed(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref,
             dv_ref[0] = acc_dv[...].astype(dv_ref.dtype)
 
 
-def _packed_specs(t, h, d, block_q, block_k):
-    """What the three packed pallas_calls share: (t_pad, nh, nq, nk) and
-    the block specs for the grid (b, head block, i, j), by which of i / j
-    walks the q blocks. Tensors are (B, T, H*D) blocks of 128 lanes; a row
-    statistic is (B, nh, heads a block, t_pad), lane-major."""
+def _specs(q, v, heads, hpb, causal, block_q, block_k):
+    """What the three pallas_calls share, for q (b, T, heads * D) and v
+    (b, T, heads * Dv) with ``hpb`` heads a block: (t_pad, nh, w, wv), the
+    kernels' static arguments, and the block specs for the grid (b, head
+    block, i, j), by which of i / j walks the q blocks. A head block is
+    ``hpb`` heads padded to whole lanes, w for q and k, wv for v; a row
+    statistic is (b, nh, hpb, t_pad), lane-major."""
+    t = q.shape[1]
+    if max(block_q, block_k) % min(block_q, block_k):
+        raise ValueError(
+            f"block_q={block_q} and block_k={block_k} must divide each "
+            "other, or trailing rows would fall outside the grid")
     t_pad = _round_up(t, max(block_q, block_k))
-    hpb = _LANES // d
+    w, wv = (_lane_pad(x.shape[-1] // heads * hpb) for x in (q, v))
     nq, nk = t_pad // block_q, t_pad // block_k
     if not _interpret() and nq > 1 and block_q % _LANES:
         raise ValueError(
-            f"block_q={block_q}: the packed kernels keep their row "
-            "statistics lane-major, so a q block that is not the whole "
-            f"sequence has to be a multiple of {_LANES} rows")
+            f"block_q={block_q}: the kernels keep their row statistics "
+            "lane-major, so a q block that is not the whole sequence has "
+            f"to be a multiple of {_LANES} rows")
 
     def specs(q_axis):
         kv_axis = 5 - q_axis  # grid axes 2 and 3
         at = lambda axis: lambda *g: (g[0], g[axis], g[1])
         return (
-            pl.BlockSpec((1, block_q, _LANES), at(q_axis)),
-            pl.BlockSpec((1, block_k, _LANES), at(kv_axis)),
+            *(pl.BlockSpec((1, block_q, x), at(q_axis)) for x in (w, wv)),
+            *(pl.BlockSpec((1, block_k, x), at(kv_axis)) for x in (w, wv)),
             pl.BlockSpec((1, 1, hpb, block_q),
                          lambda *g: (g[0], g[1], 0, g[q_axis])))
 
-    return t_pad, h // hpb, nq, nk, specs
+    kernel_args = dict(
+        # A Python float: a NumPy scalar is no weak type, and q * scale
+        # would promote the MXU's bf16 operand to f32.
+        scale=1.0 / math.sqrt(q.shape[-1] // heads), heads=hpb,
+        block_q=block_q, block_k=block_k, t_actual=t, causal=causal,
+        nq=nq, nk=nk)
+    return t_pad, heads // hpb, w, wv, kernel_args, specs
 
 
-def _fwd_pallas_packed(qf, kf, vf, h, d, scale, causal, block_q, block_k):
-    """qf,kf,vf: (B, T, H*D) lane-packed. Returns (out, lse) with out in
-    the same layout and the one row statistic the backward needs, lse = m +
-    log l, as (B, H//hpb, hpb, t_pad): 4 bytes a row and head."""
-    b, t, _ = qf.shape
-    t_pad, nh, nq, nk, specs = _packed_specs(t, h, d, block_q, block_k)
-    pad = lambda x: jnp.pad(x, ((0, 0), (0, t_pad - t), (0, 0)))
-    lane_q, lane_k, stat = specs(q_axis=2)
+def _fwd_pallas(q, k, v, *, heads, hpb, suffix, causal, block_q, block_k):
+    """q, k: (b, T, heads * D); v: (b, T, heads * Dv), Dv its own width
+    (latent attention: 192-wide scores, 128-wide values); lane-packed
+    (heads = H, hpb = 128 // D) or folded (b = B*H, heads = hpb = 1).
+    Returns (out, lse) with out in v's layout and the one row statistic the
+    backward needs, lse = m + log l, as (b, heads // hpb, hpb, t_pad): 4
+    bytes a row and head."""
+    b, t, _ = q.shape
+    t_pad, nh, w, wv, kernel_args, specs = _specs(
+        q, v, heads, hpb, causal, block_q, block_k)
+    nq, nk = kernel_args["nq"], kernel_args["nk"]
+    q_spec, o_spec, k_spec, v_spec, stat = specs(q_axis=2)
     out, lse = pl.pallas_call(
-        functools.partial(
-            _fwd_kernel_packed, scale=scale, hd=d, block_q=block_q,
-            block_k=block_k, t_actual=t, causal=causal, nq=nq, nk=nk,
-        ),
+        functools.partial(_fwd_kernel, **kernel_args),
         grid=(b, nh, nq, nk),
-        in_specs=[lane_q, lane_k, lane_k],
-        out_specs=[lane_q, stat],
+        in_specs=[q_spec, k_spec, v_spec],
+        out_specs=[o_spec, stat],
         out_shape=[
-            jax.ShapeDtypeStruct((b, t_pad, h * d), qf.dtype),
-            jax.ShapeDtypeStruct((b, nh, _LANES // d, t_pad), jnp.float32),
+            jax.ShapeDtypeStruct((b, t_pad, nh * wv), q.dtype),
+            jax.ShapeDtypeStruct((b, nh, hpb, t_pad), jnp.float32),
         ],
         scratch_shapes=[
             # m, l, acc between the sequential ki steps.
-            pltpu.VMEM((block_q, _LANES), jnp.float32),
-            pltpu.VMEM((block_q, _LANES), jnp.float32),
-            pltpu.VMEM((block_q, _LANES), jnp.float32),
+            pltpu.VMEM((block_q, wv), jnp.float32),
+            pltpu.VMEM((block_q, wv), jnp.float32),
+            pltpu.VMEM((block_q, wv), jnp.float32),
         ],
-        name="dtpu_flash_fwd_packed",
+        name="dtpu_flash_fwd" + suffix,
         interpret=_interpret(),
-    )(pad(qf), pad(kf), pad(vf))
-    return out[:, :t], lse
+    )(_pad(q, t_pad, nh * w), _pad(k, t_pad, nh * w),
+      _pad(v, t_pad, nh * wv))
+    return out[:, :t, :v.shape[-1]], lse
 
 
-def _bwd_pallas_packed(h, d, scale, causal, block_q, block_k, res, g):
-    qf, kf, vf, out, lse = res
-    b, t, _ = qf.shape
-    t_pad, nh, nq, nk, specs = _packed_specs(t, h, d, block_q, block_k)
-    pad = lambda x: jnp.pad(x, ((0, 0), (0, t_pad - t), (0, 0)))
-    qp, kp, vp = pad(qf), pad(kf), pad(vf)
-    dop = pad(g.astype(qf.dtype))
+def _bwd_pallas(res, g, *, heads, hpb, suffix, causal, block_q, block_k):
+    """dq, dk, dv from the saved row statistic: two kernels (dq with kv
+    innermost; dk/dv with q innermost), each O(T*D) HBM traffic."""
+    q, k, v, out, lse = res
+    b, t, _ = q.shape
+    t_pad, nh, w, wv, kernel_args, specs = _specs(
+        q, v, heads, hpb, causal, block_q, block_k)
+    nq, nk = kernel_args["nq"], kernel_args["nk"]
+    qp, kp = _pad(q, t_pad, nh * w), _pad(k, t_pad, nh * w)
+    vp, dop = _pad(v, t_pad, nh * wv), _pad(g.astype(q.dtype), t_pad, nh * wv)
 
     # delta_i = sum_j dO_ij O_ij per row and head, laid out like lse: XLA
     # reduces over a 64-wide minor dimension by making T minor first, so
     # this layout is the one it reaches with no copy after the reduce.
-    gf = g.astype(jnp.float32).reshape(b, t, nh, _LANES // d, d)
-    of = out.astype(jnp.float32).reshape(b, t, nh, _LANES // d, d)
+    by_head = (b, t, nh, hpb, v.shape[-1] // heads)
+    gf = g.astype(jnp.float32).reshape(by_head)
+    of = out.astype(jnp.float32).reshape(by_head)
     delta = jnp.transpose(jnp.sum(gf * of, axis=-1), (0, 2, 3, 1))
     delta = jnp.pad(delta, ((0, 0), (0, 0), (0, 0), (0, t_pad - t)))
 
-    kernel_args = dict(
-        scale=scale, hd=d, block_q=block_q, block_k=block_k, t_actual=t,
-        causal=causal, nq=nq, nk=nk)
-    lane_q, lane_k, stat = specs(q_axis=2)
+    q_spec, do_spec, k_spec, v_spec, stat = specs(q_axis=2)
     dq = pl.pallas_call(
-        functools.partial(_dq_kernel_packed, **kernel_args),
+        functools.partial(_dq_kernel, **kernel_args),
         grid=(b, nh, nq, nk),
-        in_specs=[lane_q, lane_k, lane_k, lane_q, stat, stat],
-        out_specs=lane_q,
-        out_shape=jax.ShapeDtypeStruct((b, t_pad, h * d), qf.dtype),
-        scratch_shapes=[pltpu.VMEM((block_q, _LANES), jnp.float32)],
-        name="dtpu_flash_dq_packed",
+        in_specs=[q_spec, k_spec, v_spec, do_spec, stat, stat],
+        out_specs=q_spec,
+        out_shape=jax.ShapeDtypeStruct((b, t_pad, nh * w), q.dtype),
+        scratch_shapes=[pltpu.VMEM((block_q, w), jnp.float32)],
+        name="dtpu_flash_dq" + suffix,
         interpret=_interpret(),
     )(qp, kp, vp, dop, lse, delta)
 
-    lane_q, lane_k, stat = specs(q_axis=3)
+    q_spec, do_spec, k_spec, v_spec, stat = specs(q_axis=3)
     dk, dv = pl.pallas_call(
-        functools.partial(_dkv_kernel_packed, **kernel_args),
+        functools.partial(_dkv_kernel, **kernel_args),
         grid=(b, nh, nk, nq),
-        in_specs=[lane_q, lane_k, lane_k, lane_q, stat, stat],
-        out_specs=[lane_k, lane_k],
+        in_specs=[q_spec, k_spec, v_spec, do_spec, stat, stat],
+        out_specs=[k_spec, v_spec],
         out_shape=[
-            jax.ShapeDtypeStruct((b, t_pad, h * d), kf.dtype),
-            jax.ShapeDtypeStruct((b, t_pad, h * d), vf.dtype),
+            jax.ShapeDtypeStruct((b, t_pad, nh * w), k.dtype),
+            jax.ShapeDtypeStruct((b, t_pad, nh * wv), v.dtype),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_k, _LANES), jnp.float32),
-            pltpu.VMEM((block_k, _LANES), jnp.float32),
+            pltpu.VMEM((block_k, w), jnp.float32),
+            pltpu.VMEM((block_k, wv), jnp.float32),
         ],
-        name="dtpu_flash_dkv_packed",
+        name="dtpu_flash_dkv" + suffix,
         interpret=_interpret(),
     )(qp, kp, vp, dop, lse, delta)
-    return dq[:, :t], dk[:, :t], dv[:, :t]
-
-
-def _make_packed(h, d, causal, block_q, block_k):
-    """custom_vjp fn over (B, T, H*D) arrays for this static config. Its
-    two halves are jitted: every layer of a model calls this one function
-    at one shape, so the kernels are traced and lowered once a program and
-    not once a layer (the walk is unrolled at trace time, and tracing is
-    set-up time)."""
-    # A Python float: a NumPy scalar is no weak type, and q * scale would
-    # promote the MXU's bf16 operand to f32.
-    scale = 1.0 / math.sqrt(d)
-
-    @jax.jit
-    def flash_fwd(qf, kf, vf):
-        return _fwd_pallas_packed(
-            qf, kf, vf, h, d, scale, causal, block_q, block_k)
-
-    @jax.jit
-    def flash_bwd(res, g):
-        return _bwd_pallas_packed(
-            h, d, scale, causal, block_q, block_k, res, g)
-
-    @jax.custom_vjp
-    def packed(qf, kf, vf):
-        return flash_fwd(qf, kf, vf)[0]
-
-    def fwd(qf, kf, vf):
-        out, lse = flash_fwd(qf, kf, vf)
-        return out, (qf, kf, vf, out, lse)
-
-    packed.defvjp(fwd, flash_bwd)
-    return packed
+    return (dq[:, :t, :q.shape[-1]], dk[:, :t, :k.shape[-1]],
+            dv[:, :t, :v.shape[-1]])
 
 
 @functools.lru_cache(maxsize=64)
-def _packed_cached(h, d, causal, block_q, block_k):
-    return _make_packed(h, d, causal, block_q, block_k)
+def _flash_cached(heads, hpb, suffix, causal, block_q, block_k):
+    """custom_vjp fn over (b, T, heads * D) arrays for this static config.
+    Its two halves are jitted: every layer of a model calls this one
+    function at one shape, so the kernels are traced and lowered once a
+    program and not once a layer (the walk is unrolled at trace time, and
+    tracing is set-up time)."""
+    static = dict(heads=heads, hpb=hpb, suffix=suffix, causal=causal,
+                  block_q=block_q, block_k=block_k)
+
+    @jax.jit
+    def flash_fwd(q, k, v):
+        return _fwd_pallas(q, k, v, **static)
+
+    @jax.jit
+    def flash_bwd(res, g):
+        return _bwd_pallas(res, g, **static)
+
+    @jax.custom_vjp
+    def flash(q, k, v):
+        return flash_fwd(q, k, v)[0]
+
+    def fwd(q, k, v):
+        out, lse = flash_fwd(q, k, v)
+        return out, (q, k, v, out, lse)
+
+    flash.defvjp(fwd, flash_bwd)
+    return flash
 
 
 # -------------------------------------------------------------------- public
-@functools.partial(
-    jax.custom_vjp, nondiff_argnums=(3, 4, 5)
-)
-def _flash(q, k, v, causal, block_q, block_k):
-    scale = 1.0 / np.sqrt(q.shape[-1])
-    out, _, _ = _fwd_pallas(q, k, v, scale, causal, block_q, block_k)
-    return out
-
-
-def _flash_fwd(q, k, v, causal, block_q, block_k):
-    scale = 1.0 / np.sqrt(q.shape[-1])
-    out, m_rows, l_rows = _fwd_pallas(q, k, v, scale, causal, block_q, block_k)
-    return out, (q, k, v, out, m_rows, l_rows)
-
-
-def _flash_bwd(causal, block_q, block_k, res, g):
-    scale = 1.0 / np.sqrt(res[0].shape[-1])
-    return _bwd_pallas(res, g, scale=scale, causal=causal,
-                       block_q=block_q, block_k=block_k)
-
-
-_flash.defvjp(_flash_fwd, _flash_bwd)
-
-
 def dense_attention(q, k, v, causal: bool):
     """Stock-XLA attention over (B, T, H, D) tensors — THE dense softmax
     path, shared by MultiHeadAttention's short-T branch and the Ulysses
@@ -1071,7 +812,7 @@ def flash_attention(
     q, k: (B, T, H, D); v: (B, T, H, Dv) — the layout MultiHeadAttention
     produces. Dv is D everywhere but under latent attention, whose keys
     carry the rope part and are wider than its values (192 and 128): the
-    folded kernels take that as it is, each width padded to whole lanes.
+    folded layout takes that as it is, each width padded to whole lanes.
     Returns (B, T, H, Dv) in q's dtype. Scores/softmax compute in float32.
     Mosaic on TPU, the Pallas interpreter on CPU (the test configuration);
     any other backend is an error (``_pallas_common.interpret``).
@@ -1081,8 +822,9 @@ def flash_attention(
     VMEM-clamped to 512 for float32 inputs (any length) and for bf16 above
     T=2048 (see the comment at the clamp). An EXPLICIT block_q is honored
     as passed — sweeps on chips with different VMEM budgets must measure
-    what they ask for. Inside a block the packed kernels compute by
-    sub-tiles of side ``_SUBTILE`` (module docstring), which is no argument:
+    what they ask for. Inside a block the kernels compute by sub-tiles
+    (``_subtile``: side ``_SUBTILE`` under a 128-lane head block), which is
+    no argument:
     what is skipped and what is masked follows from ``causal``, T and the
     blocks.
     """
@@ -1116,20 +858,21 @@ def flash_attention(
     if max(bq, bk) % min(bq, bk):  # clamping broke divisibility
         bq = bk = min(bq, bk)
     dv = v.shape[-1]
-    if dv == d and _packed_supported(h, d):
+    packed = dv == d and _packed_supported(h, d)
+    # How far the sub-tile walk engages is static for a shape, so it is
+    # published here, at trace time, as counts.
+    gauge = default_registry().gauge
+    for name, n in zip(("square", "computed", "masked"), subtile_counts(
+            t, bq, bk, causal, _LANES if packed else _lane_pad(d))):
+        gauge(f"flash.subtiles_{name}", n)
+    if packed:
         # Lane-packed path: kernels read heads straight from the (B, T,
         # H*D) projection layout — the reshape is free, no transposes.
-        # How far the sub-tile walk engages is static for a shape, so it is
-        # published here, at trace time, as counts.
-        gauge = default_registry().gauge
-        for name, n in zip(("square", "computed", "masked"),
-                           subtile_counts(t, bq, bk, causal)):
-            gauge(f"flash.subtiles_{name}", n)
-        packed = _packed_cached(h, d, causal, bq, bk)
-        return packed(
+        flash = _flash_cached(h, _LANES // d, "_packed", causal, bq, bk)
+        return flash(
             q.reshape(b, t, h * d), k.reshape(b, t, h * d),
             v.reshape(b, t, h * d),
         ).reshape(b, t, h, d)
     fold = lambda x: jnp.moveaxis(x, 2, 1).reshape(b * h, t, x.shape[-1])
-    out = _flash(fold(q), fold(k), fold(v), causal, bq, bk)
+    out = _flash_cached(1, 1, "", causal, bq, bk)(fold(q), fold(k), fold(v))
     return jnp.moveaxis(out.reshape(b, h, t, dv), 1, 2)

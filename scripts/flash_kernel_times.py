@@ -1,11 +1,14 @@
 #!/usr/bin/env python3
-"""Device time of the three packed flash kernels at one shape, by sub-tile
-side: how ``flash_attention._SUBTILE`` was chosen, to repeat on another chip
-or shape. Run on the chip (a TPU is required: kernel time comes from a
+"""Device time of the three flash kernels at one shape, by sub-tile side:
+how ``flash_attention._SUBTILE`` was chosen, to repeat on another chip or
+shape. Run on the chip (a TPU is required: kernel time comes from a
 profiler trace, read with ``benchmarks/trace.py``):
 
     chiprun -- python3 scripts/flash_kernel_times.py --subtiles 128,256,512
+    ... --shape 1,4096,32,192 --value-width 128    (latent attention: folded)
 
+``--subtiles`` sets ``_SUBTILE``, the side under a head block of 128 lanes (a
+folded head of 192 lanes sits in 256 and takes twice the side given).
 ``--root DIR`` imports ``distributed_tpu`` from another checkout (a parent
 commit unpacked beside this one; ``--subtiles 0`` leaves its module as it
 is). ``--check`` also compares values and gradients with
@@ -22,13 +25,14 @@ import sys
 import time
 
 HERE = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-KERNELS = ("dtpu_flash_fwd_packed", "dtpu_flash_dq_packed",
-           "dtpu_flash_dkv_packed")
+KERNELS = ("dtpu_flash_fwd", "dtpu_flash_dq", "dtpu_flash_dkv")
 
 
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--shape", default="8,1024,16,64")
+    ap.add_argument("--value-width", type=int, default=0,
+                    help="v's head width where it is not q's (0: q's)")
     ap.add_argument("--subtiles", default="0")
     ap.add_argument("--blocks", default="", help="block_q,block_k")
     ap.add_argument("--causal", type=int, default=1)
@@ -56,19 +60,21 @@ def main():
         kw["block_q"], kw["block_k"] = (int(x) for x in args.blocks.split(","))
     causal = bool(args.causal)
     rng = np.random.default_rng(0)
-    q, k, v, g = (jnp.asarray(rng.standard_normal(shape), jnp.bfloat16)
-                  for _ in range(4))
+    v_shape = shape[:3] + (args.value_width or shape[3],)
+    q, k, v, g = (jnp.asarray(rng.standard_normal(s), jnp.bfloat16)
+                  for s in (shape, shape, v_shape, v_shape))
 
     def loss(fn):
         return lambda q, k, v: jnp.sum(
             fn(q, k, v).astype(jnp.float32) * g.astype(jnp.float32))
 
+    from distributed_tpu.obs.registry import default_registry
     for s in (int(x) for x in args.subtiles.split(",")):
         if s:
             fa._SUBTILE = s
-        fa._packed_cached.cache_clear()
-        if hasattr(fa, "subtile_counts"):
-            fa.subtile_counts.cache_clear()
+        for cached in ("_flash_cached", "_packed_cached", "subtile_counts"):
+            if hasattr(fa, cached):  # by checkout: --root
+                getattr(fa, cached).cache_clear()
         flash = lambda q, k, v: fa.flash_attention(q, k, v, causal=causal,
                                                    **kw)
         step = jax.jit(jax.grad(loss(flash), argnums=(0, 1, 2)))
@@ -95,14 +101,16 @@ def main():
                 "causal": causal, "trace_s": round(trace_s, 3),
                 "compile_s": round(compile_s, 3),
                 "grad_host_ms": round(host_ms, 4)}
-        for name in KERNELS:
+        for name in KERNELS:  # folded, or with _packed behind the name
             ev = [e for e in trace_lib.matching(dev, name)]
             line[name.replace("dtpu_flash_", "") + "_ms"] = round(
                 1e3 * sum(e.seconds for e in ev) / max(len(ev), 1), 4)
             line[name.replace("dtpu_flash_", "") + "_calls"] = len(ev)
-        if hasattr(fa, "subtile_counts") and not kw:
-            line["subtiles"] = fa.subtile_counts(
-                shape[1], min(1024, shape[1]), min(1024, shape[1]), causal)
+        # What the call just traced published (None: this checkout's path
+        # for the shape publishes nothing).
+        line["subtiles"] = [
+            default_registry().gauge_value(f"flash.subtiles_{n}")
+            for n in ("square", "computed", "masked")]
         if args.check:
             dense = lambda q, k, v: fa.dense_attention(q, k, v, causal)
             f32 = lambda t: np.asarray(t.astype(jnp.float32))

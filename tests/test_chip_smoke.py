@@ -101,7 +101,7 @@ def test_cache_key_does_not_depend_on_the_call_site(
     q = jax.ShapeDtypeStruct((1, 512, 2, 64), jnp.bfloat16)
 
     def lower():
-        fa._packed_cached.cache_clear()
+        fa._flash_cached.cache_clear()
         return jax.jit(
             lambda q, k, v: fa.flash_attention(q, k, v, causal=True)
         ).trace(q, q, q).lower(lowering_platforms=("tpu",)).as_text()
@@ -114,7 +114,7 @@ def test_cache_key_does_not_depend_on_the_call_site(
     assert lower() != another_site()  # the parent's behaviour
     compile_cache.enable()
     assert lower() == another_site()
-    fa._packed_cached.cache_clear()
+    fa._flash_cached.cache_clear()
 
 
 def test_cache_key_carries_the_scope_scheme(monkeypatch,

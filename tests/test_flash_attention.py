@@ -27,10 +27,13 @@ def dense_attention(q, k, v, causal):
     return jnp.einsum("bhqk,bkhd->bqhd", a, v)
 
 
-def _qkv(shape, dtype=jnp.float32, seed=0):
+def _qkv(shape, dtype=jnp.float32, seed=0, value_dim=None):
+    """q, k of ``shape`` and v of it with ``value_dim`` columns a head."""
     rng = np.random.default_rng(seed)
+    v_shape = shape[:3] + (value_dim or shape[3],)
     return tuple(
-        jnp.asarray(rng.standard_normal(shape), dtype) for _ in range(3)
+        jnp.asarray(rng.standard_normal(s), dtype)
+        for s in (shape, shape, v_shape)
     )
 
 
@@ -177,7 +180,7 @@ def test_fused_xent_sharded_no_allgather(devices):
     assert abs(got - want) < 1e-5
 
 
-# (shape, block_q, block_k, sub-tile side): the packed kernels' sub-tile walk.
+# (shape, block_q, block_k, sub-tile side): the sub-tile walk, lane-packed.
 # The side is a module constant sized for the chip (a lane tile at least);
 # here it is patched down so that every sub-tile class (skipped, unmasked,
 # masked by the diagonal or by the padding edge) occurs at interpret-mode
@@ -203,7 +206,7 @@ def subtile(monkeypatch):
     from distributed_tpu.ops import flash_attention as fa
 
     def clear():
-        fa._packed_cached.cache_clear()
+        fa._flash_cached.cache_clear()
         fa.subtile_counts.cache_clear()
 
     def patch(side):
@@ -245,32 +248,152 @@ def test_packed_layout_matches_dense_values_and_grads(
                                    rtol=3e-4, atol=3e-4)
 
 
-@pytest.mark.parametrize("t,blocks,causal,want", [
-    (1024, (1024, 1024), True, (64, 36, 8)),    # both benchmark cells
-    (1024, (1024, 1024), False, (64, 64, 0)),
-    (1000, (1024, 1024), False, (64, 64, 8)),   # the padding edge
-    (700, (1024, 1024), False, (64, 48, 8)),   # two columns in the padding
-    (4096, (512, 1024), True, (1024, 528, 32)),  # the bf16 clamp shape
-    (600, (600, 600), True, (1, 1, 1)),         # ragged block: one sub-tile
+# (shape, value width, block_q, block_k, sub-tile side, dtype): the same walk
+# in the folded layout, one head a block, at head counts and widths the
+# packed layout refuses: a value width of its own (latent attention: 192-wide
+# keys in 256 lanes, 128-wide values, and a sub-tile of twice the side), an
+# odd head count at head_dim 64 (where 1/sqrt(d) folds into q), f32 and bf16
+# inputs.
+FOLDED_CASES = [
+    ((1, 128, 2, 48), 32, 128, 128, 32, jnp.float32),  # one block, 4 x 4
+    ((1, 120, 2, 48), 48, 128, 128, 32, jnp.float32),  # the edge crosses
+    ((2, 90, 1, 48), 32, 128, 128, 32, jnp.float32),   # a column in padding
+    ((1, 256, 2, 48), 32, 64, 128, 32, jnp.float32),   # block_q < block_k
+    ((1, 250, 3, 48), 32, 128, 64, 32, jnp.float32),   # block_q > block_k
+    ((1, 192, 3, 64), 64, 64, 64, 16, jnp.float32),    # square blocks, folds
+    ((1, 200, 2, 192), 128, 128, 128, 32, jnp.float32),  # 192 / 128, ragged
+    ((1, 256, 2, 192), 128, 128, 256, 32, jnp.bfloat16),
+    ((2, 120, 3, 64), 64, 128, 128, 32, jnp.bfloat16),
+    ((1, 200, 2, 48), 32, 64, 64, None, jnp.bfloat16),  # whole-block tiles
+]
+
+
+@pytest.mark.parametrize("causal", [False, True])
+@pytest.mark.parametrize("shape,value_dim,block_q,block_k,side,dtype",
+                         FOLDED_CASES)
+def test_folded_layout_matches_dense_values_and_grads(
+        shape, value_dim, block_q, block_k, side, dtype, causal, subtile):
+    """The same kernels in the folded (B*H, T, D) layout must match dense
+    attention in values AND all three gradients. bf16 inputs are held to
+    the dense path in f32 on the same rounded inputs, at bf16's grain."""
+    fa = subtile(side)
+    assert not (value_dim == shape[3]
+                and fa._packed_supported(shape[2], shape[3]))
+    q, k, v = _qkv(shape, dtype, seed=4, value_dim=value_dim)
+    f32 = lambda xs: [x.astype(jnp.float32) for x in xs]
+    tol = dict(rtol=2e-5, atol=2e-5) if dtype == jnp.float32 else dict(
+        rtol=2e-2, atol=2e-2)
+    flash = lambda q, k, v: flash_attention(
+        q, k, v, causal=causal, block_q=block_q, block_k=block_k)
+    out = flash(q, k, v)
+    assert out.shape == v.shape and out.dtype == dtype
+    want = dense_attention(*f32((q, k, v)), causal)
+    np.testing.assert_allclose(*f32((out, want)), **tol)
+
+    def loss(attn):
+        return lambda q, k, v: jnp.sum(
+            jnp.sin(attn(q, k, v).astype(jnp.float32)))
+
+    gf = jax.grad(loss(flash), argnums=(0, 1, 2))(q, k, v)
+    gd = jax.grad(loss(lambda q, k, v: dense_attention(q, k, v, causal)),
+                  argnums=(0, 1, 2))(*f32((q, k, v)))
+    if dtype == jnp.float32:
+        tol = dict(rtol=3e-4, atol=3e-4)
+    for a, b in zip(f32(gf), gd):
+        np.testing.assert_allclose(a, b, **tol)
+
+
+def test_passes_merge_like_rows_within_the_vmem_budget(subtile):
+    """Rows of sub-tiles whose columns are classed alike are one pass, as
+    tall as the budget of scores allows with the heads alive together; rows
+    the diagonal classes differently stay apart; rows nothing is seen of
+    are one empty pass; dk/dv's passes run down the columns."""
+    fa = subtile(None)
+    passes = lambda blocks, heads, diag, **kw: fa._passes(
+        blocks, (128, 128), heads, diag, None, **kw)
+    assert passes((512, 1024), 1, None) == [(0, 512, [(0, 1024, False)])]
+    assert passes((512, 1024), 1, None, kv_major=True) == [
+        (0, 1024, [(0, 512, False)])]
+    assert [p[:2] for p in passes((1024, 1024), 2, None)] == [
+        (0, 512), (512, 1024)]
+    assert passes((384, 384), 1, 0) == [
+        (0, 128, [(0, 128, True)]),
+        (128, 256, [(0, 128, False), (128, 256, True)]),
+        (256, 384, [(0, 256, False), (256, 384, True)])]
+    assert passes((384, 384), 1, 0, kv_major=True) == [
+        (0, 128, [(0, 128, True), (128, 384, False)]),
+        (128, 256, [(128, 256, True), (256, 384, False)]),
+        (256, 384, [(256, 384, True)])]
+    assert passes((384, 384), 1, -256) == [
+        (0, 256, []), (256, 384, [(0, 128, True)])]
+
+
+def test_folded_cases_meet_every_class_and_view(subtile):
+    """The cases above put the walk through everything it distinguishes:
+    every sub-tile class, a grid of one block and of several, and each way
+    a grid block can lie (wholly seen, on the diagonal at its corner, on it
+    further along, across the padding edge)."""
+    classes, views, grids = set(), set(), set()
+    for shape, _, block_q, block_k, side, _ in FOLDED_CASES:
+        fa = subtile(side)
+        t = shape[1]
+        t_pad = -(-t // max(block_q, block_k)) * max(block_q, block_k)
+        nq, nk = t_pad // block_q, t_pad // block_k
+        grids.add(nq * nk > 1)
+        sub_q, sub_k = (fa._subtile(x, fa._lane_pad(shape[3]))
+                        for x in (block_q, block_k))
+        assert block_q // sub_q * (block_k // sub_k) > 1 or side is None
+        for causal in (False, True):
+            for (diag, edge), _ in fa._block_views(
+                    nq, nk, block_q, block_k, t, causal):
+                views.add(("seen" if diag is None else
+                           "corner" if diag == 0 else "along")
+                          if edge is None else "edge")
+                classes |= {
+                    fa._tile_class(r0, sub_q, c0, sub_k, diag, edge)
+                    for r0 in range(0, block_q, sub_q)
+                    for c0 in range(0, block_k, sub_k)}
+    assert classes == {fa._SKIPPED, fa._MASKED, fa._UNMASKED}
+    assert views == {"seen", "corner", "along", "edge"}
+    assert grids == {False, True}
+
+
+@pytest.mark.parametrize("t,blocks,causal,lanes,want", [
+    (1024, (1024, 1024), True, 128, (64, 36, 8)),    # both GPT-2 cells
+    (1024, (1024, 1024), False, 128, (64, 64, 0)),
+    (1000, (1024, 1024), False, 128, (64, 64, 8)),   # the padding edge
+    (700, (1024, 1024), False, 128, (64, 48, 8)),  # two columns in padding
+    (4096, (512, 1024), True, 128, (1024, 528, 32)),  # the bf16 clamp shape
+    (4000, (512, 1024), False, 128, (1024, 1024, 32)),  # ... its padding edge
+    # kanana2-30b.train.ep8share: 192-wide keys sit in 256 lanes, and the
+    # sub-tile is a lane tile of the head block: 8 x 4 grid blocks a head
+    (4096, (512, 1024), True, 256, (256, 136, 16)),
+    (600, (600, 600), True, 128, (1, 1, 1)),       # ragged block: one sub-tile
 ])
-def test_subtile_counts(t, blocks, causal, want):
+def test_subtile_counts(t, blocks, causal, lanes, want):
     """The static count of the sub-tile walk, at the module's own side."""
     from distributed_tpu.ops.flash_attention import _SUBTILE, subtile_counts
 
     assert _SUBTILE == 128
-    assert subtile_counts(t, *blocks, causal) == want
+    assert subtile_counts(t, *blocks, causal, lanes) == want
 
 
-def test_subtile_gauges_published():
-    """flash_attention publishes the counts at trace time, as gauges."""
+@pytest.mark.parametrize("shape,value_dim,want", [
+    ((1, 1024, 2, 64), 64, [64.0, 36.0, 8.0]),         # packed: GPT-2 cells
+    ((1, 4096, 32, 192), 128, [256.0, 136.0, 16.0]),  # folded: kanana cell
+])
+def test_subtile_gauges_published(shape, value_dim, want):
+    """flash_attention publishes the counts at trace time, as gauges, from
+    the packed and the folded branch alike."""
     from distributed_tpu import obs
 
     reg = obs.default_registry()
-    q = jax.ShapeDtypeStruct((1, 1024, 2, 64), jnp.bfloat16)
+    q = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+    v = jax.ShapeDtypeStruct(shape[:3] + (value_dim,), jnp.bfloat16)
     jax.eval_shape(
-        lambda q, k, v: flash_attention(q, k, v, causal=True), q, q, q)
+        lambda q, k, v: flash_attention(q, k, v, causal=True), q, q, v)
     assert [reg.gauge_value(f"flash.subtiles_{n}")
-            for n in ("square", "computed", "masked")] == [64.0, 36.0, 8.0]
+            for n in ("square", "computed", "masked")] == want
 
 
 def _dot_operand_dtypes(jaxpr, found):
@@ -285,43 +408,56 @@ def _dot_operand_dtypes(jaxpr, found):
     return found
 
 
-@pytest.mark.parametrize("head_dim", [64, 128])
-def test_packed_kernels_feed_the_mxu_bf16(head_dim):
-    """Every matrix product of the three packed kernels takes bf16 operands
-    from bf16 inputs: the scale folded into q (head_dim 64: a power of two)
-    must not promote it (a NumPy scalar is no weak type), and head_dim 128
-    keeps its f32 multiply on the scores."""
-    q = jax.ShapeDtypeStruct((1, 512, 128 // head_dim, head_dim),
-                             jnp.bfloat16)
+@pytest.mark.parametrize("heads,head_dim,value_dim", [
+    (2, 64, 64), (1, 128, 128),  # packed
+    (3, 64, 64), (2, 192, 128),  # folded: odd heads; latent attention
+])
+def test_kernels_feed_the_mxu_bf16(heads, head_dim, value_dim):
+    """Every matrix product of the three kernels takes bf16 operands from
+    bf16 inputs: the scale folded into q (head_dim 64: a power of two) must
+    not promote it (a NumPy scalar is no weak type), and head_dim 128 and
+    192 keep their f32 multiply on the scores."""
+    q = jax.ShapeDtypeStruct((1, 512, heads, head_dim), jnp.bfloat16)
+    v = jax.ShapeDtypeStruct((1, 512, heads, value_dim), jnp.bfloat16)
 
     def loss(q, k, v):
         return jnp.sum(flash_attention(q, k, v, causal=True)
                        .astype(jnp.float32))
 
-    jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, q, q)
+    jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, q, v)
     assert _dot_operand_dtypes(jaxpr.jaxpr, set()) == {
         ("bfloat16", "bfloat16")}
 
 
 @pytest.mark.parametrize("causal", [False, True])
-@pytest.mark.parametrize("shape,blocks,side", [
-    ((1, 96, 2, 64), (32, 32), None),     # several blocks: from the scratch
-    ((1, 120, 2, 64), (128, 128), 32),    # one block, walked in sub-tiles
-    ((2, 100, 1, 128), (64, 64), 32),     # one head a lane block, ragged T
+@pytest.mark.parametrize("shape,value_dim,blocks,side", [
+    ((1, 96, 2, 64), 64, (32, 32), None),   # several blocks: from the scratch
+    ((1, 120, 2, 64), 64, (128, 128), 32),  # one block, walked in sub-tiles
+    ((2, 100, 1, 128), 128, (64, 64), 32),  # one head a lane block, ragged T
+    ((1, 96, 3, 48), 32, (32, 32), None),   # folded: several blocks
+    ((2, 120, 2, 192), 128, (128, 128), 32),  # folded: 192 / 128, one block
 ])
-def test_packed_residual_statistic(shape, blocks, side, causal, subtile):
+def test_residual_statistic(shape, value_dim, blocks, side, causal, subtile):
     """What the forward saves for the backward beside its inputs and output
     is ONE row statistic, lse = m + log l, 4 bytes a row and head, laid out
-    lane-major (B, head blocks, heads a block, t_pad): it must be the
-    log-sum-exp of the dense scores on every real row."""
+    lane-major (b, head blocks, heads a block, t_pad), packed (b = B) or
+    folded (b = B*H, one head a block): it must be the log-sum-exp of the
+    dense scores on every real row."""
     fa = subtile(side)
     b, t, h, d = shape
-    q, k, v = _qkv(shape, seed=5)
-    flat = lambda x: x.reshape(b, t, h * d)
-    _, lse = fa._fwd_pallas_packed(
-        flat(q), flat(k), flat(v), h, d, 1.0 / np.sqrt(d), causal, *blocks)
-    hpb = 128 // d
-    assert lse.shape[:3] == (b, h // hpb, hpb) and lse.dtype == jnp.float32
+    q, k, v = _qkv(shape, seed=5, value_dim=value_dim)
+    if value_dim == d and fa._packed_supported(h, d):
+        rows, heads, hpb = b, h, 128 // d
+        lay = lambda x: x.reshape(b, t, -1)
+    else:
+        rows, heads, hpb = b * h, 1, 1
+        lay = lambda x: jnp.moveaxis(x, 2, 1).reshape(b * h, t, -1)
+    out, lse = fa._fwd_pallas(
+        lay(q), lay(k), lay(v), heads=heads, hpb=hpb, suffix="",
+        causal=causal, block_q=blocks[0], block_k=blocks[1])
+    assert out.shape == lay(v).shape
+    assert lse.shape[:3] == (rows, heads // hpb, hpb)
+    assert lse.dtype == jnp.float32
     s = jnp.einsum("bqhd,bkhd->bhqk", q, k) / np.sqrt(d)
     if causal:
         s = jnp.where(jnp.tril(jnp.ones((t, t), bool)), s, -jnp.inf)

@@ -1,7 +1,7 @@
-"""The packed flash kernels, forward and backward, compiled by the TPU's
-own compiler for a described (not attached) v5e at the benchmark cells'
-shapes and at the scoped-VMEM clamp shape; and, for the latent-attention
-cell, the folded flash kernels at 192-wide keys and 128-wide values and the
+"""The flash kernels, forward and backward, compiled by the TPU's own
+compiler for a described (not attached) v5e: lane-packed at the GPT-2
+cells' shapes and at the scoped-VMEM clamp shape; and, for the
+latent-attention cell, folded at 192-wide keys and 128-wide values; and the
 grouped-matmul kernels at the held experts' shapes. Nothing runs: this guards the
 16 MB scoped-VMEM limit and the lane / sublane alignment of the in-kernel
 sub-tile slices, which interpret mode cannot see, at no chip time
@@ -42,26 +42,29 @@ def mosaic(monkeypatch):
     from jax.experimental.compilation_cache import compilation_cache
     monkeypatch.setattr(fa, "_interpret", lambda: False)
     monkeypatch.setattr(gm, "_interpret", lambda: False)
-    fa._packed_cached.cache_clear()
+    fa._flash_cached.cache_clear()
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
     yield
     jax.config.update("jax_enable_compilation_cache", was)
     compilation_cache.reset_cache()
-    fa._packed_cached.cache_clear()
+    fa._flash_cached.cache_clear()
 
 
-@pytest.mark.parametrize("shape", [
-    (8, 1024, 16, 64),   # gpt2-medium.train.1chip
-    (4, 1024, 20, 64),   # gpt2-large.train.fsdp4, rows of one chip
-    (1, 4096, 16, 64),   # the bf16 clamp: block_q 512, block_k 1024
+@pytest.mark.parametrize("shape,causal", [
+    ((8, 1024, 16, 64), True),   # gpt2-medium.train.1chip
+    ((4, 1024, 20, 64), True),   # gpt2-large.train.fsdp4, rows of one chip
+    ((1, 4096, 16, 64), True),   # the bf16 clamp: block_q 512, block_k 1024
+    # Not causal, every row of sub-tiles is like the next: two heads' merged
+    # passes fit the scoped VMEM only under ``_PASS_SCORES``.
+    ((8, 1024, 16, 64), False),
 ])
-def test_packed_flash_grad_compiles_for_v5e(shape, one_chip, mosaic):
+def test_packed_flash_grad_compiles_for_v5e(shape, causal, one_chip, mosaic):
     x = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
 
     def loss(q, k, v):
-        out = fa.flash_attention(q, k, v, causal=True)
+        out = fa.flash_attention(q, k, v, causal=causal)
         return jnp.sum(out.astype(jnp.float32))
 
     text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
@@ -71,17 +74,19 @@ def test_packed_flash_grad_compiles_for_v5e(shape, one_chip, mosaic):
         assert name in text
 
 
+@pytest.mark.parametrize("causal", [True, False])
 def test_folded_flash_grad_at_latent_attentions_widths_compiles_for_v5e(
-        one_chip, mosaic):
+        causal, one_chip, mosaic):
     """kanana2-30b.train.ep8share: (1, 4096, 32) heads, q and k 192 wide (in
-    256 lanes), v 128 wide; block_q 512, block_k 1024."""
+    256 lanes), v 128 wide; block_q 512, block_k 1024: three block views of
+    the unrolled walk a kernel under causality, one without."""
     qk = jax.ShapeDtypeStruct((1, 4096, 32, 192), jnp.bfloat16,
                               sharding=one_chip)
     v = jax.ShapeDtypeStruct((1, 4096, 32, 128), jnp.bfloat16,
                              sharding=one_chip)
 
     def loss(q, k, v):
-        out = fa.flash_attention(q, k, v, causal=True)
+        out = fa.flash_attention(q, k, v, causal=causal)
         return jnp.sum(out.astype(jnp.float32))
 
     compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
