@@ -3,8 +3,8 @@
 
 ``python chip_smoke.py`` (no arguments, repo root, ONE process) drives the
 normal entry points once on a TPU, at the full width of the 136M LM
-(``bench.py``'s ``_lm_bench_run`` configuration), on weights and tokens made
-from a seed:
+(the rounds 1-5 configuration, docs/PERF_ROUNDS_1-5.md), on weights and
+tokens made from a seed:
 
 - device gate: fails before any leg unless ``jax.default_backend() == "tpu"``;
 - sync: what ``jax.block_until_ready`` does, against a scalar host fetch;
@@ -38,7 +38,7 @@ import sys
 import tempfile
 import time
 
-# The 136M LM of bench.py `_lm_bench_run` / `bench_transformer_lm`.
+# The 136M LM of rounds 1-5 (GPT-2-small shape, 32k rows, untied head).
 LM = dict(vocab=32768, num_layers=12, d_model=768, num_heads=12,
           seq_len=1024, batch=32)
 # serving.Engine shape of the serve leg (ISSUE 21) — and therefore of the

@@ -82,8 +82,8 @@ from .core import (
 __all__ = ["ShardedCheckpointer", "ShardCorruptionError", "read_stats"]
 
 # Disk-read accounting for the recovery tiers: every block read from a
-# proc-*.npz lands here. The buddy-redundancy tests and `bench.py
-# recovery` snapshot these counters around a restore to PROVE a
+# proc-*.npz lands here. The buddy-redundancy tests snapshot these
+# counters around a restore to PROVE that a
 # buddy-tier recovery touched zero disk blocks (docs/RESILIENCE.md
 # "Recovery tiers").
 read_stats = {"block_reads": 0, "block_bytes": 0}

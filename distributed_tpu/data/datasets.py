@@ -221,8 +221,8 @@ def fetch_mnist(dest_dir: Optional[str] = None,
     so a partial download never poisons the cache), and returns the cache
     directory — or None on ANY failure (no network egress, bad mirror,
     corrupt payload). Never raises: hermetic environments fall through to
-    the synthetic stand-in, which callers report via their ``data`` field
-    (bench.bench_convergence). Already-complete caches return immediately.
+    the synthetic stand-in (``synthetic_ok=False`` makes ``load_mnist``
+    raise there instead). Already-complete caches return immediately.
     """
     import socket
     import urllib.parse
@@ -335,7 +335,7 @@ def load_mnist(
     synthetic_test_n: int = 10000,
 ) -> Arrays:
     # force_synthetic exists so a caller that needs BOTH splits from the
-    # same source (e.g. the convergence bench) can't end up training on a
+    # same source (e.g. a convergence run) can't end up training on a
     # cached real split and evaluating on a synthetic one when only one
     # split file is present on the machine.
     got = None

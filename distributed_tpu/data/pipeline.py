@@ -249,7 +249,7 @@ class Pipeline:
         Work is assigned by step index and reassembled in order, so the
         batch stream is BIT-IDENTICAL for any worker count; workers give
         real speedup when ``decode_fn`` releases the GIL (zlib, PIL,
-        numpy) or blocks on I/O (docs/PERF.md "Streaming input").
+        numpy) or blocks on I/O (docs/API.md "Streaming input").
       decode_readahead: how many batch steps may be decoding (or decoded,
         unconsumed) ahead of the consumer. Default ``2 * decode_workers``.
 

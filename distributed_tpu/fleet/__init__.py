@@ -29,9 +29,9 @@ multi-replica tier:
     outs = fleet.run(requests, arrival_times=times, tenants=tenants)
     fleet.last_run_telemetry  # tokens/s, p50/p99 TTFT, per-request rows
 
-``bench.py fleet`` (BENCH_fleet.json) measures tokens/s scaling vs
-replica count, tail TTFT under bursty arrivals, and the kill-a-replica
-recovery row; docs/SERVING.md "Fleet" documents semantics and limits —
+tests/test_fleet.py pins token-exact serving with and without KV
+transfer, bursty arrivals, and the kill-a-replica
+recovery; docs/SERVING.md "Fleet" documents semantics and limits —
 including the virtual-clock harness used on single-host boxes.
 """
 

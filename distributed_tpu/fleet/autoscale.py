@@ -18,7 +18,7 @@ synthetic traces):
   (when set). Bursts are what autoscaling exists for; growth is cheap
   because replica spin-up is pool allocation, not a recompile
   (``fleet.replica.EnginePrograms``), bounded in production by the warm
-  compile cache (BENCH_compile_cache.json).
+  compile cache (``utils/compile_cache.py``).
 - **Shrink** by one replica when the queue is below ``queue_low`` per
   replica AND at least one replica's worth of decode slots sits idle —
   the load provably fits in fewer replicas. Shrinking waits out

@@ -2,8 +2,8 @@
 
 Each decode replica's :class:`~distributed_tpu.serving.kv_cache.PrefixStore`
 is local: a cold replica re-earns every prefix the warm one already
-computed (BENCH_prefix.json's hit_rate 0.91 is a single warm engine, not
-the fleet). Gossip closes the gap with two pieces:
+computed (a prefix store's hit rate is one warm engine's, not
+the fleet's). Gossip closes the gap with two pieces:
 
 - **The index** (:class:`PrefixGossipIndex`, this module): replicas
   ADVERTISE their store's chain-hash keys, stamped with the weights

@@ -11,8 +11,8 @@ Replicas of one fleet share compiled dispatches through
 :class:`EnginePrograms` — the prefill/decode jit programs are keyed by
 shape, not by replica, so spinning up a decode replica costs pool
 allocation, NOT a retrace (and in production the persistent compile cache
-bounds even the first trace: BENCH_compile_cache.json, restart-to-first-
-step 2.23s→1.22s warm). That is what makes queue-depth autoscaling
+bounds even the first trace: a warm start reads its programs from
+disk). That is what makes queue-depth autoscaling
 (``fleet.autoscale``) cheap enough to react to bursts.
 
 Scheduling semantics inside a decode replica are exactly the engine's

@@ -5,7 +5,7 @@ TPU-first rationale: a deep stack of structurally identical blocks
 compiles to O(depth) static HLO ops. On TPU the XLA program is traced and
 scheduled per static op, so depth inflates compile time and — on runtimes
 with per-op dispatch cost — step time; in the pre-PR-1 v5e profile
-(docs/PERF.md) a ResNet-50 train step spent more time on per-op overhead
+(docs/PERF_ROUNDS_1-5.md) a ResNet-50 train step spent more time on per-op overhead
 (~3,500 static ops) than on convolution FLOPs. Stacking the blocks' parameters with a
 leading (S, ...) dim and scanning one block body over them emits the body
 ONCE: static op count, compile time, and the optimizer's per-tensor update

@@ -21,9 +21,9 @@ request rows, supervisor recovery rows, the resilience event log):
   snapshot files.
 - :mod:`~distributed_tpu.obs.cli` — the ``dtpu-events`` postmortem CLI.
 
-Gate: ``bench.py obs`` asserts instrumented-vs-bare fit overhead <= 3%
-and that an injected slow rank is correctly named on a supervised gang
-(BENCH_obs.json). See docs/OBSERVABILITY.md.
+Gate: tests/test_obs.py asserts the telemetry parity contracts
+and that an injected slow rank is correctly named on a supervised gang.
+See docs/OBSERVABILITY.md.
 
 jax-free at import (controller processes import it next to the
 supervisor); spans resolve jax lazily.

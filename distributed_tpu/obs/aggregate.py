@@ -24,8 +24,8 @@ max/median skew; :func:`straggler` names the slowest rank when its median
 step time exceeds the gang median by a threshold. The supervisor runs
 both at every terminal boundary and emits ``rank_skew`` (always, when
 snapshots exist) and ``straggler`` (when one is detected) events —
-verified end-to-end by ``bench.py obs`` with an injected ``slow_steps``
-fault on a real 2-worker gang.
+verified end-to-end by tests/test_obs.py with an injected ``slow_steps``
+fault on a supervised 2-worker gang.
 
 jax-free: aggregation runs on the supervisor's controller process.
 """

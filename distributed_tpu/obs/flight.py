@@ -11,7 +11,7 @@ paths where a process is about to die:
 
 - ``PreemptionHandler`` before its exit-75,
 - ``FaultInjector`` kills before their ``os._exit`` (every injected crash
-  leaves a readable dump — asserted by tests and ``bench.py obs``),
+  leaves a readable dump — asserted by tests/test_obs.py),
 - ``Model.fit``'s unhandled-exception path.
 
 Dumps land next to the supervisor's event log (``$DTPU_FLIGHT_DIR``, or

@@ -10,8 +10,8 @@ they always held (pinned by tests/test_obs.py's parity tests).
 
 Always cheap: every mutator is a dict update under one lock (~1 µs), and
 ``set_enabled(False)`` (or ``DTPU_OBS=0``) turns all of them into no-ops
-— which is what ``bench.py obs`` compares against to assert the ≤ 3%
-instrumented-vs-bare overhead gate.
+— the bare loop an instrumented one is compared against
+(tests/test_obs.py runs both halves).
 
 Deterministic snapshots: :meth:`snapshot` emits every section with sorted
 keys, so the same run produces the same key sequence (and the Prometheus
@@ -45,7 +45,7 @@ _enabled = os.environ.get(ENABLE_ENV, "1") != "0"
 def enabled() -> bool:
     """Whether the registry (and with it spans and the flight recorder)
     records anything. ``DTPU_OBS=0`` disables at import; ``set_enabled``
-    flips it at runtime (the bench's bare-vs-instrumented pair)."""
+    flips it at runtime (a bare loop beside an instrumented one)."""
     return _enabled
 
 

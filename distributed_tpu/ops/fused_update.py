@@ -5,8 +5,8 @@ the ~10-op elementwise chain (two moment EMAs, two bias corrections, the
 rsqrt-normalized step, the -lr scale; AdamW adds the decay term). On a
 model with hundreds of leaves that is hundreds of small kernels per
 optimizer step — each paying launch overhead and reading/writing its
-operands through HBM separately, which is exactly the per-step cost the
-``bench.py fused_update`` artifact measures.
+operands through HBM separately: the per-step cost this module
+removes (on the chip: root PERF.md, ``optimizer_device_ms``).
 
 This module factors the update the other way: the leaves of the master
 tree are raveled and concatenated into one flat buffer per dtype (the
@@ -39,7 +39,7 @@ row-sharding into the updated params under plain DataParallel, whose
 constrain_step pins nothing (see _segment_update). Under ZeRO/FSDP the
 segment concat gathers the sharded leaves transiently and constrain_step
 re-pins the outputs; those strategies get the fused arithmetic, not a
-comms win — docs/PERF.md is explicit.
+comms win (docs/API.md, Design notes).
 
 CPU/tests run the kernel via Pallas interpret mode (same semantics); on
 TPU it compiles to Mosaic.

@@ -38,7 +38,7 @@ kernel while tracing and the jit cache keys stay per-engine.
 CPU/tests run the kernel via Pallas interpret mode (same semantics); on
 TPU it compiles to Mosaic. Parity vs the reference path is pinned by
 tests/test_paged_kernel.py; the throughput claim is reserved for a real
-accelerator (docs/PERF.md "Fused paged attention").
+accelerator (docs/API.md "Fused paged attention").
 """
 
 from __future__ import annotations

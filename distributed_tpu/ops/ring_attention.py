@@ -25,7 +25,7 @@ late. Then every rotated hop has EXACTLY two live chunk-pairs per device,
 fully unmasked ((q_hi, k_lo) always; (q_lo, k_lo) when my > src else
 (q_hi, k_hi)), and only the resident hop applies triangular masks — ~half
 the matmul FLOPs of the naive schedule, perfectly load-balanced, same
-O(T/n) memory and ring traffic (docs/PERF.md "ring attention" A/B).
+O(T/n) memory and ring traffic (docs/PERF_ROUNDS_1-5.md, round 4).
 ``schedule="naive"`` keeps the old path for reference/debugging.
 """
 

@@ -48,13 +48,13 @@ def fused_adam(learning_rate: float = 0.001, b1: float = 0.9,
     """Adam whose whole update — moment EMAs, bias correction, step — runs
     as ONE Pallas kernel pass per same-dtype flat segment of the master
     tree, instead of the stock per-leaf tree walk (ops.fused_update; the
-    raw-speed lever measured by ``bench.py fused_update``). Numerically
+    one-pass form of the update; root PERF.md, PR 24). Numerically
     operation-for-operation identical to ``Adam``; drops into the same
     ``Strategy.init_opt_state``/``constrain_step`` seams (the moment trees
     shard exactly like stock Adam state under ZeRO-1/FSDP), and the
     ``inject_hyperparams`` wrapper keeps the learning rate runtime-mutable
     and checkpointable. CPU backends run the kernel in interpret mode
-    (same semantics, no speedup — see docs/PERF.md)."""
+    (same semantics, no speedup: docs/API.md, Design notes)."""
     from ..ops import fused_update  # lazy: pulls in pallas
 
     return optax.inject_hyperparams(fused_update.fused_adam)(

@@ -18,8 +18,8 @@ Two jobs live here:
      materialized per candidate);
    - per-step collective traffic via ``Strategy.comm_bytes_estimate``
      (unified schema across all strategies, int8/bf16-aware);
-   - an HBM-cap feasibility predicate (``Feasibility``) generalizing the
-     ``bench.py zero`` hbm_cap_row check;
+   - an HBM-cap feasibility predicate (``Feasibility``): does a
+     candidate's state fit under a per-device cap;
    - a rank over survivors: estimated step seconds = compute (analytic
      FLOPs / device peak, precision-aware) + comm (bytes / link bandwidth)
      + dispatch overhead (amortized by ``steps_per_execution``). Constants
@@ -163,7 +163,7 @@ _DEVICE_CONSTANTS = {
     # TPU v5e: 197 TFLOP/s bf16 (Google Cloud "TPU v5e").
     "TPU v5 lite": {"peak_flops": 2.0e14, "comm_bw": 9.0e10,
                     "dispatch_s": 5e-4, "reduced_speedup": 2.0},
-    # XLA:CPU EMULATES bf16 (BENCH_precision measured mixed at 0.83x f32),
+    # XLA:CPU EMULATES bf16 (a mixed policy ran slower than f32 there),
     # so reduced precision gets a PENALTY there, not a speedup — the
     # planner must not recommend a policy the backend runs slower.
     "cpu": {"peak_flops": 5.0e10, "comm_bw": 1.0e10, "dispatch_s": 1.5e-3,
@@ -263,9 +263,9 @@ class Candidate:
 
 
 class Feasibility:
-    """Reusable HBM-cap predicate — the generalization of the
-    ``bench.py zero`` hbm_cap_row check (replicated 378MB > 256MB cap =>
-    cannot train; FSDP 47MB fits). ``check`` returns None when the
+    """Reusable HBM-cap predicate: a replicated state over the cap
+    cannot train, a sharded one under it can
+    (tests/test_autoshard.py). ``check`` returns None when the
     candidate fits, else a human-readable pruning reason recorded in the
     Plan."""
 
@@ -293,8 +293,8 @@ class Plan:
     """The planner's decision record: the chosen config + its predicted
     numbers, every candidate's row, and the rationale for pruned ones.
     ``summary()`` is the JSON-safe dict that lands in
-    ``model.last_fit_telemetry["plan"]``, the JSONL event log, and
-    BENCH_autoshard.json."""
+    ``model.last_fit_telemetry["plan"]`` and the JSONL event log
+    (``auto_shard_plan``)."""
 
     chosen: dict
     candidates: List[dict]
@@ -669,8 +669,8 @@ def plan_sharding(
     ``tx``: the optax transform whose state is being priced (defaults to
     ``optim.get(optimizer)``). ``precisions`` defaults backend-aware:
     ``(None, "mixed_bfloat16")`` on accelerators, ``(None,)`` on XLA:CPU
-    (which emulates bf16 — recommending it there would be a lie the
-    BENCH_precision artifact already measured at 0.83x). ``measure=True``
+    (which emulates bf16 and runs it slower than f32: the planner
+    must not recommend what the backend loses on). ``measure=True``
     times the ``top_k`` estimate-ranked survivors with ``measure_fn``
     (seconds per step, or None to skip one candidate) and commits to the
     fastest measured."""

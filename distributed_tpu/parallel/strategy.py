@@ -196,7 +196,7 @@ class Strategy:
         leaves (the planner's dry-run path). An estimate, not a
         measurement (ring-collective (N-1)/N factors and XLA fusion are
         ignored): its job is to make traffic RATIOS across configs/dtypes
-        visible in telemetry/bench/planner, which those constant factors
+        visible in telemetry and the planner, which those constant factors
         cancel out of. Base strategy emits no collectives."""
         return self._comm_row()
 
@@ -866,7 +866,7 @@ class FullyShardedDataParallel(_HintedParallel):
         # comms win this estimate exists to expose. Int8 weight-only
         # leaves (quant.py) keep their 1-byte dtype through the
         # compute_dtype override, so a quantized serving tree reports the
-        # 4x/2x smaller gathers directly (bench.py quant).
+        # 4x/2x smaller gathers directly (tests/test_quant.py).
         gathered = sum(
             self._leaf_comm_bytes(l, compute_dtype)
             for l in jax.tree_util.tree_leaves(params)
@@ -903,7 +903,7 @@ class FSDP(FullyShardedDataParallel):
     sharding, so the whole mesh contributes to a single sharded replica.
     Per-device model state is O(params x stats / N): with Adam, ~3x params
     replicated drops to ~3x/N — the axis that trains models which OOM
-    under replication (``bench.py zero``'s simulated-HBM-cap row).
+    under replication (tests/test_zero.py pins the 1/N ratio).
 
     Compared side by side:
 

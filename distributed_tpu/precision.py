@@ -32,7 +32,7 @@ Under ``FSDP``/ZeRO strategies the compute cast is also the comms lever:
 casting the param tree to bf16 *before* the sharding-constraint-driven
 per-layer all-gathers halves the dominant collective traffic
 (``Strategy.constrain_compute_params`` pins the cast copy to the shard
-layout so GSPMD gathers compute-dtype bytes; see docs/PERF.md "Mixed
+layout so GSPMD gathers compute-dtype bytes; see docs/API.md "Mixed
 precision").
 
 Checkpoints always persist the f32 masters, so saving under one policy and

@@ -42,12 +42,12 @@ data-dependent per step, so the int8 KV cache uses per-row DYNAMIC
 scales — ``serving.Engine(kv_dtype="int8")`` stores the pools as the
 same ``{"q", "scale"}`` plain-dict leaves used here, quantizing on
 scatter and dequantizing in-trace on gather (``nn/attention.py``
-``_kv_scatter`` / ``_paged_view``; docs/SERVING.md, docs/PERF.md
-"Memory economy").
+``_kv_scatter`` / ``_paged_view``; docs/SERVING.md "int8 KV
+cache").
 
 Accuracy contract: dequantized weights differ from the originals by at
-most ``scale/2`` per element (symmetric round-to-nearest), and tests +
-``bench.py quant`` pin the end effect — bounded logit error and top-1
+most ``scale/2`` per element (symmetric round-to-nearest), and
+tests/test_quant.py pins the end effect — bounded logit error and top-1
 agreement against the f32 model on the serving LM shapes.
 """
 
@@ -216,7 +216,7 @@ def abstract_quantize_tree(tree, *, min_ndim: int = 2):
 
 def tree_param_bytes(tree) -> int:
     """Global logical byte count of a (possibly quantized) param tree —
-    the serving-HBM number ``bench.py quant`` compares across formats
+    the serving-HBM number to compare across weight formats
     (per-DEVICE resident bytes come from profiler.tree_bytes_per_device).
     Works on live arrays AND abstract ``ShapeDtypeStruct`` leaves (the
     planner's dry-run path)."""

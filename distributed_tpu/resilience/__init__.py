@@ -15,7 +15,7 @@ closes that gap with four cooperating pieces:
 - :class:`PreemptionHandler` — SIGTERM -> final checkpoint -> resume marker
   -> exit :data:`PREEMPTED_EXIT_CODE` (restart is budget-free).
 - :class:`FaultInjector` — kill / hang / slow-heartbeat / corrupt-checkpoint
-  injection so the machinery above is provable from tests and bench.py.
+  injection so the machinery above is provable from tests.
 
 Automatic resume rides the existing checkpoint contract: workers run with
 ``ModelCheckpoint(dir, restore=True)`` and a fixed seed; restore skips

@@ -2,7 +2,7 @@
 
 A supervised runtime is only trustworthy if its failure paths are
 exercised — so fault injection is a first-class, shippable tool here
-(usable from tests AND ``bench.py resilience``), not test-local
+(usable from tests AND a user's own drills), not test-local
 monkeypatching. :class:`FaultInjector` is a training callback that makes a
 worker fail in a chosen mode at a chosen step, once:
 
@@ -18,7 +18,7 @@ worker fail in a chosen mode at a chosen step, once:
   ``at_step`` on, every step sleeps ``slow_seconds``. The worker keeps
   heartbeating and finishing, just slower than its peers: the straggler
   the cross-rank skew aggregation (``obs.aggregate``) exists to name,
-  and what ``bench.py obs`` injects to verify the ``straggler`` event
+  and what tests/test_obs.py injects to verify the ``straggler`` event
   fires on a real supervised gang. Fires every step (no once-marker
   disarm after the first hit); ``fault_injected`` is emitted once.
 - ``corrupt_checkpoint``: clobber the newest checkpoint file, then die —

@@ -19,7 +19,7 @@ shard is mirrored by worker ``(j+1) % N`` (:func:`mirror_holder`).
 per-rank *segment* of a RAM-backed directory (tmpfs — ``/dev/shm`` via
 :func:`ram_dir`). On a real multi-host fleet the segment IS the peer's
 resident memory and the refresh/restore transport is the interconnect;
-on the single-box gangs the tests and ``bench.py recovery`` run, tmpfs
+on the single-box gangs the tests (tests/gang_harness.py) run, tmpfs
 stands in for both — RAM-speed, zero disk I/O, and per-segment
 invalidation mirrors per-host memory loss (the supervisor purges the
 segments of ranks that initiated a failure before relaunching: a crashed

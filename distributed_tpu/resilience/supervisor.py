@@ -77,7 +77,7 @@ def recovery_rows(events: Sequence[dict]) -> List[dict]:
     are absent — a worker without ``ModelCheckpoint(restore=True)`` emits
     no restore markers, and the row then only attributes what it can.
     The supervisor emits each row as a ``recovery`` event at run end, so
-    BENCH_recovery.json and user telemetry attribute recovery time
+    post-mortems and user telemetry attribute recovery time
     honestly instead of reporting one opaque restart latency.
 
     ``flight_dumps`` lists the flight-recorder dump files the FAILED
@@ -558,12 +558,12 @@ class Supervisor:
         the detect/gang-reform/restore/recompile split, the restore
         tier used, and the failed attempt's flight-dump paths — computed
         from the run's own event stream right before the terminal event,
-        so post-mortems and bench.py recovery read rows, not raw
+        so post-mortems and the recovery tests read rows, not raw
         timestamps. Also the cross-rank skew boundary: a `rank_skew`
         summary over the workers' metrics_snapshot flushes, plus a
         `straggler` event naming the slowest rank when its median step
         time exceeds the gang median by `straggler_threshold` (verified
-        end-to-end by bench.py obs)."""
+        end-to-end by tests/test_obs.py)."""
         if self.event_log is None:
             return
         try:

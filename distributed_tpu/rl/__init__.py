@@ -14,9 +14,9 @@ no restart, in-flight KV retained under a documented staleness contract
                              reward_fn=dtpu.rl.length_penalized_logprob())
     rows = pt.train(prompts, iterations=4, num_samples=4)
 
-``python bench.py rl`` prices the loop (BENCH_rl.json): rollout
-tokens/s, train steps/s, weight-sync latency per iteration, and reward
-improving across iterations.
+tests/test_rl.py runs the loop closed: rollouts, an update, the
+weight sync with its version accounting, and the reward
+improving across iterations (docs/RL.md).
 """
 
 from .distill import DraftDistiller, distill_loss, pack_distill

@@ -1,10 +1,10 @@
 """Draft-LM distillation: make speculative decoding pay.
 
 A speculative engine only wins when the draft's greedy chain agrees with
-the target (``docs/PERF.md`` "When speculation pays"): every rejected
+the target (docs/SERVING.md "Draft models & gossip"): every rejected
 column is a wasted draft dispatch plus a verify row that committed one
 token anyway. A randomly-initialized or layer-truncated draft agrees
-almost never (BENCH_spec.json records ~0.02 on the bench workload), so
+almost never (an accept rate near 0.02 on a serving workload), so
 speculation LOSES until the draft is trained toward the target.
 
 ``DraftDistiller`` closes that gap with the machinery the repo already

@@ -34,9 +34,9 @@ The trainer and the engine share one process group (and usually one
 ``Model`` object — the engine serves its own SNAPSHOT of the params, so
 optimizer steps never perturb in-flight decodes between syncs). This is
 deliberately the single-controller shape production RL systems argue
-about: the bench (``python bench.py rl``) prices its three couplings —
-rollout tokens/s, train steps/s, and weight-sync latency — per
-iteration.
+about: its three couplings are rollout tokens/s, train steps/s and
+weight-sync latency, which each iteration's row reports (on the chip:
+not measured).
 """
 
 from __future__ import annotations

@@ -10,7 +10,7 @@ unit WFQ charges), refilled at ``rate`` tokens/second with ``burst``
 headroom. A tenant past its bucket is rejected at submit with reason
 ``"quota"`` and a ``retry_after_s`` hint, BEFORE the request touches the
 shared queue — so a flooding tenant throttles itself and a paying tenant
-never waits behind the flood (gated in BENCH_service.json's quota row).
+never waits behind the flood (tests/test_serve_service.py, slow).
 
 Tenants without a configured limit are unmetered: quotas are an opt-in
 cap on known abusers/tiers, not a default tax. Pure host arithmetic over

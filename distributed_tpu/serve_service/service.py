@@ -626,7 +626,7 @@ class ServeService:
     @property
     def streamed_tokens(self) -> int:
         """Tokens delivered to clients so far, across all open and
-        finished streams — the bench kill row uses it to time the kill
+        finished streams — a kill drill uses it to time the kill
         mid-decode instead of guessing a wall delay."""
         return sum(len(s.tokens) for s in self._streams.values())
 

@@ -17,8 +17,8 @@ Greedy decode (``temperature=0``) is token-identical per request to
 (``Request.seed``) and can capture per-token logprobs
 (``run(return_logprobs=True)``); ``Engine.update_weights`` hot-swaps
 served weights without a restart (the ``rl.PostTrainer`` sync seam —
-docs/RL.md). ``bench.py serve`` measures the throughput/latency win
-over the static-batch baseline (docs/SERVING.md).
+docs/RL.md). What continuous batching saves over a static-batch
+server, and when: docs/SERVING.md.
 
 Memory-economy levers (docs/SERVING.md "Prefix caching & speculative
 decoding"): ``Engine(prefix_cache=True)`` shares common prompt prefixes
@@ -27,7 +27,7 @@ across requests through a refcounted, copy-on-write block store;
 seam (more concurrent slots, fidelity-gated); ``draft_model=`` enables
 speculative decoding — k candidate tokens verified in one fixed-shape
 dispatch, token-exact against vanilla decode under greedy and pinned
-seeds. ``bench.py prefix`` measures all three.
+seeds. tests/test_prefix.py pins all three.
 """
 
 from .engine import Engine

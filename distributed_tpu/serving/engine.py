@@ -89,7 +89,7 @@ SPEC_MIN_ROUNDS = 2
 
 def _ladder_k(accept_ema: float) -> int:
     """Ladder rung for an accept-rate EMA: the break-even thresholds of
-    docs/PERF.md "When speculation pays" — below 0.25 the draft's dispatch
+    docs/SERVING.md "Draft models & gossip": below 0.25 the draft's dispatch
     cost exceeds the verify savings at ANY k, so it switches off."""
     if accept_ema < 0.25:
         return 0
@@ -365,7 +365,7 @@ class Engine:
         # dense-attention path; 'fused' traces the decode and verify
         # dispatches through the fused Pallas gather+attention kernel
         # (ops.paged_attention — token-parity pinned in tests; the
-        # throughput claim is accelerator-only, docs/PERF.md). Prefill is
+        # throughput on the chip is not measured yet). Prefill is
         # chunk-parallel, not table-bound, and always uses the reference
         # path.
         from ..ops import paged_attention as paged_ops
@@ -625,7 +625,7 @@ class Engine:
                 params, hints=self.model.module.sharding_hints()
             )
             # Block until resident: the next dispatch must read the new
-            # weights, and the latency reported by callers (the bench's
+            # weights, and the latency reported by callers (the RL loop's
             # weight-sync row) must cover the transfer, not enqueue it.
             jax.block_until_ready(placed)
             self._params = placed

@@ -275,7 +275,7 @@ class Model:
         ``devices``, ``grad_accums``, ``precisions``, ``include_tp``,
         ``include_pp``, ``top_k``). The decision record lands in ``model.last_plan``,
         ``model.last_fit_telemetry["plan"]``, and the JSONL event log
-        (``auto_shard_plan``); see docs/PERF.md "Autotuned sharding".
+        (``auto_shard_plan``); see docs/API.md "Autotuned sharding".
 
         ``head_chunks=C``: fused chunked head-loss for token models.
         The module's FINAL layer (the vocab head) and the loss are applied
@@ -316,7 +316,7 @@ class Model:
         device inside the scan. This amortizes per-step host overhead
         (dispatch, placement, the per-step Python bookkeeping) over K
         steps — the Keras ``steps_per_execution`` lever, and the cure for
-        host-bound small-model training (docs/PERF.md "Multi-step
+        host-bound small-model training (docs/API.md "Multi-step
         execution"). Numerics match K=1 to float tolerance (same batch
         order, same per-step RNG fold). Callbacks, the progress line, and
         ``model.step`` advance at K-step granularity; validation is
@@ -339,7 +339,7 @@ class Model:
         overrides the policy for that layer. Under ``FSDP`` /
         ``ZeroDataParallel`` the compute cast happens before the
         sharding-constraint-driven all-gathers, halving the per-layer
-        param-gather traffic under bf16 (docs/PERF.md "Mixed
+        param-gather traffic under bf16 (docs/API.md "Mixed
         precision"). ``None`` (default) disables the policy machinery
         entirely — the pre-policy f32 behavior, byte-for-byte."""
         if strategy is None:
@@ -1673,13 +1673,13 @@ class Model:
             report["redundancy"] = red
         # Collective-traffic estimate at the dtype the bytes move in: a
         # mixed policy halves FSDP's gathered-param bytes (bf16 vs f32) —
-        # the number `bench.py precision` compares across policies.
+        # the number tests/test_precision.py compares across policies.
         report["precision"] = (
             self.precision.name if self.precision is not None else None
         )
         # Streaming-input telemetry: the decode-parallelism setting rides
         # next to the stall fractions it exists to shrink, so a stall
-        # report names the knob to turn (docs/PERF.md "Streaming input").
+        # report names the knob to turn (docs/API.md "Streaming input").
         if y is None and getattr(source, "decode_workers", None) is not None:
             report["input_decode_workers"] = int(source.decode_workers)
         report["comm_bytes_estimate"] = self.strategy.comm_bytes_estimate(
@@ -1695,9 +1695,9 @@ class Model:
         # thread says whether the double-buffered gather engaged.
         # exposed_comm_fraction is the analytic share of per-layer gather
         # traffic left serial with compute: all L gathers without overlap,
-        # only layer 0's warm-up gather with it. The span-attributed
-        # measurement lives in `bench.py overlap2`; this rides with every
-        # fit so telemetry names the lever (docs/PERF.md "Overlap round 2").
+        # only layer 0's warm-up gather with it. Exposed collective time
+        # on the chip is the benchmark's trace metric; this rides with every
+        # fit so telemetry names the lever (docs/API.md "Overlap round 2").
         from ..nn.scan import last_overlap_trace
         _otrace = last_overlap_trace()
         if _otrace is None:
@@ -1728,7 +1728,7 @@ class Model:
         # trace-time record of the most recent pipelined apply on this
         # thread — which schedule ran, its static tick count, and the
         # analytic bubble fraction (n-1)/ticks. Same warm-cache fallback
-        # discipline as the overlap record above (docs/PERF.md "Pipeline
+        # discipline as the overlap record above (docs/API.md "Pipeline
         # round 2").
         from ..nn.pipeline import last_pipeline_trace
         _ptrace = last_pipeline_trace()
@@ -1758,7 +1758,7 @@ class Model:
                 )
         # The auto-shard decision record rides with every fit it governed:
         # chosen config, predicted bytes/traffic, and the pruned
-        # candidates' rationale (docs/PERF.md "Autotuned sharding").
+        # candidates' rationale (docs/API.md "Autotuned sharding").
         if self.last_plan is not None:
             report["plan"] = self.last_plan.summary()
         # Dropless expert layers count in their state (no sync in the step

@@ -12,7 +12,7 @@ hits; the rule here is therefore:
   git-ignored path, never built from a hostname, pid, time or temp name.
 
 :func:`enable` is the only place in the repo that updates
-``jax_compilation_cache_dir``. Callers: ``chip_smoke.py`` and ``bench.py``.
+``jax_compilation_cache_dir``. Callers: ``chip_smoke.py``, ``benchmarks/``.
 Entry thresholds stay at JAX's defaults (compiles under one second are not
 cached). Tier-1 runs without it: on XLA:CPU every cache hit logs a
 multi-kilobyte ``cpu_aot_loader`` machine-feature message to stderr.

@@ -148,8 +148,8 @@ class StepTimer:
     the next staged batch / blocked on the device behind a donated
     dispatch / blocked on checkpoint saves-and-flushes), and
     ``stall_report()`` turns the buckets into seconds + fractions of the
-    timer's lifetime, including the ``input_stall_fraction`` that
-    ``bench.py overlap`` compares across prefetch depths.
+    timer's lifetime, including the ``input_stall_fraction`` to
+    compare across prefetch depths.
     """
 
     def __init__(self, warmup: int = 1):
@@ -186,7 +186,7 @@ class StepTimer:
         path contract: whether the caller is the fit loop's spans, the
         serving engine, or a checkpoint callback, stall accounting lands
         in the same registry the exporters and cross-rank aggregation
-        read. Registry-disabled runs skip the forward (the bench's bare
+        read. Registry-disabled runs skip the forward (the bare loop's
         half)."""
         self.stalls[category] = self.stalls.get(category, 0.0) + float(seconds)
         from ..obs import registry as _obs_registry  # lazy: import order
@@ -199,13 +199,13 @@ class StepTimer:
     def stall_report(self) -> dict:
         """Attributed seconds per category, the timer's total lifetime
         (``total_seconds``, wall clock since construction), per-category
-        fractions of that total (``<category>_fraction`` — the overlap
-        and obs benches read dispatch/checkpoint fractions, not just
+        fractions of that total (``<category>_fraction`` — overlap and
+        obs work reads the dispatch/checkpoint fractions, not just
         input), the ``unattributed`` remainder (total minus the
         categories' sum: callbacks, Python bookkeeping, epoch sync — an
         honest residual instead of a silent one), and the legacy
-        ``input_stall_fraction`` (= ``input_wait_fraction``) that
-        ``bench.py overlap`` compares across prefetch depths."""
+        ``input_stall_fraction`` (= ``input_wait_fraction``) to
+        compare across prefetch depths."""
         elapsed = max(time.perf_counter() - self._wall0, 1e-9)
         out = {}
         for cat in ("input_wait", "dispatch", "checkpoint_wait"):

@@ -1,6 +1,6 @@
 """Train the 136M LM at 64k context on ONE 16 GB TPU chip.
 
-The recipe, each piece measured in docs/PERF.md:
+The recipe, each piece measured in docs/PERF_ROUNDS_1-5.md:
 
 1. **Pallas flash attention** (automatic in MultiHeadAttention): O(T)
    attention memory instead of the (T, T) score matrix.
@@ -12,7 +12,7 @@ The recipe, each piece measured in docs/PERF.md:
    at T=65k, V=32k, doubled by the backward cotangent) never exist.
    Without this the 64k step cannot even compile on the chip.
 
-Measured single v5e chip (docs/PERF.md): 8,756 tok/s at T=65,536
+Measured single v5e chip, pre-PR-1 code (docs/PERF_ROUNDS_1-5.md): 8,756 tok/s at T=65,536
 (MFU 0.352) — the ladder from 16k (0.380) to 64k is nearly flat.
 
 Beyond one chip, shard the sequence itself with

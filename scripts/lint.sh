@@ -10,7 +10,7 @@
 # collective discipline, trace purity, event-schema agreement, thread
 # hygiene. Exit status is dtpu-lint's: 0 clean, 1 findings, 2 usage.
 # scripts/tier1.sh runs this same gate before pytest — a lint regression
-# fails in seconds, not after a 13-minute suite.
+# fails in seconds, not somewhere inside the suite.
 #
 # JAX_PLATFORMS=cpu: the linter never initializes jax, but importing the
 # package's CLI module pulls the top-level __init__; pin CPU so a box

@@ -1,7 +1,7 @@
-"""Auto-shard planner (parallel/auto_shard.py; docs/PERF.md "Autotuned
+"""Auto-shard planner (parallel/auto_shard.py; docs/API.md "Autotuned
 sharding"): the unified comm schema every strategy now reports, abstract
 byte accounting (live == dry-run), feasibility pruning under a synthetic
-HBM cap (mirroring the BENCH_zero 256MB-cap row), plan determinism, and
+HBM cap (replicated state over it, FSDP's under it), plan determinism, and
 ``compile(strategy="auto")`` end-to-end on a 2-device mesh. The measured-
 shortlist path (``measure=True``) is @slow — in-tier planner tests stay
 estimate-only (no dispatch sweeps) per the tier-1 time budget.
@@ -197,7 +197,7 @@ class TestFeasibility:
         assert Feasibility(None).check(10**15) is None
 
     def test_cap_prunes_replicated_keeps_fsdp(self):
-        """The BENCH_zero 256MB-cap row, generalized: under a cap between
+        """The model that only sharding can hold: under a cap between
         the replicated and FSDP footprints, replicated DP is pruned WITH
         rationale and FSDP survives + wins (estimate-only — no tree is
         materialized)."""
@@ -289,7 +289,7 @@ class TestPipelinePlanner:
     # Every dim indivisible by any 8-divisor: _largest_divisible_spec
     # degrades DP/ZeRO/FSDP to full replication and the pipelined stack's
     # 'pipe' hints leave TP nothing to shard — depth is the ONLY axis
-    # that still splits state. Same shape as bench.py's pipeline row 1.
+    # that still splits state.
     AWKWARD = dict(vocab=331, num_layers=4, d_model=36, num_heads=2,
                    d_ff=84, max_len=33, pipeline=True)
 

@@ -4,8 +4,8 @@ The full (tokens, vocab) logits tensor never materializes — the head and
 the loss (and sum-count metrics) run chunk-by-chunk under a rematerialized
 lax.scan. These tests pin numerical equivalence with the plain step on the
 CPU sim; the capability it exists for (T=65,536 on one 16 GB chip, where
-bf16 logits alone would be 4.3 GB) is measured on the real chip
-(docs/PERF.md round-5 long-context table).
+bf16 logits alone would be 4.3 GB) was measured on the real chip
+(docs/PERF_ROUNDS_1-5.md, round-5 long-context table).
 """
 
 import numpy as np
@@ -178,7 +178,7 @@ def test_chunked_head_composes_with_accumulation_and_clip():
 
 
 def test_chunked_head_with_pallas_xent_loss():
-    """The bench's loss (Pallas fused xent, interpret mode on CPU) rides
+    """The benchmark's loss (Pallas fused xent, interpret mode on CPU) rides
     the same chunked path."""
     x, y = _data()
     m = dtpu.Model(

@@ -1,6 +1,6 @@
-"""Dataset loader behaviors the benches depend on.
+"""Dataset loader behaviors a convergence run depends on.
 
-The convergence bench's data-source honesty (reporting synthetic vs real)
+Knowing whether a run trained on synthetic or real data
 rests on these: cache discovery finds pre-seeded IDX files, and the
 network-guarded fetch NEVER raises on hermetic machines.
 """

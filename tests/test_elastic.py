@@ -11,8 +11,6 @@ capacity-regain run grows 2->4. The real-gang end-to-ends are @slow; the
 policy/ledger/supervisor/cluster/pipeline units stay in tier-1.
 """
 
-import sys
-from pathlib import Path
 
 import numpy as np
 import pytest
@@ -35,8 +33,6 @@ from distributed_tpu.resilience.supervisor import (
     _initiated,
 )
 from distributed_tpu.utils.events import EventLog, read_events
-
-REPO = str(Path(__file__).resolve().parent.parent)
 
 
 # ---------------------------------------------------------------- policy ----
@@ -593,11 +589,10 @@ def test_elastic_shrink_e2e_4_to_2_with_loss_equivalence(tmp_path):
     documented equivalence contract: identical global batches (bit-exact,
     pinned by TestPipelineReshard), loss equal to f32
     reduction-regrouping tolerance (docs/RESILIENCE.md "Elastic gangs")."""
-    sys.path.insert(0, REPO)
-    import bench
+    from gang_harness import elastic_gang
 
     steps = 10
-    res, events = bench._elastic_gang(
+    res, events = elastic_gang(
         tmp_path / "run", world=4, min_workers=2, global_batch=64,
         steps=steps, fault="kill:at_step=4,rank=1", fault_above=2,
         failure_threshold=2, max_restarts=3, record_loss=True,
@@ -616,7 +611,7 @@ def test_elastic_shrink_e2e_4_to_2_with_loss_equivalence(tmp_path):
 
     # The equivalent-batch-math uninterrupted run: ONE process, same seed,
     # same GLOBAL batch stream (shard=(0,1) slices are the whole batch).
-    ref_res, ref_events = bench._elastic_gang(
+    ref_res, ref_events = elastic_gang(
         tmp_path / "ref", world=1, min_workers=1, global_batch=64,
         steps=steps, record_loss=True, timeout=900.0,
     )
@@ -637,12 +632,11 @@ def test_elastic_grow_e2e_2_to_4_on_capacity_regain(tmp_path):
     """ACCEPTANCE (ISSUE 7): capacity regained (probe flips 2 -> 4 at the
     restart boundary) grows the gang 2 -> 4; the 2-process sharded
     checkpoint restores into the 4-process gang and the run completes."""
-    sys.path.insert(0, REPO)
-    import bench
+    from gang_harness import elastic_gang
 
     cap = tmp_path / "capacity"
     cap.write_text("2")
-    res, events = bench._elastic_gang(
+    res, events = elastic_gang(
         tmp_path / "run", world=2, min_workers=2, max_workers=4,
         global_batch=64, steps=8, fault="kill:at_step=3,rank=0",
         fault_above=0, probe_file=cap, cap_flip_to=4, cap_flip_at=3,

@@ -193,13 +193,13 @@ def _warm_then_wave(lm, rng_seed, gossip, programs=None):
     return fl, out
 
 
-def test_fleet_gossip_adopt_token_exact_and_ttft(lm, tmp_path,
+def test_fleet_gossip_adopt_token_exact_and_wave_spread(lm, tmp_path,
                                                  monkeypatch):
     """The tentpole gate, in-process: the gossiping fleet adopts the
     warm replica's prefix onto the cold one (zero full re-prefills in
-    the wave), finishes first tokens strictly earlier than the
-    gossip-off fleet (which serializes the wave on the one warm
-    replica), and the token streams are identical. Adopt/advertise
+    the wave), spreads the wave over both replicas where the
+    gossip-off fleet serializes it on the one warm replica, and the
+    token streams are identical. Adopt/advertise
     events land in the log."""
     monkeypatch.setenv("DTPU_EVENT_LOG", str(tmp_path / "ev.jsonl"))
     # Same rng seed both runs: identical prompts, or token comparison
@@ -228,11 +228,15 @@ def test_fleet_gossip_adopt_token_exact_and_ttft(lm, tmp_path,
     assert sum(r["prefills_full"] for r in rows.values()) == 1
     assert sum(r["gossip_adopts"] for r in rows.values()) >= 1
     assert sum(r["gossip_serves"] for r in rows.values()) >= 1
-    # cold-replica TTFT: the gossip-off fleet pins the whole wave on
-    # the warm replica (affinity), so its worst first token waits for
-    # two predecessors; gossip spreads the wave and wins
-    assert tel["time_to_first_token"]["max"] \
-        < fl_off.last_run_telemetry["time_to_first_token"]["max"]
+    # the gossip-off fleet pins the whole wave on the warm replica
+    # (affinity), so its third request waits for a slot behind two
+    # predecessors; gossip spreads the wave over both replicas. (The
+    # first-token times that follow from it are measured walls on a
+    # virtual clock: compared, they failed under a loaded box.)
+    served_on = {r["replica"] for r in tel["requests"]}
+    served_off = {r["replica"]
+                  for r in fl_off.last_run_telemetry["requests"]}
+    assert len(served_off) == 1 < len(served_on)
     for a, b in zip(out_on, out_off):
         assert np.array_equal(np.asarray(a), np.asarray(b))
 

@@ -6,8 +6,8 @@ and params/state/opt_state donated across the whole dispatch. These tests
 pin numerical parity with the K=1 loop (same batch order, same per-step
 RNG fold), composition with the other compile levers, and the K-step
 granularity contract for callbacks/checkpoint resume. The capability it
-exists for — amortizing per-step host dispatch overhead — is measured by
-``bench.py multistep`` (docs/PERF.md "Multi-step execution").
+exists for — amortizing per-step host dispatch overhead — is not measured
+on the chip (docs/API.md "Multi-step execution").
 """
 
 import numpy as np

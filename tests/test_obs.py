@@ -7,10 +7,10 @@ the derived-view parity contract (``last_fit_telemetry`` /
 the PR 13 key sets), and the PR's satellites: the event log's cached
 append fd (rotation reopen + concurrent-writer whole-line interleaving),
 ``StepTimer.stall_report``'s unattributed remainder + per-category
-fractions, and rank-stamped structured logging. The supervised-gang
-straggler path runs for real in ``bench.py obs`` (and its schema smoke in
-test_bench.py); here the aggregation math is pinned on synthetic event
-streams and the supervisor's emission on a scripted launcher.
+fractions, and rank-stamped structured logging. The aggregation math is
+pinned on synthetic event streams, the supervisor's straggler emission
+on a scripted launcher, and the kill -> dump -> recovery-row path on a
+real gang under ``-m slow``.
 """
 
 import json
@@ -145,8 +145,8 @@ class TestSpans:
         assert sp.seconds >= 0.002
 
     def test_disabled_span_still_times_for_timer(self):
-        """obs-off: the legacy stall buckets must be unchanged (the bench's
-        bare half still reports input_stall_fraction etc.)."""
+        """obs-off: the legacy stall buckets must be unchanged (a bare
+        loop still reports input_stall_fraction etc.)."""
         reg = MetricsRegistry()
         t = StepTimer(warmup=0)
         prev = obs.set_enabled(False)

@@ -5,7 +5,7 @@ The acceptance bar: fit() with prefetch depth 2 and async checkpoints is
 BIT-IDENTICAL to the synchronous path — including kill-restart-resume
 through the supervisor — while the prefetch producer and checkpoint
 writer threads never leak (conftest's autouse teardown asserts that after
-every test here). bench.py's `overlap` mode measures the wall-clock win;
+every test here). The wall-clock win is not measured on the chip;
 these tests pin the correctness half of the contract.
 """
 

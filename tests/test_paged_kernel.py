@@ -206,13 +206,15 @@ def _requests(seed=0, n=5, vocab=32, p_range=(1, 9), m_range=(3, 9)):
     return prompts, news
 
 
-def _run_both(lm, prompts, news, **kwargs):
+def _run_both(lm, prompts, news, request_seeds=None, **kwargs):
+    seeds = request_seeds or [None] * len(prompts)
     outs = {}
     for kind in (paged_ops.REFERENCE, paged_ops.FUSED):
         engine = Engine(lm, max_slots=2, block_size=4, max_len=64,
                         decode_kernel=kind, **kwargs)
         outs[kind] = engine.run(
-            [Request(p, m) for p, m in zip(prompts, news)])
+            [Request(p, m, seed=s)
+             for p, m, s in zip(prompts, news, seeds)])
     return outs
 
 
@@ -230,11 +232,23 @@ def test_engine_fused_greedy_parity_with_churn(lm):
     _assert_token_exact(_run_both(lm, prompts, news))
 
 
-@pytest.mark.slow
+def test_engine_fused_sampled_seeded_parity(lm):
+    """Sampling at a temperature from pinned seeds: both kernels must
+    hand the sampler the same logits, or the streams part at the first
+    token whose draw lands between them."""
+    prompts, news = _requests(seed=3, n=4)
+    # Without its own seed a request draws from its process-wide id, which
+    # differs between the two engines' Request objects.
+    outs = _run_both(lm, prompts, news, request_seeds=[0, 1, 2, 3],
+                     temperature=0.8, seed=7)
+    _assert_token_exact(outs)
+
+
 def test_engine_fused_int8_kv_parity(lm):
-    """In-tier coverage of int8 dequant lives in the kernel matrix cells
-    and test_paged_view_int8_masks_before_dequantize; the end-to-end
-    engine run is a whale (its own int8 decode compile)."""
+    """The kernel matrix cells and
+    test_paged_view_int8_masks_before_dequantize cover the dequantize
+    alone; this is the engine end to end, with its own int8 decode
+    compile."""
     prompts, news = _requests(seed=1, n=4)
     _assert_token_exact(_run_both(lm, prompts, news, kv_dtype="int8"))
 
@@ -258,12 +272,9 @@ def test_engine_fused_preemption_parity(lm):
     _assert_token_exact(outs)
 
 
-@pytest.mark.slow
 def test_engine_fused_prefix_cache_parity(lm):
     """Shared leading span: prefix-store admission hands the fused path
-    refcounted blocks it never prefilled itself. @slow: the admission
-    path is scheduler-side (kernel-independent); churn + preemption keep
-    the in-tier engine coverage."""
+    refcounted blocks it never prefilled itself."""
     rng = np.random.default_rng(4)
     common = rng.integers(0, 32, (8,)).astype(np.int32)
     prompts = [np.concatenate([common,

@@ -130,8 +130,8 @@ class TestDataPipelineParallel:
     # too since PR 19 — TestInterleavedSchedule::
     # test_parity_bubble_and_telemetry pins the SAME pp2 gpipe-vs-
     # single-device parity at the tighter rtol 2e-5 in-tier, so this
-    # cell's coverage is retained there (and here via -m slow /
-    # TIER1_PIPELINE_SMOKE when touching the schedule).
+    # cell's coverage is retained there (and here via -m slow when
+    # touching the schedule).
     @pytest.mark.slow
     @pytest.mark.parametrize("pp,mb", [
         (2, 2),

@@ -282,8 +282,8 @@ class TestLossScaling:
 # --------------------------------------------------------------- checkpoint --
 class TestCheckpointRoundTrip:
     # @slow (tier-1 budget, PR 17): ~7s cast-roundtrip drive; the
-    # mixed-tracks-f32 loss-parity tests stay in-tier, and the
-    # TIER1_PRECISION_SMOKE fast path (no marker filter) still runs this.
+    # mixed-tracks-f32 loss-parity tests stay in-tier, and
+    # `pytest tests/test_precision.py` (no marker filter) still runs this.
     @pytest.mark.slow
     def test_mixed_to_f32_and_back(self, two_dev, lm_data, tmp_path):
         """Checkpoints hold the f32 masters, so save-under-mixed /

@@ -14,6 +14,7 @@ its failure mode.
 """
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -140,6 +141,36 @@ def test_shared_prefix_parity_and_hit_rate(lm):
     assert all(alloc.refcount(b) == 1 for b in engine.kv.prefix.blocks)
 
 
+def test_system_prompt_groups_hit_accounting(lm):
+    """Two system prompts of four blocks, two requests each, and two
+    requests that share nothing, over two slots. Cold, the first request
+    of a group fills its blocks and the second adopts them; warm, all four
+    adopt. The bytes saved are the adopted blocks' pool bytes: prefill
+    never wrote them."""
+    rng = np.random.default_rng(0)
+    groups = [rng.integers(0, 32, (16,)).astype(np.int32) for _ in range(2)]
+    prompts = []
+    for i in range(6):
+        tail = rng.integers(0, 32, (int(rng.integers(1, 4)),)).astype(
+            np.int32)  # shorter than a block: no request caches its tail
+        prompts.append(tail if i % 3 == 2
+                       else np.concatenate([groups[i % 2], tail]))
+    engine = Engine(lm, max_slots=2, block_size=4, max_len=64,
+                    prefix_cache=True)
+    cold_out = engine.run([Request(p, 4) for p in prompts])
+    cold = engine.last_run_telemetry["prefix_cache"]
+    assert cold["insertions"] == 8 and cold["hit_blocks"] == 8
+    assert cold["hit_rate"] == 0.5 and cold["hit_tokens"] == 2 * 16
+    assert cold["kv_bytes_saved"] == 8 * engine.kv.bytes_per_block()
+    warm_out = engine.run([Request(p, 4) for p in prompts])
+    warm = engine.last_run_telemetry["prefix_cache"]
+    assert warm["hit_tokens"] == 4 * 16
+    assert warm["insertions"] == 8  # the store's counters run on
+    assert warm["hit_blocks"] == 8 + 16
+    for c, w in zip(cold_out, warm_out):
+        np.testing.assert_array_equal(c, w)
+
+
 def test_cow_on_fully_cached_prompt(lm):
     """Re-serving an identical prompt finds its blocks fully cached; the
     admission cap (always recompute the last position) forces a write
@@ -200,11 +231,10 @@ def test_store_eviction_under_distinct_prompt_pressure(lm):
 
 
 # ------------------------------------------------------------------ int8 --
-@pytest.mark.slow
 def test_int8_kv_pools_shapes_ratio_and_fidelity(lm):
     """int8 KV pools store {q, scale} per block; the byte ratio over f32
     matches 4*hd/(hd+4) exactly, and greedy decode stays high-agreement
-    with the f32 engine (fidelity-gated, NOT bit-exact — docs/PERF.md)."""
+    with the f32 engine (fidelity-gated, NOT bit-exact: docs/SERVING.md)."""
     rng = np.random.default_rng(4)
     prompts, news = _shared_prefix_requests(rng, shared_len=8, n=4)
     f32 = Engine(lm, max_slots=2, block_size=4, max_len=64)
@@ -229,6 +259,23 @@ def test_int8_kv_pools_shapes_ratio_and_fidelity(lm):
         agree += int(np.sum(gx == gy))
         total += len(gx)
     assert agree / total >= 0.5, f"int8 KV agreement {agree}/{total}"
+
+
+@pytest.mark.parametrize("d_model,num_heads", [(64, 2), (128, 1)])
+def test_int8_pool_holds_more_slots_a_byte(d_model, num_heads):
+    """An int8 pool stores a byte an element and one f32 scale a (position,
+    head), so a pool byte holds 4 hd / (hd + 4) times the slots f32 does:
+    3.56 at head size 32, 3.88 at 128."""
+    model = dtpu.Model(dtpu.models.transformer_lm(
+        32, num_layers=1, d_model=d_model, num_heads=num_heads, max_len=16))
+    model.build((8,))
+    pool = dict(max_slots=1, block_size=4, max_blocks_per_seq=2,
+                num_blocks=3)
+    f32 = PagedKVCache(model.module, model.params, dtype=jnp.float32,
+                       **pool)
+    int8 = PagedKVCache(model.module, model.params, dtype=jnp.int8, **pool)
+    hd = d_model // num_heads
+    assert f32.bytes_per_block() * (hd + 4) == int8.bytes_per_block() * 4 * hd
 
 
 # ------------------------------------------------------------ speculative --

@@ -15,6 +15,7 @@ import pytest
 
 import distributed_tpu as dtpu
 from distributed_tpu import quant
+from distributed_tpu.utils.profiler import tree_bytes_per_device
 
 VOCAB, LAYERS, D, HEADS, MAXLEN = 96, 2, 32, 2, 64
 
@@ -197,6 +198,20 @@ def test_param_bytes_ratio():
     # biases/norms/scales stay f32, so the ratio sits under the ideal 4x
     # but must clear the serving gate on even this small LM.
     assert ratio >= 3.5
+
+
+def test_resident_bytes_of_the_serving_lm():
+    """The serving LM (4 layers of 256, 512 rows) as the devices hold it:
+    3,454,976 f32 parameters, of which quantize_model keeps the 14,336
+    one-dimensional ones in f32 and gives the matrices' 3,440,640 a byte
+    each and 10,240 f32 scales."""
+    m = dtpu.Model(dtpu.models.transformer_lm(
+        512, num_layers=4, d_model=256, num_heads=8, max_len=128))
+    m.build((32,), seed=0)
+    f32 = tree_bytes_per_device(m.params)["max_bytes_per_device"]
+    quant.quantize_model(m)
+    int8 = tree_bytes_per_device(m.params)["max_bytes_per_device"]
+    assert (f32, int8) == (13819904, 3538944)  # 3.905 x
 
 
 def test_fsdp_comm_bytes_int8(devices):

@@ -15,8 +15,8 @@ Contracts pinned here:
 - Sharded record pipelines compose with reshard: host slices assemble
   into exactly the unsharded batch, before and after a resize.
 
-Shapes are lean (tier-1 budget); the decode-bound throughput claim lives
-in ``bench.py input`` (BENCH_input.json), not here.
+Shapes are lean (tier-1 budget); what decode parallelism gains on a
+decode-bound input is not measured on the chip.
 """
 
 import os

@@ -15,8 +15,6 @@ single-process restores exercise the identical reassembly).
 import json
 import os
 import shutil
-import sys
-from pathlib import Path
 
 import jax
 import numpy as np
@@ -36,8 +34,6 @@ from distributed_tpu.resilience import (
 )
 from distributed_tpu.resilience import faults as faults_lib
 from distributed_tpu.utils.profiler import redundancy_report
-
-REPO = str(Path(__file__).resolve().parent.parent)
 
 
 # ------------------------------------------------------------------ ring ----
@@ -494,14 +490,13 @@ def _losses_by_step(events):
 
 
 def _matrix_gang(tmp, **kw):
-    sys.path.insert(0, REPO)
-    import bench
+    from gang_harness import recovery_gang
 
     kw.setdefault("width", 192)
     kw.setdefault("steps", 8)
     kw.setdefault("record_loss", True)
     kw.setdefault("timeout", 900.0)
-    res, events, store = bench._recovery_gang(tmp, **kw)
+    res, events, store = recovery_gang(tmp, **kw)
     shutil.rmtree(store, ignore_errors=True)
     return res, events
 

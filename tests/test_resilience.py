@@ -731,16 +731,3 @@ def test_cli_supervise_end_to_end(tmp_path):
     kinds = [e["event"] for e in read_events(ev)]
     assert "restart" in kinds and kinds[-1] == "run_complete"
     assert "supervisor: attempts=2 restarts=1" in proc.stdout
-
-
-@pytest.mark.slow
-def test_bench_resilience_smoke():
-    sys.path.insert(0, REPO)
-    import bench
-
-    out = bench.bench_resilience(throttled_calls=2000, beats=200,
-                                 train_steps=6, kill_step=3)
-    assert out["ok"] and out["attempts"] == 2
-    assert out["value"] is not None and out["value"] > 0
-    assert out["heartbeat_throttled_ns_per_call"] > 0
-    assert out["heartbeat_beat_ns_per_call"] > 0

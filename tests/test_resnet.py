@@ -81,8 +81,7 @@ class TestResidual:
 class TestResNet:
     # @slow (tier-1 budget, PR 17): ~7s resnet50-scale host init; the
     # block/shortcut wiring units and test_resnet18_param_count stay
-    # in-tier pinning the same constructor math at a cheaper scale, and
-    # `python bench.py resnet` builds the full resnet50.
+    # in-tier pinning the same constructor math at a cheaper scale.
     @pytest.mark.slow
     def test_resnet50_param_count(self):
         # Published torchvision/keras ResNet-50 v1.5 count.
@@ -111,7 +110,7 @@ class TestResNet:
     # @slow (tier-1 budget, PR 17): ~12s conv-stack DP training drive; the
     # architecture stays pinned in-tier (apply-shape + resnet50 param
     # count) and DP training numerics are covered in-tier by the
-    # mnist_cnn strategy suite; `python bench.py resnet` drives training.
+    # mnist_cnn strategy suite.
     @pytest.mark.slow
     def test_tiny_resnet_trains_dp(self, devices):
         # 1-block-per-stage bottleneck net on the 8-device mesh: the full

@@ -163,7 +163,7 @@ def test_post_trainer_closed_loop_improves_and_syncs(lm, sampling_engine):
     for a, b in zip(jax.tree_util.tree_leaves(engine._params),
                     jax.tree_util.tree_leaves(lm.params)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-    # History rows carry the three loop couplings the bench prices.
+    # History rows carry the loop's three couplings.
     for key in ("rollout_tokens_per_sec", "train_steps_per_sec",
                 "weight_sync_s"):
         assert rows[-1][key] > 0

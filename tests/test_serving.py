@@ -66,8 +66,27 @@ def test_continuous_batching_matches_sequential_generate(lm):
     assert 0.0 < t["kv_utilization"]["peak"] <= 1.0
 
 
-# @slow (tier-1 budget, PR 10): 11s; still runs in TIER1_SERVE_SMOKE
-# (no -m filter) and with -m slow when touching prefill.
+def test_freed_slots_refill_before_the_batch_drains(lm):
+    """What continuous batching is for, as a count: a static-batch server
+    takes requests two at a time and decodes each pair until its longer
+    answer is done, 12 + 12 + 2 steps here. The engine hands a finished
+    sequence's slot to the next request, so the same work fits in fewer
+    decode dispatches, though never fewer than the tokens over the
+    slots."""
+    rng = np.random.default_rng(5)
+    news = [12, 2, 2, 12, 2, 2]
+    prompts = [rng.integers(0, 32, (3,)).astype(np.int32) for _ in news]
+    engine = Engine(lm, max_slots=2, block_size=4, max_len=64)
+    engine.run([Request(p, m) for p, m in zip(prompts, news)])
+    steps = engine.last_run_telemetry["decode_steps"]
+    static = sum(max(news[i:i + 2]) for i in range(0, len(news), 2))
+    # a request's first token comes from its prefill
+    floor = -(-sum(m - 1 for m in news) // 2)
+    assert floor <= steps < static - len(news) // 2, (steps, static)
+
+
+# @slow (tier-1 budget, PR 10): 11s; run it with -m slow when touching
+# prefill.
 @pytest.mark.slow
 def test_prefill_chunking_matches_whole_prompt(lm):
     """The prefill/decode split at its sharpest: a chunked prefill (chunks

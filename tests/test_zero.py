@@ -14,6 +14,7 @@ import pytest
 from jax.sharding import PartitionSpec
 
 import distributed_tpu as dtpu
+from distributed_tpu.utils.profiler import tree_bytes_per_device
 
 
 def _data(n=128):
@@ -117,6 +118,40 @@ class TestFSDPOverData:
         losses = _step_losses(m, dp_run["x"], dp_run["y"], steps=10)
         np.testing.assert_allclose(losses, dp_run["losses"],
                                    rtol=2e-5, atol=2e-6)
+
+
+class TestStateBytesAcrossTheMesh:
+    """Measured per-device model state (params + Adam moments, from the
+    shard buffers) of one LM on all 8 devices: what replication pays and
+    each ZeRO stage saves, as exact ratios."""
+
+    @staticmethod
+    def _state_bytes(strategy):
+        with strategy.scope():
+            m = dtpu.Model(dtpu.models.transformer_lm(
+                64, num_layers=2, d_model=64, num_heads=4, max_len=64))
+            m.compile(optimizer=dtpu.optim.Adam(1e-3),
+                      loss="sparse_categorical_crossentropy")
+        m.build((64,))
+        return tree_bytes_per_device(
+            m.params, m.state, m.opt_state)["max_bytes_per_device"]
+
+    @pytest.fixture(scope="class")
+    def replicated(self, devices):
+        return self._state_bytes(dtpu.DataParallel())
+
+    @pytest.mark.parametrize("strategy_cls,saving", [
+        # params whole on every device, both moments in eighths
+        (dtpu.ZeroDataParallel, 3 / (1 + 2 / 8)),
+        # params and both moments in eighths
+        (dtpu.FSDP, 8.0),
+    ])
+    def test_adam_state_ratio_against_replicated(self, replicated,
+                                                 strategy_cls, saving):
+        # Every leaf's leading dimension divides by 8, so what keeps the
+        # ratios from exact is Adam's replicated step count alone.
+        sharded = self._state_bytes(strategy_cls())
+        assert replicated / sharded == pytest.approx(saving, rel=1e-3)
 
 
 class TestGradAccum:
