@@ -37,7 +37,7 @@ import jax.numpy as jnp
 from . import core, initializers
 from .core import Layer, Shape, child_scope
 from .layers import GatedMLP
-from ..ops import grouped_matmul as gmm
+from ..ops import grouped_matmul as gmm, moe_rows
 from ..quant import maybe_dequantize
 from ..precision import resolve_dtype
 
@@ -286,76 +286,61 @@ class MoE(Layer):
 
 
 # ------------------------------------------------------ dropless experts --
-# Pairs are laid out choice-major, pair j * n + i being token i's j-th choice:
-# n * k gathered rows then split as (k, n, d) along the major axis, which is
-# no data movement, where (n, k, d) would pad k to a sublane tile and copy.
-def _gather_pairs(buf, dest):
-    """Rows ``dest`` (k, n) of ``buf`` (M, d) as (k, n, d); ``dest`` is in
-    range by construction."""
-    k, n = dest.shape
-    rows = jnp.take(buf, dest.reshape(-1), axis=0, mode="clip")
-    return rows.reshape(k, n, buf.shape[-1])
-
-
+# The rows go to the experts' buffer and back by ``ops.moe_rows``'s two
+# walks over the tiles in use. Pairs are laid out choice-major, pair
+# j * n + i being token i's j-th choice. ``walk`` is what both walks follow:
+# ``(row_pair, rows_in_tile, tiles_used)``, the pair of each buffer row, the
+# valid rows of each tile and the tiles in use.
 @jax.custom_vjp
-def _dispatch(flat, src_token, row_valid, dest, held):
-    """Rows of ``flat`` (n, d) gathered into the experts' buffer (M, d):
-    row r holds token ``src_token[r]`` where ``row_valid[r]``, zeros
-    elsewhere. Its transpose is a gather too: token i's gradient is the sum
-    of the buffer rows ``dest[j, i]`` of its held pairs, so no scatter-add
-    of M rows runs in either direction."""
-    rows = jnp.take(flat, src_token, axis=0, mode="clip")
-    return jnp.where(row_valid[:, None], rows, jnp.zeros((), flat.dtype))
+def _dispatch(flat, walk):
+    """Rows of ``flat`` (n, d) into the experts' buffer (M, d): a valid row
+    of a tile in use holds its pair's token, a padded one zeros; the tiles
+    not in use are not written (nobody reads them: the grouped matmuls
+    follow ``tiles_used`` too). Its transpose is the other walk: token i's
+    gradient is the f32 sum of the buffer rows of its pairs."""
+    return moe_rows.gather_rows(flat, *walk)
 
 
-def _dispatch_fwd(flat, src_token, row_valid, dest, held):
-    return _dispatch(flat, src_token, row_valid, dest, held), (dest, held)
+def _dispatch_fwd(flat, walk):
+    return _dispatch(flat, walk), (walk, flat.shape[0])
 
 
 def _dispatch_bwd(res, d_buf):
-    dest, held = res
-    rows = _gather_pairs(d_buf, dest)
-    d_flat = jnp.sum(jnp.where(held[:, :, None], rows.astype(jnp.float32),
-                               0.0), axis=0)
-    return d_flat.astype(d_buf.dtype), None, None, None, None
+    walk, n = res
+    return moe_rows.sum_rows(d_buf, *walk, n), None
 
 
 _dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
 
 
 @jax.custom_vjp
-def _combine(out_buf, gates, dest, src_pair, row_valid):
-    """``y[i] = sum_j gates[j, i] * out_buf[dest[j, i]]``: a gather of n*k
-    buffer rows and a weighted sum over k. ``gates`` (k, n) float32, zero
-    where the pair's expert is not held. Backward: each buffer row takes
-    its token's dy times its gate (a gather of M rows), and a gate's
-    gradient is its row's dot with dy (the rows gathered again, not kept)."""
-    rows = _gather_pairs(out_buf, dest)
-    y = jnp.sum(gates[:, :, None] * rows.astype(jnp.float32), axis=0)
-    return y.astype(out_buf.dtype)
+def _combine(out_buf, gates, walk):
+    """``y[i] = sum_j gates[j, i] * out_buf[row of pair (j, i)]`` over the
+    pairs held, in float32: each valid buffer row, times its pair's gate,
+    added to its token. ``gates`` (k, n) float32; a pair whose expert is
+    not held has no row and adds nothing. Backward, one walk: each buffer
+    row takes its token's dy times its gate, and its dot with that dy is
+    its gate's gradient."""
+    return moe_rows.sum_rows(out_buf, *walk, gates.shape[1],
+                             pair_scale=gates)
 
 
-def _combine_fwd(out_buf, gates, dest, src_pair, row_valid):
-    y = _combine(out_buf, gates, dest, src_pair, row_valid)
-    return y, (out_buf, gates, dest, src_pair, row_valid)
+def _combine_fwd(out_buf, gates, walk):
+    return _combine(out_buf, gates, walk), (out_buf, gates, walk)
 
 
 def _combine_bwd(res, dy):
-    out_buf, gates, dest, src_pair, row_valid = res
-    k, n = dest.shape
-    row_gate = jnp.where(row_valid, gates.reshape(-1)[src_pair], 0.0)
-    d_out = (jnp.take(dy, src_pair % n, axis=0, mode="clip").astype(
-        jnp.float32) * row_gate[:, None]).astype(out_buf.dtype)
-    rows = _gather_pairs(out_buf, dest)
-    d_gates = jnp.sum(rows.astype(jnp.float32)
-                      * dy.astype(jnp.float32)[None], axis=-1)
-    return d_out, jnp.where(gates != 0, d_gates, 0.0), None, None, None
+    out_buf, gates, walk = res
+    d_out, d_gates = moe_rows.gather_rows(
+        dy, *walk, pair_scale=gates, dot_with=out_buf)
+    return d_out, d_gates, None
 
 
 _combine.defvjp(_combine_fwd, _combine_bwd)
 
 # Names of a DroplessMoE's counters in its state (``counters``).
-_COUNTERS = ("steps", "pairs", "held_rows", "load_max_sum")
+_COUNTERS = ("steps", "pairs", "held_rows", "load_max_sum", "tiles_used",
+             "buffer_tiles")
 
 
 class DroplessMoE(Layer):
@@ -391,20 +376,29 @@ class DroplessMoE(Layer):
     sized for every pair landing here, each group starting on a tile
     boundary (``ops.grouped_matmul.group_layout``). Three grouped matmuls run
     over the groups' tiles in use, and the rows go back weighted by their
-    gates. Dispatch, combine and both their transposes are gathers, the
-    pairs laid out choice-major so that no gathered block is copied to be
-    reshaped.
+    gates. Dispatch, combine and both their transposes follow the tiles in
+    use too (``ops.moe_rows``): into the buffer, each valid row of a tile in
+    use fetches its token's row (dispatch; combine's backward, which also
+    takes each row's dot with dy for its gate's gradient); out of it, each
+    valid row is added in float32 to its token (combine, times the pair's
+    gate; dispatch's backward). A pair whose expert is not held has no row,
+    costs nothing and adds exactly zero; the tiles not in use are neither
+    written nor read, so what the layer moves follows the share it holds,
+    not the buffer's static worst case.
 
     Device scopes under the layer's own (``moe``): ``route`` (router, top-k,
-    sort, gather, weighted sum back), ``experts`` (the grouped matmuls and
+    sort, the row walks both ways), ``experts`` (the grouped matmuls and
     the activation between them) and ``shared``. Counters, cumulative over
     train steps, in the layer's state and so read with no device sync
     inside a step loop (``counters``): ``steps``, ``pairs`` (tokens x
-    ``top_k``), ``held_rows`` (pairs whose expert is held here) and
+    ``top_k``), ``held_rows`` (pairs whose expert is held here),
     ``load_max_sum`` (the busiest of all ``num_experts`` experts' pairs,
-    summed over steps). ``record_choice`` adds an output for whoever compares
-    the layer with another implementation: ``choice`` in the state, the
-    experts the last train step chose for the first example of its batch,
+    summed over steps), ``tiles_used`` (tiles of the buffer the step went
+    over) and ``buffer_tiles`` (tiles of its static worst case: their ratio
+    is the gauge ``moe.buffer_used_pct``). ``record_choice`` adds an output
+    for whoever compares the layer with another implementation: ``choice``
+    in the state, the experts the last train step chose for the first
+    example of its batch,
     (..., top_k) in the shape of one example. Routing is discrete, so such a
     comparison has to start from the same experts before it can see rounding.
     """
@@ -485,7 +479,7 @@ class DroplessMoE(Layer):
             idx, gates = self.route(
                 flat.astype(jnp.float32), maybe_dequantize(params["router"]),
                 state["router_bias"])
-            # (k, n), choice-major: see _gather_pairs.
+            # (k, n), choice-major: pair j * n + i is token i's j-th choice.
             local = idx.T - self.expert_offset
             held = jnp.logical_and(local >= 0, local < g)
             group = jnp.where(held, local, g).reshape(-1)
@@ -503,10 +497,11 @@ class DroplessMoE(Layer):
             # (pairs not held fall outside the buffer and are dropped).
             src_pair = jnp.full((rows,), n * k, jnp.int32).at[dest].set(
                 jnp.arange(n * k, dtype=jnp.int32), mode="drop")
-            row_valid = src_pair < n * k
-            src_pair = jnp.minimum(src_pair, n * k - 1)
-            dest = jnp.where(held, dest.reshape(k, n), 0)
-            buf = _dispatch(flat, src_pair % n, row_valid, dest, held)
+            walk = (src_pair,
+                    moe_rows.tile_rows(sizes, row_starts, tile_group,
+                                       tiles_used),
+                    tiles_used)
+            buf = _dispatch(flat, walk)
         with jax.named_scope("experts"):
             weight = lambda name: maybe_dequantize(params[name]).astype(dt)
             hidden = jax.nn.silu(gmm.grouped_matmul(
@@ -515,8 +510,7 @@ class DroplessMoE(Layer):
             out_buf = gmm.grouped_matmul(
                 hidden, weight("w_down"), tile_group, tiles_used)
         with jax.named_scope("route"):
-            y = _combine(out_buf, jnp.where(held, gates.T, 0.0), dest,
-                         src_pair, row_valid)
+            y = _combine(out_buf, jnp.where(held, gates.T, 0.0), walk)
         if self.shared is not None:
             with child_scope("shared"):
                 y = y + self.shared.apply(params["shared"], {}, flat)[0]
@@ -538,6 +532,10 @@ class DroplessMoE(Layer):
                     jnp.float32),
                 load_max_sum=state["load_max_sum"] + jnp.max(loads).astype(
                     jnp.float32),
+                tiles_used=state["tiles_used"] + tiles_used[0].astype(
+                    jnp.float32),
+                buffer_tiles=state["buffer_tiles"] + float(
+                    rows // gmm.TILE_M),
             )
             if self.record_choice:
                 new_state["choice"] = idx.reshape(x.shape[:-1] + (k,))[0]

@@ -1766,9 +1766,14 @@ class Model:
         moe_counters = moe_lib.counters(self.state)
         if moe_counters:
             report["moe"] = moe_counters
+            total = lambda name: sum(
+                c[name] for c in moe_counters.values())
             for name in ("pairs", "held_rows"):
-                obs_reg.gauge(f"moe.{name}", sum(
-                    c[name] for c in moe_counters.values()))
+                obs_reg.gauge(f"moe.{name}", total(name))
+            # Tiles the row walks and the grouped matmuls went over, of the
+            # tiles of the buffers' static worst case.
+            obs_reg.gauge("moe.buffer_used_pct", 100.0 * total(
+                "tiles_used") / max(total("buffer_tiles"), 1.0))
         # The legacy dict is a VIEW stored in the metrics registry
         # (key-for-key identical — pinned by the obs parity test): one
         # telemetry surface, backward-compatible reader.

@@ -202,6 +202,67 @@ def test_no_pair_is_dropped_when_one_expert_takes_every_token(whole_layer):
     assert layer.apply(p, state, x, train=False)[1] == {}
 
 
+@pytest.mark.parametrize("held,offset", [(16, 0), (4, 4), (2, 14)])
+def test_a_train_step_counts_the_tiles_it_went_over(whole_layer, held,
+                                                    offset):
+    """``tiles_used``: each held expert's pairs rounded up to tiles of 128
+    rows, an expert nobody chose still one; ``buffer_tiles``: the tiles of
+    the buffer's static worst case. The walks and the grouped matmuls go
+    over the first and never over the rest."""
+    from distributed_tpu.ops import grouped_matmul as gmm
+
+    _, params, state, x = whole_layer
+    layer = expert_layer(held, offset)
+    p = share_of(params, offset, held)
+    sizes = loads_of(layer, p, state, x)[offset:offset + held]
+    new = layer.apply(p, state, x, train=True)[1]
+    assert float(new["tiles_used"]) == sum(
+        max(-(-int(s) // gmm.TILE_M), 1) for s in sizes)
+    n = x.shape[0] * x.shape[1]
+    assert float(new["buffer_tiles"]) == gmm.buffer_rows(
+        n * TOP_K, held) // gmm.TILE_M
+    again = layer.apply(p, new, x, train=True)[1]  # cumulative
+    assert float(again["tiles_used"]) == 2 * float(new["tiles_used"])
+    assert float(again["buffer_tiles"]) == 2 * float(new["buffer_tiles"])
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_the_tiny_presets_expert_layer_matches_the_reference(dtype):
+    """The expert layer at the rehearsal's tiny configuration (experts 4-7
+    of 16 held, top-3, two shared), output and every gradient, against
+    ``reference/deepseek_v3.py``'s: in float32 to the equations, in bfloat16
+    to its rounding."""
+    cfg = harness.load_json(os.path.join(
+        ROOT, "tests", "bench_harness", "configs", "kanana-tiny.json"))
+    d, offset = cfg["hidden_size"], cfg["deployment"]["expert_offset"]
+    layer = nn.DroplessMoE(
+        fam.router_experts(cfg), cfg["moe_intermediate_size"],
+        top_k=cfg["num_experts_per_tok"],
+        experts_held=cfg["n_routed_experts"], expert_offset=offset,
+        shared_hidden_dim=cfg["n_shared_experts"] * cfg[
+            "moe_intermediate_size"],
+        routed_scaling=cfg["routed_scaling_factor"], dtype=dtype)
+    params, state, _ = layer.init(jax.random.PRNGKey(11), (48, d))
+    x = jax.random.normal(jax.random.PRNGKey(12), (2, 48, d))
+    w = jax.random.normal(jax.random.PRNGKey(13), x.shape)
+
+    def system(p, x):
+        return layer.apply(p, state, x, train=True)[0]
+
+    def plain(p, x):
+        y, _ = ref.experts(
+            reference_block(p, state), x.reshape(-1, d),
+            top_k=cfg["num_experts_per_tok"],
+            scaling=cfg["routed_scaling_factor"], expert_offset=offset)
+        return y.reshape(x.shape)
+
+    rel = 1e-4 if dtype == "float32" else 3e-2
+    assert close(system(params, x), plain(params, x), rel)
+    got = jax.grad(lambda p, x: jnp.sum(system(p, x) * w), (0, 1))(params, x)
+    want = jax.grad(lambda p, x: jnp.sum(plain(p, x) * w), (0, 1))(params, x)
+    assert_trees_close(got, want, rel)
+
+
 def loads_of(layer, params, state, x):
     idx, _ = layer.route(x.reshape(-1, D), params["router"],
                          state["router_bias"])
@@ -362,9 +423,44 @@ def test_fit_steps_count_and_learn(tiny):
         assert c["steps"] == 6.0 and c["pairs"] == 6 * 2 * 48 * 3
         assert 0 < c["held_rows"] < c["pairs"]
         assert c["load_max_sum"] >= c["pairs"] / 16
+        # 2 x 48 x 3 pairs fit one tile a held expert: 4 of the buffer's 7
+        assert c["tiles_used"] == 6 * 4 and c["buffer_tiles"] == 6 * 7
     reg = dtpu.obs.default_registry()
     assert reg.gauge_value("moe.pairs") == sum(
         c["pairs"] for c in counted.values())
+    assert reg.gauge_value("moe.buffer_used_pct") == pytest.approx(
+        100.0 * 4 / 7)
+
+
+def test_no_row_movement_of_the_step_gathers_the_whole_buffer(tiny):
+    """A count on XLA:CPU, never a speed: under ``moe*/route`` the lowered
+    train step has no gather whose result is (pairs, d) or (buffer rows, d),
+    the worst-case movements ``ops/moe_rows.py`` replaced (the interpreted
+    kernels' own gathers are a tile's)."""
+    import re
+
+    from distributed_tpu.ops import grouped_matmul as gmm
+
+    cfg, model, x, y = tiny
+    d, k = cfg["hidden_size"], cfg["num_experts_per_tok"]
+    pairs = x.size * k
+    whole = {(pairs, d), (gmm.buffer_rows(pairs, cfg["n_routed_experts"]), d)}
+    gather = re.compile(r"= \w+\[([\d,]*)\][^ ]* gather\(.*op_name=\"([^\"]*)\"")
+
+    def results(text, under):
+        # less the unit dimensions XLA leaves in a gather's result
+        return [tuple(int(s) for s in m.group(1).split(",") if s not in "1")
+                for m in map(gather.search, text.splitlines())
+                if m and under in m.group(2)]
+
+    # the expression finds such a gather where there is one
+    take = jax.jit(lambda b, i: jnp.take(b, i, axis=0)).lower(
+        jnp.zeros((max(whole)[0], d)), jnp.zeros((pairs,), jnp.int32))
+    assert (pairs, d) in results(take.compile().as_text(), "")
+    found = results(model.lower_train_step(x, y).compile().as_text(),
+                    "/moe/route/")
+    assert found  # the router's own small gathers
+    assert not whole & set(found)
 
 
 def test_the_operation_count_knows_the_models_parameters(tiny):

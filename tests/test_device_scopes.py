@@ -157,6 +157,7 @@ def test_deepseek_layers_scope_paths_are_their_parameter_paths(deepseek):
 
 @pytest.mark.parametrize("inner,primitive", [
     ("route", "top_k"), ("route", "gather"), ("route", "scatter"),
+    ("route", "dtpu_moe_rows_gather"), ("route", "dtpu_moe_rows_sum"),
     ("experts", "dtpu_gmm"), ("shared/dense", "dot_general"),
 ])
 def test_the_expert_layer_names_its_routing_its_experts_and_its_shared(
@@ -164,13 +165,17 @@ def test_the_expert_layer_names_its_routing_its_experts_and_its_shared(
     """``moe*/route`` holds the router, the top-k, the sort and the gathers
     both ways; ``moe*/experts`` the grouped matmuls; ``moe*/shared`` the
     shared gated MLP: ``benchmarks/scopes_moe.py`` splits the expert layers'
-    device time by them."""
+    device time by them. The two row walks (``ops/moe_rows.py``) run under
+    ``route`` in both passes: each is the other's transpose."""
     _, names = deepseek
     assert has(names, rf"jit\(step\)/jvp\(residual_3\)/main/moe/{inner}/"
                       rf"{JAX}\w*{primitive}")
     if primitive not in ("top_k", "scatter"):  # indices have no gradient
         assert has(names, rf"jit\(step\)/transpose\(jvp\(residual_3\)\)/"
                           rf"{JAX}main/moe/{inner}/")
+    if primitive.startswith("dtpu_moe_rows"):
+        assert has(names, rf"jit\(step\)/transpose\(jvp\(residual_3\)\)/"
+                          rf"{JAX}main/moe/route/{JAX}{primitive}")
 
 
 def test_every_operation_of_the_deepseek_step_is_under_a_scope(deepseek):
