@@ -190,7 +190,7 @@ def deepseek_v3_lm(
     choices in its state (``nn.DroplessMoE``). A final RMSNorm and an
     untied, bias-free head; no position table (RoPE is inside the
     attention)."""
-    layers = [nn.Embedding(vocab_size, d_model, dtype=dtype)]
+    blocks = []
     for i in range(num_layers):
         attn = nn.LatentAttention(
             num_heads, kv_rank=kv_rank, nope_dim=nope_dim, rope_dim=rope_dim,
@@ -206,6 +206,21 @@ def deepseek_v3_lm(
                 routed_scaling=routed_scaling,
                 bias_update_rate=bias_update_rate,
                 record_choice=record_choice, dtype=dtype)
+        blocks.append((attn, ffn))
+    return _rms_norm_lm("deepseek_v3_lm", vocab_size, d_model, blocks,
+                        epsilon, dtype)
+
+
+def _rms_norm_lm(name, vocab_size, d_model, blocks, epsilon, dtype,
+                 embedding_std=0.02):
+    """Token-in, logits-out LM of pre-RMSNorm residual blocks: an embedding,
+    for each (attention, ffn) of ``blocks`` two residuals ``RMSNorm ->
+    layer``, a final RMSNorm and an untied, bias-free head. The parameter
+    paths (which are the device scopes) read ``residual``, ``residual_1``,
+    ...: attention at even indices, the MLP or expert layer at odd ones."""
+    layers = [nn.Embedding(vocab_size, d_model, dtype=dtype,
+                           stddev=embedding_std)]
+    for attn, ffn in blocks:
         layers += [
             nn.Residual(nn.Sequential([nn.RMSNorm(epsilon), attn],
                                       name="main")),
@@ -214,4 +229,63 @@ def deepseek_v3_lm(
         ]
     layers += [nn.RMSNorm(epsilon),
                nn.Dense(vocab_size, use_bias=False, dtype=dtype)]
-    return nn.Sequential(layers, name="deepseek_v3_lm")
+    return nn.Sequential(layers, name=name)
+
+
+def qwen3_moe_lm(
+    vocab_size: int,
+    *,
+    num_layers: int,
+    d_model: int,
+    num_heads: int,
+    num_kv_heads: int,
+    head_dim: int,
+    num_experts: int,
+    top_k: int,
+    moe_hidden: int,
+    experts_held: Optional[int] = None,
+    expert_offset: int = 0,
+    index_topk: Optional[int] = None,
+    index_heads: int = 16,
+    index_dim: int = 64,
+    record_choice: bool = False,
+    rope_theta: float = 10000.0,
+    epsilon: float = 1e-6,
+    embedding_std: float = 0.02,
+    flash="auto",
+    dtype=None,
+) -> nn.Sequential:
+    """The Qwen3-MoE block as a token-in, logits-out LM, with or without a
+    learned selection of keys (Keye-VL-2.0-30B-A3B's language model is one
+    with: ``sa_config``): pre-RMSNorm residual blocks of
+    ``nn.GroupedQueryAttention`` (``num_heads`` query heads over
+    ``num_kv_heads`` K/V heads of ``head_dim``, RoPE, per-head RMSNorm on q
+    and k) and ``nn.DroplessMoE`` with softmax scoring over ``num_experts``
+    experts of ``moe_hidden``, ``top_k`` a token, gates normalised over the
+    chosen, no shared expert and no dense layer. ``index_topk`` gives every
+    attention layer its indexer (``index_heads`` heads of ``index_dim``, one
+    key head), whose loss joins the objective through the layers' state
+    (``nn.GroupedQueryAttention``). ``experts_held`` / ``expert_offset`` are
+    this chip's share of every expert layer, as in ``deepseek_v3_lm``, with
+    which the block assembly is shared; ``record_choice`` keeps each expert
+    layer's last choices and each attention layer's last selection in its
+    state. A final RMSNorm and an untied, bias-free head; no position
+    table. ``embedding_std`` is the embedding's initial scale: every layer
+    here adds to the residual stream what attention averaged over thousands
+    of keys or what the few experts held computed, so under the library's
+    normal(0.02) a freshly initialised stack's tokens are all but one
+    vector after the first layer, and every token picks the same experts
+    (root PERF.md, PR 32)."""
+    blocks = [(
+        nn.GroupedQueryAttention(
+            num_heads, num_kv_heads, head_dim, rope_theta=rope_theta,
+            epsilon=epsilon, index_topk=index_topk, index_heads=index_heads,
+            index_dim=index_dim,
+            record_selection=record_choice, flash=flash, dtype=dtype),
+        nn.DroplessMoE(
+            num_experts, moe_hidden, top_k=top_k, experts_held=experts_held,
+            expert_offset=expert_offset, scoring="softmax",
+            record_choice=record_choice, dtype=dtype),
+    ) for _ in range(num_layers)]
+    return _rms_norm_lm("qwen3_moe_lm", vocab_size, d_model, blocks, epsilon,
+                        dtype, embedding_std)

@@ -1,4 +1,5 @@
 from .attention import (
+    GroupedQueryAttention,
     LatentAttention,
     MultiHeadAttention,
     PositionalEmbedding,
@@ -47,6 +48,7 @@ __all__ = [
     "RandomCrop",
     "MultiHeadAttention",
     "LatentAttention",
+    "GroupedQueryAttention",
     "MoE",
     "DroplessMoE",
     "RMSNorm",

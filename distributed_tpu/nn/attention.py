@@ -17,13 +17,15 @@ than bolted on. TPU notes:
 
 from __future__ import annotations
 
+import functools
+import math
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 
 from . import initializers
-from .core import Layer, Shape, child_scope
+from .core import Layer, Shape, child_scope, read_counters
 from ..precision import resolve_dtype
 from ..quant import _QMAX, QKEY, SKEY, dequantize, maybe_dequantize, shape_of
 
@@ -699,6 +701,370 @@ class LatentAttention(Layer):
 
             ctx = dense_attention(q, k, v, self.causal)
         return proj(ctx.reshape(b, t, h * self.v_dim), "wo"), {}
+
+
+def rope_half(x, theta: float):
+    """Rotary position embedding on the two halves of the last axis
+    (``rotate_half``, Llama's and Qwen's): dimensions (i, i + d/2) of ``x``
+    (B, T, H, d) at position t turn by the angle t * theta^(-2i/d), from
+    position 0. Float32 inside, ``x``'s dtype out."""
+    t, d = x.shape[1], x.shape[-1]
+    inv_freq = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
+    angle = jnp.arange(t, dtype=jnp.float32)[:, None] * inv_freq[None]
+    cos = jnp.tile(jnp.cos(angle), 2)[None, :, None, :]
+    sin = jnp.tile(jnp.sin(angle), 2)[None, :, None, :]
+    xf = x.astype(jnp.float32)
+    turned = jnp.concatenate([-xf[..., d // 2:], xf[..., :d // 2]], axis=-1)
+    return (xf * cos + turned * sin).astype(x.dtype)
+
+
+def _rms(x, scale, epsilon):
+    xf = x.astype(jnp.float32)
+    ms = jnp.mean(jnp.square(xf), axis=-1, keepdims=True)
+    return (xf * jax.lax.rsqrt(ms + epsilon) * scale).astype(x.dtype)
+
+
+# ------------------------------------------- learned selection of the keys --
+def index_scores(qi, ki, w):
+    """The lightning indexer's score of every key for a block of queries:
+    ``I[t, s] = sum_j w[t, j] * relu(qi[t, j] . ki[s])`` in float32, for qi
+    (n, J, d), the one key head ki (T, d) and w (n, J)."""
+    dots = jnp.einsum("qjd,sd->qjs", qi, ki,
+                      preferred_element_type=jnp.float32)
+    return jnp.sum(jax.nn.relu(dots) * w[:, :, None].astype(jnp.float32),
+                   axis=1)
+
+
+def _mean_probs(q, k, lse, picked):
+    """The main attention's probabilities over the selected keys, averaged
+    over the heads: (n, T) float32 for a block of queries q (n, H, D), keys k
+    (T, Hkv, D), the attention's own row statistic lse (n, H) (each row's
+    log-sum-exp of its scaled scores over its selection) and ``picked`` (n,
+    T) bool; zero off the selection. One exp(score - lse) a pair, no second
+    softmax. A K/V head at a time, its query heads' rows stacked into one
+    plain product whose rows end in the keys."""
+    n, h, d = q.shape
+    t, g = k.shape[0], k.shape[1]
+    m = h // g
+    total = jnp.zeros((n, t), jnp.float32)
+    for kv in range(g):
+        heads = slice(kv * m, (kv + 1) * m)
+        rows = jnp.moveaxis(q[:, heads], 1, 0).reshape(m * n, d)
+        s = jnp.dot(rows, k[:, kv].T, preferred_element_type=jnp.float32
+                    ).reshape(m, n, t) / jnp.sqrt(jnp.float32(d))
+        p = jnp.exp(s - lse[:, heads].T[:, :, None])
+        total = total + jnp.sum(jnp.where(picked, p, 0.0), axis=0)
+    return total / h
+
+
+# Queries a block in which a selecting layer scores, selects and computes
+# L_I: a (block, T) float32 score matrix an indexer head is all that is live
+# (256 MB at 16 heads and T = 8192).
+INDEX_BLOCK = 512
+
+
+def _cut(a, block: int):
+    """``a`` (T, ...) in blocks of queries, (T // n, n, ...): n is ``block``
+    where that divides T, else their greatest common divisor."""
+    n = math.gcd(a.shape[0], block)
+    return a.reshape((a.shape[0] // n, n) + a.shape[1:])
+
+
+def _select_sequence(qi, ki, w, *, topk, block):
+    """The selection (T, T) int8 of one sequence: query t keeps the ``topk``
+    keys s <= t with the largest index score, a block of ``block`` queries
+    at a time (a (block, T) score matrix a head is all that is live)."""
+    from ..ops.topk_select import topk_mask
+
+    t = qi.shape[0]
+
+    def rows_of(blk):
+        qi_b, w_b, rows = blk
+        causal = rows[:, None] >= jnp.arange(t)[None, :]
+        scores = index_scores(qi_b, ki, w_b)
+        with jax.named_scope("select"):
+            return topk_mask(scores, topk, causal).astype(jnp.int8)
+
+    return jax.lax.map(rows_of, tuple(
+        _cut(a, block) for a in (qi, w, jnp.arange(t)))).reshape(t, t)
+
+
+def select_keys(qi, ki, w, *, topk: int, block: int = INDEX_BLOCK):
+    """The learned selection of a batch, (B, T, T) int8, non-zero where query
+    t keeps key s: the ``topk`` keys s <= t with the largest index score
+    (``index_scores``; every key s <= t while there are no more than
+    ``topk``), for the indexer's queries qi (B, T, J, d), key ki (B, T, d)
+    and head weights w (B, T, J). No gradient."""
+    qi, ki, w = jax.lax.stop_gradient((qi, ki, w))
+    one = functools.partial(_select_sequence, topk=topk, block=block)
+    return jax.lax.map(lambda a: one(*a), (qi, ki, w))
+
+
+def _index_loss_sequence(qi, ki, w, q, k, lse, selection, *, block):
+    """(sum over one sequence's queries of the KL, its gradient in (qi, ki,
+    w)), a block of queries at a time: ``index_loss``."""
+    def step(d_ki, blk):
+        qi_b, w_b, q_b, lse_b, picked = blk
+        picked = picked != 0
+
+        def kl_sum(qi_b, w_b, ki):
+            scores = index_scores(qi_b, ki, w_b)
+            target = _mean_probs(q_b, k, lse_b, picked)
+            log_index = jax.nn.log_softmax(
+                jnp.where(picked, scores, jnp.float32(-1e30)), axis=-1)
+            return jnp.sum(jnp.where(
+                picked, jax.scipy.special.xlogy(target, target)
+                - target * log_index, 0.0))
+
+        kl, (d_qi, d_w, d_ki_b) = jax.value_and_grad(kl_sum, (0, 1, 2))(
+            qi_b, w_b, ki)
+        return d_ki + d_ki_b.astype(jnp.float32), (kl, d_qi, d_w)
+
+    d_ki, (kl, d_qi, d_w) = jax.lax.scan(
+        step, jnp.zeros(ki.shape, jnp.float32), tuple(
+            _cut(a, block) for a in (qi, w, q, lse.T, selection)))
+    return jnp.sum(kl), (d_qi.reshape(qi.shape), d_ki, d_w.reshape(w.shape))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7,))
+def index_loss(qi, ki, w, q, k, lse, selection, block=INDEX_BLOCK):
+    """``L_I``, the loss that trains the indexer: the mean over a batch's
+    queries of KL(the main attention's probabilities over the query's
+    selection, averaged over its heads || the softmax of the query's index
+    scores over its selection) (DeepSeek-V3.2-Exp's sparse training stage),
+    for the indexer's qi (B, T, J, d), ki (B, T, d) and w (B, T, J), the
+    main attention's q (B, T, H, D), k (B, T, Hkv, D) and row statistic lse
+    (B, H, T), and the ``selection`` (B, T, T). Its gradient reaches qi, ki
+    and w only: the selection and the main attention's side are constants.
+    The gradient is taken where the scores are, in the forward pass, a block
+    of queries at a time, so no (T, T) matrix is kept for the backward
+    pass."""
+    return _index_loss_fwd(qi, ki, w, q, k, lse, selection, block)[0]
+
+
+def _index_loss_fwd(qi, ki, w, q, k, lse, selection, block):
+    one = functools.partial(_index_loss_sequence, block=block)
+    kl, grads = jax.lax.map(lambda a: one(*a),
+                            (qi, ki, w, q, k, lse, selection))
+    queries = qi.shape[0] * qi.shape[1]
+    scaled = tuple((g / queries).astype(a.dtype)
+                   for g, a in zip(grads, (qi, ki, w)))
+    return jnp.sum(kl) / queries, (scaled, q, k, lse)
+
+
+def _index_loss_bwd(block, res, ct):
+    grads, q, k, lse = res
+    d_qi, d_ki, d_w = ((g * ct).astype(g.dtype) for g in grads)
+    return (d_qi, d_ki, d_w, jnp.zeros_like(q), jnp.zeros_like(k),
+            jnp.zeros_like(lse), None)
+
+
+index_loss.defvjp(_index_loss_fwd, _index_loss_bwd)
+
+# Names of a selecting GroupedQueryAttention's counters in its state.
+_SELECT_COUNTERS = ("steps", "queries", "causal_pairs", "selected_pairs",
+                    "blocks_total", "blocks_computed")
+
+
+class GroupedQueryAttention(Layer):
+    """Causal grouped-query self-attention over (B, T, D) inputs with RoPE
+    and per-head RMSNorm on queries and keys (Qwen3's), and optionally a
+    learned selection of the keys each query sees (DeepSeek-V3.2-Exp's
+    lightning indexer), for training and full forward passes:
+
+        q = rope(RMSNorm_hd(x Wq))      (T, H, hd)
+        k = rope(RMSNorm_hd(x Wk))      (T, Hkv, hd)     v = x Wv
+        out = softmax(q k^T / sqrt(hd), over the keys seen) v  Wo
+
+    Query head h reads K/V head h // (H / Hkv); RoPE is the half-split
+    rotation at ``rope_theta``, from position 0; no bias.
+
+    With ``index_topk`` the layer carries an indexer of ``index_heads``
+    heads of ``index_dim`` and one key head, and query t sees the
+    ``index_topk`` keys s <= t with the largest index score (every key s <= t
+    while there are no more than that):
+
+        qI = rope(x WqI)   (T, J, dI)       kI = rope(LayerNorm(x WkI))  (T, dI)
+        w = x Ww / sqrt(J dI)               I[t, s] = sum_j w[t, j] relu(qI[t, j] . kI[s])
+
+    The selection has no gradient: the model's loss flows through the keys
+    selected as through a constant mask. The indexer learns from ``L_I``,
+    the KL between the main attention's probabilities and the index scores'
+    softmax over the selection (``index_loss``), with the block's input a
+    constant too, so ``L_I`` moves ``indexer``'s leaves and nothing else. A
+    train step hands ``L_I`` to the objective through the state key
+    ``aux_loss`` (``training/model.py`` adds every such key to the loss).
+    The index scores are computed twice a train step, a block of
+    ``INDEX_BLOCK`` queries at a time: once to select (``select_keys``),
+    and, after the attention whose row statistic the probabilities are
+    recomputed from, once more with their gradient (``index_loss``).
+
+    Device scopes under the layer's own (which starts with
+    ``multi_head_attention``, so ``benchmarks/scopes.py`` sorts all of it
+    under attention): ``indexer`` (its projections, the scores, ``L_I`` and
+    its gradient) and, inside it, ``select``. Counters, cumulative over
+    train steps, in the layer's state (``select_counters``): ``steps``,
+    ``queries``, ``causal_pairs`` (pairs s <= t), ``selected_pairs``, and the
+    flash kernels' grid blocks at or below the diagonal, ``blocks_total``,
+    with those that held a selected pair and were walked,
+    ``blocks_computed`` (both 0 on the dense path). ``record_selection`` adds
+    ``selection`` to the state: the selection of the first example of the
+    last train step, bit-packed along the keys, (T, T / 8) uint8, for
+    whoever compares the layer with another implementation: a selection is
+    discrete, and such a comparison has to start from the same keys.
+
+    The scope, and the parameter key, starts with ``multi_head_attention``.
+    No cached decode (ROADMAP.md: an indexer cache does not exist yet)."""
+
+    decode_safe = False
+
+    def __init__(self, num_heads: int, num_kv_heads: int, head_dim: int, *,
+                 rope_theta: float = 10000.0, epsilon: float = 1e-6,
+                 index_topk: Optional[int] = None, index_heads: int = 16,
+                 index_dim: int = 64,
+                 record_selection: bool = False, dtype=None, flash="auto",
+                 name: Optional[str] = None):
+        super().__init__(name)
+        self.num_heads = int(num_heads)
+        self.num_kv_heads = int(num_kv_heads)
+        if self.num_heads % self.num_kv_heads:
+            raise ValueError(
+                f"num_heads {num_heads} is no multiple of num_kv_heads "
+                f"{num_kv_heads}")
+        self.head_dim = int(head_dim)
+        self.rope_theta = float(rope_theta)
+        self.epsilon = float(epsilon)
+        self.index_topk = int(index_topk) if index_topk else None
+        self.index_heads = int(index_heads)
+        self.index_dim = int(index_dim)
+        self.record_selection = bool(record_selection) and bool(index_topk)
+        self.dtype = dtype
+        self.flash = flash
+
+    def default_name(self) -> str:
+        return "multi_head_attention_gqa"
+
+    def init(self, key, input_shape: Shape):
+        t, d = input_shape[-2], input_shape[-1]
+        h, g, hd = self.num_heads, self.num_kv_heads, self.head_dim
+        keys = jax.random.split(key, 7)
+        init = initializers.get("glorot_uniform")
+        ones = lambda n: {"scale": jnp.ones((n,), jnp.float32)}
+        params = {
+            "wq": init(keys[0], (d, h * hd), jnp.float32),
+            "wk": init(keys[1], (d, g * hd), jnp.float32),
+            "wv": init(keys[2], (d, g * hd), jnp.float32),
+            "wo": init(keys[3], (h * hd, d), jnp.float32),
+            "q_norm": ones(hd), "k_norm": ones(hd),
+        }
+        state = {}
+        if self.index_topk:
+            j, di = self.index_heads, self.index_dim
+            params["indexer"] = {
+                "wq": init(keys[4], (d, j * di), jnp.float32),
+                "wk": init(keys[5], (d, di), jnp.float32),
+                "k_norm": {"scale": jnp.ones((di,), jnp.float32),
+                           "bias": jnp.zeros((di,), jnp.float32)},
+                "ww": init(keys[6], (d, j), jnp.float32),
+            }
+            state = {"aux_loss": jnp.float32(0.0)}
+            state.update({c: jnp.float32(0.0) for c in _SELECT_COUNTERS})
+            if self.record_selection:
+                state["selection"] = jnp.zeros((t, -(-t // 8)), jnp.uint8)
+        return params, state, tuple(input_shape)
+
+    def sharding_hints(self):
+        return {"wq": "col", "wk": "col", "wv": "col", "wo": "row"}
+
+    _use_flash = MultiHeadAttention._use_flash
+
+    def _indexer(self, params, x):
+        """The indexer's queries, key and head weights of the block's input
+        (a constant: the indexer's loss moves the indexer alone)."""
+        dt = x.dtype
+        b, t, _ = x.shape
+        j, di = self.index_heads, self.index_dim
+        x = jax.lax.stop_gradient(x)
+        proj = lambda w: jnp.dot(x, maybe_dequantize(params[w]).astype(dt))
+        qi = rope_half(proj("wq").reshape(b, t, j, di), self.rope_theta)
+        with child_scope("k_norm"):
+            kf = proj("wk").astype(jnp.float32)
+            kf = kf - jnp.mean(kf, axis=-1, keepdims=True)
+            var = jnp.mean(jnp.square(kf), axis=-1, keepdims=True)
+            ki = (kf * jax.lax.rsqrt(var + self.epsilon)
+                  * params["k_norm"]["scale"] + params["k_norm"]["bias"]
+                  ).astype(dt)
+        ki = rope_half(ki[:, :, None, :], self.rope_theta)[:, :, 0]
+        w = proj("ww").astype(jnp.float32) / jnp.sqrt(jnp.float32(j * di))
+        return qi, ki, w
+
+    def apply(self, params, state, x, *, train=False, rng=None):
+        from ..ops import flash_attention as fa
+        from ..parallel.auto_shard import ambient_mesh
+
+        dt = resolve_dtype(self.dtype)
+        if dt is not None:
+            x = x.astype(dt)
+        b, t, _ = x.shape
+        h, g, hd = self.num_heads, self.num_kv_heads, self.head_dim
+        proj = lambda a, w: jnp.dot(
+            a, maybe_dequantize(params[w]).astype(a.dtype))
+        q = proj(x, "wq").reshape(b, t, h, hd)
+        k = proj(x, "wk").reshape(b, t, g, hd)
+        v = proj(x, "wv").reshape(b, t, g, hd)
+        with child_scope("q_norm"):
+            q = _rms(q, params["q_norm"]["scale"], self.epsilon)
+        with child_scope("k_norm"):
+            k = _rms(k, params["k_norm"]["scale"], self.epsilon)
+        q, k = rope_half(q, self.rope_theta), rope_half(k, self.rope_theta)
+        selection = None
+        blocks = (jnp.float32(0.0), 0)
+        if self.index_topk:
+            with child_scope("indexer"):
+                index = self._indexer(params["indexer"], x)
+                selection = select_keys(*index, topk=self.index_topk,
+                                        block=INDEX_BLOCK)
+        flash = self._use_flash(t) and ambient_mesh()[0] is None
+        if selection is None:
+            ctx = (fa.flash_attention(q, k, v, causal=True) if flash
+                   else fa.dense_attention(q, k, v, True))
+        elif flash and hd == 128:
+            flags, total = fa.selection_blocks(selection, *fa.resolve_blocks(
+                t, jnp.dtype(q.dtype).itemsize))
+            blocks = (jnp.sum(flags).astype(jnp.float32), b * total)
+            ctx, lse = fa.flash_attention(
+                q, k, v, causal=True, selection=selection,
+                selection_flags=flags, return_lse=True)
+        else:
+            ctx, lse = fa.dense_attention(q, k, v, True, selection,
+                                          return_lse=True)
+        out = proj(ctx.reshape(b, t, h * hd), "wo")
+        if not (train and self.index_topk):
+            return out, {}
+        with child_scope("indexer"):
+            loss = index_loss(*index, *jax.lax.stop_gradient((q, k)), lse,
+                              selection, INDEX_BLOCK)
+            new_state = dict(
+                state, aux_loss=loss,
+                steps=state["steps"] + 1.0,
+                queries=state["queries"] + float(b * t),
+                causal_pairs=state["causal_pairs"] + float(
+                    b * t * (t + 1) // 2),
+                selected_pairs=state["selected_pairs"] + jnp.sum(
+                    selection, dtype=jnp.float32),
+                blocks_total=state["blocks_total"] + float(blocks[1]),
+                blocks_computed=state["blocks_computed"] + blocks[0])
+            if self.record_selection:
+                new_state["selection"] = jnp.packbits(
+                    selection[0].astype(bool), axis=-1)
+        return out, new_state
+
+
+def select_counters(state) -> dict:
+    """``{layer path: {counter: value}}`` of every selecting
+    ``GroupedQueryAttention`` in a model's ``state`` tree
+    (``core.read_counters``)."""
+    return read_counters(state, _SELECT_COUNTERS)
 
 
 class PositionalEmbedding(Layer):

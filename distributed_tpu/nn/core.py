@@ -239,6 +239,27 @@ def child_scope(key: str):
     return jax.named_scope(key)
 
 
+def read_counters(state, names) -> dict:
+    """``{layer path: {counter: value}}`` of every layer in a model's
+    ``state`` tree whose state holds all of ``names`` (a layer that counts in
+    its own state, inside the step program), fetched from the device: call
+    it outside a step loop (``Model.fit`` does, once, when a fit ends)."""
+    out = {}
+
+    def walk(tree, path):
+        if not isinstance(tree, dict):
+            return
+        if all(c in tree for c in names):
+            values = jax.device_get({c: tree[c] for c in names})
+            out["/".join(path)] = {c: float(v) for c, v in values.items()}
+            return
+        for key, sub in tree.items():
+            walk(sub, path + (key,))
+
+    walk(state, ())
+    return out
+
+
 def apply_layers(layers, params, state, x, *, train=False, rng=None):
     """Apply a sequence of layers with Sequential's rng-split and state-
     collection discipline. The SINGLE implementation of that discipline:

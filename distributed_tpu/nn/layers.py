@@ -558,14 +558,19 @@ class GatedMLP(Layer):
 
 
 class Embedding(Layer):
-    def __init__(self, vocab_size: int, dim: int, dtype=None, name=None):
+    """A table of ``vocab_size`` rows of ``dim``, drawn normal(``stddev``)."""
+
+    def __init__(self, vocab_size: int, dim: int, dtype=None,
+                 stddev: float = 0.02, name=None):
         super().__init__(name)
         self.vocab_size = int(vocab_size)
         self.dim = int(dim)
         self.dtype = dtype
+        self.stddev = float(stddev)
 
     def init(self, key, input_shape: Shape):
-        table = initializers.normal(0.02)(key, (self.vocab_size, self.dim), jnp.float32)
+        table = initializers.normal(self.stddev)(
+            key, (self.vocab_size, self.dim), jnp.float32)
         return {"table": table}, {}, tuple(input_shape) + (self.dim,)
 
     def apply(self, params, state, x, *, train=False, rng=None):

@@ -35,7 +35,7 @@ import jax
 import jax.numpy as jnp
 
 from . import core, initializers
-from .core import Layer, Shape, child_scope
+from .core import Layer, Shape, child_scope, read_counters
 from .layers import GatedMLP
 from ..ops import grouped_matmul as gmm, moe_rows
 from ..quant import maybe_dequantize
@@ -357,6 +357,11 @@ class DroplessMoE(Layer):
     each expert that took more pairs than the mean expert in that step's
     batch and as much up for each that took fewer (the paper trains with
     0.001, the default here; 0 freezes it). No auxiliary loss.
+    ``scoring="softmax"`` is Qwen3-MoE's router (``norm_topk_prob``) in the
+    same place: scores are the softmax over all ``num_experts`` in float32,
+    a token's experts the ``top_k`` largest, no selection bias
+    (``bias_update_rate`` is ignored and the buffer stays at zeros), gates
+    as above.
 
     The layer holds experts ``[expert_offset, expert_offset +
     experts_held)`` (all of them by default) and returns the part of the
@@ -407,8 +412,13 @@ class DroplessMoE(Layer):
                  experts_held: Optional[int] = None, expert_offset: int = 0,
                  shared_hidden_dim: int = 0, routed_scaling: float = 1.0,
                  bias_update_rate: float = 1e-3, record_choice: bool = False,
-                 dtype=None, name: Optional[str] = None):
+                 scoring: str = "sigmoid", dtype=None,
+                 name: Optional[str] = None):
         super().__init__(name)
+        if scoring not in ("sigmoid", "softmax"):
+            raise ValueError(
+                f"scoring must be 'sigmoid' or 'softmax', got {scoring!r}")
+        self.scoring = scoring
         self.num_experts = int(num_experts)
         self.hidden_dim = int(hidden_dim)
         self.top_k = int(top_k)
@@ -424,7 +434,9 @@ class DroplessMoE(Layer):
                 f"experts [{expert_offset}, {expert_offset} + {experts_held})"
                 f" are not among the {num_experts} routed over")
         self.routed_scaling = float(routed_scaling)
-        self.bias_update_rate = float(bias_update_rate)
+        # Softmax scoring has no selection bias: the buffer stays at zeros.
+        self.bias_update_rate = (float(bias_update_rate)
+                                 if scoring == "sigmoid" else 0.0)
         self.record_choice = bool(record_choice)
         self.dtype = dtype
         self.shared = (GatedMLP(shared_hidden_dim, dtype=dtype)
@@ -463,7 +475,11 @@ class DroplessMoE(Layer):
         """``(expert index, gate)``, each (n, top_k): see the class."""
         logits = jnp.dot(tokens_f32, router,
                          precision=jax.lax.Precision.HIGHEST)
-        scores = jax.nn.sigmoid(logits)
+        if self.scoring == "softmax":
+            scores = jax.nn.softmax(logits, axis=-1)
+            bias = 0.0
+        else:
+            scores = jax.nn.sigmoid(logits)
         _, idx = jax.lax.top_k(scores + bias, self.top_k)
         chosen = jnp.take_along_axis(scores, idx, axis=-1)
         gates = chosen / (jnp.sum(chosen, axis=-1, keepdims=True) + 1e-20)
@@ -544,19 +560,5 @@ class DroplessMoE(Layer):
 
 def counters(state) -> dict:
     """``{layer path: {counter: value}}`` of every ``DroplessMoE`` in a
-    model's ``state`` tree, fetched from the device: call it outside a step
-    loop (``Model.fit`` does, once, when a fit ends)."""
-    out = {}
-
-    def walk(tree, path):
-        if not isinstance(tree, dict):
-            return
-        if all(c in tree for c in _COUNTERS):
-            values = jax.device_get({c: tree[c] for c in _COUNTERS})
-            out["/".join(path)] = {c: float(v) for c, v in values.items()}
-            return
-        for key, sub in tree.items():
-            walk(sub, path + (key,))
-
-    walk(state, ())
-    return out
+    model's ``state`` tree (``core.read_counters``)."""
+    return read_counters(state, _COUNTERS)

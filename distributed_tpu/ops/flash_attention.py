@@ -61,6 +61,17 @@ MXU operands, exact divide, once a row):
 - dk/dv compute the score tile column-major (k q^T), so p^T and ds^T are
   left operands as they stand, and read their row statistics lane-major.
 
+Two things a caller may add, both for 128-wide heads in the lane-packed
+layout, neither touching a call that does not ask: *shared K/V heads*
+(grouped queries: a query head block h reads K/V block h // group through
+its index map, and dk/dv's grid walks a group's query heads one after
+another into one accumulator), and a *selection* (learned sparse attention:
+an int8 (T, T) operand, one for all heads, and-ed with ``_valid_mask`` in
+every segment's mask, with a flag a grid block in scalar memory so that a
+block with no selected pair runs no walk; the kernels are then named
+``dtpu_flash_*_sel`` and hand back their row statistic). A selection masks
+dense sub-tiles: it saves work only where whole grid blocks go unselected.
+
 The walk is unrolled at trace time (the LLO scheduler packs straight-line
 code best; an in-kernel ``fori_loop`` over sub-tiles cannot merge runs),
 so the custom_vjp's two halves are jitted: a model's layers share one
@@ -381,18 +392,66 @@ def _p_ds_lse(a, b, c, d, lse, delta, valid, scale):
     return p, ds
 
 
-def _walk_blocks(kernel_walk, one_block, views, qi, ki):
+def _walk_blocks(kernel_walk, one_block, views, qi, ki, live=None):
     """Run ``kernel_walk(diag, edge)`` for the view this grid block lies in:
-    statically in a grid of one block, else under one ``pl.when`` a view."""
+    statically in a grid of one block, else under one ``pl.when`` a view.
+    ``live`` (a selection was handed in): the block holds a selected pair;
+    one that holds none runs no walk, as one above the diagonal runs none."""
     if one_block:
         return kernel_walk(*views[0][0])
     for view, in_view in views:
-        pl.when(in_view(qi, ki))(functools.partial(kernel_walk, *view))
+        here = in_view(qi, ki)
+        if live is not None:
+            here = jnp.logical_and(here, live)
+        pl.when(here)(functools.partial(kernel_walk, *view))
+
+
+def _seg_valid(sel, row_at, col_at, span, segs, t_actual, causal,
+               kv_major=False):
+    """One mask (or None: every pair counts) for each segment of a pass.
+    ``span`` is the pass's own (start, stop) inside the grid block, whose
+    row r and column c are ``row_at(r)`` and ``col_at(c)`` of the sequence:
+    rows, with ``segs`` runs of columns, or, ``kv_major``, columns with runs
+    of rows and masks transposed. A segment the diagonal or the padding edge
+    crosses builds ``_valid_mask``; with a selection (``sel``: its flags and
+    its block, laid out as the masks are) each mask is and-ed with the pairs
+    selected, and a segment below the diagonal is masked by them alone."""
+    out = []
+    for s0, s1, masked in segs:
+        (r0, r1), (c0, c1) = ((s0, s1), span) if kv_major else (
+            span, (s0, s1))
+        ok = _valid_mask(row_at(r0), col_at(c0), r1 - r0, c1 - c0,
+                         t_actual, causal, kv_major) if masked else None
+        if sel is not None:
+            block = (sel[1][0, c0:c1, r0:r1] if kv_major
+                     else sel[1][0, r0:r1, c0:c1])
+            picked = block.astype(jnp.int32) != 0
+            ok = picked if ok is None else jnp.logical_and(ok, picked)
+        out.append(ok)
+    return out
+
+
+def _live(sel, nq, nk, qi, ki):
+    """Whether grid block (qi, ki) of this batch row holds a selected pair
+    (the selection's flags, in scalar memory); None with no selection."""
+    if sel is None:
+        return None
+    return sel[0][(pl.program_id(0) * nq + qi) * nk + ki] != 0
+
+
+def _selecting(kernel, n_in):
+    """``kernel`` as a selection-taking ``pallas_call`` hands over its refs:
+    the flags first (scalar prefetch), the selection's block after the
+    ``n_in`` inputs the kernel has anyway."""
+    def body(flags_ref, *refs, **kw):
+        return kernel(*refs[:n_in], *refs[n_in + 1:],
+                      sel=(flags_ref, refs[n_in]), **kw)
+    return body
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_out_ref, m_ref, l_ref,
                 acc_ref, *, scale, heads, block_q, block_k, t_actual,
-                causal, nq, nk):
+                causal, nq, nk, sel=None):
     """One (b, hblk, qi, ki) grid step on (1, block, lanes) tiles of
     ``heads`` heads side by side: q and k as wide as each other, v, and with
     it the output and the statistics, as wide as itself. Each q sub-tile
@@ -407,7 +466,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_out_ref, m_ref, l_ref,
     v_lanes = _head_lanes(v_ref, heads)
     lanes = list(zip(_head_lanes(q_ref, heads), v_lanes))
     fold = _scale_folds(scale)
-    one_block = nq == 1 and nk == 1
+    one_block = nq == 1 and nk == 1 and sel is None
 
     def finish(rows, m, l, acc):
         l = jnp.maximum(l, 1e-30)
@@ -427,10 +486,9 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_out_ref, m_ref, l_ref,
                 q = q * scale
             ks = [k_ref[0, c0:c1, :] for c0, c1, _ in segs]
             vs = [v_ref[0, c0:c1, :] for c0, c1, _ in segs]
-            valid = [
-                _valid_mask(qi * block_q + r0, ki * block_k + c0, r1 - r0,
-                            c1 - c0, t_actual, causal) if masked else None
-                for c0, c1, masked in segs]
+            valid = _seg_valid(sel, lambda r: qi * block_q + r,
+                               lambda c: ki * block_k + c, (r0, r1), segs,
+                               t_actual, causal)
             # Both heads' scores, then both softmaxes, then both P V: the
             # order the bundle scheduler packs best.
             done = []
@@ -474,7 +532,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_out_ref, m_ref, l_ref,
 
     _walk_blocks(
         walk, one_block,
-        _block_views(nq, nk, block_q, block_k, t_actual, causal), qi, ki)
+        _block_views(nq, nk, block_q, block_k, t_actual, causal), qi, ki,
+        _live(sel, nq, nk, qi, ki))
 
     if not one_block:
         @pl.when(ki == nk - 1)
@@ -484,7 +543,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_out_ref, m_ref, l_ref,
 
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, dq_ref,
                acc_ref, *, scale, heads, block_q, block_k, t_actual, causal,
-               nq, nk):
+               nq, nk, sel=None):
     """dq for one (b, hblk, qi, ki) grid step, walked like the forward: per
     q sub-tile, dq += ds K over the kv columns its rows see."""
     qi = pl.program_id(2)
@@ -493,7 +552,7 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, dq_ref,
     q_lanes, v_lanes = _head_lanes(q_ref, heads), _head_lanes(v_ref, heads)
     lanes = list(zip(q_lanes, v_lanes))
     fold = _scale_folds(scale)
-    one_block = nq == 1 and nk == 1
+    one_block = nq == 1 and nk == 1 and sel is None
 
     def finish(rows, acc):
         if fold:
@@ -514,10 +573,9 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, dq_ref,
             do = do_ref[0, rows, :]
             ks = [k_ref[0, c0:c1, :] for c0, c1, _ in segs]
             vs = [v_ref[0, c0:c1, :] for c0, c1, _ in segs]
-            valid = [
-                _valid_mask(qi * block_q + r0, ki * block_k + c0, r1 - r0,
-                            c1 - c0, t_actual, causal) if masked else None
-                for c0, c1, masked in segs]
+            valid = _seg_valid(sel, lambda r: qi * block_q + r,
+                               lambda c: ki * block_k + c, (r0, r1), segs,
+                               t_actual, causal)
             accs = []
             for group in _head_groups(lanes):
                 dss = []
@@ -544,7 +602,8 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, dq_ref,
 
     _walk_blocks(
         walk, one_block,
-        _block_views(nq, nk, block_q, block_k, t_actual, causal), qi, ki)
+        _block_views(nq, nk, block_q, block_k, t_actual, causal), qi, ki,
+        _live(sel, nq, nk, qi, ki))
 
     if not one_block:
         @pl.when(ki == nk - 1)
@@ -554,19 +613,25 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, dq_ref,
 
 def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, dk_ref,
                 dv_ref, acc_dk, acc_dv, *, scale, heads, block_q, block_k,
-                t_actual, causal, nq, nk):
+                t_actual, causal, nq, nk, group=1, sel=None):
     """dk/dv for one (b, hblk, ki, qi) grid step, the transpose of the dq
     walk: per kv sub-tile, dv += p^T dO and dk += ds^T q over the q rows
     that see its columns. The score tile is computed column-major (k q^T),
     so that p^T and ds^T are the products' left operands as they stand;
-    the row statistics come lane-major, (heads, rows)."""
+    the row statistics come lane-major, (heads, rows). Where ``group`` query
+    heads share a K/V head, the head block is the K/V head's and the last
+    grid axis walks the q blocks of each of its query heads in turn
+    (``group * nq`` steps), all summed into the one dk and dv."""
     ki = pl.program_id(2)
-    qi = pl.program_id(3)
+    step = qi = pl.program_id(3)
+    last = group * nq - 1
+    if group > 1:
+        qi = jax.lax.rem(step, nq)
     subs = [_subtile(x, q_ref.shape[-1]) for x in (block_q, block_k)]
     q_lanes, v_lanes = _head_lanes(q_ref, heads), _head_lanes(v_ref, heads)
     lanes = list(enumerate(zip(q_lanes, v_lanes)))
     fold = _scale_folds(scale)
-    one_block = nq == 1 and nk == 1
+    one_block = nq == 1 and nk == 1 and group == 1 and sel is None
 
     def walk(diag, edge):
         for c0, c1, segs in _passes(
@@ -583,11 +648,9 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, dk_ref,
             if fold:
                 qs = [q * scale for q in qs]
             dos = [do_ref[0, r0:r1, :] for r0, r1, _ in segs]
-            valid = [
-                _valid_mask(qi * block_q + r0, ki * block_k + c0, r1 - r0,
-                            c1 - c0, t_actual, causal, kv_major=True)
-                if masked else None
-                for r0, r1, masked in segs]
+            valid = _seg_valid(sel, lambda r: qi * block_q + r,
+                               lambda c: ki * block_k + c, (c0, c1), segs,
+                               t_actual, causal, kv_major=True)
             dvs, dks = [], []
             for group in _head_groups(lanes):
                 p_ds = []
@@ -613,36 +676,42 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, dk_ref,
                 acc_dv[cols, :] += dv
 
     if not one_block:
-        @pl.when(qi == 0)
+        @pl.when(step == 0)
         def _init():
             acc_dk[...] = jnp.zeros_like(acc_dk)
             acc_dv[...] = jnp.zeros_like(acc_dv)
 
     _walk_blocks(
         walk, one_block,
-        _block_views(nq, nk, block_q, block_k, t_actual, causal), qi, ki)
+        _block_views(nq, nk, block_q, block_k, t_actual, causal), qi, ki,
+        _live(sel, nq, nk, qi, ki))
 
     if not one_block:
-        @pl.when(qi == nq - 1)
+        @pl.when(step == last)
         def _finish():
             dk_ref[0] = acc_dk[...].astype(dk_ref.dtype)
             dv_ref[0] = acc_dv[...].astype(dv_ref.dtype)
 
 
-def _specs(q, v, heads, hpb, causal, block_q, block_k):
+def _specs(q, v, heads, hpb, causal, block_q, block_k, group=1):
     """What the three pallas_calls share, for q (b, T, heads * D) and v
-    (b, T, heads * Dv) with ``hpb`` heads a block: (t_pad, nh, w, wv), the
-    kernels' static arguments, and the block specs for the grid (b, head
-    block, i, j), by which of i / j walks the q blocks. A head block is
-    ``hpb`` heads padded to whole lanes, w for q and k, wv for v; a row
-    statistic is (b, nh, hpb, t_pad), lane-major."""
+    (b, T, heads // group * Dv) with ``hpb`` heads a block: (t_pad, nh, w,
+    wv), the kernels' static arguments, and the block specs for the grid
+    (b, head block, i, j), by which of i / j walks the q blocks. A head
+    block is ``hpb`` heads padded to whole lanes, w for q and k, wv for v; a
+    row statistic is (b, nh, hpb, t_pad), lane-major. ``group`` query heads
+    share a K/V head (one head a block): a query head block h reads K/V
+    block h // group, and dk/dv's grid is (b, K/V head, kv block, query
+    head of the group x q block), ``specs(3, dkv=True)``. The last spec is
+    the selection's block, (block_q, block_k), or transposed for dk/dv."""
     t = q.shape[1]
     if max(block_q, block_k) % min(block_q, block_k):
         raise ValueError(
             f"block_q={block_q} and block_k={block_k} must divide each "
             "other, or trailing rows would fall outside the grid")
     t_pad = _round_up(t, max(block_q, block_k))
-    w, wv = (_lane_pad(x.shape[-1] // heads * hpb) for x in (q, v))
+    w = _lane_pad(q.shape[-1] // heads * hpb)
+    wv = _lane_pad(v.shape[-1] // (heads // group) * hpb)
     nq, nk = t_pad // block_q, t_pad // block_k
     if not _interpret() and nq > 1 and block_q % _LANES:
         raise ValueError(
@@ -650,14 +719,34 @@ def _specs(q, v, heads, hpb, causal, block_q, block_k):
             "lane-major, so a q block that is not the whole sequence has "
             f"to be a multiple of {_LANES} rows")
 
-    def specs(q_axis):
+    def specs(q_axis, dkv=False):
         kv_axis = 5 - q_axis  # grid axes 2 and 3
-        at = lambda axis: lambda *g: (g[0], g[axis], g[1])
+        if group == 1:
+            at = lambda axis: lambda *g: (g[0], g[axis], g[1])
+            q_at, kv_at = at(q_axis), at(kv_axis)
+            stat_at = lambda *g: (g[0], g[1], 0, g[q_axis])
+            sel_at = lambda *g: (g[0], g[q_axis], g[kv_axis])
+        elif dkv:
+            q_at = lambda *g: (g[0], g[3] % nq, g[1] * group + g[3] // nq)
+            kv_at = lambda *g: (g[0], g[2], g[1])
+            stat_at = lambda *g: (g[0], g[1] * group + g[3] // nq, 0,
+                                  g[3] % nq)
+            sel_at = lambda *g: (g[0], g[3] % nq, g[2])
+        else:
+            q_at = lambda *g: (g[0], g[q_axis], g[1])
+            kv_at = lambda *g: (g[0], g[kv_axis], g[1] // group)
+            stat_at = lambda *g: (g[0], g[1], 0, g[q_axis])
+            sel_at = lambda *g: (g[0], g[q_axis], g[kv_axis])
+        if dkv:  # the selection transposed, as dk/dv's masks are
+            sel_spec = pl.BlockSpec(
+                (1, block_k, block_q),
+                lambda *g: (lambda b, i, j: (b, j, i))(*sel_at(*g)))
+        else:
+            sel_spec = pl.BlockSpec((1, block_q, block_k), sel_at)
         return (
-            *(pl.BlockSpec((1, block_q, x), at(q_axis)) for x in (w, wv)),
-            *(pl.BlockSpec((1, block_k, x), at(kv_axis)) for x in (w, wv)),
-            pl.BlockSpec((1, 1, hpb, block_q),
-                         lambda *g: (g[0], g[1], 0, g[q_axis])))
+            *(pl.BlockSpec((1, block_q, x), q_at) for x in (w, wv)),
+            *(pl.BlockSpec((1, block_k, x), kv_at) for x in (w, wv)),
+            pl.BlockSpec((1, 1, hpb, block_q), stat_at), sel_spec)
 
     kernel_args = dict(
         # A Python float: a NumPy scalar is no weak type, and q * scale
@@ -668,110 +757,164 @@ def _specs(q, v, heads, hpb, causal, block_q, block_k):
     return t_pad, heads // hpb, w, wv, kernel_args, specs
 
 
-def _fwd_pallas(q, k, v, *, heads, hpb, suffix, causal, block_q, block_k):
-    """q, k: (b, T, heads * D); v: (b, T, heads * Dv), Dv its own width
-    (latent attention: 192-wide scores, 128-wide values); lane-packed
-    (heads = H, hpb = 128 // D) or folded (b = B*H, heads = hpb = 1).
-    Returns (out, lse) with out in v's layout and the one row statistic the
-    backward needs, lse = m + log l, as (b, heads // hpb, hpb, t_pad): 4
-    bytes a row and head."""
+def _call(kernel, n_in, grid, in_specs, out_specs, out_shape, scratch,
+          name, sel_spec, selection):
+    """The ``pallas_call`` of one of the three kernels: as it always was
+    with no selection; with one (``selection``: its flags and its padded
+    pairs), the flags are prefetched into scalar memory and the pairs'
+    block joins the inputs, under the kernel's name plus ``_sel``."""
+    if selection is None:
+        return pl.pallas_call(
+            kernel, grid=grid, in_specs=in_specs, out_specs=out_specs,
+            out_shape=out_shape, scratch_shapes=scratch, name=name,
+            interpret=_interpret())
+    call = pl.pallas_call(
+        _selecting(kernel, n_in),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=grid,
+            in_specs=[*in_specs, sel_spec], out_specs=out_specs,
+            scratch_shapes=scratch),
+        out_shape=out_shape, name=name + "_sel", interpret=_interpret())
+    return lambda *operands: call(selection[0], *operands, selection[1])
+
+
+def _pad_selection(selection, t_pad):
+    """(flags, pairs padded to the grid's square) of a selection's (flags,
+    pairs (b, T, T)); None stays None."""
+    if selection is None:
+        return None
+    flags, pairs = selection
+    extra = t_pad - pairs.shape[1]
+    return flags, jnp.pad(pairs, ((0, 0), (0, extra), (0, extra)))
+
+
+def _fwd_pallas(q, k, v, selection=None, *, heads, hpb, suffix, causal,
+                block_q, block_k, group=1):
+    """q: (b, T, heads * D); k: (b, T, heads // group * D); v: (b, T, heads
+    // group * Dv), Dv its own width (latent attention: 192-wide scores,
+    128-wide values); lane-packed (heads = H, hpb = 128 // D) or folded (b =
+    B*H, heads = hpb = 1). ``selection``: (flags (b * nq * nk,) int32, pairs
+    (b, T, T) int8), see ``flash_attention``. Returns (out, lse) with out as
+    wide as the query heads' values and the one row statistic the backward
+    needs, lse = m + log l, as (b, heads // hpb, hpb, t_pad): 4 bytes a row
+    and head."""
     b, t, _ = q.shape
     t_pad, nh, w, wv, kernel_args, specs = _specs(
-        q, v, heads, hpb, causal, block_q, block_k)
+        q, v, heads, hpb, causal, block_q, block_k, group)
     nq, nk = kernel_args["nq"], kernel_args["nk"]
-    q_spec, o_spec, k_spec, v_spec, stat = specs(q_axis=2)
-    out, lse = pl.pallas_call(
-        functools.partial(_fwd_kernel, **kernel_args),
-        grid=(b, nh, nq, nk),
-        in_specs=[q_spec, k_spec, v_spec],
-        out_specs=[o_spec, stat],
-        out_shape=[
+    q_spec, o_spec, k_spec, v_spec, stat, sel_spec = specs(q_axis=2)
+    out, lse = _call(
+        functools.partial(_fwd_kernel, **kernel_args), 3,
+        (b, nh, nq, nk), [q_spec, k_spec, v_spec], [o_spec, stat],
+        [
             jax.ShapeDtypeStruct((b, t_pad, nh * wv), q.dtype),
             jax.ShapeDtypeStruct((b, nh, hpb, t_pad), jnp.float32),
         ],
-        scratch_shapes=[
+        [
             # m, l, acc between the sequential ki steps.
             pltpu.VMEM((block_q, wv), jnp.float32),
             pltpu.VMEM((block_q, wv), jnp.float32),
             pltpu.VMEM((block_q, wv), jnp.float32),
         ],
-        name="dtpu_flash_fwd" + suffix,
-        interpret=_interpret(),
-    )(_pad(q, t_pad, nh * w), _pad(k, t_pad, nh * w),
-      _pad(v, t_pad, nh * wv))
-    return out[:, :t, :v.shape[-1]], lse
+        "dtpu_flash_fwd" + suffix, sel_spec,
+        _pad_selection(selection, t_pad),
+    )(_pad(q, t_pad, nh * w), _pad(k, t_pad, nh // group * w),
+      _pad(v, t_pad, nh // group * wv))
+    return out[:, :t, :v.shape[-1] * group], lse
 
 
-def _bwd_pallas(res, g, *, heads, hpb, suffix, causal, block_q, block_k):
+def _bwd_pallas(res, g, *, heads, hpb, suffix, causal, block_q, block_k,
+                group=1):
     """dq, dk, dv from the saved row statistic: two kernels (dq with kv
     innermost; dk/dv with q innermost), each O(T*D) HBM traffic."""
-    q, k, v, out, lse = res
+    q, k, v, out, lse, selection = res
     b, t, _ = q.shape
     t_pad, nh, w, wv, kernel_args, specs = _specs(
-        q, v, heads, hpb, causal, block_q, block_k)
+        q, v, heads, hpb, causal, block_q, block_k, group)
     nq, nk = kernel_args["nq"], kernel_args["nk"]
-    qp, kp = _pad(q, t_pad, nh * w), _pad(k, t_pad, nh * w)
-    vp, dop = _pad(v, t_pad, nh * wv), _pad(g.astype(q.dtype), t_pad, nh * wv)
+    nkv = nh // group
+    qp, kp = _pad(q, t_pad, nh * w), _pad(k, t_pad, nkv * w)
+    vp, dop = _pad(v, t_pad, nkv * wv), _pad(g.astype(q.dtype), t_pad,
+                                             nh * wv)
+    selection = _pad_selection(selection, t_pad)
 
     # delta_i = sum_j dO_ij O_ij per row and head, laid out like lse: XLA
     # reduces over a 64-wide minor dimension by making T minor first, so
     # this layout is the one it reaches with no copy after the reduce.
-    by_head = (b, t, nh, hpb, v.shape[-1] // heads)
+    by_head = (b, t, nh, hpb, out.shape[-1] // heads)
     gf = g.astype(jnp.float32).reshape(by_head)
     of = out.astype(jnp.float32).reshape(by_head)
     delta = jnp.transpose(jnp.sum(gf * of, axis=-1), (0, 2, 3, 1))
     delta = jnp.pad(delta, ((0, 0), (0, 0), (0, 0), (0, t_pad - t)))
 
-    q_spec, do_spec, k_spec, v_spec, stat = specs(q_axis=2)
-    dq = pl.pallas_call(
-        functools.partial(_dq_kernel, **kernel_args),
-        grid=(b, nh, nq, nk),
-        in_specs=[q_spec, k_spec, v_spec, do_spec, stat, stat],
-        out_specs=q_spec,
-        out_shape=jax.ShapeDtypeStruct((b, t_pad, nh * w), q.dtype),
-        scratch_shapes=[pltpu.VMEM((block_q, w), jnp.float32)],
-        name="dtpu_flash_dq" + suffix,
-        interpret=_interpret(),
+    q_spec, do_spec, k_spec, v_spec, stat, sel_spec = specs(q_axis=2)
+    dq = _call(
+        functools.partial(_dq_kernel, **kernel_args), 6,
+        (b, nh, nq, nk), [q_spec, k_spec, v_spec, do_spec, stat, stat],
+        q_spec, jax.ShapeDtypeStruct((b, t_pad, nh * w), q.dtype),
+        [pltpu.VMEM((block_q, w), jnp.float32)],
+        "dtpu_flash_dq" + suffix, sel_spec, selection,
     )(qp, kp, vp, dop, lse, delta)
 
-    q_spec, do_spec, k_spec, v_spec, stat = specs(q_axis=3)
-    dk, dv = pl.pallas_call(
-        functools.partial(_dkv_kernel, **kernel_args),
-        grid=(b, nh, nk, nq),
-        in_specs=[q_spec, k_spec, v_spec, do_spec, stat, stat],
-        out_specs=[k_spec, v_spec],
-        out_shape=[
-            jax.ShapeDtypeStruct((b, t_pad, nh * w), k.dtype),
-            jax.ShapeDtypeStruct((b, t_pad, nh * wv), v.dtype),
+    q_spec, do_spec, k_spec, v_spec, stat, sel_spec = specs(
+        q_axis=3, dkv=True)
+    if group > 1:
+        kernel_args = dict(kernel_args, group=group)
+    dk, dv = _call(
+        functools.partial(_dkv_kernel, **kernel_args), 6,
+        (b, nkv, nk, group * nq),
+        [q_spec, k_spec, v_spec, do_spec, stat, stat], [k_spec, v_spec],
+        [
+            jax.ShapeDtypeStruct((b, t_pad, nkv * w), k.dtype),
+            jax.ShapeDtypeStruct((b, t_pad, nkv * wv), v.dtype),
         ],
-        scratch_shapes=[
+        [
             pltpu.VMEM((block_k, w), jnp.float32),
             pltpu.VMEM((block_k, wv), jnp.float32),
         ],
-        name="dtpu_flash_dkv" + suffix,
-        interpret=_interpret(),
+        "dtpu_flash_dkv" + suffix, sel_spec,
+        # dk/dv's masks are transposed, and so is what they are and-ed with.
+        selection and (selection[0], jnp.swapaxes(selection[1], 1, 2)),
     )(qp, kp, vp, dop, lse, delta)
     return (dq[:, :t, :q.shape[-1]], dk[:, :t, :k.shape[-1]],
             dv[:, :t, :v.shape[-1]])
 
 
 @functools.lru_cache(maxsize=64)
-def _flash_cached(heads, hpb, suffix, causal, block_q, block_k):
+def _flash_cached(heads, hpb, suffix, causal, block_q, block_k, group=1,
+                  selecting=False):
     """custom_vjp fn over (b, T, heads * D) arrays for this static config.
     Its two halves are jitted: every layer of a model calls this one
     function at one shape, so the kernels are traced and lowered once a
     program and not once a layer (the walk is unrolled at trace time, and
-    tracing is set-up time)."""
+    tracing is set-up time). ``selecting``: the function takes a fourth
+    argument, the selection (flags, pairs), which has no gradient, and
+    returns the row statistic beside the output."""
     static = dict(heads=heads, hpb=hpb, suffix=suffix, causal=causal,
                   block_q=block_q, block_k=block_k)
+    if group > 1:
+        static["group"] = group
 
     @jax.jit
-    def flash_fwd(q, k, v):
-        return _fwd_pallas(q, k, v, **static)
+    def flash_fwd(q, k, v, selection=None):
+        return _fwd_pallas(q, k, v, selection, **static)
 
     @jax.jit
     def flash_bwd(res, g):
         return _bwd_pallas(res, g, **static)
+
+    if selecting:
+        @jax.custom_vjp
+        def flash(q, k, v, selection):
+            return flash_fwd(q, k, v, selection)
+
+        def fwd(q, k, v, selection):
+            out, lse = flash_fwd(q, k, v, selection)
+            return (out, lse), (q, k, v, out, lse, selection)
+
+        # lse goes out as a constant (flash_attention stops its gradient).
+        flash.defvjp(fwd, lambda res, g: (*flash_bwd(res, g[0]), None))
+        return flash
 
     @jax.custom_vjp
     def flash(q, k, v):
@@ -779,19 +922,27 @@ def _flash_cached(heads, hpb, suffix, causal, block_q, block_k):
 
     def fwd(q, k, v):
         out, lse = flash_fwd(q, k, v)
-        return out, (q, k, v, out, lse)
+        return out, (q, k, v, out, lse, None)
 
     flash.defvjp(fwd, flash_bwd)
     return flash
 
 
 # -------------------------------------------------------------------- public
-def dense_attention(q, k, v, causal: bool):
+def dense_attention(q, k, v, causal: bool, selection=None,
+                    return_lse: bool = False):
     """Stock-XLA attention over (B, T, H, D) tensors — THE dense softmax
     path, shared by MultiHeadAttention's short-T branch and the Ulysses
     non-flash branch, so mask/scale/dtype policy lives in exactly one
-    place."""
+    place. k and v may have fewer heads than q (grouped queries: query head
+    h reads K/V head h // (H // Hkv)). ``selection`` (B, T, T), non-zero
+    where a query may see a key, is and-ed with the causal mask.
+    ``return_lse``: also each row's log-sum-exp of its scaled, masked
+    scores, (B, H, T) float32, as a constant (``flash_attention``'s)."""
     hd = q.shape[-1]
+    group = q.shape[2] // k.shape[2]
+    if group > 1:
+        k, v = (jnp.repeat(x, group, axis=2) for x in (k, v))
     s = jnp.einsum(
         "bqhd,bkhd->bhqk", q, k, preferred_element_type=jnp.float32
     ) / jnp.sqrt(jnp.float32(hd))
@@ -799,36 +950,21 @@ def dense_attention(q, k, v, causal: bool):
         t = q.shape[1]
         mask = jnp.tril(jnp.ones((t, t), bool))
         s = jnp.where(mask[None, None], s, jnp.float32(-1e30))
+    if selection is not None:
+        s = jnp.where((selection != 0)[:, None], s, jnp.float32(-1e30))
     a = jax.nn.softmax(s, axis=-1).astype(q.dtype)
-    return jnp.einsum("bhqk,bkhd->bqhd", a, v)
+    out = jnp.einsum("bhqk,bkhd->bqhd", a, v)
+    if return_lse:
+        return out, jax.lax.stop_gradient(
+            jax.scipy.special.logsumexp(s, axis=-1))
+    return out
 
 
-def flash_attention(
-    q, k, v, *, causal: bool = False,
-    block_q: Optional[int] = None, block_k: int = 1024,
-):
-    """softmax(Q K^T / sqrt(d)) V without materializing the (T, T) scores.
-
-    q, k: (B, T, H, D); v: (B, T, H, Dv) — the layout MultiHeadAttention
-    produces. Dv is D everywhere but under latent attention, whose keys
-    carry the rope part and are wider than its values (192 and 128): the
-    folded layout takes that as it is, each width padded to whole lanes.
-    Returns (B, T, H, Dv) in q's dtype. Scores/softmax compute in float32.
-    Mosaic on TPU, the Pallas interpreter on CPU (the test configuration);
-    any other backend is an error (``_pallas_common.interpret``).
-
-    ``block_q`` / ``block_k`` are the DMA blocks: what one grid step holds
-    in VMEM. ``block_q=None`` (default) resolves to the swept 1024, scoped-
-    VMEM-clamped to 512 for float32 inputs (any length) and for bf16 above
-    T=2048 (see the comment at the clamp). An EXPLICIT block_q is honored
-    as passed — sweeps on chips with different VMEM budgets must measure
-    what they ask for. Inside a block the kernels compute by sub-tiles
-    (``_subtile``: side ``_SUBTILE`` under a 128-lane head block), which is
-    no argument:
-    what is skipped and what is masked follows from ``causal``, T and the
-    blocks.
-    """
-    b, t, h, d = q.shape
+def resolve_blocks(t: int, itemsize: int, block_q: Optional[int] = None,
+                   block_k: int = 1024):
+    """The (block_q, block_k) ``flash_attention`` runs a sequence of ``t``
+    rows with: its swept defaults, clamped to the sequence and to each
+    other."""
     rt = _round_up(t, 8)
     if block_q is None:
         # Swept default with scoped-VMEM clamps (16MB limit on v5e). The
@@ -847,7 +983,7 @@ def flash_attention(
         #   still beats the old 256 default by ~11% at T=4096
         #   (docs/PERF.md round-4 sweep).
         block_q = 1024
-        if jnp.dtype(q.dtype).itemsize >= 4 or rt > 2048:
+        if itemsize >= 4 or rt > 2048:
             block_q = 512
     bq = min(block_q, rt)
     # Clamp block_k to the q-rounded sequence length: t_pad is a multiple of
@@ -857,21 +993,106 @@ def flash_attention(
     bk = min(block_k, _round_up(t, bq))
     if max(bq, bk) % min(bq, bk):  # clamping broke divisibility
         bq = bk = min(bq, bk)
+    return bq, bk
+
+
+def selection_blocks(selection, block_q: int, block_k: int):
+    """``(flags, total)`` of a causal selection (B, T, T) under these grid
+    blocks: ``flags`` (B, nq, nk) int32, 1 where the block holds a selected
+    pair (the kernels run no walk in the others), and ``total``, the blocks
+    at or below the diagonal: what a plain causal call would walk."""
+    b, t, _ = selection.shape
+    t_pad = _round_up(t, max(block_q, block_k))
+    nq, nk = t_pad // block_q, t_pad // block_k
+    pairs = jnp.pad(selection, ((0, 0), (0, t_pad - t), (0, t_pad - t)))
+    flags = jnp.any(pairs.reshape(b, nq, block_q, nk, block_k) != 0,
+                    axis=(2, 4)).astype(jnp.int32)
+    total = sum(qi * block_q - ki * block_k > -block_q
+                for qi in range(nq) for ki in range(nk))
+    return flags, total
+
+
+def flash_attention(
+    q, k, v, *, causal: bool = False,
+    block_q: Optional[int] = None, block_k: int = 1024,
+    selection=None, selection_flags=None, return_lse: bool = False,
+):
+    """softmax(Q K^T / sqrt(d)) V without materializing the (T, T) scores.
+
+    q: (B, T, H, D); k: (B, T, Hkv, D); v: (B, T, Hkv, Dv) — the layout
+    MultiHeadAttention produces. Dv is D everywhere but under latent
+    attention, whose keys carry the rope part and are wider than its values
+    (192 and 128): the folded layout takes that as it is, each width padded
+    to whole lanes. Hkv is H, or divides it (grouped queries: query head h
+    reads K/V head h // (H // Hkv)); with 128-wide heads the kernels read
+    the shared head in place, in the backward too, where dk/dv sum over a
+    group's query heads; any other shape repeats K and V.
+    Returns (B, T, H, Dv) in q's dtype. Scores/softmax compute in float32.
+    Mosaic on TPU, the Pallas interpreter on CPU (the test configuration);
+    any other backend is an error (``_pallas_common.interpret``).
+
+    ``selection`` (B, T, T) int8: non-zero where a query may see a key, one
+    selection for all heads, and-ed with the causal mask in all three
+    kernels (``dtpu_flash_*_sel``; 128-wide heads only). A grid block that
+    holds no selected pair runs no walk (``selection_blocks``, whose flags
+    may be handed in as ``selection_flags`` by a caller that counts them).
+    A selection has no gradient. Without one the kernels are the plain ones:
+    no operand and no branch is added. ``return_lse`` (with a selection):
+    also the kernels' row statistic, each row's log-sum-exp of its scaled
+    scores over the keys it sees, (B, H, T) float32, as a constant: what the
+    probabilities are recomputed from, exp(score - lse), by whoever needs
+    them after the call.
+
+    ``block_q`` / ``block_k`` are the DMA blocks: what one grid step holds
+    in VMEM. ``block_q=None`` (default) resolves to the swept 1024, scoped-
+    VMEM-clamped to 512 for float32 inputs (any length) and for bf16 above
+    T=2048 (``resolve_blocks``). An EXPLICIT block_q is honored
+    as passed — sweeps on chips with different VMEM budgets must measure
+    what they ask for. Inside a block the kernels compute by sub-tiles
+    (``_subtile``: side ``_SUBTILE`` under a 128-lane head block), which is
+    no argument:
+    what is skipped and what is masked follows from ``causal``, T and the
+    blocks.
+    """
+    b, t, h, d = q.shape
+    bq, bk = resolve_blocks(t, jnp.dtype(q.dtype).itemsize, block_q, block_k)
     dv = v.shape[-1]
     packed = dv == d and _packed_supported(h, d)
+    group = h // k.shape[2]
+    if group > 1 and not (packed and d == _LANES):
+        k, v = (jnp.repeat(x, group, axis=2) for x in (k, v))
+        group = 1
     # How far the sub-tile walk engages is static for a shape, so it is
     # published here, at trace time, as counts.
     gauge = default_registry().gauge
     for name, n in zip(("square", "computed", "masked"), subtile_counts(
             t, bq, bk, causal, _LANES if packed else _lane_pad(d))):
         gauge(f"flash.subtiles_{name}", n)
+    if selection is not None:
+        if not (packed and d == _LANES and causal):
+            raise ValueError(
+                "a selection is taken by the causal lane-packed kernels at "
+                f"128-wide heads; got heads of {d} and {dv}, causal={causal}")
+        if selection_flags is None:
+            selection_flags = selection_blocks(selection, bq, bk)[0]
+        flash = _flash_cached(h, 1, "", causal, bq, bk, group, True)
+        out, lse = flash(
+            q.reshape(b, t, h * d), k.reshape(b, t, -1), v.reshape(b, t, -1),
+            (selection_flags.reshape(-1), selection.astype(jnp.int8)))
+        out = out.reshape(b, t, h, d)
+        if return_lse:
+            return out, jax.lax.stop_gradient(lse[:, :, 0, :t])
+        return out
+    if return_lse:
+        raise ValueError("return_lse is the selection-taking kernels'")
     if packed:
         # Lane-packed path: kernels read heads straight from the (B, T,
         # H*D) projection layout — the reshape is free, no transposes.
-        flash = _flash_cached(h, _LANES // d, "_packed", causal, bq, bk)
+        flash = _flash_cached(h, _LANES // d, "_packed", causal, bq, bk,
+                              group)
         return flash(
-            q.reshape(b, t, h * d), k.reshape(b, t, h * d),
-            v.reshape(b, t, h * d),
+            q.reshape(b, t, h * d), k.reshape(b, t, -1),
+            v.reshape(b, t, -1),
         ).reshape(b, t, h, d)
     fold = lambda x: jnp.moveaxis(x, 2, 1).reshape(b * h, t, x.shape[-1])
     out = _flash_cached(1, 1, "", causal, bq, bk)(fold(q), fold(k), fold(v))

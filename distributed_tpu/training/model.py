@@ -42,6 +42,7 @@ from ..nn.core import (
     eval_sample_weights as _eval_sample_weights,
 )
 from ..nn import moe as moe_lib
+from ..nn import attention as attention_lib
 from ..ops import losses as losses_lib
 from ..ops import metrics as metrics_lib
 from ..parallel.strategy import SingleDevice, Strategy, current_strategy
@@ -1774,6 +1775,10 @@ class Model:
             # tiles of the buffers' static worst case.
             obs_reg.gauge("moe.buffer_used_pct", 100.0 * total(
                 "tiles_used") / max(total("buffer_tiles"), 1.0))
+        # Attention layers that select their keys count the same way.
+        select_counters = attention_lib.select_counters(self.state)
+        if select_counters:
+            report["select"] = select_counters
         # The legacy dict is a VIEW stored in the metrics registry
         # (key-for-key identical — pinned by the obs parity test): one
         # telemetry surface, backward-compatible reader.

@@ -1,18 +1,25 @@
 #!/usr/bin/env python3
-"""What the first-step limits of the DeepSeek-V3 family's cell
-(``benchmarks/families/deepseek_v3.py``) catch. The plain reference computes
-a wrong model on purpose (no shared expert, gates left unnormalised, pairs
-over a capacity dropped, the experts' matmuls or every weight matmul in
-int8), at the cell's own size, weights and first batch for ``--seed``, and
-stands in for the program in the driver's own comparison
-(``reference.compare`` and ``family.first_step_checks``, as
-``drivers/train_family.py`` calls them): its loss, its gradient as
-``system_grads``, and the reference held to the wrong model's own choices
-of experts. Each wrong model has to fail a check. ``int8`` is the cell's
-control, the precision below the configuration's bfloat16: run it on the
-chip beside the cell's own runs. Run by hand; PERF.md keeps the readings.
+"""What the first-step limits of an expert-layer family's cell
+(``benchmarks/families/deepseek_v3.py``, ``keye_vl2.py``) catch. The plain
+reference computes a wrong model on purpose (DeepSeek-V3: no shared expert,
+gates left unnormalised, pairs over a capacity dropped, the experts' matmuls
+or every weight matmul in int8; Keye-VL-2.0: every weight matmul in int8,
+the learned selection of keys ignored, half the keys selected), at the
+cell's own size, weights and first batch for ``--seed``, and stands in for
+the program in the driver's own comparison (``reference.compare`` and
+``family.first_step_checks``, as ``drivers/train_family.py`` calls them):
+its loss, its gradient as ``system_grads``, and the reference held to the
+wrong model's own choices of experts and keys. Each wrong model has to fail
+a check. ``int8`` is a cell's control, the precision below the
+configuration's bfloat16; ``no_selection`` is the Keye cell's second, and
+``half_selection`` its third: a selection that keeps too few keys agrees
+with the reference held to it in everything but the keys it missed. Run
+them on the chip beside the cell's own runs. Run by hand; PERF.md keeps the
+readings.
 
     chiprun --chips 1 -- python3 scripts/moe_wrong_models.py --variants int8
+    chiprun --chips 1 -- python3 scripts/moe_wrong_models.py \
+        --cell keye-vl2-30b.train.dsa8k
     JAX_PLATFORMS=cpu python3 scripts/moe_wrong_models.py [--seed N]
 
 (float32 at "highest" on either backend; on the CPU some minutes a model
@@ -32,14 +39,37 @@ sys.path.insert(0, ROOT)
 from benchmarks import harness, traffic as traffic_lib  # noqa: E402
 
 CELL = "kanana2-30b.train.ep8share"
-VARIANTS = ("no_shared", "unnormalised_gates", "capacity", "int8_experts",
-            "int8")
+VARIANTS = {
+    "deepseek_v3": ("no_shared", "unnormalised_gates", "capacity",
+                    "int8_experts", "int8"),
+    "keye_vl2": ("int8", "no_selection", "half_selection"),
+}
+
+
+def held_to(own):
+    """A wrong model's own choices (what its ``loss_and_grads`` returns
+    beside the gradient) in the form that function takes as ``forced``."""
+    if isinstance(own, tuple):  # keye_vl2: (selections, experts, sum of L_I)
+        keys, chosen, _ = own
+        return {"experts": chosen, "keys": keys}
+    return [c[0] for c in own]
+
+
+def forced_of(held):
+    """``held_to``'s choices in the form the family's ``compare`` takes."""
+    import jax.numpy as jnp
+
+    if isinstance(held, dict):
+        return dict(held, keys=[jnp.packbits(k, axis=-1)
+                                for k in held["keys"]])
+    return held
 
 
 def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--seed", type=int, default=2147483659)
-    ap.add_argument("--variants", default=",".join(VARIANTS))
+    ap.add_argument("--variants", default=None,
+                    help="default: every wrong model of the cell's family")
     ap.add_argument("--cell", default=CELL)
     ap.add_argument("--manifest", default=harness.MANIFEST)
     args = ap.parse_args()
@@ -66,16 +96,23 @@ def main() -> int:
     kw = fam.reference_kwargs(cfg)
     p = fam.reference_params(model.params, model.state, cfg)
 
-    run = jax.jit(lambda variant, p: ref.loss_and_grads(
-        p, x, y, kw=kw, variant=variant), static_argnums=(0,))
+    run = jax.jit(lambda variant, p, forced: ref.loss_and_grads(
+        p, x, y, kw=kw, variant=variant, forced=forced), static_argnums=(0,))
     pairs = int(tr["seq_len"]) * int(cfg["num_experts_per_tok"])
     out = {"seed": args.seed, "backend": jax.default_backend(),
            "variants": {}}
-    for variant in args.variants.split(","):
-        loss, grads, chosen = run(variant, p)
+    variants = (args.variants.split(",") if args.variants
+                else VARIANTS[cfg["family"]])
+    for variant in variants:
+        # Its choices first, then its loss and gradient held to them, as
+        # ``compare`` holds the reference: on the v5e the two programs of
+        # one reference, choosing for itself and held to those choices, read
+        # 0.016-0.044 apart in the worst leaves (PERF.md section 6, PR 32).
+        held = held_to(run(variant, p, None)[2])
+        loss, grads, _ = run(variant, p, held)
         compared = jax.device_get(ref.compare(
-            p, x, y, kw=kw, system_grads=grads,
-            forced=[c[0] for c in chosen]))
+            p, x, y, kw=kw, system_grads=grads, forced=forced_of(held)))
+        del held
         row = fam.first_step_checks(
             float(loss), float(ref._norm(grads)), compared, pairs)
         del grads

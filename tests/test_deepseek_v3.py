@@ -26,6 +26,7 @@ from benchmarks import harness  # noqa: E402
 MANIFEST = {"paths": ["tests/bench_harness", "benchmarks"]}
 ref = harness.load_module(MANIFEST, "reference", "deepseek_v3")
 fam = harness.load_module(MANIFEST, "families", "deepseek_v3")
+keye_ref = harness.load_module(MANIFEST, "reference", "keye_vl2")
 
 D, HEADS, KV_RANK, NOPE, ROPE, V = 64, 2, 32, 16, 8, 16
 EXPERTS, HIDDEN, TOP_K, SCALING = 16, 32, 3, 2.448
@@ -106,10 +107,11 @@ def test_latent_attention_matches_the_reference(flash):
 
 
 # ---------------------------------------------------------- expert layer --
-def expert_layer(held=None, offset=0, shared=2):
+def expert_layer(held=None, offset=0, shared=2, scoring="sigmoid"):
     return nn.DroplessMoE(
         EXPERTS, HIDDEN, top_k=TOP_K, experts_held=held, expert_offset=offset,
-        shared_hidden_dim=shared * HIDDEN, routed_scaling=SCALING)
+        shared_hidden_dim=shared * HIDDEN, scoring=scoring,
+        routed_scaling=SCALING if scoring == "sigmoid" else 1.0)
 
 
 def reference_block(params, state, shared=True):
@@ -124,10 +126,15 @@ def reference_block(params, state, shared=True):
     return b
 
 
-def reference_layer(params, state, x, offset, shared=True):
+def reference_layer(params, state, x, offset, shared=True, scoring="sigmoid"):
     flat = x.reshape(-1, x.shape[-1])
-    y, _ = ref.experts(reference_block(params, state, shared), flat,
-                       top_k=TOP_K, scaling=SCALING, expert_offset=offset)
+    block = reference_block(params, state, shared)
+    if scoring == "softmax":  # Qwen3-MoE's router: reference/keye_vl2.py's
+        y, _ = keye_ref.experts(block, flat, kw={
+            "top_k": TOP_K, "expert_offset": offset})
+    else:
+        y, _ = ref.experts(block, flat, top_k=TOP_K, scaling=SCALING,
+                           expert_offset=offset)
     return y.reshape(x.shape)
 
 
@@ -148,21 +155,31 @@ def share_of(params, lo, n):
                            for k in ("w_gate", "w_up", "w_down")})
 
 
+@pytest.mark.parametrize("scoring", ["sigmoid", "softmax"])
 @pytest.mark.parametrize("held,offset", [(16, 0), (4, 4), (2, 14)])
 def test_expert_layer_matches_the_reference_on_its_share(whole_layer, held,
-                                                         offset):
+                                                         offset, scoring):
+    """``softmax``: scores over all experts, no selection bias (the state's
+    is not zero here, and is ignored), no shared expert, no scaling: the
+    router of ``reference/keye_vl2.py``."""
     _, params, state, x = whole_layer
-    layer = expert_layer(held, offset)
+    softmax = scoring == "softmax"
+    layer = expert_layer(held, offset, shared=0 if softmax else 2,
+                         scoring=scoring)
     p = share_of(params, offset, held)
+    if softmax:
+        p = {k: v for k, v in p.items() if k != "shared"}
     w = jax.random.normal(jax.random.PRNGKey(9), x.shape)
 
     def system(p, x):
         return layer.apply(p, state, x, train=True)[0]
 
-    assert close(system(p, x), reference_layer(p, state, x, offset))
+    def reference(p, x):
+        return reference_layer(p, state, x, offset, scoring=scoring)
+
+    assert close(system(p, x), reference(p, x))
     got = jax.grad(lambda p, x: jnp.sum(system(p, x) * w), (0, 1))(p, x)
-    want = jax.grad(lambda p, x: jnp.sum(
-        reference_layer(p, state, x, offset) * w), (0, 1))(p, x)
+    want = jax.grad(lambda p, x: jnp.sum(reference(p, x) * w), (0, 1))(p, x)
     assert_trees_close(got, want)
 
 

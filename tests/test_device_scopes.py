@@ -187,3 +187,67 @@ def test_every_operation_of_the_deepseek_step_is_under_a_scope(deepseek):
             continue
         m = re.match(r"jit\(step\)/(?:transpose\()?(?:jvp\()?(\w*)", name)
         assert m and m.group(1) in tops, name
+
+
+# ------------------------- grouped queries over a learned selection of keys --
+@pytest.fixture(scope="module")
+def selecting():
+    m = dtpu.Model(dtpu.models.qwen3_moe_lm(
+        64, num_layers=2, d_model=16, num_heads=4, num_kv_heads=2, head_dim=8,
+        num_experts=8, experts_held=4, expert_offset=2, top_k=2, moe_hidden=8,
+        index_topk=4, index_heads=2, index_dim=4,
+        record_choice=True))
+    m.compile(optimizer=dtpu.optim.Adam(1e-3),
+              loss="sparse_categorical_crossentropy", metrics=())
+    m.build((16,), seed=0)
+    x = np.zeros((4, 16), np.int32)
+    text = m.lower_train_step(x, x).compile().as_text()
+    return m, sorted(set(re.findall(r'op_name="(jit\(step\)[^"]*)"', text)))
+
+
+def test_selecting_layers_scope_paths_are_their_parameter_paths(selecting):
+    model, names = selecting
+    paths = layer_paths(model.params)
+    gqa = ("residual_2", "main", "multi_head_attention_gqa")
+    for want in (gqa, gqa + ("q_norm",), gqa + ("k_norm",),
+                 gqa + ("indexer",), gqa + ("indexer", "k_norm"),
+                 ("residual_3", "main", "moe")):
+        assert want in paths
+    for path in paths:
+        top, rest = path[0], "/".join(path[1:])
+        tail = f"/{rest}/" if rest else "/"
+        assert has(names, rf"jit\(step\)/jvp\({top}\){tail}"), path
+        assert has(names, rf"jit\(step\)/transpose\(jvp\({top}\)\)/{JAX}"
+                          rf"{tail[1:]}"), path
+
+
+@pytest.mark.parametrize("inner,primitive", [
+    ("indexer", "dot_general"), ("indexer/k_norm", "rsqrt"),
+    ("indexer/{JAX}select", "ge"), ("indexer/{JAX}select", "and"),
+])
+def test_the_selecting_layer_names_its_indexer_and_its_selection(
+        selecting, inner, primitive):
+    """``multi_head_attention_gqa/indexer`` holds the indexer's projections,
+    the index scores, L_I and its gradient, and inside it ``select`` the
+    row-wise top-k (the bisection's loop): ``benchmarks/scopes_dsa.py``
+    splits the attention layers' device time by them. The component starts
+    with ``multi_head_attention``, so ``attn_device_ms`` holds all of it.
+    L_I's gradient is taken where the scores are, in the forward pass, and
+    the selection has none: neither has a ``transpose(`` of its own."""
+    _, names = selecting
+    inner = inner.format(JAX=JAX)
+    assert has(names, rf"jit\(step\)/jvp\(residual_2\)/main/"
+                      rf"multi_head_attention_gqa/{inner}/{JAX}\w*{primitive}")
+    assert not has(names, rf"jit\(step\)/transpose\(jvp\(residual_2\)\)/"
+                          rf"{JAX}(?:jvp\()?select\)?/")
+
+
+def test_every_operation_of_the_selecting_step_is_under_a_scope(selecting):
+    model, names = selecting
+    tops = {p[0] for p in layer_paths(model.params)} | {
+        "cast", "loss", "metrics", "optimizer"}
+    for name in names:
+        if name == "jit(step)":
+            continue
+        m = re.match(r"jit\(step\)/(?:transpose\()?(?:jvp\()?(\w*)", name)
+        assert m and m.group(1) in tops, name

@@ -97,6 +97,30 @@ def test_folded_flash_grad_at_latent_attentions_widths_compiles_for_v5e(
     assert "_packed" not in text
 
 
+@pytest.mark.parametrize("kv_heads,selecting", [(4, True), (4, False)])
+def test_grouped_query_flash_grad_compiles_for_v5e(kv_heads, selecting,
+                                                   one_chip, mosaic):
+    """keye-vl2-30b.train.dsa8k: (1, 8192, 32) query heads over 4 K/V heads
+    of 128, block_q 512, block_k 1024, with the int8 selection operand (its
+    block converted and and-ed with the masks in VMEM, its flags in scalar
+    memory) and without; dk/dv walk a group's eight query heads a K/V head."""
+    spec = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                     sharding=one_chip)
+    q = spec((1, 8192, 32, 128), jnp.bfloat16)
+    kv = spec((1, 8192, kv_heads, 128), jnp.bfloat16)
+    sel = spec((1, 8192, 8192), jnp.int8) if selecting else None
+
+    def loss(q, k, v, sel):
+        out = fa.flash_attention(q, k, v, causal=True, selection=sel)
+        return jnp.sum(out.astype(jnp.float32))
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        q, kv, kv, sel).compile().as_text()
+    suffix = "_sel" if selecting else "_packed"
+    for name in ("dtpu_flash_fwd", "dtpu_flash_dq", "dtpu_flash_dkv"):
+        assert name + suffix in text
+
+
 @pytest.mark.parametrize("k,n", [(2048, 768), (768, 2048)])
 def test_grouped_matmul_grad_compiles_for_v5e(k, n, one_chip, mosaic):
     """The three kernels at the cell's shapes: 16 held experts, a buffer for
