@@ -26,6 +26,9 @@ import jax.numpy as jnp
 
 from . import initializers
 from .core import Layer, Shape, child_scope, read_counters
+from ..obs.registry import default_registry
+from ..ops.index_scores import (
+    block_index_scores, index_scores, kernel_fits, tile_counts)
 from ..precision import resolve_dtype
 from ..quant import _QMAX, QKEY, SKEY, dequantize, maybe_dequantize, shape_of
 
@@ -725,16 +728,6 @@ def _rms(x, scale, epsilon):
 
 
 # ------------------------------------------- learned selection of the keys --
-def index_scores(qi, ki, w):
-    """The lightning indexer's score of every key for a block of queries:
-    ``I[t, s] = sum_j w[t, j] * relu(qi[t, j] . ki[s])`` in float32, for qi
-    (n, J, d), the one key head ki (T, d) and w (n, J)."""
-    dots = jnp.einsum("qjd,sd->qjs", qi, ki,
-                      preferred_element_type=jnp.float32)
-    return jnp.sum(jax.nn.relu(dots) * w[:, :, None].astype(jnp.float32),
-                   axis=1)
-
-
 def _mean_probs(q, k, lse, picked):
     """The main attention's probabilities over the selected keys, averaged
     over the heads: (n, T) float32 for a block of queries q (n, H, D), keys k
@@ -800,15 +793,28 @@ def select_keys(qi, ki, w, *, topk: int, block: int = INDEX_BLOCK):
     return jax.lax.map(lambda a: one(*a), (qi, ki, w))
 
 
-def _index_loss_sequence(qi, ki, w, q, k, lse, selection, *, block):
+def _index_loss_sequence(qi, ki, w, q, k, lse, selection, *, block, kernel):
     """(sum over one sequence's queries of the KL, its gradient in (qi, ki,
     w)), a block of queries at a time: ``index_loss``."""
+    t, j, d = qi.shape
+    n = math.gcd(t, block)
+    itemsize = jnp.dtype(qi.dtype).itemsize
+    # The scores' gradient recomputes them in a kernel where their shapes
+    # tile; else autodiff's, which keeps the (n, J, T) products.
+    kernel = kernel and kernel_fits(n, j, d, t, itemsize)
+    if kernel:  # static for a shape, published at trace time as counts
+        gauge = default_registry().gauge
+        for name, count in zip(("square", "computed"),
+                               tile_counts(t, n, itemsize)):
+            gauge(f"index.tiles_{name}", count)
+
     def step(d_ki, blk):
-        qi_b, w_b, q_b, lse_b, picked = blk
+        qi_b, w_b, q_b, lse_b, picked, row0 = blk
         picked = picked != 0
 
         def kl_sum(qi_b, w_b, ki):
-            scores = index_scores(qi_b, ki, w_b)
+            scores = (block_index_scores(qi_b, ki, w_b, row0) if kernel
+                      else index_scores(qi_b, ki, w_b))
             target = _mean_probs(q_b, k, lse_b, picked)
             log_index = jax.nn.log_softmax(
                 jnp.where(picked, scores, jnp.float32(-1e30)), axis=-1)
@@ -822,12 +828,14 @@ def _index_loss_sequence(qi, ki, w, q, k, lse, selection, *, block):
 
     d_ki, (kl, d_qi, d_w) = jax.lax.scan(
         step, jnp.zeros(ki.shape, jnp.float32), tuple(
-            _cut(a, block) for a in (qi, w, q, lse.T, selection)))
+            _cut(a, block) for a in (qi, w, q, lse.T, selection)) + (
+                jnp.arange(0, t, n),))
     return jnp.sum(kl), (d_qi.reshape(qi.shape), d_ki, d_w.reshape(w.shape))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(7,))
-def index_loss(qi, ki, w, q, k, lse, selection, block=INDEX_BLOCK):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8))
+def index_loss(qi, ki, w, q, k, lse, selection, block=INDEX_BLOCK,
+               kernel=False):
     """``L_I``, the loss that trains the indexer: the mean over a batch's
     queries of KL(the main attention's probabilities over the query's
     selection, averaged over its heads || the softmax of the query's index
@@ -838,12 +846,14 @@ def index_loss(qi, ki, w, q, k, lse, selection, block=INDEX_BLOCK):
     and w only: the selection and the main attention's side are constants.
     The gradient is taken where the scores are, in the forward pass, a block
     of queries at a time, so no (T, T) matrix is kept for the backward
-    pass."""
-    return _index_loss_fwd(qi, ki, w, q, k, lse, selection, block)[0]
+    pass. ``kernel``: whether the caller runs its attention in the flash
+    kernels; the scores' own gradient then recomputes them a key tile at a
+    time in one (``ops/index_scores.py``), where their shapes tile."""
+    return _index_loss_fwd(qi, ki, w, q, k, lse, selection, block, kernel)[0]
 
 
-def _index_loss_fwd(qi, ki, w, q, k, lse, selection, block):
-    one = functools.partial(_index_loss_sequence, block=block)
+def _index_loss_fwd(qi, ki, w, q, k, lse, selection, block, kernel):
+    one = functools.partial(_index_loss_sequence, block=block, kernel=kernel)
     kl, grads = jax.lax.map(lambda a: one(*a),
                             (qi, ki, w, q, k, lse, selection))
     queries = qi.shape[0] * qi.shape[1]
@@ -852,7 +862,7 @@ def _index_loss_fwd(qi, ki, w, q, k, lse, selection, block):
     return jnp.sum(kl) / queries, (scaled, q, k, lse)
 
 
-def _index_loss_bwd(block, res, ct):
+def _index_loss_bwd(block, kernel, res, ct):
     grads, q, k, lse = res
     d_qi, d_ki, d_w = ((g * ct).astype(g.dtype) for g in grads)
     return (d_qi, d_ki, d_w, jnp.zeros_like(q), jnp.zeros_like(k),
@@ -1025,10 +1035,11 @@ class GroupedQueryAttention(Layer):
                 selection = select_keys(*index, topk=self.index_topk,
                                         block=INDEX_BLOCK)
         flash = self._use_flash(t) and ambient_mesh()[0] is None
+        kernels = flash and hd == 128  # what takes a selection in Mosaic
         if selection is None:
             ctx = (fa.flash_attention(q, k, v, causal=True) if flash
                    else fa.dense_attention(q, k, v, True))
-        elif flash and hd == 128:
+        elif kernels:
             flags, total = fa.selection_blocks(selection, *fa.resolve_blocks(
                 t, jnp.dtype(q.dtype).itemsize))
             blocks = (jnp.sum(flags).astype(jnp.float32), b * total)
@@ -1043,7 +1054,7 @@ class GroupedQueryAttention(Layer):
             return out, {}
         with child_scope("indexer"):
             loss = index_loss(*index, *jax.lax.stop_gradient((q, k)), lse,
-                              selection, INDEX_BLOCK)
+                              selection, INDEX_BLOCK, kernels)
             new_state = dict(
                 state, aux_loss=loss,
                 steps=state["steps"] + 1.0,

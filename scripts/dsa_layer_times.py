@@ -10,9 +10,12 @@ trace, read with ``benchmarks/trace.py`` and ``benchmarks/scopes.py``):
         --topk 64 --heads 4 --kv-heads 2 --d-model 64
 
 One JSON line: milliseconds a call on the host clock, and from the trace the
-milliseconds under ``indexer``, ``select`` and the rest of the layer and the
-twelve operations with most time. ``--rehearse`` runs it once on the CPU
-(dense path, no trace) to prove the control flow.
+milliseconds under ``indexer``, ``select`` and the rest of the layer, the
+twelve operations with most time, and every instruction under ``indexer``
+(``select`` apart) that takes 0.05 ms or more: its name, its output's shape,
+its calls and their milliseconds together (a block of queries is a call:
+16 a layer at T = 8192), and the end of its ``op_name``. ``--rehearse`` runs
+it once on the CPU (dense path, no trace) to prove the control flow.
 """
 
 from __future__ import annotations
@@ -20,6 +23,7 @@ from __future__ import annotations
 import argparse
 import json
 import os
+import re
 import shutil
 import sys
 import time
@@ -91,7 +95,7 @@ def main():
         names = scopes.op_names(path)
         # By scope and by instruction; a loop's own event encloses its
         # body's and is left out of both.
-        sums, by_name = {}, {}
+        sums, by_name, indexer = {}, {}, {}
         for e in trace_lib.device(parsed).ops:
             if e.op.startswith("while"):
                 continue
@@ -99,7 +103,17 @@ def main():
             inner = scopes_dsa._inner(scope_path) or "rest"
             sums[inner] = sums.get(inner, 0.0) + 1e3 * e.seconds
             by_name[e.name] = by_name.get(e.name, 0.0) + 1e3 * e.seconds
+            if inner == "indexer":
+                calls_ms = indexer.setdefault(e.name, [0, 0.0])
+                calls_ms[0] += 1
+                calls_ms[1] += 1e3 * e.seconds
         out["scope_ms"] = sums
+        out["indexer_instructions"] = [
+            [name.split(" = ")[0].lstrip("%"),
+             "".join(re.findall(r" = (\(?\w+\[[\d,]*\])", name)[:1]),
+             calls, ms, names.get(name, "")[-70:]]
+            for name, (calls, ms) in sorted(
+                indexer.items(), key=lambda kv: -kv[1][1]) if ms >= 0.05]
         out["top_ops_ms"] = [[n, 1e3 * s]
                              for n, s in trace_lib.top_ops(parsed, 12)]
         out["top_instructions_ms"] = [

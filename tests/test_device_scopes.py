@@ -251,3 +251,29 @@ def test_every_operation_of_the_selecting_step_is_under_a_scope(selecting):
             continue
         m = re.match(r"jit\(step\)/(?:transpose\()?(?:jvp\()?(\w*)", name)
         assert m and m.group(1) in tops, name
+
+
+def test_the_index_scores_kernel_runs_under_the_indexers_scope():
+    """Where the selecting layer runs its flash kernels (128-wide heads, an
+    indexer of 64), the index scores' gradient is ``dtpu_index_scores_bwd``
+    (``ops/index_scores.py``): its operations carry
+    ``multi_head_attention_gqa/indexer`` as the plain form's did, so
+    ``dsa_index_device_ms`` reads the kernel with no scope renamed, and none
+    lies under ``select``."""
+    m = dtpu.Model(dtpu.models.qwen3_moe_lm(
+        64, num_layers=1, d_model=16, num_heads=2, num_kv_heads=1,
+        head_dim=128, num_experts=4, experts_held=2, expert_offset=0,
+        top_k=2, moe_hidden=8, index_topk=32, index_heads=2, index_dim=64,
+        flash=True))
+    m.compile(optimizer=dtpu.optim.Adam(1e-3),
+              loss="sparse_categorical_crossentropy", metrics=())
+    m.build((128,), seed=0)
+    x = np.zeros((1, 128), np.int32)
+    text = m.lower_train_step(x, x).compile().as_text()
+    names = [n for n in set(re.findall(r'op_name="(jit\(step\)[^"]*)"', text))
+             if "dtpu_index_scores_bwd" in n]
+    assert names
+    for name in names:
+        assert re.match(r"jit\(step\)/jvp\(residual\)/main/"
+                        r"multi_head_attention_gqa/indexer/", name), name
+        assert "select/" not in name.split("dtpu_index_scores_bwd")[0], name

@@ -2,7 +2,8 @@
 compiler for a described (not attached) v5e: lane-packed at the GPT-2
 cells' shapes and at the scoped-VMEM clamp shape; and, for the
 latent-attention cell, folded at 192-wide keys and 128-wide values; and the
-grouped-matmul kernels at the held experts' shapes. Nothing runs: this guards the
+grouped-matmul kernels at the held experts' shapes; and the index scores'
+backward kernel at the selecting cell's. Nothing runs: this guards the
 16 MB scoped-VMEM limit and the lane / sublane alignment of the in-kernel
 sub-tile slices, which interpret mode cannot see, at no chip time
 (on-chip-measurement guide, third rehearsal; the whole step programs are
@@ -12,6 +13,7 @@ Kept in ONE file: the worker that runs it loads libtpu and keeps its lock.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -20,6 +22,7 @@ from jax.sharding import SingleDeviceSharding
 
 from distributed_tpu.ops import flash_attention as fa
 from distributed_tpu.ops import grouped_matmul as gm
+from distributed_tpu.ops import index_scores as ix
 
 
 @pytest.fixture(scope="module")
@@ -42,6 +45,7 @@ def mosaic(monkeypatch):
     from jax.experimental.compilation_cache import compilation_cache
     monkeypatch.setattr(fa, "_interpret", lambda: False)
     monkeypatch.setattr(gm, "_interpret", lambda: False)
+    monkeypatch.setattr(ix, "_interpret", lambda: False)
     fa._flash_cached.cache_clear()
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
@@ -141,3 +145,24 @@ def test_grouped_matmul_grad_compiles_for_v5e(k, n, one_chip, mosaic):
     ).compile().as_text()
     for name in ("dtpu_gmm_nt", "dtpu_gmm_tn"):
         assert name in text
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+def test_index_scores_gradient_compiles_for_v5e(dtype, one_chip, mosaic):
+    """keye-vl2-30b.train.dsa8k: a block of 512 queries of the indexer's 16
+    heads of 64 against 8,192 keys, the cotangent (512, 8192) float32: the
+    heads in pairs, ``(w qi)^T``, G's accumulator, a key tile of 512 (256
+    under float32 inputs) and its products fit the 16 MB a kernel may use,
+    and every in-kernel slice is tile-aligned."""
+    spec = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                     sharding=one_chip)
+
+    def loss(qi, ki, w, d_scores, row0):
+        return jnp.sum(ix.block_index_scores(qi, ki, w, row0) * d_scores)
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        spec((512, 16, 64), dtype), spec((8192, 64), dtype),
+        spec((512, 16), jnp.float32), spec((512, 8192), jnp.float32),
+        spec((), jnp.int32)).compile().as_text()
+    assert "dtpu_index_scores_bwd" in text
+    assert not re.search(r"(?:f32|bf16|pred)\[512,16,8192\]", text)
