@@ -2,6 +2,7 @@ from .cnn import cifar_cnn, mnist_cnn
 from .resnet import resnet, resnet18, resnet34, resnet50
 from .transformer import (
     deepseek_v3_lm,
+    lfm2_moe_lm,
     qwen3_moe_lm,
     transformer_block,
     transformer_lm,
@@ -19,6 +20,7 @@ __all__ = [
     "transformer_block",
     "deepseek_v3_lm",
     "qwen3_moe_lm",
+    "lfm2_moe_lm",
     "vit",
     "vit_tiny",
     "vit_small",

@@ -15,6 +15,7 @@ from __future__ import annotations
 from typing import Optional
 
 from .. import nn
+from ..obs.registry import default_registry
 
 
 def transformer_block(
@@ -212,24 +213,38 @@ def deepseek_v3_lm(
 
 
 def _rms_norm_lm(name, vocab_size, d_model, blocks, epsilon, dtype,
-                 embedding_std=0.02):
+                 embedding_std=0.02, tie_embeddings=False):
     """Token-in, logits-out LM of pre-RMSNorm residual blocks: an embedding,
-    for each (attention, ffn) of ``blocks`` two residuals ``RMSNorm ->
-    layer``, a final RMSNorm and an untied, bias-free head. The parameter
+    for each (mixer, ffn) of ``blocks`` two residuals ``RMSNorm -> layer``,
+    a final RMSNorm and a bias-free head: untied, a ``Dense`` with its own
+    kernel, or with ``tie_embeddings`` the embedding's own table
+    (``nn.TiedSequential``: one leaf, read at both ends). The parameter
     paths (which are the device scopes) read ``residual``, ``residual_1``,
-    ...: attention at even indices, the MLP or expert layer at odd ones."""
+    ...: the token mixer (attention or ``nn.ShortConv``) at even indices,
+    the MLP or expert layer at odd ones; the head is ``dense`` either way.
+    What was assembled is published by kind as the gauges
+    ``model.layers_{conv,attention,dense,experts}``."""
     layers = [nn.Embedding(vocab_size, d_model, dtype=dtype,
                            stddev=embedding_std)]
-    for attn, ffn in blocks:
+    for mixer, ffn in blocks:
         layers += [
-            nn.Residual(nn.Sequential([nn.RMSNorm(epsilon), attn],
+            nn.Residual(nn.Sequential([nn.RMSNorm(epsilon), mixer],
                                       name="main")),
             nn.Residual(nn.Sequential([nn.RMSNorm(epsilon), ffn],
                                       name="main")),
         ]
-    layers += [nn.RMSNorm(epsilon),
-               nn.Dense(vocab_size, use_bias=False, dtype=dtype)]
-    return nn.Sequential(layers, name=name)
+    convs = sum(isinstance(m, nn.ShortConv) for m, _ in blocks)
+    dense = sum(isinstance(f, nn.GatedMLP) for _, f in blocks)
+    for kind, n in (("conv", convs), ("attention", len(blocks) - convs),
+                    ("dense", dense), ("experts", len(blocks) - dense)):
+        default_registry().gauge(f"model.layers_{kind}", n)
+    layers.append(nn.RMSNorm(epsilon))
+    if tie_embeddings:
+        return nn.TiedSequential(
+            layers + [nn.TiedHead(vocab_size, dtype=dtype)], name=name)
+    return nn.Sequential(
+        layers + [nn.Dense(vocab_size, use_bias=False, dtype=dtype)],
+        name=name)
 
 
 def qwen3_moe_lm(
@@ -289,3 +304,77 @@ def qwen3_moe_lm(
     ) for _ in range(num_layers)]
     return _rms_norm_lm("qwen3_moe_lm", vocab_size, d_model, blocks, epsilon,
                         dtype, embedding_std)
+
+
+LAYER_TYPES = ("conv", "full_attention")
+
+
+def lfm2_moe_lm(
+    vocab_size: int,
+    *,
+    layer_types,
+    num_dense_layers: int,
+    d_model: int,
+    num_heads: int,
+    num_kv_heads: int,
+    head_dim: int,
+    d_ff: int,
+    num_experts: int,
+    top_k: int,
+    moe_hidden: int,
+    conv_kernel: int = 3,
+    experts_held: Optional[int] = None,
+    expert_offset: int = 0,
+    routed_scaling: float = 1.0,
+    bias_update_rate: float = 1e-3,
+    record_choice: bool = False,
+    rope_theta: float = 1000000.0,
+    epsilon: float = 1e-5,
+    tie_embeddings: bool = True,
+    flash="auto",
+    dtype=None,
+) -> nn.Sequential:
+    """LFM2-MoE's block as a token-in, logits-out LM (LFM2-8B-A1B is one;
+    ``model_type: lfm2_moe``): a stack whose layers are not all alike.
+    ``layer_types`` names each layer's token mixer, ``"conv"``
+    (``nn.ShortConv`` with ``conv_kernel`` taps) or ``"full_attention"``
+    (``nn.GroupedQueryAttention``: ``num_heads`` query heads over
+    ``num_kv_heads`` K/V heads of ``head_dim``, RoPE, per-head RMSNorm on q
+    and k, no indexer), and independently of it the first
+    ``num_dense_layers`` layers carry a gated SiLU MLP of ``d_ff`` and the
+    rest ``nn.DroplessMoE``: sigmoid scores over ``num_experts`` experts of
+    ``moe_hidden``, ``top_k`` a token chosen with the selection bias
+    (``use_expert_bias``; moved by ``bias_update_rate`` a step), gates
+    normalised over the chosen (their sum + 1e-6, the published constant)
+    times ``routed_scaling``, no shared expert.
+    ``experts_held`` / ``expert_offset`` are this chip's share of every
+    expert layer and ``record_choice`` keeps each expert layer's last
+    choices in its state, as in ``deepseek_v3_lm``, with which the block
+    assembly is shared. Pre-RMSNorm residuals, a final RMSNorm, and with
+    ``tie_embeddings`` (the published model's) the logits are over the
+    embedding's own table. Training and full forward passes only."""
+    layer_types = tuple(layer_types)
+    unknown = sorted(set(layer_types) - set(LAYER_TYPES))
+    if unknown:
+        raise ValueError(
+            f"layer_types may hold {LAYER_TYPES}, got {unknown}")
+    blocks = []
+    for i, kind in enumerate(layer_types):
+        if kind == "conv":
+            mixer = nn.ShortConv(conv_kernel, dtype=dtype)
+        else:
+            mixer = nn.GroupedQueryAttention(
+                num_heads, num_kv_heads, head_dim, rope_theta=rope_theta,
+                epsilon=epsilon, flash=flash, dtype=dtype)
+        if i < num_dense_layers:
+            ffn = nn.GatedMLP(d_ff, dtype=dtype)
+        else:
+            ffn = nn.DroplessMoE(
+                num_experts, moe_hidden, top_k=top_k,
+                experts_held=experts_held, expert_offset=expert_offset,
+                routed_scaling=routed_scaling,
+                bias_update_rate=bias_update_rate,
+                record_choice=record_choice, dtype=dtype)
+        blocks.append((mixer, ffn))
+    return _rms_norm_lm("lfm2_moe_lm", vocab_size, d_model, blocks, epsilon,
+                        dtype, tie_embeddings=tie_embeddings)

@@ -9,7 +9,7 @@ from .moe import DroplessMoE, MoE
 from .pipeline import PipelinedBlocks
 from .scan import ScannedBlocks
 from .remat import Remat
-from .core import Lambda, Layer, Residual, Sequential
+from .core import Lambda, Layer, Residual, Sequential, TiedSequential
 from .layers import (
     Activation,
     AvgPool2D,
@@ -24,12 +24,15 @@ from .layers import (
     LayerNorm,
     MaxPool2D,
     RMSNorm,
+    ShortConv,
     SpaceToDepth,
+    TiedHead,
 )
 
 __all__ = [
     "Layer",
     "Sequential",
+    "TiedSequential",
     "Residual",
     "Lambda",
     "Conv2D",
@@ -53,6 +56,8 @@ __all__ = [
     "DroplessMoE",
     "RMSNorm",
     "GatedMLP",
+    "ShortConv",
+    "TiedHead",
     "PipelinedBlocks",
     "ScannedBlocks",
     "PositionalEmbedding",

@@ -447,6 +447,36 @@ class Sequential(Layer):
         return f"Sequential([{inner}])"
 
 
+class TiedSequential(Sequential):
+    """A ``Sequential`` whose last layer owns no leaf and is handed its
+    first layer's parameters instead: a language model whose head is its
+    embedding's table (``[Embedding, ..., TiedHead]``). A ``Sequential``
+    hands each layer its own subtree alone, so tying is the container's
+    work, not a layer's. The table stays ONE leaf, under the embedding's
+    key: one entry in the optimizer and in a checkpoint, and its gradient
+    is the sum of the two uses', which is autodiff's of a leaf read twice.
+    The head still runs under its own name as a device scope.
+
+    For training and full forward passes: the decode paths and
+    ``compile(head_chunks=...)`` apply a model's layers themselves, each on
+    its own subtree, and the head says so when it finds no table there."""
+
+    def init(self, key, input_shape):
+        params, state, shape = super().init(key, input_shape)
+        head = self.layers[-1]
+        if head.name in params:
+            raise ValueError(
+                f"the tied layer {head.name!r} owns parameters "
+                f"({sorted(params[head.name])}); it has to read "
+                f"{self.layers[0].name!r}'s alone")
+        return params, state, shape
+
+    def apply(self, params, state, x, *, train=False, rng=None):
+        tied = {**params,
+                self.layers[-1].name: params[self.layers[0].name]}
+        return apply_layers(self.layers, tied, state, x, train=train, rng=rng)
+
+
 class Residual(Layer):
     """Skip connection: ``y = activation(main(x) + shortcut(x))``.
 

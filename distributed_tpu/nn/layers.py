@@ -557,6 +557,101 @@ class GatedMLP(Layer):
         return linear("dense_2", hidden), {}
 
 
+def _shifted(a, back: int):
+    """``a`` (..., T, D) moved ``back`` positions along T, zeros moved in:
+    ``out[t] = a[t - back]``."""
+    if back == 0:
+        return a
+    pad = [(0, 0)] * (a.ndim - 2) + [(back, 0), (0, 0)]
+    return lax.slice_in_dim(jnp.pad(a, pad), 0, a.shape[-2], axis=-2)
+
+
+@jax.checkpoint
+def gated_taps(bcz, taps):
+    """The gated short convolution between its two products: with (b, c, z)
+    = split3(``bcz``) (..., T, 3D) and ``taps`` (K, D) float32,
+
+        g = b * z;  h[t] = sum_j taps[j] * g[t - (K - 1) + j];  out = c * h
+
+    written as the equation reads (K shifted copies, K multiply-adds), the
+    products and sums in float32, returned in ``bcz``'s dtype. A
+    ``jax.checkpoint``: the backward pass keeps ``bcz`` alone, as it arrived,
+    and computes g and h again. Without it autodiff keeps the float32 copies
+    of what it multiplied by, up to 20 bytes a channel and token where
+    ``bcz`` in bfloat16 is 6 (v5e compile, PR 34: the in-projection's output
+    was held as float32, 201 MB a layer at T = 8192, and the step's
+    temporaries read 7.50 GB where they now read 6.53)."""
+    b, c, z = jnp.split(bcz, 3, axis=-1)
+    k = taps.shape[0]
+    f32 = lambda a: a.astype(jnp.float32)
+    # g's shifted copies as products of b's and z's shifted copies, each
+    # widened after its shift: every term then reads the layer's input at an
+    # offset and XLA makes one pass of it in the input's dtype (a g computed
+    # once and read at K offsets, or b and z widened first, it writes to
+    # memory as float32 before the taps: v5e compile, PR 34).
+    h = sum(taps[j] * f32(_shifted(b, k - 1 - j)) * f32(_shifted(z, k - 1 - j))
+            for j in range(k))
+    return (f32(c) * h).astype(bcz.dtype)
+
+
+class ShortConv(Layer):
+    """LFM2's gated short convolution over (B, T, D) inputs (Liquid AI's
+    ``Lfm2ShortConv``; ``model_type: lfm2`` / ``lfm2_moe``), a token mixer
+    that looks ``kernel_size - 1`` positions back and no further:
+
+        (b, c, z) = split3(x W_in)                   W_in (D, 3D), this order
+        g = b * z
+        h[t] = sum_j taps[j] * g[t - (K - 1) + j]    taps (K, D), g[< 0] = 0
+        out = (c * h) W_out                          W_out (D, D)
+
+    a depthwise causal convolution (one tap a channel and offset) between
+    two elementwise gates and two matrix products; no bias anywhere. The
+    part between the products is ``gated_taps``: memory-bound elementwise
+    work that XLA fuses into a few passes over ``x W_in`` (1.6 ms a layer,
+    both passes, of the 7.9 the layer takes at T = 8192, D = 2048 on the
+    v5e: root PERF.md, PR 34). Its kernels
+    ``w_in``, ``taps`` and ``w_out`` sit under the layer's own key,
+    ``short_conv``, which is its device scope; inside it ``mix`` holds the
+    gates and the taps without the two products. The taps start uniform in
+    +-1/sqrt(K), PyTorch's default for the ``Conv1d`` they are published as.
+
+    For training and full forward passes: no cached decode (a rolling state
+    of K - 1 rows a layer does not exist in ``serving/`` yet, ROADMAP.md)."""
+
+    decode_safe = False  # reads the K - 1 positions before each one
+
+    def __init__(self, kernel_size: int = 3, dtype=None,
+                 name: Optional[str] = None):
+        super().__init__(name)
+        self.kernel_size = int(kernel_size)
+        if self.kernel_size < 1:
+            raise ValueError(f"kernel_size must be >= 1, got {kernel_size}")
+        self.dtype = dtype
+
+    def init(self, key, input_shape: Shape):
+        d, k = input_shape[-1], self.kernel_size
+        k_in, k_taps, k_out = jax.random.split(key, 3)
+        glorot = initializers.get("glorot_uniform")
+        bound = k ** -0.5
+        params = {
+            "w_in": glorot(k_in, (d, 3 * d), jnp.float32),
+            "taps": jax.random.uniform(k_taps, (k, d), jnp.float32,
+                                       -bound, bound),
+            "w_out": glorot(k_out, (d, d), jnp.float32),
+        }
+        return params, {}, tuple(input_shape)
+
+    def apply(self, params, state, x, *, train=False, rng=None):
+        dt = resolve_dtype(self.dtype)
+        if dt is not None:
+            x = x.astype(dt)
+        bcz = jnp.dot(x, maybe_dequantize(params["w_in"]).astype(x.dtype))
+        with child_scope("mix"):
+            y = gated_taps(bcz, params["taps"].astype(jnp.float32))
+        return jnp.dot(y, maybe_dequantize(params["w_out"]).astype(x.dtype)
+                       ), {}
+
+
 class Embedding(Layer):
     """A table of ``vocab_size`` rows of ``dim``, drawn normal(``stddev``)."""
 
@@ -586,3 +681,37 @@ class Embedding(Layer):
         if dt is not None:
             table = table.astype(dt)
         return jnp.take(table, x, axis=0), {}
+
+
+class TiedHead(Layer):
+    """Bias-free logits ``x E^T`` over the ``units`` rows of an embedding's
+    table ``E`` (units, D) that is another layer's leaf: this layer owns
+    none, and ``nn.TiedSequential`` hands it the embedding's parameters. Its
+    key, and so its device scope, is ``dense``: where an untied head's
+    product sits (``benchmarks/scopes.py`` files a top-level ``dense*``
+    under the head)."""
+
+    def __init__(self, units: int, dtype=None, name: Optional[str] = None):
+        super().__init__(name)
+        self.units = int(units)
+        self.dtype = dtype
+
+    def default_name(self) -> str:
+        return "dense"
+
+    def init(self, key, input_shape: Shape):
+        return {}, {}, tuple(input_shape[:-1]) + (self.units,)
+
+    def apply(self, params, state, x, *, train=False, rng=None):
+        if "table" not in params:
+            raise ValueError(
+                f"{self.name!r} owns no table: it is applied by "
+                "nn.TiedSequential, which hands it the embedding's (the "
+                "decode paths and head_chunks apply it on its own subtree)")
+        table = maybe_dequantize(params["table"])
+        dt = resolve_dtype(self.dtype)
+        if dt is not None:
+            x = x.astype(dt)
+        return lax.dot_general(
+            x, table.astype(x.dtype),
+            (((x.ndim - 1,), (1,)), ((), ()))), {}

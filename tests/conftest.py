@@ -138,19 +138,32 @@ def pytest_sessionfinish(session, exitstatus):
 # ``conftest`` would shadow this one for ``from conftest import ...``.)
 # ``test_bench_kanana.py`` pins, in two tests, whole ``workloads`` lists that
 # end with the kanana cell; a later family's cell (``test_bench_keye.py``
-# asserts the same of its own) is kept from those two in the same way.
+# asserts the same of its own, and of ``per_layer``'s last five entries) is
+# kept from those two in the same way, and ``test_bench_keye.py``'s two from
+# the family after it. ``test_bench_lfm2.py`` asserts that its cell is among
+# a metric's ``workloads`` and never what a whole list is, so the family
+# after it adds nothing here (ROADMAP D17).
 FAMILIES_SEEN = {
     "test_config_files_state_their_departures": {"gpt2"},
     "test_run_py_lists_the_scope_metrics_for_the_train_cells": {"gpt2"},
     "test_the_cell_reports_what_the_issue_lists": {"gpt2", "deepseek_v3"},
     "test_run_py_lists_the_scope_metrics_for_the_new_cell": {
         "gpt2", "deepseek_v3"},
+    "test_the_cell_reports_what_issue_32_lists": {
+        "gpt2", "deepseek_v3", "keye_vl2"},
+    "test_run_py_lists_the_scope_metrics_for_the_keye_cell": {
+        "gpt2", "deepseek_v3", "keye_vl2"},
 }
 
 
 def entries_of(manifest: dict, families) -> dict:
     """``manifest`` less every configuration whose family is not among
-    ``families``, its cells, and their names in the metrics' ``workloads``."""
+    ``families``, its cells, their names in the metrics' ``workloads``, and
+    the metrics that listed no other cell. The last is for
+    ``test_bench_keye.py::test_the_cell_reports_what_issue_32_lists``, which
+    holds ``manifest["per_layer"][-5:]`` to its own five metrics: a later
+    family's metrics, which list that family's cells alone, have to go with
+    its cells (ROADMAP D17 retires such whole-list pins)."""
     import copy
 
     from benchmarks import harness
@@ -161,10 +174,13 @@ def entries_of(manifest: dict, families) -> dict:
     kept = {c["name"] for c in out["configs"]}
     out["workloads"] = [w for w in out["workloads"] if w["config"] in kept]
     cells = {w["name"] for w in out["workloads"]}
-    for metric in out["end_to_end"] + out["per_layer"]:
-        if "workloads" in metric:
-            metric["workloads"] = [w for w in metric["workloads"]
-                                   if w in cells]
+    for section in ("end_to_end", "per_layer"):
+        for metric in out[section]:
+            if "workloads" in metric:
+                metric["workloads"] = [w for w in metric["workloads"]
+                                       if w in cells]
+        out[section] = [m for m in out[section]
+                        if m.get("workloads", True)]
     return out
 
 

@@ -277,3 +277,83 @@ def test_the_index_scores_kernel_runs_under_the_indexers_scope():
         assert re.match(r"jit\(step\)/jvp\(residual\)/main/"
                         r"multi_head_attention_gqa/indexer/", name), name
         assert "select/" not in name.split("dtpu_index_scores_bwd")[0], name
+
+
+# ------------------- short convolutions, attention and a tied head together --
+@pytest.fixture(scope="module")
+def hybrid():
+    m = dtpu.Model(dtpu.models.lfm2_moe_lm(
+        64, layer_types=("conv", "full_attention", "conv"),
+        num_dense_layers=1, d_model=16, num_heads=4, num_kv_heads=2,
+        head_dim=4, d_ff=24, num_experts=8, experts_held=4, expert_offset=2,
+        top_k=2, moe_hidden=8, record_choice=True))
+    m.compile(optimizer=dtpu.optim.Adam(1e-3),
+              loss="sparse_categorical_crossentropy", metrics=())
+    m.build((16,), seed=0)
+    x = np.zeros((4, 16), np.int32)
+    text = m.lower_train_step(x, x).compile().as_text()
+    return m, sorted(set(re.findall(r'op_name="(jit\(step\)[^"]*)"', text)))
+
+
+def test_hybrid_layers_scope_paths_are_their_parameter_paths(hybrid):
+    model, names = hybrid
+    paths = layer_paths(model.params)
+    for want in (("residual", "main", "short_conv"),
+                 ("residual_1", "main", "gated_mlp", "dense_2"),
+                 ("residual_2", "main", "multi_head_attention_gqa", "q_norm"),
+                 ("residual_3", "main", "moe"),
+                 ("residual_4", "main", "short_conv"), ("embedding",)):
+        assert want in paths
+    assert ("dense",) not in paths  # the tied head owns no leaf
+    for path in paths:
+        top, rest = path[0], "/".join(path[1:])
+        tail = f"/{rest}/" if rest else "/"
+        assert has(names, rf"jit\(step\)/jvp\({top}\){tail}"), path
+        assert has(names, rf"jit\(step\)/transpose\(jvp\({top}\)\)/{JAX}"
+                          rf"{tail[1:]}"), path
+
+
+@pytest.mark.parametrize("scope,primitive", [
+    ("short_conv", "dot_general"), ("short_conv/mix", "mul"),
+    ("short_conv/mix", "pad"),
+])
+def test_the_short_conv_names_its_products_and_its_mix(hybrid, scope,
+                                                       primitive):
+    """``short_conv`` holds the layer's two products and, inside it, ``mix``
+    the gates and the taps: ``benchmarks/scopes_conv.py`` reads
+    ``shortconv_device_ms`` and ``shortconv_mix_device_ms`` from them, both
+    passes (the backward of ``mix`` is a checkpoint's: JAX enters the name
+    stack again under it)."""
+    _, names = hybrid
+    for block in ("residual", "residual_4"):
+        assert has(names, rf"jit\(step\)/jvp\({block}\)/main/{scope}/"
+                          rf"{JAX}\w*{primitive}")
+        assert has(names, rf"jit\(step\)/transpose\(jvp\({block}\)\)/"
+                          rf"{JAX}main/{scope}/")
+    # no product of the layer under ``mix``
+    assert not has(names, r".*/short_conv/mix/(?:[\w()]+/)*dot_general")
+
+
+def test_the_tied_heads_product_runs_under_the_heads_scope(hybrid):
+    """The logits' product is ``dense`` at the top level, forward and
+    backward, where an untied head's is (``benchmarks/scopes.py`` files a
+    top-level ``dense*`` under ``head_loss``); the table's other use, the
+    gather, stays under ``embedding``."""
+    _, names = hybrid
+    assert has(names, r"jit\(step\)/jvp\(dense\)/dot_general")
+    assert has(names, r"jit\(step\)/transpose\(jvp\(dense\)\)/"
+                      r"dot_general")
+    assert has(names, rf"jit\(step\)/jvp\(embedding\)/{JAX}gather")
+    assert not has(names, r"jit\(step\)/jvp\(embedding\)/dot_general")
+
+
+def test_every_operation_of_the_hybrid_step_is_under_a_scope(hybrid):
+    model, names = hybrid
+    tops = {p[0] for p in layer_paths(model.params)} | {
+        "cast", "loss", "metrics", "optimizer", "dense"}
+    for name in names:
+        if name == "jit(step)":
+            continue
+        m = re.match(r"jit\(step\)/(?:transpose\()?(?:jvp\()?(\w*)", name)
+        assert m and m.group(1) in tops, name
+
