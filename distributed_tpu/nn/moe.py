@@ -379,21 +379,27 @@ class DroplessMoE(Layer):
     experts are sorted by expert (a counting sort: a pair's rank in its
     expert's group is a running count) into one buffer of static shape,
     sized for every pair landing here, each group starting on a tile
-    boundary (``ops.grouped_matmul.group_layout``). Three grouped matmuls run
-    over the groups' tiles in use, and the rows go back weighted by their
-    gates. Dispatch, combine and both their transposes follow the tiles in
-    use too (``ops.moe_rows``): into the buffer, each valid row of a tile in
-    use fetches its token's row (dispatch; combine's backward, which also
-    takes each row's dot with dy for its gate's gradient); out of it, each
-    valid row is added in float32 to its token (combine, times the pair's
-    gate; dispatch's backward). A pair whose expert is not held has no row,
-    costs nothing and adds exactly zero; the tiles not in use are neither
-    written nor read, so what the layer moves follows the share it holds,
-    not the buffer's static worst case.
+    boundary (``ops.grouped_matmul.group_layout``). The experts' gated MLP
+    runs over the groups' tiles in use (``ops.grouped_matmul.
+    grouped_gated_mlp``: three grouped matmuls forward and six backward,
+    the activation, its backward and the sum of the buffer's two gradients
+    in their epilogues), and the rows go back weighted by their gates.
+    Dispatch, combine and both their transposes follow the tiles in use too
+    (``ops.moe_rows``): into the buffer, each valid row of a tile in use
+    fetches its token's row (dispatch; combine's backward, which also takes
+    each row's dot with dy for its gate's gradient); out of it, each valid
+    row is added in float32 to its token (combine, times the pair's gate;
+    dispatch's backward). A pair whose expert is not held has no row, costs
+    nothing and adds exactly zero; the tiles not in use are neither written
+    nor read nor stepped over by anything between dispatch and combine, in
+    either pass, so what the layer moves and computes follows the share it
+    holds (``moe.buffer_used_pct`` of the buffer), not the buffer's static
+    worst case. What the layer still does for every expert held, whatever
+    its rows: the weights' casts to the compute dtype.
 
     Device scopes under the layer's own (``moe``): ``route`` (router, top-k,
-    sort, the row walks both ways), ``experts`` (the grouped matmuls and
-    the activation between them) and ``shared``. Counters, cumulative over
+    sort, the row walks both ways), ``experts`` (the weights' casts and the
+    nine grouped matmuls) and ``shared``. Counters, cumulative over
     train steps, in the layer's state and so read with no device sync
     inside a step loop (``counters``): ``steps``, ``pairs`` (tokens x
     ``top_k``), ``held_rows`` (pairs whose expert is held here),
@@ -520,11 +526,9 @@ class DroplessMoE(Layer):
             buf = _dispatch(flat, walk)
         with jax.named_scope("experts"):
             weight = lambda name: maybe_dequantize(params[name]).astype(dt)
-            hidden = jax.nn.silu(gmm.grouped_matmul(
-                buf, weight("w_gate"), tile_group, tiles_used)
-            ) * gmm.grouped_matmul(buf, weight("w_up"), tile_group, tiles_used)
-            out_buf = gmm.grouped_matmul(
-                hidden, weight("w_down"), tile_group, tiles_used)
+            out_buf = gmm.grouped_gated_mlp(
+                buf, weight("w_gate"), weight("w_up"), weight("w_down"),
+                tile_group, tiles_used)
         with jax.named_scope("route"):
             y = _combine(out_buf, jnp.where(held, gates.T, 0.0), walk)
         if self.shared is not None:

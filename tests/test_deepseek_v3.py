@@ -480,6 +480,45 @@ def test_no_row_movement_of_the_step_gathers_the_whole_buffer(tiny):
     assert not whole & set(found)
 
 
+def test_no_pass_under_experts_goes_over_the_whole_buffer(tiny):
+    """A count on XLA:CPU, never a speed: under ``moe*/experts`` the lowered
+    train step has no elementwise instruction (the activation, its backward,
+    the sum of d buf's two terms: all epilogues of the grouped matmuls
+    since PR 35) whose result has the static buffer's row count. What the
+    interpreted kernels do to a whole buffer is to slice it and to update
+    slices of it."""
+    import re
+
+    from distributed_tpu.ops import grouped_matmul as gmm
+
+    cfg, model, x, y = tiny
+    pairs = x.size * cfg["num_experts_per_tok"]
+    rows = gmm.buffer_rows(pairs, cfg["n_routed_experts"])
+    hidden = cfg["moe_intermediate_size"]
+    instruction = re.compile(
+        r"= \w+\[(\d+),[\d,]*\][^ ]* ([\w-]+)\(.*op_name=\"([^\"]*)\"")
+    moves = {"fusion", "dynamic-update-slice", "dynamic-slice", "slice",
+             "broadcast", "get-tuple-element", "copy", "bitcast"}
+
+    def passes(text, under):
+        return {m.group(2) for m in map(instruction.search, text.splitlines())
+                if m and int(m.group(1)) == rows and under in m.group(3)
+                } - moves
+
+    # the expression finds the replaced passes where there are some
+    def parents(g, u, d1, d2):
+        with jax.named_scope("moe"), jax.named_scope("experts"):
+            return jax.nn.silu(g) * u, d1 + d2
+
+    wide = jnp.ones((rows, hidden))
+    assert {"multiply", "add"} <= passes(
+        jax.jit(parents).lower(wide, wide, wide, wide).compile().as_text(),
+        "moe/experts/")
+    text = model.lower_train_step(x, y).compile().as_text()
+    assert "/moe/experts/" in text
+    assert not passes(text, "/moe/experts/")
+
+
 def test_the_operation_count_knows_the_models_parameters(tiny):
     """``flops_deepseek_v3`` counts the matmul weights the program holds:
     all parameters but the norms' scales and the embedding, the routed

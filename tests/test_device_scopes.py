@@ -134,11 +134,12 @@ def deepseek():
     m.build((16,), seed=0)
     x = np.zeros((4, 16), np.int32)
     text = m.lower_train_step(x, x).compile().as_text()
-    return m, sorted(set(re.findall(r'op_name="(jit\(step\)[^"]*)"', text)))
+    return (m, sorted(set(re.findall(r'op_name="(jit\(step\)[^"]*)"', text))),
+            text)
 
 
 def test_deepseek_layers_scope_paths_are_their_parameter_paths(deepseek):
-    model, names = deepseek
+    model, names, _ = deepseek
     paths = layer_paths(model.params)
     for want in (("residual", "main", "multi_head_attention_latent"),
                  ("residual", "main", "multi_head_attention_latent",
@@ -167,7 +168,7 @@ def test_the_expert_layer_names_its_routing_its_experts_and_its_shared(
     shared gated MLP: ``benchmarks/scopes_moe.py`` splits the expert layers'
     device time by them. The two row walks (``ops/moe_rows.py``) run under
     ``route`` in both passes: each is the other's transpose."""
-    _, names = deepseek
+    _, names, _ = deepseek
     assert has(names, rf"jit\(step\)/jvp\(residual_3\)/main/moe/{inner}/"
                       rf"{JAX}\w*{primitive}")
     if primitive not in ("top_k", "scatter"):  # indices have no gradient
@@ -178,8 +179,27 @@ def test_the_expert_layer_names_its_routing_its_experts_and_its_shared(
                           rf"{JAX}main/moe/route/{JAX}{primitive}")
 
 
+@pytest.mark.parametrize("wrapper,kernel", [
+    (r"jvp\(residual_3\)", "dtpu_gmm"),
+    (r"transpose\(jvp\(residual_3\)\)", "dtpu_gmm_nt"),
+    (r"transpose\(jvp\(residual_3\)\)", "dtpu_gmm_tn"),
+])
+def test_an_expert_layer_calls_each_grouped_matmul_kernel_three_times(
+        deepseek, wrapper, kernel):
+    """Nine ``dtpu_gmm*`` calls a layer-step under ``moe*/experts``, each
+    one product (the activation, its backward and the sum of d buf's terms
+    are epilogues): three forward, three a kind backward.
+    ``benchmarks/layer_metrics/moe_experts_roofline.py`` prices a traced
+    call at a ninth of a layer's work. An interpreted call is one loop over
+    its grid."""
+    _, _, text = deepseek
+    call = re.compile(rf' while\(.*op_name="jit\(step\)/{wrapper}/{JAX}main/'
+                      rf'moe/experts/{JAX}{kernel}/while"')
+    assert sum(bool(call.search(line)) for line in text.splitlines()) == 3
+
+
 def test_every_operation_of_the_deepseek_step_is_under_a_scope(deepseek):
-    model, names = deepseek
+    model, names, _ = deepseek
     tops = {p[0] for p in layer_paths(model.params)} | {
         "cast", "loss", "metrics", "optimizer"}
     for name in names:

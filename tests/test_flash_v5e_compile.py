@@ -125,26 +125,32 @@ def test_grouped_query_flash_grad_compiles_for_v5e(kv_heads, selecting,
         assert name + suffix in text
 
 
-@pytest.mark.parametrize("k,n", [(2048, 768), (768, 2048)])
-def test_grouped_matmul_grad_compiles_for_v5e(k, n, one_chip, mosaic):
-    """The three kernels at the cell's shapes: 16 held experts, a buffer for
-    all 24,576 pairs of a step, gate/up (2048 -> 768) and down (768 ->
-    2048): the weight block, the f32 d rhs block and the row tiles fit the
-    16 MB a kernel may use."""
-    rows = gm.buffer_rows(4096 * 6, 16)
-    spec = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
-                                                     sharding=one_chip)
+@pytest.mark.parametrize("pairs,groups,hidden", [
+    (4096 * 6, 16, 768), (8192 * 8, 16, 768), (8192 * 4, 8, 1792)])
+def test_grouped_gated_mlp_grad_compiles_for_v5e(pairs, groups, hidden,
+                                                 one_chip, mosaic):
+    """The held experts' nine calls at the three expert cells' shapes
+    (kanana, Keye, LFM2: a buffer for every pair of a step, 2048 wide, 16
+    experts of 768 or 8 of 1792): grids that end at ``tiles_used``, read at
+    run time; the weight block, the f32 d rhs block, the row tiles and the
+    epilogues' g, u, dg and du tiles fit the 16 MB a kernel may use."""
+    rows, d = gm.buffer_rows(pairs, groups), 2048
+    spec = lambda shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(
+        shape, dtype, sharding=one_chip)
 
-    def loss(lhs, rhs, tile_group, used):
-        out = gm.grouped_matmul(lhs, rhs, tile_group, used)
-        return jnp.sum(out.astype(jnp.float32))
+    def both(buf, w_gate, w_up, w_down, tile_group, used, d_out):
+        out, vjp = jax.vjp(lambda *a: gm.grouped_gated_mlp(
+            *a, tile_group, used), buf, w_gate, w_up, w_down)
+        return out, vjp(d_out)
 
-    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
-        spec((rows, k), jnp.bfloat16), spec((16, k, n), jnp.bfloat16),
-        spec((rows // gm.TILE_M,), jnp.int32), spec((1,), jnp.int32)
-    ).compile().as_text()
-    for name in ("dtpu_gmm_nt", "dtpu_gmm_tn"):
-        assert name in text
+    text = jax.jit(both).lower(
+        spec((rows, d)), spec((groups, d, hidden)), spec((groups, d, hidden)),
+        spec((groups, hidden, d)), spec((rows // gm.TILE_M,), jnp.int32),
+        spec((1,), jnp.int32), spec((rows, d))).compile().as_text()
+    calls = re.findall(r'custom_call_target="tpu_custom_call".*?'
+                       r'op_name="[^"]*/(dtpu_gmm\w*)/pallas_call"', text)
+    assert sorted(calls) == (["dtpu_gmm"] * 3 + ["dtpu_gmm_nt"] * 3
+                             + ["dtpu_gmm_tn"] * 3)
 
 
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
