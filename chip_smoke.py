@@ -64,27 +64,22 @@ def emit(leg: str, dev: dict, **fields) -> None:
 
 
 class CacheCounter:
-    """Counts persistent-compile-cache lookups and hits (jax.monitoring)."""
-
-    def __init__(self):
-        import jax
-
-        self.requests = 0
-        self.hits = 0
-        jax.monitoring.register_event_listener(self._on_event)
-
-    def _on_event(self, event: str, **_):
-        if event == "/jax/compilation_cache/compile_requests_use_cache":
-            self.requests += 1
-        elif event == "/jax/compilation_cache/cache_hits":
-            self.hits += 1
+    """Persistent-compile-cache lookups and hits since a snapshot, from the
+    compile ledger's counters (``distributed_tpu.obs.compile_ledger``: the
+    package registers the one listener when it is imported; ``DTPU_OBS=0``
+    turns it off, and this reads zeros)."""
 
     def snapshot(self):
-        return self.requests, self.hits
+        from distributed_tpu.obs import default_registry
+
+        reg = default_registry()
+        return (int(reg.counter_value("compile/cache_lookups")),
+                int(reg.counter_value("compile/cache_hits")))
 
     def since(self, snap) -> dict:
-        return {"cache_requests": self.requests - snap[0],
-                "cache_hits": self.hits - snap[1]}
+        requests, hits = self.snapshot()
+        return {"cache_requests": requests - snap[0],
+                "cache_hits": hits - snap[1]}
 
 
 def _timed(fn):

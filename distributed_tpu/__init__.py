@@ -25,8 +25,17 @@ Quickstart (the reference's local->distributed 6-line-diff contract):
     model.fit(x, y, batch_size=64 * strategy.num_replicas_in_sync, epochs=3)
 """
 
-from . import cluster, data, models, nn, ops, optim, parallel, precision, utils
+import time as _time
+
+_import_t0 = _time.perf_counter()
+
 from . import obs  # jax-free at import; spans resolve jax lazily
+
+# The ``import`` phase of the span timeline: this file's first line to its
+# last (docs/OBSERVABILITY.md "Span tracer").
+_import_phase = obs.spans.begin("import", start=_import_t0)
+
+from . import cluster, data, models, nn, ops, optim, parallel, precision, utils
 from .precision import Policy
 from .checkpoint import Checkpointer, ShardedCheckpointer, export_hdf5, import_hdf5
 from .training import callbacks
@@ -112,3 +121,10 @@ __all__ = [
     "rl",  # lazy: see __getattr__
     "__version__",
 ]
+
+# jax is imported by now: JAX's compile events feed the compile ledger, and
+# the timeline and the ledger are written out at exit where a run has a
+# dump location (obs.flight's rule; an unsupervised run has none).
+obs.compile_ledger.install()
+obs.flight.dump_timeline_at_exit()
+_import_phase.end()

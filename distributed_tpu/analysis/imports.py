@@ -46,6 +46,7 @@ JAX_FREE_MODULES: Tuple[str, ...] = (
     "distributed_tpu.obs",
     "distributed_tpu.obs.aggregate",
     "distributed_tpu.obs.cli",
+    "distributed_tpu.obs.compile_ledger",
     "distributed_tpu.obs.export",
     "distributed_tpu.obs.flight",
     "distributed_tpu.obs.registry",

@@ -10,7 +10,12 @@ request rows, supervisor recovery rows, the resilience event log):
 - :mod:`~distributed_tpu.obs.spans` — nested host-side spans
   (``obs.span("prefill")``) that accrue into the registry, forward to
   ``jax.profiler.TraceAnnotation`` (same names on XProf), and carry the
-  ``StepTimer`` stall-category attribution through one code path.
+  ``StepTimer`` stall-category attribution through one code path; each
+  closed span is also one record (start, end, thread, parent) of the
+  registry's ``timeline``, on one clock from the process's start.
+- :mod:`~distributed_tpu.obs.compile_ledger` — one record per program and
+  stage (trace, lower, backend; cache hit or miss) from JAX's own
+  monitoring events, put down to the span that asked for the compile.
 - :mod:`~distributed_tpu.obs.flight` — a bounded ring of the last N
   per-step records, dumped (fsync'd JSONL) on preemption, fault-injected
   kills, and unhandled exceptions: the seconds before death.
@@ -31,7 +36,7 @@ supervisor); spans resolve jax lazily.
 
 from __future__ import annotations
 
-from . import aggregate, export, flight, registry, spans
+from . import aggregate, compile_ledger, export, flight, registry, spans
 from .flight import FlightRecorder, default_recorder, dump as dump_flight
 from .registry import (
     MetricsRegistry,
@@ -46,6 +51,7 @@ __all__ = [
     "MetricsRegistry",
     "Span",
     "aggregate",
+    "compile_ledger",
     "current_span",
     "default_recorder",
     "default_registry",

@@ -4,6 +4,7 @@
     dtpu-events run.events.jsonl --flight /tmp/flight-rank1-pid33.jsonl
     dtpu-events run.events.jsonl --json
     dtpu-events run.events.jsonl --follow   # live tail for a running gang
+    dtpu-events --timeline timeline-rank0-pid33.jsonl   # a set-up, by span
 
 Reads a supervised run's JSONL event log (``utils.events``) and renders a
 human postmortem: the attempt timeline, injected faults, per-recovery
@@ -19,6 +20,17 @@ it lands, surviving the writer's rotate/truncate the same way
 and skipping a torn tail line until its newline arrives — watch a
 serving gang (``serve_service``) or a supervised training run without
 re-running the postmortem.
+
+``--timeline`` reads what ``obs.flight.dump_timeline`` wrote at a
+process's exit instead (the span timeline and the compile ledger) and
+renders the process from its start: each top-level span with its start,
+duration and self time (the duration less what its child spans and the
+compile ledger's records cover), the gaps between top-level spans as
+``caller``, under each span its children and the programs it compiled with
+their stage seconds and ``hit`` / ``miss``, and the set-up window's sums
+over the programs that a span of the program asked for (the caller's own
+are under its ``caller`` rows and in no sum; docs/OBSERVABILITY.md
+"Compile ledger").
 
 jax-free: runs on any controller box against a copied log file.
 """
@@ -256,6 +268,205 @@ def render(summary: dict, *, tail: int = 10) -> str:
     return "\n".join(lines)
 
 
+# ------------------------------------------------------------- timeline --
+PROGRAM_MIN_S = 0.1  # programs under this are summed, not named
+GAP_MIN_S = 0.001  # gaps between top-level spans under this are not shown
+MISSED_SHOWN = 5  # cache misses named in the window's line, longest first
+STAGES = ("trace", "lower", "backend")
+
+
+def _union_s(intervals, lo=None, hi=None) -> float:
+    """Seconds the union of ``(start, end)`` nanosecond intervals covers,
+    each cut to ``[lo, hi]`` first."""
+    total, reach = 0, None
+    for s, e in sorted(intervals):
+        if lo is not None:
+            s, e = max(s, lo), min(e, hi)
+        if e <= s:
+            continue
+        if reach is None or s > reach:
+            total += e - s
+            reach = e
+        elif e > reach:
+            total += e - reach
+            reach = e
+    return total / 1e9
+
+
+def _programs(records) -> dict:
+    """Compile records of one span, by program: seconds a stage, cache
+    verdicts, and the union a stage over all of them."""
+    by_name: dict = {}
+    for r in records:
+        row = by_name.setdefault(
+            r["fun_name"], {"name": r["fun_name"], "seconds": 0.0,
+                            **{st: 0.0 for st in STAGES}, "cache": []})
+        seconds = (r["end"] - r["start"]) / 1e9
+        row[r["stage"]] += seconds
+        row["seconds"] += seconds
+        if r.get("cache"):
+            row["cache"].append(r["cache"])
+    rows = sorted(by_name.values(), key=lambda row: -row["seconds"])
+    verdicts = [c for row in rows for c in row["cache"]]
+    return {
+        "named": [row for row in rows if row["seconds"] >= PROGRAM_MIN_S],
+        "others": sum(row["seconds"] < PROGRAM_MIN_S for row in rows),
+        "stage_s": {st: _union_s((r["start"], r["end"]) for r in records
+                                 if r["stage"] == st) for st in STAGES},
+        "hit": verdicts.count("hit"), "miss": verdicts.count("miss"),
+        "uncached": verdicts.count("uncached"),
+    }
+
+
+def summarize_timeline(records: List[dict]) -> dict:
+    """A timeline dump as data: the main thread's top-level spans in order
+    with the ``caller`` gaps between them, each span's children, programs
+    and self time, and the set-up window's sums. Times are seconds from
+    the process's start."""
+    header = next((r for r in records
+                   if r.get("kind") == "timeline_header"), {})
+    spans = [r for r in records if r.get("kind") == "span"]
+    compiles = [r for r in records if r.get("kind") == "compile"]
+    zero = header.get("process_start") or min(
+        [r["start"] for r in spans + compiles], default=0)
+    main = header.get("main_thread")
+    if main is None and spans:
+        main = spans[0]["thread"]
+
+    def node(span):
+        lo, hi, thread = span["start"], span["end"], span["thread"]
+        inside = lambda r: (r["thread"] == thread and r["start"] >= lo
+                            and r["end"] <= hi)
+        children = sorted(
+            (c for c in spans if c is not span and inside(c)
+             and c["parent"] == span["path"]), key=lambda c: c["start"])
+        mine = [r for r in compiles
+                if inside(r) and r.get("span") == span["path"]]
+        covered = ([(c["start"], c["end"]) for c in children]
+                   + [(r["start"], r["end"]) for r in mine])
+        return {
+            "path": span["path"], "start": (lo - zero) / 1e9,
+            "seconds": (hi - lo) / 1e9,
+            "self_seconds": (hi - lo) / 1e9 - _union_s(covered, lo, hi),
+            "children": [node(c) for c in children],
+            "programs": _programs(mine) if mine else None,
+        }
+
+    top = sorted((sp for sp in spans
+                  if sp["thread"] == main and sp["parent"] is None),
+                 key=lambda sp: sp["start"])
+    rows, reach = [], zero
+    for sp in top:
+        if sp["start"] - reach >= GAP_MIN_S * 1e9:
+            gap = [r for r in compiles if r["thread"] == main
+                   and r["start"] >= reach and r["end"] <= sp["start"]]
+            rows.append({
+                "path": "(before import)" if sp["path"] == "import"
+                and reach == zero else "caller",
+                "start": (reach - zero) / 1e9,
+                "seconds": (sp["start"] - reach) / 1e9,
+                "self_seconds": None, "children": [],
+                "programs": _programs(gap) if gap else None})
+        rows.append(node(sp))
+        reach = max(reach, sp["end"])
+    setups = [sp for sp in top if sp["path"] == "fit_setup"]
+    window = None
+    if setups:
+        end = setups[-1]["end"]
+        # The program's own: a record with no span is a program the
+        # caller compiled (its rows show it), and no sum of the window's.
+        inside = [r for r in compiles
+                  if r["end"] <= end and r.get("span") is not None]
+        window = {
+            "seconds": (end - zero) / 1e9,
+            "before_import_s": next(
+                (r["seconds"] for r in rows
+                 if r["path"] == "(before import)"), 0.0),
+            "spans_s": _union_s(((sp["start"], sp["end"]) for sp in top),
+                                zero, end),
+            **{f"{st}_s": _union_s(((r["start"], r["end"]) for r in inside
+                                    if r["stage"] == st), zero, end)
+               for st in STAGES},
+            "cache_misses": sum(r.get("cache") == "miss" for r in inside),
+            "longest_missed": [
+                [r["fun_name"], (r["end"] - r["start"]) / 1e9]
+                for r in sorted(inside, key=lambda r: r["start"] - r["end"])
+                if r.get("cache") == "miss"][:MISSED_SHOWN],
+        }
+    return {
+        "pid": header.get("pid"), "rank": header.get("rank"),
+        "spans": len(spans), "compile_records": len(compiles),
+        "dropped": header.get("dropped") or {},
+        "short_traces": header.get("short_traces", 0),
+        "short_trace_seconds": header.get("short_trace_seconds", 0.0),
+        "other_threads": sorted({sp["thread"] for sp in spans} - {main}),
+        "rows": rows, "window": window,
+    }
+
+
+def _program_lines(programs: dict, pad: str) -> List[str]:
+    st = programs["stage_s"]
+    lines = [
+        f"{pad}programs: trace {st['trace']:.3f} s, lower "
+        f"{st['lower']:.3f}, backend {st['backend']:.3f} (union); "
+        f"{programs['hit']} hit, {programs['miss']} miss, "
+        f"{programs['uncached']} uncached"]
+    for row in programs["named"]:
+        cache = ",".join(sorted(set(row["cache"]))) or "-"
+        lines.append(
+            f"{pad}  {row['name']}: trace {row['trace']:.3f} lower "
+            f"{row['lower']:.3f} backend {row['backend']:.3f} [{cache}]")
+    if programs["others"]:
+        lines.append(f"{pad}  and {programs['others']} under "
+                     f"{PROGRAM_MIN_S} s each")
+    return lines
+
+
+def render_timeline(summary: dict) -> str:
+    dropped = sum(summary["dropped"].values())
+    lines = [
+        f"timeline: pid {summary['pid']} rank {summary['rank']}, "
+        f"{summary['spans']} spans, {summary['compile_records']} compile "
+        f"records, {dropped} dropped, {summary['short_traces']} traces "
+        f"under 1 ms ({summary['short_trace_seconds']:.3f} s) counted and "
+        "not kept; seconds from the process's start",
+        f"{'start':>9} {'seconds':>9} {'self':>9}  span"]
+
+    def emit(row, depth):
+        self_s = ("-" if row["self_seconds"] is None
+                  else f"{row['self_seconds']:.3f}")
+        lines.append(f"{row['start']:9.3f} {row['seconds']:9.3f} "
+                     f"{self_s:>9}  {'  ' * depth}{row['path']}")
+        if row["programs"]:
+            lines.extend(_program_lines(
+                row["programs"], " " * 32 + "  " * depth))
+        for child in row["children"]:
+            emit(child, depth + 1)
+
+    for row in summary["rows"]:
+        emit(row, 0)
+    if summary["other_threads"]:
+        lines.append(f"  spans of {len(summary['other_threads'])} other "
+                     "thread(s) are in the file and not shown")
+    w = summary["window"]
+    if w is not None:
+        missed = str(w["cache_misses"]) + (
+            " (longest: " + ", ".join(
+                f"{name} {seconds:.1f} s"
+                for name, seconds in w["longest_missed"]) + ")"
+            if w["longest_missed"] else "")
+        lines.append(
+            f"set-up window (the process's start to the end of the last "
+            f"fit_setup): {w['seconds']:.3f} s; before import "
+            f"{w['before_import_s']:.3f}, in the program's top-level "
+            f"spans {w['spans_s']:.3f}, caller "
+            f"{w['seconds'] - w['before_import_s'] - w['spans_s']:.3f}; "
+            f"the program's own compiles: trace {w['trace_s']:.3f}, "
+            f"lower {w['lower_s']:.3f}, backend {w['backend_s']:.3f}; "
+            f"cache misses: {missed}")
+    return "\n".join(lines)
+
+
 def event_line(event: dict) -> str:
     """One event as one follow-mode line: timestamp, kind, then the
     payload keys in emit order (the transport's own ts/event/pid are
@@ -324,8 +535,13 @@ def follow(path, *, poll_s: float = 0.2, stop=None):
 
 def main(argv: Optional[List[str]] = None) -> int:
     ap = argparse.ArgumentParser(prog="dtpu-events", description=__doc__)
-    ap.add_argument("event_log", type=str,
+    ap.add_argument("event_log", type=str, nargs="?",
                     help="JSONL event log (the supervisor's DTPU_EVENT_LOG)")
+    ap.add_argument("--timeline", type=str, metavar="FILE",
+                    help="render a timeline dump (timeline-rank<r>-pid<p>"
+                         ".jsonl beside the flight dumps) instead: the "
+                         "process from its start by span, with the "
+                         "programs each span compiled")
     ap.add_argument("--flight", action="append", default=[],
                     help="extra flight-dump file(s) to include (dumps "
                          "referenced by flight_dump events are found "
@@ -342,6 +558,18 @@ def main(argv: Optional[List[str]] = None) -> int:
                          "lands; waits for the file if it does not exist "
                          "yet; ctrl-C to stop)")
     args = ap.parse_args(argv)
+    if args.timeline:
+        records = read_dump(args.timeline)
+        if not records:
+            print(f"dtpu-events: no readable timeline in {args.timeline}",
+                  file=sys.stderr)
+            return 2
+        summary = summarize_timeline(records)
+        print(json.dumps(summary) if args.json
+              else render_timeline(summary))
+        return 0
+    if args.event_log is None:
+        ap.error("an event log, or --timeline FILE, is required")
     if args.follow:
         try:
             for event in follow(args.event_log):

@@ -22,10 +22,22 @@ from the recovery postmortem. The dump file reuses the event-log
 durability idiom: whole JSON lines, flushed and fsync'd, with a torn
 final line skipped on read (``utils.events.read_events`` reads dumps
 too — same skip-torn-tail property).
+
+The set-up's record goes the same way. :func:`dump_timeline` writes the
+span timeline (``obs.spans``) and the compile ledger
+(``obs.compile_ledger``) beside the flight dump, as
+``timeline-rank<r>-pid<p>.jsonl``: a ``timeline_header`` line (the
+process's start, the main thread, the traces the ledger counted and did
+not keep), then one ``span`` or ``compile`` line a
+record, every time in Unix nanoseconds. ``dtpu-events --timeline <file>``
+renders it. The package registers it to run at process exit
+(:func:`dump_timeline_at_exit`); by the rule above a run with no dump
+location writes nothing.
 """
 
 from __future__ import annotations
 
+import atexit
 import collections
 import json
 import os
@@ -37,7 +49,7 @@ from typing import List, Optional
 from ..utils import event_schema as evs
 from ..utils import events as events_lib
 from ..utils.logging import rank_world
-from . import registry as registry_mod
+from . import compile_ledger, registry as registry_mod, spans
 
 ENV_DIR = "DTPU_FLIGHT_DIR"
 
@@ -136,6 +148,70 @@ def default_dump_path() -> Optional[Path]:
     return Path(base) / f"flight-rank{rank}-pid{os.getpid()}.jsonl"
 
 
+def dump_timeline(path=None) -> Optional[Path]:
+    """Write the default registry's span timeline and compile ledger to
+    ``path`` (default: ``timeline-rank<r>-pid<p>.jsonl`` in
+    :func:`default_dump_path`'s directory). Returns the path, or None
+    where no dump location is configured or nothing was recorded."""
+    reg = registry_mod.default_registry()
+    records = (
+        [{"kind": "span", **r} for r in reg.journal(spans.TIMELINE)]
+        + [{"kind": "compile", **r}
+           for r in reg.journal(compile_ledger.LEDGER)])
+    if not records:
+        return None
+    rank, world = rank_world()
+    if path is None:
+        beside = default_dump_path()
+        if beside is None:
+            return None
+        path = beside.with_name(f"timeline-rank{rank}-pid{os.getpid()}.jsonl")
+    path = Path(path)
+    header = {
+        "kind": "timeline_header",
+        "process_start": spans.process_start_ns(),
+        "written": time.time_ns(),
+        "pid": os.getpid(),
+        "rank": rank,
+        "world": world,
+        "main_thread": threading.main_thread().ident,
+        "records": len(records),
+        "capacity": registry_mod.JOURNAL_CAPACITY,
+        "dropped": {
+            name: int(reg.counter_value(f"journal_dropped/{name}"))
+            for name in (spans.TIMELINE, compile_ledger.LEDGER)},
+        "short_traces": int(reg.counter_value("compile/short_traces")),
+        "short_trace_seconds": reg.counter_value(
+            "compile/short_trace_seconds"),
+    }
+    path.parent.mkdir(parents=True, exist_ok=True)
+    with open(path, "w") as f:
+        f.write("\n".join(json.dumps(r) for r in [header] + records))
+        f.write("\n")
+    return path
+
+
+_exit_dump_registered = False
+
+
+def dump_timeline_at_exit() -> None:
+    """Register :func:`dump_timeline` to run when the interpreter exits,
+    once a process. Never raises there: a failed dump must not change how
+    a process ends."""
+    global _exit_dump_registered
+    if _exit_dump_registered:
+        return
+    _exit_dump_registered = True
+
+    def at_exit():
+        try:
+            dump_timeline()
+        except Exception:
+            pass
+
+    atexit.register(at_exit)
+
+
 def read_dump(path) -> List[dict]:
     """All well-formed records of a dump, torn final line skipped — the
     same read the event log uses (a crash mid-dump must never make the
@@ -166,5 +242,7 @@ __all__ = [
     "default_dump_path",
     "default_recorder",
     "dump",
+    "dump_timeline",
+    "dump_timeline_at_exit",
     "read_dump",
 ]

@@ -39,6 +39,10 @@ DEFAULT_BUCKETS = (0.001, 0.005, 0.01, 0.05, 0.1, 0.5, 1.0, 5.0, 10.0, 30.0)
 
 DEFAULT_RING_SIZE = 256
 
+# Records a journal keeps (the span timeline and the compile ledger, each).
+# A set-up of the largest benchmark cell leaves a few hundred of either.
+JOURNAL_CAPACITY = 4096
+
 _enabled = os.environ.get(ENABLE_ENV, "1") != "0"
 
 
@@ -101,6 +105,7 @@ class MetricsRegistry:
         self._gauges: Dict[str, float] = {}
         self._histograms: Dict[str, Histogram] = {}
         self._rings: Dict[str, collections.deque] = {}
+        self._journals: Dict[str, List[dict]] = {}
         self._reports: Dict[str, dict] = {}
 
     # ------------------------------------------------------------- mutators
@@ -143,6 +148,25 @@ class MetricsRegistry:
                 self._rings[name] = ring
             ring.append(dict(record))
 
+    def journal_append(self, name: str, record: dict) -> None:
+        """Append to the named journal, which keeps its FIRST
+        ``JOURNAL_CAPACITY`` records, in order: what a process does first
+        (its set-up) is never pushed out by what it does for hours after.
+        A record past the capacity is dropped and counted
+        (``journal_dropped/<name>``). The span timeline (``obs.spans``) and
+        the compile ledger (``obs.compile_ledger``) live here; neither
+        rides in :meth:`snapshot` (``obs.flight.dump_timeline`` writes
+        them out)."""
+        if not _enabled:
+            return
+        with self._lock:
+            journal = self._journals.setdefault(name, [])
+            if len(journal) < JOURNAL_CAPACITY:
+                journal.append(record)
+                return
+            key = f"journal_dropped/{name}"
+            self._counters[key] = self._counters.get(key, 0.0) + 1.0
+
     def set_report(self, name: str, report: dict) -> dict:
         """Store a structured telemetry view (e.g. the dict behind
         ``model.last_fit_telemetry``) and return the STORED object, so the
@@ -163,6 +187,18 @@ class MetricsRegistry:
         with self._lock:
             ring = self._rings.get(name)
             return [dict(r) for r in ring] if ring is not None else []
+
+    def journal(self, name: str) -> List[dict]:
+        with self._lock:
+            return [dict(r) for r in self._journals.get(name, ())]
+
+    def journal_clear(self, name: str) -> None:
+        """Empty the named journal, so that it keeps the next
+        ``JOURNAL_CAPACITY`` records (a long-lived process that has
+        written its set-up out; a test in a worker that ran a thousand
+        before it)."""
+        with self._lock:
+            self._journals.pop(name, None)
 
     def counter_value(self, name: str) -> float:
         with self._lock:
@@ -203,6 +239,7 @@ class MetricsRegistry:
             self._gauges.clear()
             self._histograms.clear()
             self._rings.clear()
+            self._journals.clear()
             self._reports.clear()
 
 
@@ -217,6 +254,7 @@ def default_registry() -> MetricsRegistry:
 __all__ = [
     "DEFAULT_BUCKETS",
     "Histogram",
+    "JOURNAL_CAPACITY",
     "MetricsRegistry",
     "default_registry",
     "enabled",

@@ -775,6 +775,9 @@ class Engine:
                     )
                 )
         timer = StepTimer(warmup=0)
+        # The first dispatches of each kind enter the span timeline, the
+        # rest accrue into their histograms alone (obs.spans).
+        in_timeline = obs_spans.loop_gate()
         obs_reg = obs_registry.default_registry()
         sched = Scheduler(self.max_slots)
         self._sched = sched
@@ -861,7 +864,8 @@ class Engine:
                 buf[0, :c] = seq.tokens[start:start + c]
                 # prefill attribution flows through the span tracer (same
                 # name lands on XProf timelines and in the registry).
-                with obs_spans.span("prefill", timer=timer):
+                with obs_spans.span("prefill", timer=timer,
+                                    timeline=in_timeline("prefill")):
                     tok, logp, self.kv.caches = self._prefill_fn(
                         self._params, self._state, self.kv.caches, buf,
                         self.kv.block_tables[seq.slot],
@@ -1014,7 +1018,8 @@ class Engine:
                 ).astype(np.int32)
                 dummy_keys = np.zeros((self.max_slots, 2), np.uint32)
                 cur = cand[:, 0].copy()
-                with obs_spans.span("draft", timer=timer):
+                with obs_spans.span("draft", timer=timer,
+                                    timeline=in_timeline("draft")):
                     for j in range(1, kw):
                         prop, _, self._draft_kv.caches = (
                             self._draft_decode_fn(
@@ -1035,7 +1040,9 @@ class Engine:
                 positions = np.where(
                     ready_mask, self.kv.positions, 0
                 ).astype(np.int32)
-                with obs_spans.span("decode", timer=timer) as sp_dec:
+                with obs_spans.span(
+                        "decode", timer=timer,
+                        timeline=in_timeline("decode")) as sp_dec:
                     toks, logps, self.kv.caches = self._verify_fn(
                         self._params, self._state, self.kv.caches, cand,
                         tables, positions, keys,
@@ -1119,7 +1126,8 @@ class Engine:
             positions = np.where(ready_mask, self.kv.positions, 0).astype(
                 np.int32
             )
-            with obs_spans.span("decode", timer=timer) as sp_dec:
+            with obs_spans.span("decode", timer=timer,
+                                timeline=in_timeline("decode")) as sp_dec:
                 sampled, logps, self.kv.caches = self._decode_fn(
                     self._params, self._state, self.kv.caches, tokens,
                     tables, positions, keys,

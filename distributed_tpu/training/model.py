@@ -208,35 +208,47 @@ class Model:
     # ------------------------------------------------------------------ build
     def build(self, input_shape: Sequence[int], seed: int = 0):
         """Materialize params/state for an unbatched input shape, placed
-        according to the strategy (replicated under DP)."""
-        self.input_shape = tuple(int(d) for d in input_shape)
-        self._seed = seed
-        if self._auto_shard is not None and self.compiled:
-            # compile(strategy="auto"): pick the strategy/precision/K
-            # BEFORE materializing — the planner prices candidates from
-            # abstract shapes, so the 3x-params optimizer tree is never
-            # built under a layout that would then be thrown away.
-            self._commit_auto_plan()
-        key = jax.random.PRNGKey(seed)
-        params, state, _ = self.module.init(key, self.input_shape)
-        # Tensor-parallel role tree (empty for unhinted models); strategies
-        # without a model axis ignore it.
-        self._param_hints = self.module.sharding_hints()
-        # Per-layer explicit dtype= overrides: Policy.cast_to_compute skips
-        # these subtrees so the layer's own cast wins over the policy.
-        self._dtype_hints = self.module.dtype_hints()
-        if self.precision is not None:
-            # Master-weight storage dtype (f32 for every mixed_* preset,
-            # so this is a no-op there; a custom all-low-precision policy
-            # casts here, at build).
-            params = self.precision.cast_params_to_storage(params)
-        self.params = self.strategy.put_params(params, hints=self._param_hints)
-        self.state = self.strategy.put_params(state)
-        if self.compiled:
-            self.opt_state = self.strategy.init_opt_state(self.tx, self.params)
-        self.built = True
-        self._decode_dtype = None  # re-derived on next generate()
-        self._generate_fns = {}
+        according to the strategy (replicated under DP). The ``build`` span
+        and its children (``plan``, ``init``, ``place``, ``opt_state``)
+        block on nothing the code did not: placement is asynchronous, so
+        ``build/place`` can end with transfers still in flight
+        (docs/OBSERVABILITY.md)."""
+        with obs_spans.span("build"):
+            self.input_shape = tuple(int(d) for d in input_shape)
+            self._seed = seed
+            if self._auto_shard is not None and self.compiled:
+                # compile(strategy="auto"): pick the strategy/precision/K
+                # BEFORE materializing — the planner prices candidates from
+                # abstract shapes, so the 3x-params optimizer tree is never
+                # built under a layout that would then be thrown away.
+                with obs_spans.span("plan"):
+                    self._commit_auto_plan()
+            key = jax.random.PRNGKey(seed)
+            with obs_spans.span("init"):
+                params, state, _ = self.module.init(key, self.input_shape)
+            # Tensor-parallel role tree (empty for unhinted models);
+            # strategies without a model axis ignore it.
+            self._param_hints = self.module.sharding_hints()
+            # Per-layer explicit dtype= overrides: Policy.cast_to_compute
+            # skips these subtrees so the layer's own cast wins over the
+            # policy.
+            self._dtype_hints = self.module.dtype_hints()
+            if self.precision is not None:
+                # Master-weight storage dtype (f32 for every mixed_* preset,
+                # so this is a no-op there; a custom all-low-precision policy
+                # casts here, at build).
+                params = self.precision.cast_params_to_storage(params)
+            with obs_spans.span("place"):
+                self.params = self.strategy.put_params(
+                    params, hints=self._param_hints)
+                self.state = self.strategy.put_params(state)
+            if self.compiled:
+                with obs_spans.span("opt_state"):
+                    self.opt_state = self.strategy.init_opt_state(
+                        self.tx, self.params)
+            self.built = True
+            self._decode_dtype = None  # re-derived on next generate()
+            self._generate_fns = {}
         return self
 
     def compile(
@@ -343,104 +355,105 @@ class Model:
         param-gather traffic under bf16 (docs/API.md "Mixed
         precision"). ``None`` (default) disables the policy machinery
         entirely — the pre-policy f32 behavior, byte-for-byte."""
-        if strategy is None:
-            # A plain recompile keeps the current strategy but drops any
-            # pending auto plan (and its fit-default grad_accum): the new
-            # optimizer/loss configuration invalidates the old decision.
-            self._auto_shard = None
-            self._auto_grad_accum = None
-            self.last_plan = None
-        elif isinstance(strategy, str) and strategy == "auto":
-            self._auto_shard = {
-                "hbm_cap_bytes": hbm_cap_bytes,
-                "measure": bool(measure),
-                "pinned_precision": precision is not None,
-                "pinned_k": steps_per_execution is not None,
-                **(dict(auto_options) if auto_options else {}),
-            }
-            self.last_plan = None
-            self._auto_grad_accum = None
-        elif isinstance(strategy, Strategy):
-            self._auto_shard = None
-            self._auto_grad_accum = None
-            self.last_plan = None
-            self.strategy = strategy
+        with obs_spans.span("compile"):
+            if strategy is None:
+                # A plain recompile keeps the current strategy but drops any
+                # pending auto plan (and its fit-default grad_accum): the new
+                # optimizer/loss configuration invalidates the old decision.
+                self._auto_shard = None
+                self._auto_grad_accum = None
+                self.last_plan = None
+            elif isinstance(strategy, str) and strategy == "auto":
+                self._auto_shard = {
+                    "hbm_cap_bytes": hbm_cap_bytes,
+                    "measure": bool(measure),
+                    "pinned_precision": precision is not None,
+                    "pinned_k": steps_per_execution is not None,
+                    **(dict(auto_options) if auto_options else {}),
+                }
+                self.last_plan = None
+                self._auto_grad_accum = None
+            elif isinstance(strategy, Strategy):
+                self._auto_shard = None
+                self._auto_grad_accum = None
+                self.last_plan = None
+                self.strategy = strategy
+                if self.built:
+                    # Re-place live params/state under the new strategy (the
+                    # opt state re-inits below, like every recompile).
+                    self.params = strategy.put_params(
+                        self.params, hints=self._param_hints
+                    )
+                    self.state = strategy.put_params(self.state)
+            else:
+                raise ValueError(
+                    "strategy must be None, the string 'auto', or a "
+                    f"parallel.Strategy instance; got {strategy!r}"
+                )
+            self.precision = precision_lib.get(precision)
+            self.tx = optim.get(optimizer, **optimizer_kwargs)
+            if grad_clip is not None:
+                if grad_clip <= 0:
+                    raise ValueError(f"grad_clip must be > 0, got {grad_clip}")
+                self.tx = optax.chain(
+                    optax.clip_by_global_norm(float(grad_clip)), self.tx
+                )
+            if gradient_accumulation_steps is not None:
+                n = gradient_accumulation_steps
+                if not isinstance(n, (int, np.integer)) or n < 1:
+                    raise ValueError(
+                        "gradient_accumulation_steps must be an integer >= 1, "
+                        f"got {gradient_accumulation_steps!r}"
+                    )
+                if n > 1:
+                    self.tx = optax.MultiSteps(self.tx, every_k_schedule=int(n))
+            if self.precision is not None and self.precision.loss_scaling:
+                # Outermost wrapper: the step body reads opt_state.scale to
+                # multiply the loss before autodiff, and the wrapper unscales
+                # + finite-checks the gradients before anything else (clip,
+                # accumulation, the optimizer) sees them.
+                self.tx = optim.dynamic_loss_scaling(
+                    self.tx,
+                    init_scale=self.precision.initial_loss_scale,
+                    growth_interval=self.precision.loss_scale_growth_interval,
+                    factor=self.precision.loss_scale_factor,
+                )
+            self.loss_fn = losses_lib.get(loss)
+            self.metric_fns = [(metrics_lib.name_of(m), metrics_lib.get(m)) for m in metrics]
+            if head_chunks is not None:
+                if not isinstance(head_chunks, (int, np.integer)) or head_chunks < 1:
+                    raise ValueError(
+                        f"head_chunks must be an integer >= 1, got {head_chunks!r}"
+                    )
+                _split_head(self.module)  # fail fast on unsuitable modules
+            self.head_chunks = int(head_chunks) if head_chunks else None
+            if steps_per_execution is not None:
+                if (
+                    not isinstance(steps_per_execution, (int, np.integer))
+                    or steps_per_execution < 1
+                ):
+                    raise ValueError(
+                        "steps_per_execution must be an integer >= 1, got "
+                        f"{steps_per_execution!r}"
+                    )
+            self.steps_per_execution = (
+                int(steps_per_execution) if steps_per_execution else None
+            )
+            self.compiled = True
+            # Every cached compiled function depends on the (loss, metrics,
+            # optimizer, precision) configuration set here — including predict
+            # and the generate scans, whose compute dtype follows the policy.
+            self._train_step = self._eval_step = self._predict_step = None
+            self._multi_train_steps = {}
+            self._accum_train_steps = {}
+            self._decode_dtype = None
+            self._generate_fns = {}
             if self.built:
-                # Re-place live params/state under the new strategy (the
-                # opt state re-inits below, like every recompile).
-                self.params = strategy.put_params(
-                    self.params, hints=self._param_hints
-                )
-                self.state = strategy.put_params(self.state)
-        else:
-            raise ValueError(
-                "strategy must be None, the string 'auto', or a "
-                f"parallel.Strategy instance; got {strategy!r}"
-            )
-        self.precision = precision_lib.get(precision)
-        self.tx = optim.get(optimizer, **optimizer_kwargs)
-        if grad_clip is not None:
-            if grad_clip <= 0:
-                raise ValueError(f"grad_clip must be > 0, got {grad_clip}")
-            self.tx = optax.chain(
-                optax.clip_by_global_norm(float(grad_clip)), self.tx
-            )
-        if gradient_accumulation_steps is not None:
-            n = gradient_accumulation_steps
-            if not isinstance(n, (int, np.integer)) or n < 1:
-                raise ValueError(
-                    "gradient_accumulation_steps must be an integer >= 1, "
-                    f"got {gradient_accumulation_steps!r}"
-                )
-            if n > 1:
-                self.tx = optax.MultiSteps(self.tx, every_k_schedule=int(n))
-        if self.precision is not None and self.precision.loss_scaling:
-            # Outermost wrapper: the step body reads opt_state.scale to
-            # multiply the loss before autodiff, and the wrapper unscales
-            # + finite-checks the gradients before anything else (clip,
-            # accumulation, the optimizer) sees them.
-            self.tx = optim.dynamic_loss_scaling(
-                self.tx,
-                init_scale=self.precision.initial_loss_scale,
-                growth_interval=self.precision.loss_scale_growth_interval,
-                factor=self.precision.loss_scale_factor,
-            )
-        self.loss_fn = losses_lib.get(loss)
-        self.metric_fns = [(metrics_lib.name_of(m), metrics_lib.get(m)) for m in metrics]
-        if head_chunks is not None:
-            if not isinstance(head_chunks, (int, np.integer)) or head_chunks < 1:
-                raise ValueError(
-                    f"head_chunks must be an integer >= 1, got {head_chunks!r}"
-                )
-            _split_head(self.module)  # fail fast on unsuitable modules
-        self.head_chunks = int(head_chunks) if head_chunks else None
-        if steps_per_execution is not None:
-            if (
-                not isinstance(steps_per_execution, (int, np.integer))
-                or steps_per_execution < 1
-            ):
-                raise ValueError(
-                    "steps_per_execution must be an integer >= 1, got "
-                    f"{steps_per_execution!r}"
-                )
-        self.steps_per_execution = (
-            int(steps_per_execution) if steps_per_execution else None
-        )
-        self.compiled = True
-        # Every cached compiled function depends on the (loss, metrics,
-        # optimizer, precision) configuration set here — including predict
-        # and the generate scans, whose compute dtype follows the policy.
-        self._train_step = self._eval_step = self._predict_step = None
-        self._multi_train_steps = {}
-        self._accum_train_steps = {}
-        self._decode_dtype = None
-        self._generate_fns = {}
-        if self.built:
-            if self._auto_shard is not None:
-                # Already built: plan now (input shape is known) and
-                # re-place the live tree under the winner.
-                self._commit_auto_plan(replace_live=True)
-            self.opt_state = self.strategy.init_opt_state(self.tx, self.params)
+                if self._auto_shard is not None:
+                    # Already built: plan now (input shape is known) and
+                    # re-place the live tree under the winner.
+                    self._commit_auto_plan(replace_live=True)
+                self.opt_state = self.strategy.init_opt_state(self.tx, self.params)
         return self
 
     # -------------------------------------------------------- auto sharding
@@ -1182,7 +1195,15 @@ class Model:
         mid-epoch stop rewinds a seekable source (``data.Pipeline``) to
         the step actually trained. Per-fit stall accounting (input_wait /
         dispatch / checkpoint_wait seconds and the input-stall fraction)
-        lands in ``model.last_fit_telemetry``."""
+        lands in ``model.last_fit_telemetry``.
+
+        In the span timeline a fit is the phase ``fit_setup`` (this
+        function's entry to the step loop's first ``input_wait``), the
+        step loop's ``input_wait`` and ``dispatch`` spans (the first 8 of
+        each; all of them in the histograms) and the phase
+        ``fit_teardown`` (the last epoch's end to the return: callbacks'
+        ``on_train_end``, the counters' read, the report)."""
+        setup_phase = obs_spans.begin("fit_setup")
         if not self.compiled:
             raise RuntimeError("Call compile() before fit()")
         from .. import quant as quant_lib
@@ -1294,6 +1315,7 @@ class Model:
         # model._stall_timer). Summarized into last_fit_telemetry at exit.
         timer = StepTimer(warmup=0)
         self._stall_timer = timer
+        in_timeline = obs_spans.loop_gate()
         # Reset the thread's scanned-overlap trace record so this fit's
         # telemetry can only see a record ITS OWN tracing wrote (a warm
         # jit cache writes none — the report then under-claims rather
@@ -1483,15 +1505,20 @@ class Model:
             # depth 0 stages inline — byte-identical, just synchronous.
             staged = DevicePrefetcher(stage, sizes, depth=prefetch)
             done = 0
+            setup_phase.end()  # the first epoch's; a no-op after
             last_iter_t = time.perf_counter()
             try:
                 for k in sizes:
                     # input_wait / dispatch flow through obs spans (ONE
                     # attribution code path: StepTimer bucket + registry
                     # stall counter + span histogram + XProf annotation).
-                    with obs_spans.span("input_wait", timer=timer) as sp_in:
+                    with obs_spans.span(
+                            "input_wait", timer=timer,
+                            timeline=in_timeline("input_wait")) as sp_in:
                         _, batch = staged.get()
-                    with obs_spans.span("dispatch", timer=timer) as sp_disp:
+                    with obs_spans.span(
+                            "dispatch", timer=timer,
+                            timeline=in_timeline("dispatch")) as sp_disp:
                         if multi_k == 1:
                             rng = self._step_rng()
                             (self.params, self.state, self.opt_state, loss,
@@ -1649,6 +1676,8 @@ class Model:
                 )
             if self.stop_training:
                 break
+        setup_phase.end()  # a fit that ran no epoch
+        teardown_phase = obs_spans.begin("fit_teardown")
         for cb in callbacks:
             # on_train_end BEFORE the telemetry summary: ModelCheckpoint's
             # train-end wait() (flushing a background writer) attributes
@@ -1795,6 +1824,7 @@ class Model:
                 obs_reg.gauge(f"fit/device_memory/{key}", val)
         self.last_fit_telemetry = obs_reg.set_report("model.fit", report)
         self._stall_timer = None
+        teardown_phase.end()
         return history
 
     # --------------------------------------------------------------- evaluate

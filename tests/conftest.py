@@ -143,6 +143,13 @@ def pytest_sessionfinish(session, exitstatus):
 # the family after it. ``test_bench_lfm2.py`` asserts that its cell is among
 # a metric's ``workloads`` and never what a whole list is, so the family
 # after it adds nothing here (ROADMAP D17).
+# PR 36's seven ``setup_*`` metrics list all five training cells, so they
+# outlive the filter by family and would sit in ``per_layer``'s last places,
+# which ``test_bench_keye.py`` pins: the tests below see the manifest
+# without them. ``test_bench_setup.py`` asserts what they are.
+METRICS_SINCE = frozenset({
+    "setup_import_s", "setup_build_s", "setup_trace_s", "setup_lower_s",
+    "setup_backend_s", "setup_cache_misses", "setup_unseen_s"})
 FAMILIES_SEEN = {
     "test_config_files_state_their_departures": {"gpt2"},
     "test_run_py_lists_the_scope_metrics_for_the_train_cells": {"gpt2"},
@@ -153,6 +160,12 @@ FAMILIES_SEEN = {
         "gpt2", "deepseek_v3", "keye_vl2"},
     "test_run_py_lists_the_scope_metrics_for_the_keye_cell": {
         "gpt2", "deepseek_v3", "keye_vl2"},
+    # test_bench_lfm2.py pins no list of cells, but the whole set of its
+    # cell's metrics: it sees every family, less METRICS_SINCE.
+    "test_the_cell_reports_what_issue_34_lists": {
+        "gpt2", "deepseek_v3", "keye_vl2", "lfm2_moe"},
+    "test_run_py_lists_the_metrics_for_the_lfm2_cell": {
+        "gpt2", "deepseek_v3", "keye_vl2", "lfm2_moe"},
 }
 
 
@@ -180,7 +193,8 @@ def entries_of(manifest: dict, families) -> dict:
                 metric["workloads"] = [w for w in metric["workloads"]
                                        if w in cells]
         out[section] = [m for m in out[section]
-                        if m.get("workloads", True)]
+                        if m.get("workloads", True)
+                        and m["name"] not in METRICS_SINCE]
     return out
 
 
