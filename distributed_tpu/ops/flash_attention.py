@@ -63,14 +63,23 @@ MXU operands, exact divide, once a row):
 
 Two things a caller may add, both for 128-wide heads in the lane-packed
 layout, neither touching a call that does not ask: *shared K/V heads*
-(grouped queries: a query head block h reads K/V block h // group through
-its index map, and dk/dv's grid walks a group's query heads one after
-another into one accumulator), and a *selection* (learned sparse attention:
-an int8 (T, T) operand, one for all heads, and-ed with ``_valid_mask`` in
-every segment's mask, with a flag a grid block in scalar memory so that a
-block with no selected pair runs no walk; the kernels are then named
-``dtpu_flash_*_sel`` and hand back their row statistic). A selection masks
-dense sub-tiles: it saves work only where whole grid blocks go unselected.
+(grouped queries: the forward's and dq's grids, (b, K/V head, q block, kv
+block, head of the group), walk a group's query heads innermost on the K
+and V blocks, and the selection's, that the first of them fetched, since a
+block whose index did not change is not copied again: each is read once a
+K/V head, not once a query head (``kv_block_fetches``; the gauges
+``flash.kv_block_fetches`` / ``_a_head``). The q-side blocks hold the whole
+group's lanes and the body takes its head's as a view; where that is more
+than VMEM has room for, at a large group or float32 inputs, fewer heads
+share a fetch, down to one (``_heads_a_fetch``). dk/dv's grid walks,
+q block by q block, a group's query heads into one accumulator, the
+selection's block staying put under them), and a *selection* (learned
+sparse attention: an int8 (T, T) operand, one for all heads, and-ed with
+``_valid_mask`` in every segment's mask, with a flag a grid block in scalar
+memory so that a block with no selected pair runs no walk; the kernels are
+then named ``dtpu_flash_*_sel`` and hand back their row statistic). A
+selection masks dense sub-tiles: it saves work only where whole grid blocks
+go unselected.
 
 The walk is unrolled at trace time (the LLO scheduler packs straight-line
 code best; an in-kernel ``fori_loop`` over sub-tiles cannot merge runs),
@@ -272,6 +281,46 @@ def subtile_counts(t: int, block_q: int, block_k: int, causal: bool,
             classes.count(_MASKED))
 
 
+def kv_block_fetches(b: int, head_blocks: int, share: int, nq: int, nk: int):
+    """(fetches, a_head): the K block fetches (V's are as many) of one
+    forward call over the grid ``_specs`` lays out for ``b`` rows,
+    ``head_blocks`` query head blocks, ``share`` of them walked on one fetch
+    (``_heads_a_fetch``; 1 with one K/V head a query head), and nq x nk grid
+    blocks, that is the grid steps at which the K/V index map's value is
+    not the step's before, and what one fetch a query head block would
+    make: the same number where ``share`` is 1. The pipeline copies no
+    block whose index did not change, so with the sharing heads the
+    innermost axis a block is fetched once for all of them. A selection's
+    block is fetched as often, but for a single kv block (nk 1), which
+    never moves while the selection's moves with the q block, nq times as
+    often. Static for a shape."""
+    runs = nq * nk if nk > 1 else 1  # one kv block: it never moves
+    return b * (head_blocks // share) * runs, b * head_blocks * runs
+
+
+_VMEM = 16 << 20  # what a kernel may hold of VMEM: the v5e's scoped limit
+
+
+def _heads_a_fetch(group, block_q, block_k, itemsize):
+    """How many of the ``group`` query heads of a K/V head (128 wide, one a
+    head block) the forward and dq walk on one fetch of its K and V blocks
+    and the selection's: the most, of ``group``'s divisors, that ``_VMEM``
+    has room for. The q-side blocks hold the lanes of all the sharing
+    heads, double-buffered (q and o, or q, dO and dq), beside a float32
+    scratch slab a head (m, l and acc, or dq's accumulator) and their rows
+    of the statistics, so what a step holds grows with the heads times
+    block_q; beside them stand K, V and the selection, double-buffered, and
+    a pass's scores and probabilities. One head a fetch is the layout of a
+    call with no shared head, and holds what that holds. dk/dv's blocks are
+    one head's under any sharing. (Held against what the v5e's compiler
+    asks for: PERF.md section 6 and CHANGES.md, PR 37.)"""
+    per_row = _LANES * max(4 * itemsize + 12, 6 * itemsize + 4) + 128
+    others = (4 * block_k * _LANES * itemsize + 2 * block_q * block_k
+              + (4 + itemsize) * min(block_q * block_k, _PASS_SCORES))
+    return next(s for s in range(group, 0, -1) if group % s == 0 and (
+        s == 1 or s * block_q * per_row + others <= _VMEM))
+
+
 def _block_views(nq, nk, block_q, block_k, t_actual, causal):
     """The distinct ways a grid block (qi, ki) lies against the diagonal
     (causal) or the padding edge (not causal), static for a shape:
@@ -439,6 +488,27 @@ def _live(sel, nq, nk, qi, ki):
     return sel[0][(pl.program_id(0) * nq + qi) * nk + ki] != 0
 
 
+def _head_of_group(group, lanes=(), stats=(), scratch=()):
+    """The refs of a forward or dq grid step as one query head's, the body's
+    own terms. Where ``group`` > 1 query heads walk on one fetch
+    (``_heads_a_fetch``) the grid's last axis is theirs, over the K/V
+    head's blocks (and the selection's) that the step before left in VMEM:
+    the ``lanes`` refs (q, o, dO, dq) hold all their lanes a q block and
+    the ``stats`` refs their rows of the statistics, fetched and written
+    back once for all, and the ``scratch`` refs carry a slab a head. Each
+    comes back as the view of this step's head; with one head a fetch, as
+    it stands."""
+    if group == 1:
+        return (*lanes, *stats, *scratch)
+    hd = pl.program_id(4)
+
+    def own(ref):
+        w = ref.shape[-1] // group
+        return ref.at[:, :, pl.ds(pl.multiple_of(hd * w, _LANES), w)]
+    return (*(own(r) for r in lanes), *(r.at[:, pl.ds(hd, 1)] for r in stats),
+            *(r.at[hd] for r in scratch))
+
+
 def _selecting(kernel, n_in):
     """``kernel`` as a selection-taking ``pallas_call`` hands over its refs:
     the flags first (scalar prefetch), the selection's block after the
@@ -451,7 +521,7 @@ def _selecting(kernel, n_in):
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_out_ref, m_ref, l_ref,
                 acc_ref, *, scale, heads, block_q, block_k, t_actual,
-                causal, nq, nk, sel=None):
+                causal, nq, nk, group=1, sel=None):
     """One (b, hblk, qi, ki) grid step on (1, block, lanes) tiles of
     ``heads`` heads side by side: q and k as wide as each other, v, and with
     it the output and the statistics, as wide as itself. Each q sub-tile
@@ -459,14 +529,19 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_out_ref, m_ref, l_ref,
     head, an unmasked run and a masked one, under one row maximum. Scratch
     carries m, l and acc, each head's replicated across its span of v's
     lanes, between the sequential ki steps; a grid of one block needs
-    neither scratch nor ``pl.when``."""
+    neither scratch nor ``pl.when``. Where ``group`` query heads walk on one
+    fetch of their K/V head's blocks the grid has them as its last axis and
+    the step is one head's (``_head_of_group``): k, v and the selection stay
+    put for their steps."""
     qi = pl.program_id(2)
     ki = pl.program_id(3)
+    q_ref, o_ref, lse_out_ref, m_ref, l_ref, acc_ref = _head_of_group(
+        group, (q_ref, o_ref), (lse_out_ref,), (m_ref, l_ref, acc_ref))
     subs = [_subtile(x, q_ref.shape[-1]) for x in (block_q, block_k)]
     v_lanes = _head_lanes(v_ref, heads)
     lanes = list(zip(_head_lanes(q_ref, heads), v_lanes))
     fold = _scale_folds(scale)
-    one_block = nq == 1 and nk == 1 and sel is None
+    one_block = nq == 1 and nk == 1 and group == 1 and sel is None
 
     def finish(rows, m, l, acc):
         l = jnp.maximum(l, 1e-30)
@@ -543,16 +618,19 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_out_ref, m_ref, l_ref,
 
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, dq_ref,
                acc_ref, *, scale, heads, block_q, block_k, t_actual, causal,
-               nq, nk, sel=None):
-    """dq for one (b, hblk, qi, ki) grid step, walked like the forward: per
-    q sub-tile, dq += ds K over the kv columns its rows see."""
+               nq, nk, group=1, sel=None):
+    """dq for one (b, hblk, qi, ki) grid step, walked like the forward (and
+    over its grid, the ``group`` heads of one fetch innermost): per q
+    sub-tile, dq += ds K over the kv columns its rows see."""
     qi = pl.program_id(2)
     ki = pl.program_id(3)
+    q_ref, do_ref, dq_ref, lse_ref, dl_ref, acc_ref = _head_of_group(
+        group, (q_ref, do_ref, dq_ref), (lse_ref, dl_ref), (acc_ref,))
     subs = [_subtile(x, q_ref.shape[-1]) for x in (block_q, block_k)]
     q_lanes, v_lanes = _head_lanes(q_ref, heads), _head_lanes(v_ref, heads)
     lanes = list(zip(q_lanes, v_lanes))
     fold = _scale_folds(scale)
-    one_block = nq == 1 and nk == 1 and sel is None
+    one_block = nq == 1 and nk == 1 and group == 1 and sel is None
 
     def finish(rows, acc):
         if fold:
@@ -620,13 +698,14 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, dk_ref,
     so that p^T and ds^T are the products' left operands as they stand;
     the row statistics come lane-major, (heads, rows). Where ``group`` query
     heads share a K/V head, the head block is the K/V head's and the last
-    grid axis walks the q blocks of each of its query heads in turn
-    (``group * nq`` steps), all summed into the one dk and dv."""
+    grid axis walks, q block by q block, each of its query heads in turn
+    (``nq * group`` steps, the head minor: the selection's block stays put
+    for a group's steps), all summed into the one dk and dv."""
     ki = pl.program_id(2)
     step = qi = pl.program_id(3)
     last = group * nq - 1
     if group > 1:
-        qi = jax.lax.rem(step, nq)
+        qi = step // group
     subs = [_subtile(x, q_ref.shape[-1]) for x in (block_q, block_k)]
     q_lanes, v_lanes = _head_lanes(q_ref, heads), _head_lanes(v_ref, heads)
     lanes = list(enumerate(zip(q_lanes, v_lanes)))
@@ -696,14 +775,20 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, dk_ref,
 def _specs(q, v, heads, hpb, causal, block_q, block_k, group=1):
     """What the three pallas_calls share, for q (b, T, heads * D) and v
     (b, T, heads // group * Dv) with ``hpb`` heads a block: (t_pad, nh, w,
-    wv), the kernels' static arguments, and the block specs for the grid
-    (b, head block, i, j), by which of i / j walks the q blocks. A head
-    block is ``hpb`` heads padded to whole lanes, w for q and k, wv for v; a
-    row statistic is (b, nh, hpb, t_pad), lane-major. ``group`` query heads
-    share a K/V head (one head a block): a query head block h reads K/V
-    block h // group, and dk/dv's grid is (b, K/V head, kv block, query
-    head of the group x q block), ``specs(3, dkv=True)``. The last spec is
-    the selection's block, (block_q, block_k), or transposed for dk/dv."""
+    wv), the kernels' static arguments, and ``specs``: the grid and the
+    block specs, by which of the grid's axes 2 and 3 walks the q blocks. A
+    head block is ``hpb`` heads padded to whole lanes, w for q and k, wv for
+    v; a row statistic is (b, nh, hpb, t_pad), lane-major. The grid is (b,
+    head block, i, j). ``group`` query heads share a K/V head (one head a
+    block), and ``share`` of them (``_heads_a_fetch``: all, where VMEM has
+    the room) fetch its K and V blocks, and the selection's, once for all:
+    the forward's and dq's grid is then (b, query heads by ``share``, q
+    block, kv block, head of the ``share``), the q-side blocks all the
+    sharing heads' (``_head_of_group``); with no room for two, query head
+    block h reads K/V block h // group on the plain grid. dk/dv's grid,
+    ``specs(3, dkv=True)``, is (b, K/V head, kv block, q block x head of
+    the group). The last spec is the selection's block, (block_q, block_k),
+    or transposed for dk/dv."""
     t = q.shape[1]
     if max(block_q, block_k) % min(block_q, block_k):
         raise ValueError(
@@ -712,49 +797,62 @@ def _specs(q, v, heads, hpb, causal, block_q, block_k, group=1):
     t_pad = _round_up(t, max(block_q, block_k))
     w = _lane_pad(q.shape[-1] // heads * hpb)
     wv = _lane_pad(v.shape[-1] // (heads // group) * hpb)
+    b, nh = q.shape[0], heads // hpb
     nq, nk = t_pad // block_q, t_pad // block_k
     if not _interpret() and nq > 1 and block_q % _LANES:
         raise ValueError(
             f"block_q={block_q}: the kernels keep their row statistics "
             "lane-major, so a q block that is not the whole sequence has "
             f"to be a multiple of {_LANES} rows")
+    share = _heads_a_fetch(group, block_q, block_k, q.dtype.itemsize)
 
     def specs(q_axis, dkv=False):
         kv_axis = 5 - q_axis  # grid axes 2 and 3
-        if group == 1:
+        q_heads = 1  # heads a q-side block holds
+        if group == 1 or (share == 1 and not dkv):
+            grid = (b, nh, *((nk, nq) if dkv else (nq, nk)))
             at = lambda axis: lambda *g: (g[0], g[axis], g[1])
             q_at, kv_at = at(q_axis), at(kv_axis)
+            if group > 1:
+                kv_at = lambda *g: (g[0], g[kv_axis], g[1] // group)
             stat_at = lambda *g: (g[0], g[1], 0, g[q_axis])
             sel_at = lambda *g: (g[0], g[q_axis], g[kv_axis])
         elif dkv:
-            q_at = lambda *g: (g[0], g[3] % nq, g[1] * group + g[3] // nq)
+            grid = (b, nh // group, nk, nq * group)
+            q_at = lambda *g: (g[0], g[3] // group,
+                               g[1] * group + g[3] % group)
             kv_at = lambda *g: (g[0], g[2], g[1])
-            stat_at = lambda *g: (g[0], g[1] * group + g[3] // nq, 0,
-                                  g[3] % nq)
-            sel_at = lambda *g: (g[0], g[3] % nq, g[2])
+            stat_at = lambda *g: (g[0], g[1] * group + g[3] % group, 0,
+                                  g[3] // group)
+            sel_at = lambda *g: (g[0], g[3] // group, g[2])
         else:
-            q_at = lambda *g: (g[0], g[q_axis], g[1])
-            kv_at = lambda *g: (g[0], g[kv_axis], g[1] // group)
-            stat_at = lambda *g: (g[0], g[1], 0, g[q_axis])
-            sel_at = lambda *g: (g[0], g[q_axis], g[kv_axis])
+            grid = (b, nh // share, nq, nk, share)
+            q_heads = share
+            q_at = lambda *g: (g[0], g[2], g[1])
+            kv_at = lambda *g: (g[0], g[3], g[1])
+            if share < group:
+                kv_at = lambda *g: (g[0], g[3], g[1] // (group // share))
+            stat_at = lambda *g: (g[0], g[1], 0, g[2])
+            sel_at = lambda *g: (g[0], g[2], g[3])
         if dkv:  # the selection transposed, as dk/dv's masks are
             sel_spec = pl.BlockSpec(
                 (1, block_k, block_q),
                 lambda *g: (lambda b, i, j: (b, j, i))(*sel_at(*g)))
         else:
             sel_spec = pl.BlockSpec((1, block_q, block_k), sel_at)
-        return (
-            *(pl.BlockSpec((1, block_q, x), q_at) for x in (w, wv)),
+        return grid, (
+            *(pl.BlockSpec((1, block_q, q_heads * x), q_at)
+              for x in (w, wv)),
             *(pl.BlockSpec((1, block_k, x), kv_at) for x in (w, wv)),
-            pl.BlockSpec((1, 1, hpb, block_q), stat_at), sel_spec)
+            pl.BlockSpec((1, q_heads, hpb, block_q), stat_at), sel_spec)
 
     kernel_args = dict(
         # A Python float: a NumPy scalar is no weak type, and q * scale
         # would promote the MXU's bf16 operand to f32.
         scale=1.0 / math.sqrt(q.shape[-1] // heads), heads=hpb,
         block_q=block_q, block_k=block_k, t_actual=t, causal=causal,
-        nq=nq, nk=nk)
-    return t_pad, heads // hpb, w, wv, kernel_args, specs
+        nq=nq, nk=nk, group=share)  # dk/dv's ``group`` is the group itself
+    return t_pad, nh, w, wv, kernel_args, specs
 
 
 def _call(kernel, n_in, grid, in_specs, out_specs, out_shape, scratch,
@@ -801,21 +899,17 @@ def _fwd_pallas(q, k, v, selection=None, *, heads, hpb, suffix, causal,
     b, t, _ = q.shape
     t_pad, nh, w, wv, kernel_args, specs = _specs(
         q, v, heads, hpb, causal, block_q, block_k, group)
-    nq, nk = kernel_args["nq"], kernel_args["nk"]
-    q_spec, o_spec, k_spec, v_spec, stat, sel_spec = specs(q_axis=2)
+    grid, (q_spec, o_spec, k_spec, v_spec, stat, sel_spec) = specs(q_axis=2)
+    # m, l, acc between the sequential ki steps, of each head of a group.
+    carried = pltpu.VMEM((*grid[4:], block_q, wv), jnp.float32)
     out, lse = _call(
         functools.partial(_fwd_kernel, **kernel_args), 3,
-        (b, nh, nq, nk), [q_spec, k_spec, v_spec], [o_spec, stat],
+        grid, [q_spec, k_spec, v_spec], [o_spec, stat],
         [
             jax.ShapeDtypeStruct((b, t_pad, nh * wv), q.dtype),
             jax.ShapeDtypeStruct((b, nh, hpb, t_pad), jnp.float32),
         ],
-        [
-            # m, l, acc between the sequential ki steps.
-            pltpu.VMEM((block_q, wv), jnp.float32),
-            pltpu.VMEM((block_q, wv), jnp.float32),
-            pltpu.VMEM((block_q, wv), jnp.float32),
-        ],
+        [carried, carried, carried],
         "dtpu_flash_fwd" + suffix, sel_spec,
         _pad_selection(selection, t_pad),
     )(_pad(q, t_pad, nh * w), _pad(k, t_pad, nh // group * w),
@@ -831,7 +925,6 @@ def _bwd_pallas(res, g, *, heads, hpb, suffix, causal, block_q, block_k,
     b, t, _ = q.shape
     t_pad, nh, w, wv, kernel_args, specs = _specs(
         q, v, heads, hpb, causal, block_q, block_k, group)
-    nq, nk = kernel_args["nq"], kernel_args["nk"]
     nkv = nh // group
     qp, kp = _pad(q, t_pad, nh * w), _pad(k, t_pad, nkv * w)
     vp, dop = _pad(v, t_pad, nkv * wv), _pad(g.astype(q.dtype), t_pad,
@@ -847,23 +940,21 @@ def _bwd_pallas(res, g, *, heads, hpb, suffix, causal, block_q, block_k,
     delta = jnp.transpose(jnp.sum(gf * of, axis=-1), (0, 2, 3, 1))
     delta = jnp.pad(delta, ((0, 0), (0, 0), (0, 0), (0, t_pad - t)))
 
-    q_spec, do_spec, k_spec, v_spec, stat, sel_spec = specs(q_axis=2)
+    grid, (q_spec, do_spec, k_spec, v_spec, stat, sel_spec) = specs(q_axis=2)
     dq = _call(
         functools.partial(_dq_kernel, **kernel_args), 6,
-        (b, nh, nq, nk), [q_spec, k_spec, v_spec, do_spec, stat, stat],
+        grid, [q_spec, k_spec, v_spec, do_spec, stat, stat],
         q_spec, jax.ShapeDtypeStruct((b, t_pad, nh * w), q.dtype),
-        [pltpu.VMEM((block_q, w), jnp.float32)],
+        [pltpu.VMEM((*grid[4:], block_q, w), jnp.float32)],
         "dtpu_flash_dq" + suffix, sel_spec, selection,
     )(qp, kp, vp, dop, lse, delta)
 
-    q_spec, do_spec, k_spec, v_spec, stat, sel_spec = specs(
+    grid, (q_spec, do_spec, k_spec, v_spec, stat, sel_spec) = specs(
         q_axis=3, dkv=True)
-    if group > 1:
-        kernel_args = dict(kernel_args, group=group)
     dk, dv = _call(
-        functools.partial(_dkv_kernel, **kernel_args), 6,
-        (b, nkv, nk, group * nq),
-        [q_spec, k_spec, v_spec, do_spec, stat, stat], [k_spec, v_spec],
+        functools.partial(_dkv_kernel, **dict(kernel_args, group=group)), 6,
+        grid, [q_spec, k_spec, v_spec, do_spec, stat, stat],
+        [k_spec, v_spec],
         [
             jax.ShapeDtypeStruct((b, t_pad, nkv * w), k.dtype),
             jax.ShapeDtypeStruct((b, t_pad, nkv * wv), v.dtype),
@@ -1068,6 +1159,13 @@ def flash_attention(
     for name, n in zip(("square", "computed", "masked"), subtile_counts(
             t, bq, bk, causal, _LANES if packed else _lane_pad(d))):
         gauge(f"flash.subtiles_{name}", n)
+    # So is how often the kernels fetch a K/V block, beside once a head.
+    t_pad = _round_up(t, max(bq, bk))
+    rows, head_blocks = (b, h * d // _LANES) if packed else (b * h, 1)
+    share = _heads_a_fetch(group, bq, bk, jnp.dtype(q.dtype).itemsize)
+    for name, n in zip(("", "_a_head"), kv_block_fetches(
+            rows, head_blocks, share, t_pad // bq, t_pad // bk)):
+        gauge(f"flash.kv_block_fetches{name}", n)
     if selection is not None:
         if not (packed and d == _LANES and causal):
             raise ValueError(

@@ -396,16 +396,20 @@ def test_subtile_gauges_published(shape, value_dim, want):
             for n in ("square", "computed", "masked")] == want
 
 
-def _dot_operand_dtypes(jaxpr, found):
+def _eqns(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs inside its equations."""
     for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "dot_general":
-            found.add(tuple(str(v.aval.dtype) for v in eqn.invars))
+        yield eqn
         for param in eqn.params.values():
             for sub in param if isinstance(param, (list, tuple)) else [param]:
                 sub = getattr(sub, "jaxpr", sub)
                 if hasattr(sub, "eqns"):
-                    _dot_operand_dtypes(sub, found)
-    return found
+                    yield from _eqns(sub)
+
+
+def _dot_operand_dtypes(jaxpr):
+    return {tuple(str(v.aval.dtype) for v in eqn.invars)
+            for eqn in _eqns(jaxpr) if eqn.primitive.name == "dot_general"}
 
 
 @pytest.mark.parametrize("heads,head_dim,value_dim", [
@@ -425,8 +429,7 @@ def test_kernels_feed_the_mxu_bf16(heads, head_dim, value_dim):
                        .astype(jnp.float32))
 
     jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, q, v)
-    assert _dot_operand_dtypes(jaxpr.jaxpr, set()) == {
-        ("bfloat16", "bfloat16")}
+    assert _dot_operand_dtypes(jaxpr.jaxpr) == {("bfloat16", "bfloat16")}
 
 
 @pytest.mark.parametrize("causal", [False, True])
@@ -465,3 +468,328 @@ def test_residual_statistic(shape, value_dim, blocks, side, causal, subtile):
     np.testing.assert_allclose(
         np.asarray(lse[..., :t]).reshape(b, h, t), np.asarray(want),
         rtol=2e-5, atol=2e-5)
+
+
+# ---------------------------------------------------- grouped query heads --
+# 128-wide heads in the lane-packed layout, ``group`` query heads a K/V head:
+# the forward's and dq's grids walk a group's heads innermost, on the K, V and
+# selection blocks the first of them fetched, and dk/dv's its q blocks
+# head-minor. Blocks of (64, 128) at T = 256 (or 200, padded to it): four q
+# blocks and two kv blocks, so the scratch a head is carried and read back.
+GROUP_BLOCKS = dict(block_q=64, block_k=128)
+
+
+def _grouped_qkv(group, t, b=2, kv_heads=2, seed=7):
+    rng = np.random.default_rng(seed)
+    return tuple(
+        jnp.asarray(rng.standard_normal((b, t, heads, 128)), jnp.float32)
+        for heads in (group * kv_heads, kv_heads, kv_heads,
+                      group * kv_heads))  # q, k, v and the output's weights
+
+
+def _spread_selection(t, b=2, seed=11, density=0.3):
+    """(b, t, t) int8, causal with the diagonal kept, spread over the keys."""
+    rng = np.random.default_rng(seed)
+    sel = np.tril((rng.random((b, t, t)) < density) | np.eye(t, dtype=bool))
+    return jnp.asarray(sel, jnp.int8)
+
+
+def _close(got, want, rel=2e-5):
+    for a, b in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        scale = float(jnp.max(jnp.abs(b))) + 1e-12
+        assert float(jnp.max(jnp.abs(a - b))) < rel * scale + 1e-7
+
+
+@pytest.mark.parametrize("t", [256, 200])
+@pytest.mark.parametrize("selecting", [True, False])
+@pytest.mark.parametrize("group", [2, 4, 8])
+def test_grouped_kernels_match_dense_attention(group, selecting, t):
+    """Values, the row statistic and all three gradients of the kernels that
+    share a K/V head's blocks among its query heads, with a selection and
+    without, at a T the blocks divide and one they pad."""
+    from distributed_tpu.ops import flash_attention as fa
+
+    q, k, v, w = _grouped_qkv(group, t)
+    sel = _spread_selection(t) if selecting else None
+    flash = lambda q, k, v: jnp.sum(w * flash_attention(
+        q, k, v, causal=True, selection=sel, **GROUP_BLOCKS))
+    dense = lambda q, k, v: jnp.sum(w * fa.dense_attention(
+        q, k, v, True, sel))
+    _close(jax.value_and_grad(flash, (0, 1, 2))(q, k, v),
+           jax.value_and_grad(dense, (0, 1, 2))(q, k, v))
+    want = fa.dense_attention(q, k, v, True, sel, return_lse=True)[1]
+    if selecting:
+        lse = flash_attention(q, k, v, causal=True, selection=sel,
+                              return_lse=True, **GROUP_BLOCKS)[1]
+    else:
+        flat = lambda x: x.reshape(*x.shape[:2], -1)
+        lse = fa._fwd_pallas(
+            flat(q), flat(k), flat(v), heads=q.shape[2], hpb=1, suffix="",
+            causal=True, group=group, **GROUP_BLOCKS)[1][:, :, 0, :t]
+    _close(lse, want)
+
+
+def test_a_cleared_flag_skips_the_walk_for_every_head_of_the_group():
+    """A grid block whose flag is 0 runs no walk in any of the three kernels,
+    for each head that shares it: with the flag of a block that does hold
+    selected pairs cleared by hand, the kernels agree with the dense path
+    over a selection that lacks those pairs; and a block the selection
+    itself leaves empty is flagged so."""
+    from distributed_tpu.ops import flash_attention as fa
+
+    group, t = 4, 256
+    q, k, v, w = _grouped_qkv(group, t)
+    sel = _spread_selection(t).at[0, 128:192, :128].set(0)
+    flags, _ = fa.selection_blocks(sel, 64, 128)
+    assert not flags[0, 2, 0] and flags[0, 3, 0] and flags[1, 3, 0]
+    flags = flags.at[1, 3, 0].set(0)
+    without = sel.at[1, 192:, :128].set(0)
+    flash = lambda q, k, v: jnp.sum(w * flash_attention(
+        q, k, v, causal=True, selection=sel, selection_flags=flags,
+        **GROUP_BLOCKS))
+    dense = lambda q, k, v: jnp.sum(w * fa.dense_attention(
+        q, k, v, True, without))
+    _close(jax.value_and_grad(flash, (0, 1, 2))(q, k, v),
+           jax.value_and_grad(dense, (0, 1, 2))(q, k, v))
+
+
+def _kernel_specs(heads, kv_heads, selecting, t=256, d=128, value_dim=None,
+                  blocks=GROUP_BLOCKS):
+    """{kernel name: (grid, [(block shape, {grid step: block index})])} of
+    the three ``pallas_call``s in the gradient of a causal call at B = 2,
+    every operand and result in the call's order, the steps in the grid's."""
+    shape = lambda h, w: jax.ShapeDtypeStruct((2, t, h, w), jnp.float32)
+    sel = jax.ShapeDtypeStruct((2, t, t), jnp.int8) if selecting else None
+    loss = lambda q, k, v, sel: jnp.sum(flash_attention(
+        q, k, v, causal=True, selection=sel, **blocks))
+    jaxpr = jax.make_jaxpr(jax.grad(loss, (0, 1, 2)))(
+        shape(heads, d), shape(kv_heads, d),
+        shape(kv_heads, value_dim or d), sel)
+    out = {}
+    for eqn in _eqns(jaxpr.jaxpr):
+        if eqn.primitive.name != "pallas_call":
+            continue
+        mapping = eqn.params["grid_mapping"]
+        flags = [np.zeros(1, np.int32)] * mapping.num_index_operands
+        steps = list(np.ndindex(*mapping.grid))
+        out[eqn.params["name"]] = (mapping.grid, [
+            (tuple(dim.block_size for dim in bm.block_shape),
+             {g: tuple(int(i) for i in jax.core.eval_jaxpr(
+                 bm.index_map_jaxpr.jaxpr, bm.index_map_jaxpr.consts,
+                 *g, *flags)) for g in steps})
+            for bm in mapping.block_mappings])
+    return out
+
+
+@pytest.mark.parametrize("heads,d,value_dim,selecting,suffix", [
+    (2, 128, 128, True, "_sel"),    # one head a block, with a selection
+    (2, 128, 128, False, "_packed"),
+    (4, 64, 64, False, "_packed"),  # two heads a block
+    (2, 192, 128, False, ""),       # folded: b = B * H, 256 and 128 lanes
+])
+def test_one_head_a_kv_head_keeps_the_grids_and_specs(
+        heads, d, value_dim, selecting, suffix):
+    """Without grouped queries the three calls get the (b, head block, i, j)
+    grids, the one-head-block specs and the index maps they always had."""
+    specs = _kernel_specs(heads, heads, selecting, d=d, value_dim=value_dim)
+    b, nh = (4, 1) if suffix == "" else (2, heads * d // 128)
+    w, wv = (256, 128) if suffix == "" else (128, 128)
+    q_at = lambda b, h, qi, ki: (b, qi, h)
+    kv_at = lambda b, h, qi, ki: (b, ki, h)
+    stat_at = lambda b, h, qi, ki: (b, h, 0, qi)
+    blocks = {"q": ((1, 64, w), q_at), "o": ((1, 64, wv), q_at),
+              "k": ((1, 128, w), kv_at), "v": ((1, 128, wv), kv_at),
+              "stat": ((1, 1, 128 // max(d, 64) if suffix else 1, 64),
+                       stat_at)}
+    operands = {"fwd": "q k v o stat", "dq": "q k v o stat stat q",
+                "dkv": "q k v o stat stat k v"}
+    for kernel, names in operands.items():
+        grid, got = specs[f"dtpu_flash_{kernel}{suffix}"]
+        dkv = kernel == "dkv"
+        assert grid == (b, nh, *((2, 4) if dkv else (4, 2)))
+        want = [blocks[n] for n in names.split()]
+        if selecting:  # after the inputs; transposed for dk/dv
+            want.insert(3 if kernel == "fwd" else 6, (
+                (1, 128, 64) if dkv else (1, 64, 128),
+                lambda b, h, qi, ki: (b, ki, qi) if dkv else (b, qi, ki)))
+        assert [shape for shape, _ in got] == [shape for shape, _ in want]
+        for (_, table), (_, at) in zip(got, want):
+            assert table == {
+                g: at(g[0], g[1], *(g[:1:-1] if dkv else g[2:]))
+                for g in table}
+
+
+def _changes(table):
+    """How often a block's index changes over a grid walked in order: the
+    copies the pipeline makes of it."""
+    seen = list(table.values())
+    return 1 + sum(a != b for a, b in zip(seen, seen[1:]))
+
+
+@pytest.mark.parametrize("group", [1, 2, 4])
+def test_kv_blocks_are_fetched_once_a_kv_head(group):
+    """``kv_block_fetches`` against the calls' own index maps: walking each
+    grid in order, the forward's and dq's K, V and selection blocks, and
+    dk/dv's selection block, change once for all the heads of a group."""
+    from distributed_tpu.ops.flash_attention import kv_block_fetches
+
+    specs = _kernel_specs(2 * group, 2, True)
+    fetches, a_head = kv_block_fetches(2, 2 * group, group, 4, 2)
+    assert (fetches, a_head) == (2 * 2 * 4 * 2, 2 * 2 * group * 4 * 2)
+    for kernel, sel_at in (("fwd", 3), ("dq", 6)):
+        _, blocks = specs[f"dtpu_flash_{kernel}_sel"]
+        assert [_changes(blocks[i][1]) for i in (1, 2, sel_at)] == [fetches] * 3
+    _, blocks = specs["dtpu_flash_dkv_sel"]
+    assert _changes(blocks[6][1]) == fetches  # the selection, transposed
+    assert _changes(blocks[1][1]) == 2 * 2 * 2  # K: once a K/V head and block
+
+
+@pytest.mark.parametrize("group", [1, 4])
+def test_a_single_kv_block_is_fetched_once_a_kv_head(group):
+    """One kv block and four q blocks: K and V never move under a K/V head,
+    which is what ``kv_block_fetches`` counts; the selection's block moves
+    with the q block, four times as often, and once for a group's heads."""
+    from distributed_tpu.ops.flash_attention import kv_block_fetches
+
+    specs = _kernel_specs(2 * group, 2, True,
+                          blocks=dict(block_q=64, block_k=256))
+    fetches, a_head = kv_block_fetches(2, 2 * group, group, 4, 1)
+    assert (fetches, a_head) == (2 * 2, 2 * 2 * group)
+    for kernel, sel_at in (("fwd", 3), ("dq", 6)):
+        grid, blocks = specs[f"dtpu_flash_{kernel}_sel"]
+        assert grid == ((2, 2, 4, 1, 4) if group > 1 else (2, 2, 4, 1))
+        assert [_changes(blocks[i][1]) for i in (1, 2)] == [fetches] * 2
+        assert _changes(blocks[sel_at][1]) == 4 * fetches
+
+
+# --------------------------------------------- fewer heads a fetch than all --
+@pytest.fixture
+def vmem(monkeypatch):
+    """Set what ``_heads_a_fetch`` takes a kernel's VMEM to be, with no
+    function traced under another budget left in ``_flash_cached``."""
+    from distributed_tpu.ops import flash_attention as fa
+
+    fa._flash_cached.cache_clear()
+    yield lambda n: monkeypatch.setattr(fa, "_VMEM", n)
+    fa._flash_cached.cache_clear()
+
+
+@pytest.mark.parametrize("group,block_q,block_k,itemsize,share", [
+    (8, 512, 1024, 2, 8),    # keye-vl2-30b.train.dsa8k: the whole group
+    (8, 1024, 1024, 2, 2),   # its layer at T <= 2048, bf16: block_q 1024
+    (8, 512, 1024, 4, 4),    # float32 inputs
+    (16, 512, 1024, 2, 8),   # half of a group of sixteen
+    (32, 512, 1024, 4, 4),   # multi-query attention, float32
+    (6, 512, 1024, 2, 6), (6, 1024, 1024, 2, 2), (7, 1024, 1024, 2, 1),
+    (8, 512, 2048, 2, 4), (8, 256, 1024, 2, 8), (1, 1024, 1024, 4, 1),
+])
+def test_heads_a_fetch_follow_the_scoped_vmem(group, block_q, block_k,
+                                              itemsize, share):
+    """As many of a group's heads walk on one fetch as the v5e's 16 MiB hold
+    of their q-side blocks and scratch: a divisor of the group, down to one
+    (``test_flash_v5e_compile.py`` compiles these shapes for the chip)."""
+    from distributed_tpu.ops.flash_attention import _heads_a_fetch
+
+    assert _heads_a_fetch(group, block_q, block_k, itemsize) == share
+
+
+@pytest.mark.parametrize("selecting", [True, False])
+@pytest.mark.parametrize("budget,share", [(1_000_000, 2), (500_000, 1)])
+def test_fewer_heads_a_fetch_match_dense_attention(budget, share, selecting,
+                                                   vmem):
+    """A group of four with room for two heads a fetch, and for one (the
+    plain grid, each head reading K/V block h // group): the grids say so,
+    and values, row statistic and gradients are the dense path's."""
+    from distributed_tpu.ops import flash_attention as fa
+
+    vmem(budget)
+    group, t = 4, 200
+    suffix = "_sel" if selecting else "_packed"
+    specs = _kernel_specs(2 * group, 2, selecting)
+    for kernel in ("fwd", "dq"):
+        grid, blocks = specs[f"dtpu_flash_{kernel}{suffix}"]
+        assert grid == ((2, 4, 4, 2, 2) if share == 2 else (2, 8, 4, 2))
+        assert blocks[0][0] == (1, 64, share * 128)
+        assert _changes(blocks[1][1]) == fa.kv_block_fetches(
+            2, 8, share, 4, 2)[0]
+    assert specs[f"dtpu_flash_dkv{suffix}"][0] == (2, 2, 2, 16)
+    q, k, v, w = _grouped_qkv(group, t)
+    sel = _spread_selection(t) if selecting else None
+    flash = lambda q, k, v: jnp.sum(w * flash_attention(
+        q, k, v, causal=True, selection=sel, **GROUP_BLOCKS))
+    dense = lambda q, k, v: jnp.sum(w * fa.dense_attention(
+        q, k, v, True, sel))
+    _close(jax.value_and_grad(flash, (0, 1, 2))(q, k, v),
+           jax.value_and_grad(dense, (0, 1, 2))(q, k, v))
+    if selecting:
+        _close(flash_attention(q, k, v, causal=True, selection=sel,
+                               return_lse=True, **GROUP_BLOCKS)[1],
+               fa.dense_attention(q, k, v, True, sel, return_lse=True)[1])
+
+
+def test_kv_block_fetches_at_the_selecting_cells_shape():
+    """keye-vl2-30b.train.dsa8k: 32 query heads over 4 K/V heads, 16 x 8 grid
+    blocks: 512 fetches where one a query head makes 4,096, published as
+    gauges at trace time; the same number twice with no grouped queries."""
+    from distributed_tpu import obs
+    from distributed_tpu.ops.flash_attention import kv_block_fetches
+
+    assert kv_block_fetches(1, 32, 8, 16, 8) == (512, 4096)
+    assert kv_block_fetches(1, 32, 1, 16, 8) == (4096, 4096)
+    assert kv_block_fetches(8, 8, 1, 1, 1) == (64, 64)  # one block: it stays
+    shape = lambda h: jax.ShapeDtypeStruct((1, 8192, h, 128), jnp.bfloat16)
+    sel = jax.ShapeDtypeStruct((1, 8192, 8192), jnp.int8)
+    attend = lambda q, k, v, sel: flash_attention(
+        q, k, v, causal=True, selection=sel)
+    reg = obs.default_registry()
+    names = ("flash.kv_block_fetches", "flash.kv_block_fetches_a_head")
+    jax.eval_shape(attend, shape(32), shape(4), shape(4), sel)
+    assert [reg.gauge_value(n) for n in names] == [512.0, 4096.0]
+    jax.eval_shape(attend, shape(32), shape(32), shape(32), None)
+    assert [reg.gauge_value(n) for n in names] == [4096.0, 4096.0]
+
+
+# ------------------------------------------- calls with no shared K/V head --
+# The digest of str(make_jaxpr(grad)) at the parent of PR 37 (commit fb5b43e,
+# JAX 0.9.0, the interpreter), addresses blanked: the three kernels' bodies,
+# grids and index maps of the calls that share no K/V head in place, at the
+# four cells that make them (both GPT-2 cells' layout, kanana's folded
+# widths, LFM2's repeated 64-wide heads) and at 128-wide heads with one K/V
+# head a query head, with a selection and without.
+PARENT_JAX = "0.9.0"
+PARENT_JAXPRS = {
+    "packed64": ((8, 1024, 16, 16, 64, 64, False),
+                 "c89acd0a5c7f8381d2fd77fb489042fab9071119fab6d844b3d53fc603d4ba4e"),
+    "packed64_long": ((1, 4096, 16, 16, 64, 64, False),
+                      "b4e7ff96060b941408c9a895bf954b8c11b0bfde1b6b72157b508d12677723d6"),
+    "folded": ((1, 4096, 32, 32, 192, 128, False),
+               "420a22c491223d568f3d523f2ff850254d3bebbcd9d5d5d87cca426cc27531a7"),
+    "repeated64": ((1, 8192, 32, 8, 64, 64, False),
+                   "43955745a889a1527e994b1120367fc807ef2de499980228a029653821a0458c"),
+    "sel_128": ((1, 2048, 4, 4, 128, 128, True),
+                "8840d978165b0f0e5d827c8b8395cff36854862e719188f44e53f89591379f79"),
+    "packed_128": ((1, 2048, 4, 4, 128, 128, False),
+                   "1398f70fe554d646d20f8ea87e07fa5231a5daab181f5458836ec54cd8ed6328"),
+}
+
+
+@pytest.mark.parametrize("case", list(PARENT_JAXPRS))
+def test_calls_that_share_no_kv_head_in_place_trace_as_before(case):
+    """With one K/V head a query head (or K and V repeated) the gradient's
+    jaxpr, kernels' bodies and index maps included, is the parent's text."""
+    import hashlib
+    import re
+
+    (b, t, h, kv, d, dv, selecting), digest = PARENT_JAXPRS[case]
+    if jax.__version__ != PARENT_JAX:
+        pytest.skip(f"the parent's digests were taken under JAX {PARENT_JAX}")
+    shape = lambda heads, width: jax.ShapeDtypeStruct(
+        (b, t, heads, width), jnp.bfloat16)
+    sel = jax.ShapeDtypeStruct((b, t, t), jnp.int8) if selecting else None
+    loss = lambda q, k, v, sel: jnp.sum(flash_attention(
+        q, k, v, causal=True, selection=sel).astype(jnp.float32))
+    text = str(jax.make_jaxpr(jax.grad(loss, (0, 1, 2)))(
+        shape(h, d), shape(kv, d), shape(kv, dv), sel))
+    text = re.sub(r"0x[0-9a-f]+", "0x", text)
+    assert hashlib.sha256(text.encode()).hexdigest() == digest

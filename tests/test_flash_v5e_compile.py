@@ -101,18 +101,30 @@ def test_folded_flash_grad_at_latent_attentions_widths_compiles_for_v5e(
     assert "_packed" not in text
 
 
-@pytest.mark.parametrize("kv_heads,selecting", [(4, True), (4, False)])
-def test_grouped_query_flash_grad_compiles_for_v5e(kv_heads, selecting,
-                                                   one_chip, mosaic):
+@pytest.mark.parametrize("t,kv_heads,dtype,selecting", [
+    (8192, 4, jnp.bfloat16, True), (8192, 4, jnp.bfloat16, False),
+    # The cell's layer off the cell's shape, at the blocks a call resolves
+    # to: block_q 1024 at T <= 2048 under bf16, float32 inputs, a group of
+    # sixteen, and one K/V head for all 32 (multi-query attention).
+    (1024, 4, jnp.bfloat16, True), (2048, 4, jnp.bfloat16, True),
+    (2048, 4, jnp.bfloat16, False), (8192, 4, jnp.float32, True),
+    (8192, 2, jnp.bfloat16, True), (2048, 1, jnp.float32, False),
+])
+def test_grouped_query_flash_grad_compiles_for_v5e(t, kv_heads, dtype,
+                                                   selecting, one_chip,
+                                                   mosaic):
     """keye-vl2-30b.train.dsa8k: (1, 8192, 32) query heads over 4 K/V heads
     of 128, block_q 512, block_k 1024, with the int8 selection operand (its
     block converted and and-ed with the masks in VMEM, its flags in scalar
-    memory) and without; dk/dv walk a group's eight query heads a K/V head."""
+    memory) and without; dk/dv walk a group's eight query heads a K/V head,
+    and the forward and dq as many of them on one fetch of K, V and the
+    selection as the 16 MB a kernel may use hold of their q-side blocks and
+    scratch (``_heads_a_fetch``: all eight in the cell)."""
     spec = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
                                                      sharding=one_chip)
-    q = spec((1, 8192, 32, 128), jnp.bfloat16)
-    kv = spec((1, 8192, kv_heads, 128), jnp.bfloat16)
-    sel = spec((1, 8192, 8192), jnp.int8) if selecting else None
+    q = spec((1, t, 32, 128), dtype)
+    kv = spec((1, t, kv_heads, 128), dtype)
+    sel = spec((1, t, t), jnp.int8) if selecting else None
 
     def loss(q, k, v, sel):
         out = fa.flash_attention(q, k, v, causal=True, selection=sel)
