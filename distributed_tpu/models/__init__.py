@@ -2,6 +2,7 @@ from .cnn import cifar_cnn, mnist_cnn
 from .resnet import resnet, resnet18, resnet34, resnet50
 from .transformer import (
     deepseek_v3_lm,
+    laguna_lm,
     lfm2_moe_lm,
     qwen3_moe_lm,
     transformer_block,
@@ -21,6 +22,7 @@ __all__ = [
     "deepseek_v3_lm",
     "qwen3_moe_lm",
     "lfm2_moe_lm",
+    "laguna_lm",
     "vit",
     "vit_tiny",
     "vit_small",

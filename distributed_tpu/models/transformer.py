@@ -378,3 +378,98 @@ def lfm2_moe_lm(
         blocks.append((mixer, ffn))
     return _rms_norm_lm("lfm2_moe_lm", vocab_size, d_model, blocks, epsilon,
                         dtype, tie_embeddings=tie_embeddings)
+
+
+ATTENTION_TYPES = ("full_attention", "sliding_attention")
+MLP_TYPES = ("dense", "sparse")
+
+
+def laguna_lm(
+    vocab_size: int,
+    *,
+    layer_types,
+    mlp_layer_types,
+    num_heads_per_layer,
+    d_model: int,
+    num_kv_heads: int,
+    head_dim: int,
+    d_ff: int,
+    num_experts: int,
+    top_k: int,
+    moe_hidden: int,
+    shared_hidden: int,
+    sliding_window: int,
+    rope_parameters: dict,
+    experts_held: Optional[int] = None,
+    expert_offset: int = 0,
+    routed_scaling: float = 1.0,
+    bias_update_rate: float = 0.0,
+    record_choice: bool = False,
+    gate: bool = True,
+    epsilon: float = 1e-6,
+    embedding_std: float = 0.02,
+    flash="auto",
+    dtype=None,
+) -> nn.Sequential:
+    """Laguna's block as a token-in, logits-out LM (poolside's Laguna-XS.2
+    is one; ``model_type: laguna``): a stack whose attention layers are of
+    two kinds with unlike head counts. ``layer_types`` names each layer's
+    attention, ``"sliding_attention"`` (query t sees the last
+    ``sliding_window`` keys) or ``"full_attention"``;
+    ``num_heads_per_layer`` gives each layer's query heads over
+    ``num_kv_heads`` K/V heads of ``head_dim``; ``rope_parameters`` holds,
+    for each of the two kinds, its ``rope_theta``, its
+    ``partial_rotary_factor`` (the share of a head's dimensions rotated, the
+    first ones) and its ``rope_type`` with, under ``"yarn"``, YaRN's
+    parameters (``nn.GroupedQueryAttention``'s ``rope_scaling``). Every
+    attention layer norms q and k a head and, with ``gate``, gates each
+    head's output from the block's normed input. ``mlp_layer_types`` names
+    each layer's MLP independently: ``"dense"``, a gated SiLU MLP of
+    ``d_ff``, or ``"sparse"``, ``nn.DroplessMoE``: sigmoid scores over
+    ``num_experts`` experts of ``moe_hidden``, ``top_k`` a token, gates
+    normalised over the chosen times ``routed_scaling``, plus one shared
+    gated MLP of ``shared_hidden`` on every token; ``bias_update_rate`` 0
+    leaves the selection bias at zeros (no balancing is published).
+    ``experts_held`` / ``expert_offset`` are this chip's share of every
+    expert layer and ``record_choice`` keeps each expert layer's last
+    choices in its state, as in ``deepseek_v3_lm``, with which the block
+    assembly is shared. Pre-RMSNorm residuals, a final RMSNorm and an
+    untied, bias-free head; ``embedding_std`` as in ``qwen3_moe_lm``.
+    Training and full forward passes only."""
+    layer_types, mlp_layer_types = tuple(layer_types), tuple(mlp_layer_types)
+    heads = tuple(num_heads_per_layer)
+    unknown = sorted(set(layer_types) - set(ATTENTION_TYPES)) + sorted(
+        set(mlp_layer_types) - set(MLP_TYPES))
+    if unknown:
+        raise ValueError(
+            f"layer_types may hold {ATTENTION_TYPES} and mlp_layer_types "
+            f"{MLP_TYPES}, got {unknown}")
+    if not len(layer_types) == len(mlp_layer_types) == len(heads):
+        raise ValueError(
+            "layer_types, mlp_layer_types and num_heads_per_layer name "
+            f"{len(layer_types)}, {len(mlp_layer_types)} and {len(heads)} "
+            "layers")
+    blocks = []
+    for kind, mlp, h in zip(layer_types, mlp_layer_types, heads):
+        rope = rope_parameters[kind]
+        mixer = nn.GroupedQueryAttention(
+            h, num_kv_heads, head_dim,
+            rope_theta=float(rope["rope_theta"]), epsilon=epsilon,
+            window=sliding_window if kind == "sliding_attention" else None,
+            rotary_dim=int(head_dim * rope.get("partial_rotary_factor", 1)),
+            rope_scaling=rope, gate=gate, flash=flash, dtype=dtype)
+        if mlp == "dense":
+            ffn = nn.GatedMLP(d_ff, dtype=dtype)
+        else:
+            ffn = nn.DroplessMoE(
+                num_experts, moe_hidden, top_k=top_k,
+                experts_held=experts_held, expert_offset=expert_offset,
+                shared_hidden_dim=shared_hidden,
+                routed_scaling=routed_scaling,
+                bias_update_rate=bias_update_rate,
+                record_choice=record_choice, dtype=dtype)
+        blocks.append((mixer, ffn))
+    default_registry().gauge("model.layers_sliding",
+                             layer_types.count("sliding_attention"))
+    return _rms_norm_lm("laguna_lm", vocab_size, d_model, blocks, epsilon,
+                        dtype, embedding_std)

@@ -23,6 +23,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from . import initializers
 from .core import Layer, Shape, child_scope, read_counters
@@ -721,6 +722,54 @@ def rope_half(x, theta: float):
     return (xf * cos + turned * sin).astype(x.dtype)
 
 
+def yarn_inv_freq(dim: int, theta: float, *, factor: float,
+                  original_max_position: int, beta_fast: float = 32.0,
+                  beta_slow: float = 1.0, truncate: bool = True):
+    """YaRN's inverse frequencies (Peng et al. 2023, arXiv:2309.00071) of a
+    rotation over ``dim`` dimensions, (dim / 2,) float32, as Hugging Face
+    ``transformers`` computes them (``modeling_rope_utils.py:
+    _compute_yarn_parameters``): a pair that turns more than ``beta_fast``
+    times over the ``original_max_position`` positions keeps its frequency
+    theta^(-2i/dim), one that turns fewer than ``beta_slow`` times has it
+    divided by ``factor`` (its positions interpolated), and between the
+    two, by the pair's index, a linear ramp blends them. Static for a
+    layer: NumPy."""
+    def correction_dim(rotations):
+        return dim * math.log(original_max_position / (
+            rotations * 2 * math.pi)) / (2 * math.log(theta))
+
+    low, high = correction_dim(beta_fast), correction_dim(beta_slow)
+    if truncate:
+        low, high = math.floor(low), math.ceil(high)
+    low, high = max(low, 0), min(high, dim - 1)
+    if low == high:
+        high += 0.001  # no singularity
+    pos_freqs = np.float32(theta) ** (
+        np.arange(0, dim, 2, dtype=np.float32) / np.float32(dim))
+    ramp = np.clip((np.arange(dim // 2, dtype=np.float32) - low)
+                   / np.float32(high - low), 0, 1)  # the interpolated share
+    return ((1.0 / (factor * pos_freqs)) * ramp
+            + (1.0 / pos_freqs) * (1 - ramp)).astype(np.float32)
+
+
+def rope_rotary(x, inv_freq, scale: float = 1.0):
+    """``rope_half`` with given frequencies on the first ``2 *
+    len(inv_freq)`` dimensions of the last axis of ``x`` (B, T, H, d), the
+    rest passed through (a partial rotation), and cos and sin times
+    ``scale`` (YaRN's attention factor: it scales the rotated dimensions
+    alone). Float32 inside, ``x``'s dtype out."""
+    t, r = x.shape[1], 2 * len(inv_freq)
+    angle = jnp.arange(t, dtype=jnp.float32)[:, None] * jnp.asarray(
+        inv_freq, jnp.float32)[None]
+    cos = (jnp.tile(jnp.cos(angle), 2) * scale)[None, :, None, :]
+    sin = (jnp.tile(jnp.sin(angle), 2) * scale)[None, :, None, :]
+    xf = x.astype(jnp.float32)
+    rot = xf[..., :r]
+    turned = jnp.concatenate([-rot[..., r // 2:], rot[..., :r // 2]], axis=-1)
+    return jnp.concatenate(
+        [rot * cos + turned * sin, xf[..., r:]], axis=-1).astype(x.dtype)
+
+
 def _rms(x, scale, epsilon):
     xf = x.astype(jnp.float32)
     ms = jnp.mean(jnp.square(xf), axis=-1, keepdims=True)
@@ -874,20 +923,40 @@ index_loss.defvjp(_index_loss_fwd, _index_loss_bwd)
 # Names of a selecting GroupedQueryAttention's counters in its state.
 _SELECT_COUNTERS = ("steps", "queries", "causal_pairs", "selected_pairs",
                     "blocks_total", "blocks_computed")
+_WINDOW_COUNTERS = ("steps", "queries", "causal_pairs", "window_pairs",
+                    "walked_pairs")
 
 
 class GroupedQueryAttention(Layer):
     """Causal grouped-query self-attention over (B, T, D) inputs with RoPE
-    and per-head RMSNorm on queries and keys (Qwen3's), and optionally a
-    learned selection of the keys each query sees (DeepSeek-V3.2-Exp's
-    lightning indexer), for training and full forward passes:
+    and per-head RMSNorm on queries and keys, for training and full forward
+    passes; optionally a learned selection of the keys each query sees
+    (DeepSeek-V3.2-Exp's lightning indexer), a sliding window, a partial or
+    YaRN-scaled rotation and a head-wise output gate:
 
         q = rope(RMSNorm_hd(x Wq))      (T, H, hd)
         k = rope(RMSNorm_hd(x Wk))      (T, Hkv, hd)     v = x Wv
         out = softmax(q k^T / sqrt(hd), over the keys seen) v  Wo
 
     Query head h reads K/V head h // (H / Hkv); RoPE is the half-split
-    rotation at ``rope_theta``, from position 0; no bias.
+    rotation at ``rope_theta``, from position 0; no bias. Three families'
+    layer: Qwen3-MoE's with an indexer (Keye-VL-2.0: ``index_topk``,
+    ``index_heads``, ``index_dim``, ``record_selection``), LFM2-MoE's
+    attention layers (no optional argument) and Laguna's (``window`` in the
+    sliding layers; ``rotary_dim`` and ``rope_scaling`` in the full ones;
+    ``gate`` in both).
+
+    ``window``: query t sees the keys t - window < s <= t (the flash
+    kernels' ``window``; ``dtpu_flash_*_swa``). ``rotary_dim``: the first
+    that many of a head's dimensions are rotated and the rest passed
+    through. ``rope_scaling``: a dict with ``rope_type`` ``"yarn"`` and
+    YaRN's ``factor``, ``original_max_position_embeddings``, ``beta_fast``,
+    ``beta_slow`` and ``attention_factor`` (default 0.1 ln(factor) + 1): the
+    frequencies of ``yarn_inv_freq`` and cos and sin times the factor.
+    ``gate``: one gate a head and token from the layer's input, on the
+    head's output before ``Wo``:
+
+        out = (softmax(...) v * sigmoid(x Wg)[..., None]) Wo     Wg (D, H)
 
     With ``index_topk`` the layer carries an indexer of ``index_heads``
     heads of ``index_dim`` and one key head, and query t sees the
@@ -911,8 +980,16 @@ class GroupedQueryAttention(Layer):
 
     Device scopes under the layer's own (which starts with
     ``multi_head_attention``, so ``benchmarks/scopes.py`` sorts all of it
-    under attention): ``indexer`` (its projections, the scores, ``L_I`` and
-    its gradient) and, inside it, ``select``. Counters, cumulative over
+    under attention; ``multi_head_attention_swa`` with a window, else
+    ``multi_head_attention_gqa``): ``indexer`` (its projections, the scores,
+    ``L_I`` and its gradient) and, inside it, ``select``; ``gate`` (the
+    gate's projection, its sigmoid and its product with the heads'
+    outputs). A windowed layer counts in its state, cumulative over train
+    steps (``window_counters``): ``steps``, ``queries``, ``causal_pairs``
+    (pairs s <= t), ``window_pairs`` (those inside the window) and
+    ``walked_pairs``, the pairs of the sub-tiles the windowed kernels
+    computed (``flash_attention.subtile_counts``; 0 on the dense path).
+    A selecting layer's counters, cumulative over
     train steps, in the layer's state (``select_counters``): ``steps``,
     ``queries``, ``causal_pairs`` (pairs s <= t), ``selected_pairs``, and the
     flash kernels' grid blocks at or below the diagonal, ``blocks_total``,
@@ -932,7 +1009,11 @@ class GroupedQueryAttention(Layer):
                  rope_theta: float = 10000.0, epsilon: float = 1e-6,
                  index_topk: Optional[int] = None, index_heads: int = 16,
                  index_dim: int = 64,
-                 record_selection: bool = False, dtype=None, flash="auto",
+                 record_selection: bool = False,
+                 window: Optional[int] = None,
+                 rotary_dim: Optional[int] = None,
+                 rope_scaling: Optional[dict] = None, gate: bool = False,
+                 dtype=None, flash="auto",
                  name: Optional[str] = None):
         super().__init__(name)
         self.num_heads = int(num_heads)
@@ -948,16 +1029,54 @@ class GroupedQueryAttention(Layer):
         self.index_heads = int(index_heads)
         self.index_dim = int(index_dim)
         self.record_selection = bool(record_selection) and bool(index_topk)
+        self.window = int(window) if window else None
+        if self.window and self.index_topk:
+            raise ValueError("a layer takes a window or an indexer, not both")
+        self.rotary_dim = int(rotary_dim or head_dim)
+        if not 0 < self.rotary_dim <= self.head_dim or self.rotary_dim % 2:
+            raise ValueError(
+                f"rotary_dim {rotary_dim} is no even width within the "
+                f"head's {head_dim}")
+        # The rotation's frequencies and the factor on cos and sin, where
+        # they are not ``rope_half``'s own (None: that function as it is).
+        self.rotation = None
+        if rope_scaling and rope_scaling.get("rope_type") != "default":
+            if rope_scaling.get("rope_type") != "yarn":
+                raise ValueError(
+                    f"rope_scaling of type {rope_scaling.get('rope_type')!r}"
+                    "; 'yarn' and 'default' are what the layer computes")
+            factor = float(rope_scaling["factor"])
+            scale = rope_scaling.get("attention_factor")
+            self.rotation = (yarn_inv_freq(
+                self.rotary_dim, self.rope_theta, factor=factor,
+                original_max_position=int(
+                    rope_scaling["original_max_position_embeddings"]),
+                beta_fast=float(rope_scaling.get("beta_fast") or 32),
+                beta_slow=float(rope_scaling.get("beta_slow") or 1),
+                truncate=bool(rope_scaling.get("truncate", True))),
+                float(scale) if scale else (
+                    0.1 * math.log(factor) + 1.0 if factor > 1 else 1.0))
+        elif self.rotary_dim < self.head_dim:
+            r = self.rotary_dim
+            self.rotation = (1.0 / np.float32(self.rope_theta) ** (
+                np.arange(0, r, 2, dtype=np.float32) / np.float32(r)), 1.0)
+        self.gate = bool(gate)
         self.dtype = dtype
         self.flash = flash
 
     def default_name(self) -> str:
-        return "multi_head_attention_gqa"
+        return ("multi_head_attention_swa" if self.window
+                else "multi_head_attention_gqa")
+
+    def _rope(self, x):
+        if self.rotation is None:
+            return rope_half(x, self.rope_theta)
+        return rope_rotary(x, *self.rotation)
 
     def init(self, key, input_shape: Shape):
         t, d = input_shape[-2], input_shape[-1]
         h, g, hd = self.num_heads, self.num_kv_heads, self.head_dim
-        keys = jax.random.split(key, 7)
+        keys = jax.random.split(key, 8 if self.gate else 7)
         init = initializers.get("glorot_uniform")
         ones = lambda n: {"scale": jnp.ones((n,), jnp.float32)}
         params = {
@@ -968,6 +1087,10 @@ class GroupedQueryAttention(Layer):
             "q_norm": ones(hd), "k_norm": ones(hd),
         }
         state = {}
+        if self.gate:
+            params["wg"] = init(keys[7], (d, h), jnp.float32)
+        if self.window:
+            state = {c: jnp.float32(0.0) for c in _WINDOW_COUNTERS}
         if self.index_topk:
             j, di = self.index_heads, self.index_dim
             params["indexer"] = {
@@ -984,7 +1107,8 @@ class GroupedQueryAttention(Layer):
         return params, state, tuple(input_shape)
 
     def sharding_hints(self):
-        return {"wq": "col", "wk": "col", "wv": "col", "wo": "row"}
+        hints = {"wq": "col", "wk": "col", "wv": "col", "wo": "row"}
+        return dict(hints, wg="col") if self.gate else hints
 
     _use_flash = MultiHeadAttention._use_flash
 
@@ -1026,7 +1150,7 @@ class GroupedQueryAttention(Layer):
             q = _rms(q, params["q_norm"]["scale"], self.epsilon)
         with child_scope("k_norm"):
             k = _rms(k, params["k_norm"]["scale"], self.epsilon)
-        q, k = rope_half(q, self.rope_theta), rope_half(k, self.rope_theta)
+        q, k = self._rope(q), self._rope(k)
         selection = None
         blocks = (jnp.float32(0.0), 0)
         if self.index_topk:
@@ -1036,7 +1160,16 @@ class GroupedQueryAttention(Layer):
                                         block=INDEX_BLOCK)
         flash = self._use_flash(t) and ambient_mesh()[0] is None
         kernels = flash and hd == 128  # what takes a selection in Mosaic
-        if selection is None:
+        walked = 0  # pairs of the sub-tiles the windowed kernels compute
+        if self.window:
+            if flash:
+                ctx = fa.flash_attention(q, k, v, causal=True,
+                                         window=self.window)
+                walked = fa.walked_pairs(
+                    t, h, hd, jnp.dtype(q.dtype).itemsize, self.window)
+            else:
+                ctx = fa.dense_attention(q, k, v, True, window=self.window)
+        elif selection is None:
             ctx = (fa.flash_attention(q, k, v, causal=True) if flash
                    else fa.dense_attention(q, k, v, True))
         elif kernels:
@@ -1049,7 +1182,22 @@ class GroupedQueryAttention(Layer):
         else:
             ctx, lse = fa.dense_attention(q, k, v, True, selection,
                                           return_lse=True)
+        if self.gate:
+            with child_scope("gate"):
+                g = jax.nn.sigmoid(proj(x, "wg").astype(jnp.float32))
+                ctx = (ctx.astype(jnp.float32) * g[..., None]).astype(
+                    ctx.dtype)
         out = proj(ctx.reshape(b, t, h * hd), "wo")
+        if train and self.window:
+            w = min(self.window, t)
+            return out, dict(
+                steps=state["steps"] + 1.0,
+                queries=state["queries"] + float(b * t),
+                causal_pairs=state["causal_pairs"] + float(
+                    b * t * (t + 1) // 2),
+                window_pairs=state["window_pairs"] + float(
+                    b * (w * (w + 1) // 2 + (t - w) * w)),
+                walked_pairs=state["walked_pairs"] + float(b * walked))
         if not (train and self.index_topk):
             return out, {}
         with child_scope("indexer"):
@@ -1069,6 +1217,13 @@ class GroupedQueryAttention(Layer):
                 new_state["selection"] = jnp.packbits(
                     selection[0].astype(bool), axis=-1)
         return out, new_state
+
+
+def window_counters(state) -> dict:
+    """``{layer path: {counter: value}}`` of every windowed
+    ``GroupedQueryAttention`` in a model's ``state`` tree
+    (``core.read_counters``)."""
+    return read_counters(state, _WINDOW_COUNTERS)
 
 
 def select_counters(state) -> dict:

@@ -118,12 +118,15 @@ from ._pallas_common import (
 
 
 # -------------------------------------------------- shared kernel helpers --
-def _valid_mask(row0, col0, rows, cols, t_actual, causal, kv_major=False):
+def _valid_mask(row0, col0, rows, cols, t_actual, causal, kv_major=False,
+                window=None):
     """(rows, cols) mask of the score tile whose first row and column are
     ``row0`` and ``col0``: real columns, and under causality the lower-
-    triangular band. ``kv_major``: of the transposed tile, (cols, rows). The
-    single source of truth for masking: the three kernels build it over a
-    run of sub-tiles the diagonal or the padding edge crosses."""
+    triangular band, ``window`` columns wide where one is given (row r sees
+    r - window < c <= r). ``kv_major``: of the transposed tile, (cols,
+    rows). The single source of truth for masking: the three kernels build
+    it over a run of sub-tiles the diagonal, the window's edge or the
+    padding edge crosses."""
     shape, r_ax, c_ax = ((cols, rows), 1, 0) if kv_major else (
         (rows, cols), 0, 1)
     col = col0 + jax.lax.broadcasted_iota(jnp.int32, shape, c_ax)
@@ -131,6 +134,8 @@ def _valid_mask(row0, col0, rows, cols, t_actual, causal, kv_major=False):
     if causal:
         row = row0 + jax.lax.broadcasted_iota(jnp.int32, shape, r_ax)
         valid = jnp.logical_and(valid, col <= row)
+        if window is not None:
+            valid = jnp.logical_and(valid, col > row - window)
     return valid
 
 
@@ -197,12 +202,14 @@ def _subtile(block: int, lanes: int = _LANES) -> int:
 _SKIPPED, _MASKED, _UNMASKED = range(3)
 
 
-def _tile_class(r0, rows, c0, cols, diag, edge):
+def _tile_class(r0, rows, c0, cols, diag, edge, window=None):
     """Class of the score sub-tile of ``rows`` x ``cols`` at (r0, c0). Row r
     sees the columns below lim(r): r + diag + 1 under causality (``diag``
     is the first row's distance below the first column's diagonal), else
-    ``edge``, the number of real columns (None: all). A sub-tile no row
-    sees any of is skipped; one every row sees all of needs no mask."""
+    ``edge``, the number of real columns (None: all); with a ``window``
+    (causal) it sees the last ``window`` of them only, from lim(r) - window
+    on. A sub-tile no row sees any of is skipped; one every row sees all of
+    needs no mask."""
     if diag is not None:
         lo, hi = r0 + diag + 1, r0 + rows + diag
     elif edge is not None:
@@ -211,6 +218,11 @@ def _tile_class(r0, rows, c0, cols, diag, edge):
         return _UNMASKED
     if c0 >= hi:
         return _SKIPPED
+    if window is not None and diag is not None:
+        if c0 + cols <= lo - window:  # left of the first row's window
+            return _SKIPPED
+        if c0 < hi - window:  # the last row's window starts inside it
+            return _MASKED
     return _UNMASKED if c0 + cols <= lo else _MASKED
 
 
@@ -236,14 +248,15 @@ def _segments(classes, size):
 _PASS_SCORES = 1024 * 1024
 
 
-def _passes(blocks, subs, heads, diag, edge, kv_major=False):
+def _passes(blocks, subs, heads, diag, edge, kv_major=False, window=None):
     """[(start, stop, segs)] down a grid block of ``blocks`` = (block_q,
     block_k) in sub-tiles of ``subs`` = (sub_q, sub_k): for each row of
     sub-tiles its ``_segments`` along the columns (``kv_major``: for each
     column, along the rows), with neighbouring rows whose segments are alike
     merged into one pass while ``_PASS_SCORES`` holds it with ``heads`` heads
     a block: a grid block the diagonal does not touch is one product as tall
-    as it is wide. ``segs`` is empty where nothing is to compute."""
+    as it is wide. ``segs`` is empty where nothing is to compute: above the
+    diagonal, in the padding, or left of the ``window``."""
     (block, lim), (sub, sub_lim) = (
         (blocks[::-1], subs[::-1]) if kv_major else (blocks, subs))
     most = _PASS_SCORES // (lim * min(heads, 2))  # alive: _head_groups
@@ -251,7 +264,7 @@ def _passes(blocks, subs, heads, diag, edge, kv_major=False):
     for i in range(0, block, sub):
         segs = _segments([
             _tile_class(*((j, subs[0], i) if kv_major else (i, subs[0], j)),
-                        subs[1], diag, edge)
+                        subs[1], diag, edge, window)
             for j in range(0, lim, sub_lim)], sub_lim)
         if passes and passes[-1][1:] == (i, segs) and (
                 i + sub - passes[-1][0] <= most):
@@ -263,25 +276,77 @@ def _passes(blocks, subs, heads, diag, edge, kv_major=False):
 
 @functools.lru_cache(maxsize=64)
 def subtile_counts(t: int, block_q: int, block_k: int, causal: bool,
-                   lanes: int = _LANES):
+                   lanes: int = _LANES, window: Optional[int] = None):
     """(square, computed, masked): the sub-tiles of the padded T x T score
     square for these blocks and a head block of ``lanes`` lanes (q's and
     k's), those the kernels compute (the rest lie
-    wholly above the diagonal, or wholly in the padding when not causal)
-    and, of the computed, those that build a mask (the diagonal crosses
-    them, or the padding edge when not causal). Static for a shape."""
+    wholly above the diagonal, wholly left of the ``window``, or wholly in
+    the padding when not causal) and, of the computed, those that build a
+    mask (the diagonal or the window's edge crosses them, or the padding
+    edge when not causal). Static for a shape."""
     sub_q, sub_k = _subtile(block_q, lanes), _subtile(block_k, lanes)
     t_pad = _round_up(t, max(block_q, block_k))
     diag, edge = (0, None) if causal else (None, t)
     classes = [
-        _tile_class(r0, sub_q, c0, sub_k, diag, edge)
+        _tile_class(r0, sub_q, c0, sub_k, diag, edge, window)
         for r0 in range(0, t_pad, sub_q) for c0 in range(0, t_pad, sub_k)
     ]
     return (len(classes), len(classes) - classes.count(_SKIPPED),
             classes.count(_MASKED))
 
 
-def kv_block_fetches(b: int, head_blocks: int, share: int, nq: int, nk: int):
+class _Static:
+    """``maximum`` / ``minimum`` of Python ints, for ``_band`` at trace time
+    (``jnp``'s would stage an operation inside a jitted function)."""
+    maximum, minimum = staticmethod(max), staticmethod(min)
+
+
+def _band(i, dkv, block_q, block_k, nq, nk, window, lib=jnp):
+    """(first, last) block along the inner grid axis that the outer block
+    ``i`` of a windowed call sees any of: for q block ``i`` (the forward's
+    and dq's grids) the kv blocks from its first row's window start to its
+    last row's diagonal; for kv block ``i`` (``dkv``) the q blocks from its
+    first column's diagonal to the last row whose window reaches its last
+    column. ``i`` may be a grid index (``lib`` = jnp, in an index map or a
+    kernel) or a Python int (``lib`` = ``_Static``)."""
+    if dkv:
+        return i * block_k // block_q, lib.minimum(
+            (i * block_k + block_k + window - 2) // block_q, nq - 1)
+    return (lib.maximum(i * block_q - (window - 1), 0) // block_k,
+            lib.minimum((i * block_q + block_q - 1) // block_k, nk - 1))
+
+
+def _band_steps(dkv, block_q, block_k, nq, nk, window):
+    """Steps of a windowed call's inner grid axis: the most blocks any outer
+    block's band holds (at T = 8192, window 512 and (512, 1024) blocks 2 of
+    the 8 kv blocks, and 3 of the 16 q blocks under dk/dv)."""
+    return max(hi - lo + 1 for lo, hi in (
+        _band(i, dkv, block_q, block_k, nq, nk, window, _Static)
+        for i in range(nk if dkv else nq)))
+
+
+def _band_block(i, j, dkv, **band):
+    """(block, whether it lies in the band) of step ``j`` of a windowed
+    call's inner grid axis under outer block ``i``. Past the band's end the
+    block is the band's last (an index map that does not move copies
+    nothing) and the kernels run no walk."""
+    lo, hi = _band(i, dkv, **band)
+    return jnp.minimum(lo + j, hi), lo + j <= hi
+
+
+def _inner_step(i, j, dkv, block_q, block_k, nq, nk, window):
+    """(steps, block, in_band) of a kernel's step ``j`` along its grid's
+    inner axis under outer block ``i``: with no window every block of the
+    axis, step ``j`` on block ``j`` (``in_band`` None); with one, the band's
+    (``_band_steps``, ``_band_block``)."""
+    if window is None:
+        return (nq if dkv else nk), j, None
+    band = dict(block_q=block_q, block_k=block_k, nq=nq, nk=nk, window=window)
+    return (_band_steps(dkv, **band), *_band_block(i, j, dkv, **band))
+
+
+def kv_block_fetches(b: int, head_blocks: int, share: int, nq: int, nk: int,
+                     band=None):
     """(fetches, a_head): the K block fetches (V's are as many) of one
     forward call over the grid ``_specs`` lays out for ``b`` rows,
     ``head_blocks`` query head blocks, ``share`` of them walked on one fetch
@@ -293,8 +358,18 @@ def kv_block_fetches(b: int, head_blocks: int, share: int, nq: int, nk: int):
     innermost axis a block is fetched once for all of them. A selection's
     block is fetched as often, but for a single kv block (nk 1), which
     never moves while the selection's moves with the q block, nq times as
-    often. Static for a shape."""
+    often. ``band`` ((block_q, block_k, window) of a windowed call): the
+    grid's kv axis holds a q block's band only (``_band_steps``), and a kv
+    block two q blocks' bands share stays put between them. Static for a
+    shape."""
     runs = nq * nk if nk > 1 else 1  # one kv block: it never moves
+    if band is not None:
+        block_q, block_k, window = band
+        steps = _band_steps(False, block_q, block_k, nq, nk, window)
+        seen = [min(lo + j, hi) for lo, hi in (
+            _band(qi, False, block_q, block_k, nq, nk, window, _Static)
+            for qi in range(nq)) for j in range(steps)]
+        runs = 1 + sum(a != c for a, c in zip(seen, seen[1:]))
     return b * (head_blocks // share) * runs, b * head_blocks * runs
 
 
@@ -321,17 +396,33 @@ def _heads_a_fetch(group, block_q, block_k, itemsize):
         s == 1 or s * block_q * per_row + others <= _VMEM))
 
 
-def _block_views(nq, nk, block_q, block_k, t_actual, causal):
+def _block_views(nq, nk, block_q, block_k, t_actual, causal, window=None):
     """The distinct ways a grid block (qi, ki) lies against the diagonal
     (causal) or the padding edge (not causal), static for a shape:
     [((diag, edge), in_view)] with ``_tile_class``'s ``diag`` / ``edge`` in
     the block's own coordinates and ``in_view(qi, ki)`` true in exactly
     those blocks. Blocks nothing is seen of appear in no view. A block
-    wholly below the diagonal, or wholly real, is (None, None)."""
+    wholly below the diagonal, or wholly real, is (None, None). With a
+    ``window`` a block wholly left of it appears in no view either, one the
+    window's edge crosses is a view by its distance from the diagonal as
+    one the diagonal crosses is, and (None, None) is a block wholly inside
+    the band."""
     views = {}
     for qi in range(nq):
         for ki in range(nk):
-            if causal:
+            if causal and window is not None:
+                d = qi * block_q - ki * block_k
+                if d <= -block_q or d >= block_k - 1 + window:
+                    continue
+                if block_k - 1 <= d <= window - block_q:
+                    views[None, None] = lambda qi, ki: jnp.logical_and(
+                        qi * block_q - ki * block_k >= block_k - 1,
+                        qi * block_q - ki * block_k <= window - block_q)
+                else:
+                    views[d, None] = functools.partial(
+                        lambda d, qi, ki:
+                        qi * block_q - ki * block_k == d, d)
+            elif causal:
                 d = qi * block_q - ki * block_k
                 if d <= -block_q:
                     continue
@@ -456,13 +547,13 @@ def _walk_blocks(kernel_walk, one_block, views, qi, ki, live=None):
 
 
 def _seg_valid(sel, row_at, col_at, span, segs, t_actual, causal,
-               kv_major=False):
+               kv_major=False, window=None):
     """One mask (or None: every pair counts) for each segment of a pass.
     ``span`` is the pass's own (start, stop) inside the grid block, whose
     row r and column c are ``row_at(r)`` and ``col_at(c)`` of the sequence:
     rows, with ``segs`` runs of columns, or, ``kv_major``, columns with runs
-    of rows and masks transposed. A segment the diagonal or the padding edge
-    crosses builds ``_valid_mask``; with a selection (``sel``: its flags and
+    of rows and masks transposed. A segment the diagonal, the ``window``'s
+    edge or the padding edge crosses builds ``_valid_mask``; with a selection (``sel``: its flags and
     its block, laid out as the masks are) each mask is and-ed with the pairs
     selected, and a segment below the diagonal is masked by them alone."""
     out = []
@@ -470,7 +561,8 @@ def _seg_valid(sel, row_at, col_at, span, segs, t_actual, causal,
         (r0, r1), (c0, c1) = ((s0, s1), span) if kv_major else (
             span, (s0, s1))
         ok = _valid_mask(row_at(r0), col_at(c0), r1 - r0, c1 - c0,
-                         t_actual, causal, kv_major) if masked else None
+                         t_actual, causal, kv_major, window
+                         ) if masked else None
         if sel is not None:
             block = (sel[1][0, c0:c1, r0:r1] if kv_major
                      else sel[1][0, r0:r1, c0:c1])
@@ -480,11 +572,13 @@ def _seg_valid(sel, row_at, col_at, span, segs, t_actual, causal,
     return out
 
 
-def _live(sel, nq, nk, qi, ki):
+def _live(sel, nq, nk, qi, ki, in_band=None):
     """Whether grid block (qi, ki) of this batch row holds a selected pair
-    (the selection's flags, in scalar memory); None with no selection."""
+    (the selection's flags, in scalar memory); with no selection
+    ``in_band``: a windowed call's word on the step (``_inner_step``), None
+    where every block runs its walk."""
     if sel is None:
-        return None
+        return in_band
     return sel[0][(pl.program_id(0) * nq + qi) * nk + ki] != 0
 
 
@@ -521,7 +615,7 @@ def _selecting(kernel, n_in):
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_out_ref, m_ref, l_ref,
                 acc_ref, *, scale, heads, block_q, block_k, t_actual,
-                causal, nq, nk, group=1, sel=None):
+                causal, nq, nk, group=1, sel=None, window=None):
     """One (b, hblk, qi, ki) grid step on (1, block, lanes) tiles of
     ``heads`` heads side by side: q and k as wide as each other, v, and with
     it the output and the statistics, as wide as itself. Each q sub-tile
@@ -532,9 +626,12 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_out_ref, m_ref, l_ref,
     neither scratch nor ``pl.when``. Where ``group`` query heads walk on one
     fetch of their K/V head's blocks the grid has them as its last axis and
     the step is one head's (``_head_of_group``): k, v and the selection stay
-    put for their steps."""
+    put for their steps. With a ``window`` the kv axis holds a q block's
+    band only (``_band_steps``) and step kj is on kv block ``_band_block``."""
     qi = pl.program_id(2)
-    ki = pl.program_id(3)
+    kj = pl.program_id(3)
+    steps, ki, in_band = _inner_step(qi, kj, False, block_q, block_k, nq, nk,
+                                     window)
     q_ref, o_ref, lse_out_ref, m_ref, l_ref, acc_ref = _head_of_group(
         group, (q_ref, o_ref), (lse_out_ref,), (m_ref, l_ref, acc_ref))
     subs = [_subtile(x, q_ref.shape[-1]) for x in (block_q, block_k)]
@@ -552,7 +649,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_out_ref, m_ref, l_ref,
 
     def walk(diag, edge):
         for r0, r1, segs in reversed(_passes(
-                (block_q, block_k), subs, heads, diag, edge)):
+                (block_q, block_k), subs, heads, diag, edge, window=window)):
             if not segs:
                 continue
             rows = slice(r0, r1)
@@ -563,7 +660,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_out_ref, m_ref, l_ref,
             vs = [v_ref[0, c0:c1, :] for c0, c1, _ in segs]
             valid = _seg_valid(sel, lambda r: qi * block_q + r,
                                lambda c: ki * block_k + c, (r0, r1), segs,
-                               t_actual, causal)
+                               t_actual, causal, window=window)
             # Both heads' scores, then both softmaxes, then both P V: the
             # order the bundle scheduler packs best.
             done = []
@@ -599,7 +696,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_out_ref, m_ref, l_ref,
             acc_ref[rows, :] = acc + alpha * acc_ref[rows, :]
 
     if not one_block:
-        @pl.when(ki == 0)
+        @pl.when(kj == 0)
         def _init():
             m_ref[...] = jnp.full_like(m_ref, _NEG)
             l_ref[...] = jnp.zeros_like(l_ref)
@@ -607,23 +704,26 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_out_ref, m_ref, l_ref,
 
     _walk_blocks(
         walk, one_block,
-        _block_views(nq, nk, block_q, block_k, t_actual, causal), qi, ki,
-        _live(sel, nq, nk, qi, ki))
+        _block_views(nq, nk, block_q, block_k, t_actual, causal, window),
+        qi, ki, _live(sel, nq, nk, qi, ki, in_band))
 
     if not one_block:
-        @pl.when(ki == nk - 1)
+        @pl.when(kj == steps - 1)
         def _finish():
             finish(slice(None), m_ref[...], l_ref[...], acc_ref[...])
 
 
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, dq_ref,
                acc_ref, *, scale, heads, block_q, block_k, t_actual, causal,
-               nq, nk, group=1, sel=None):
+               nq, nk, group=1, sel=None, window=None):
     """dq for one (b, hblk, qi, ki) grid step, walked like the forward (and
-    over its grid, the ``group`` heads of one fetch innermost): per q
-    sub-tile, dq += ds K over the kv columns its rows see."""
+    over its grid, the ``group`` heads of one fetch innermost, a ``window``'s
+    band alone on the kv axis): per q sub-tile, dq += ds K over the kv
+    columns its rows see."""
     qi = pl.program_id(2)
-    ki = pl.program_id(3)
+    kj = pl.program_id(3)
+    steps, ki, in_band = _inner_step(qi, kj, False, block_q, block_k, nq, nk,
+                                     window)
     q_ref, do_ref, dq_ref, lse_ref, dl_ref, acc_ref = _head_of_group(
         group, (q_ref, do_ref, dq_ref), (lse_ref, dl_ref), (acc_ref,))
     subs = [_subtile(x, q_ref.shape[-1]) for x in (block_q, block_k)]
@@ -641,7 +741,7 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, dq_ref,
         lses = _stat_columns(lse_ref[0, 0], v_lanes)
         deltas = _stat_columns(dl_ref[0, 0], v_lanes)
         for r0, r1, segs in _passes(
-                (block_q, block_k), subs, heads, diag, edge):
+                (block_q, block_k), subs, heads, diag, edge, window=window):
             if not segs:
                 continue
             rows = slice(r0, r1)
@@ -653,7 +753,7 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, dq_ref,
             vs = [v_ref[0, c0:c1, :] for c0, c1, _ in segs]
             valid = _seg_valid(sel, lambda r: qi * block_q + r,
                                lambda c: ki * block_k + c, (r0, r1), segs,
-                               t_actual, causal)
+                               t_actual, causal, window=window)
             accs = []
             for group in _head_groups(lanes):
                 dss = []
@@ -674,24 +774,24 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, dq_ref,
                 acc_ref[rows, :] += acc
 
     if not one_block:
-        @pl.when(ki == 0)
+        @pl.when(kj == 0)
         def _init():
             acc_ref[...] = jnp.zeros_like(acc_ref)
 
     _walk_blocks(
         walk, one_block,
-        _block_views(nq, nk, block_q, block_k, t_actual, causal), qi, ki,
-        _live(sel, nq, nk, qi, ki))
+        _block_views(nq, nk, block_q, block_k, t_actual, causal, window),
+        qi, ki, _live(sel, nq, nk, qi, ki, in_band))
 
     if not one_block:
-        @pl.when(ki == nk - 1)
+        @pl.when(kj == steps - 1)
         def _finish():
             finish(slice(None), acc_ref[...])
 
 
 def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, dk_ref,
                 dv_ref, acc_dk, acc_dv, *, scale, heads, block_q, block_k,
-                t_actual, causal, nq, nk, group=1, sel=None):
+                t_actual, causal, nq, nk, group=1, sel=None, window=None):
     """dk/dv for one (b, hblk, ki, qi) grid step, the transpose of the dq
     walk: per kv sub-tile, dv += p^T dO and dk += ds^T q over the q rows
     that see its columns. The score tile is computed column-major (k q^T),
@@ -700,12 +800,15 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, dk_ref,
     heads share a K/V head, the head block is the K/V head's and the last
     grid axis walks, q block by q block, each of its query heads in turn
     (``nq * group`` steps, the head minor: the selection's block stays put
-    for a group's steps), all summed into the one dk and dv."""
+    for a group's steps), all summed into the one dk and dv. With a
+    ``window`` the last axis holds the q blocks of a kv block's band only."""
     ki = pl.program_id(2)
     step = qi = pl.program_id(3)
-    last = group * nq - 1
     if group > 1:
         qi = step // group
+    steps, qi, in_band = _inner_step(ki, qi, True, block_q, block_k, nq, nk,
+                                     window)
+    last = group * steps - 1
     subs = [_subtile(x, q_ref.shape[-1]) for x in (block_q, block_k)]
     q_lanes, v_lanes = _head_lanes(q_ref, heads), _head_lanes(v_ref, heads)
     lanes = list(enumerate(zip(q_lanes, v_lanes)))
@@ -714,7 +817,8 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, dk_ref,
 
     def walk(diag, edge):
         for c0, c1, segs in _passes(
-                (block_q, block_k), subs, heads, diag, edge, kv_major=True):
+                (block_q, block_k), subs, heads, diag, edge, kv_major=True,
+                window=window):
             cols = slice(c0, c1)
             if not segs:
                 if one_block:  # padding columns: nothing sees them
@@ -729,7 +833,8 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, dk_ref,
             dos = [do_ref[0, r0:r1, :] for r0, r1, _ in segs]
             valid = _seg_valid(sel, lambda r: qi * block_q + r,
                                lambda c: ki * block_k + c, (c0, c1), segs,
-                               t_actual, causal, kv_major=True)
+                               t_actual, causal, kv_major=True,
+                               window=window)
             dvs, dks = [], []
             for group in _head_groups(lanes):
                 p_ds = []
@@ -762,8 +867,8 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, dk_ref,
 
     _walk_blocks(
         walk, one_block,
-        _block_views(nq, nk, block_q, block_k, t_actual, causal), qi, ki,
-        _live(sel, nq, nk, qi, ki))
+        _block_views(nq, nk, block_q, block_k, t_actual, causal, window),
+        qi, ki, _live(sel, nq, nk, qi, ki, in_band))
 
     if not one_block:
         @pl.when(step == last)
@@ -772,7 +877,7 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, dk_ref,
             dv_ref[0] = acc_dv[...].astype(dv_ref.dtype)
 
 
-def _specs(q, v, heads, hpb, causal, block_q, block_k, group=1):
+def _specs(q, v, heads, hpb, causal, block_q, block_k, group=1, window=None):
     """What the three pallas_calls share, for q (b, T, heads * D) and v
     (b, T, heads // group * Dv) with ``hpb`` heads a block: (t_pad, nh, w,
     wv), the kernels' static arguments, and ``specs``: the grid and the
@@ -788,7 +893,10 @@ def _specs(q, v, heads, hpb, causal, block_q, block_k, group=1):
     block h reads K/V block h // group on the plain grid. dk/dv's grid,
     ``specs(3, dkv=True)``, is (b, K/V head, kv block, q block x head of
     the group). The last spec is the selection's block, (block_q, block_k),
-    or transposed for dk/dv."""
+    or transposed for dk/dv. With a ``window`` grid axis 3 is as long as the
+    longest band (``_band_steps``) and its step j is on the j-th block of
+    the band of axis 2's block (``_band_block``): blocks left of the band
+    are neither stepped over nor fetched."""
     t = q.shape[1]
     if max(block_q, block_k) % min(block_q, block_k):
         raise ValueError(
@@ -809,29 +917,38 @@ def _specs(q, v, heads, hpb, causal, block_q, block_k, group=1):
     def specs(q_axis, dkv=False):
         kv_axis = 5 - q_axis  # grid axes 2 and 3
         q_heads = 1  # heads a q-side block holds
+        outer, inner = (nk, nq) if dkv else (nq, nk)
+        on = lambda g, j: j  # the block step j of grid axis 3 is on
+        if window is not None:
+            band = dict(block_q=block_q, block_k=block_k, nq=nq, nk=nk,
+                        window=window)
+            inner = _band_steps(dkv, **band)
+            on = lambda g, j: _band_block(g[2], j, dkv, **band)[0]
         if group == 1 or (share == 1 and not dkv):
-            grid = (b, nh, *((nk, nq) if dkv else (nq, nk)))
-            at = lambda axis: lambda *g: (g[0], g[axis], g[1])
+            grid = (b, nh, outer, inner)
+            at = lambda axis: lambda *g: (
+                g[0], g[2] if axis == 2 else on(g, g[3]), g[1])
             q_at, kv_at = at(q_axis), at(kv_axis)
             if group > 1:
-                kv_at = lambda *g: (g[0], g[kv_axis], g[1] // group)
-            stat_at = lambda *g: (g[0], g[1], 0, g[q_axis])
+                kv_at = lambda *g: (g[0], on(g, g[3]), g[1] // group)
+            stat_at = lambda *g: (g[0], g[1], 0, at(q_axis)(*g)[1])
             sel_at = lambda *g: (g[0], g[q_axis], g[kv_axis])
         elif dkv:
-            grid = (b, nh // group, nk, nq * group)
-            q_at = lambda *g: (g[0], g[3] // group,
+            grid = (b, nh // group, outer, inner * group)
+            q_at = lambda *g: (g[0], on(g, g[3] // group),
                                g[1] * group + g[3] % group)
             kv_at = lambda *g: (g[0], g[2], g[1])
             stat_at = lambda *g: (g[0], g[1] * group + g[3] % group, 0,
-                                  g[3] // group)
+                                  on(g, g[3] // group))
             sel_at = lambda *g: (g[0], g[3] // group, g[2])
         else:
-            grid = (b, nh // share, nq, nk, share)
+            grid = (b, nh // share, outer, inner, share)
             q_heads = share
             q_at = lambda *g: (g[0], g[2], g[1])
-            kv_at = lambda *g: (g[0], g[3], g[1])
+            kv_at = lambda *g: (g[0], on(g, g[3]), g[1])
             if share < group:
-                kv_at = lambda *g: (g[0], g[3], g[1] // (group // share))
+                kv_at = lambda *g: (g[0], on(g, g[3]),
+                                    g[1] // (group // share))
             stat_at = lambda *g: (g[0], g[1], 0, g[2])
             sel_at = lambda *g: (g[0], g[2], g[3])
         if dkv:  # the selection transposed, as dk/dv's masks are
@@ -852,6 +969,8 @@ def _specs(q, v, heads, hpb, causal, block_q, block_k, group=1):
         scale=1.0 / math.sqrt(q.shape[-1] // heads), heads=hpb,
         block_q=block_q, block_k=block_k, t_actual=t, causal=causal,
         nq=nq, nk=nk, group=share)  # dk/dv's ``group`` is the group itself
+    if window is not None:
+        kernel_args["window"] = window
     return t_pad, nh, w, wv, kernel_args, specs
 
 
@@ -887,7 +1006,7 @@ def _pad_selection(selection, t_pad):
 
 
 def _fwd_pallas(q, k, v, selection=None, *, heads, hpb, suffix, causal,
-                block_q, block_k, group=1):
+                block_q, block_k, group=1, window=None):
     """q: (b, T, heads * D); k: (b, T, heads // group * D); v: (b, T, heads
     // group * Dv), Dv its own width (latent attention: 192-wide scores,
     128-wide values); lane-packed (heads = H, hpb = 128 // D) or folded (b =
@@ -898,7 +1017,7 @@ def _fwd_pallas(q, k, v, selection=None, *, heads, hpb, suffix, causal,
     and head."""
     b, t, _ = q.shape
     t_pad, nh, w, wv, kernel_args, specs = _specs(
-        q, v, heads, hpb, causal, block_q, block_k, group)
+        q, v, heads, hpb, causal, block_q, block_k, group, window)
     grid, (q_spec, o_spec, k_spec, v_spec, stat, sel_spec) = specs(q_axis=2)
     # m, l, acc between the sequential ki steps, of each head of a group.
     carried = pltpu.VMEM((*grid[4:], block_q, wv), jnp.float32)
@@ -918,13 +1037,13 @@ def _fwd_pallas(q, k, v, selection=None, *, heads, hpb, suffix, causal,
 
 
 def _bwd_pallas(res, g, *, heads, hpb, suffix, causal, block_q, block_k,
-                group=1):
+                group=1, window=None):
     """dq, dk, dv from the saved row statistic: two kernels (dq with kv
     innermost; dk/dv with q innermost), each O(T*D) HBM traffic."""
     q, k, v, out, lse, selection = res
     b, t, _ = q.shape
     t_pad, nh, w, wv, kernel_args, specs = _specs(
-        q, v, heads, hpb, causal, block_q, block_k, group)
+        q, v, heads, hpb, causal, block_q, block_k, group, window)
     nkv = nh // group
     qp, kp = _pad(q, t_pad, nh * w), _pad(k, t_pad, nkv * w)
     vp, dop = _pad(v, t_pad, nkv * wv), _pad(g.astype(q.dtype), t_pad,
@@ -973,7 +1092,7 @@ def _bwd_pallas(res, g, *, heads, hpb, suffix, causal, block_q, block_k,
 
 @functools.lru_cache(maxsize=64)
 def _flash_cached(heads, hpb, suffix, causal, block_q, block_k, group=1,
-                  selecting=False):
+                  selecting=False, window=None):
     """custom_vjp fn over (b, T, heads * D) arrays for this static config.
     Its two halves are jitted: every layer of a model calls this one
     function at one shape, so the kernels are traced and lowered once a
@@ -985,6 +1104,8 @@ def _flash_cached(heads, hpb, suffix, causal, block_q, block_k, group=1,
                   block_q=block_q, block_k=block_k)
     if group > 1:
         static["group"] = group
+    if window is not None:
+        static["window"] = window
 
     @jax.jit
     def flash_fwd(q, k, v, selection=None):
@@ -1021,13 +1142,14 @@ def _flash_cached(heads, hpb, suffix, causal, block_q, block_k, group=1,
 
 # -------------------------------------------------------------------- public
 def dense_attention(q, k, v, causal: bool, selection=None,
-                    return_lse: bool = False):
+                    return_lse: bool = False, window: Optional[int] = None):
     """Stock-XLA attention over (B, T, H, D) tensors — THE dense softmax
     path, shared by MultiHeadAttention's short-T branch and the Ulysses
     non-flash branch, so mask/scale/dtype policy lives in exactly one
     place. k and v may have fewer heads than q (grouped queries: query head
     h reads K/V head h // (H // Hkv)). ``selection`` (B, T, T), non-zero
-    where a query may see a key, is and-ed with the causal mask.
+    where a query may see a key, is and-ed with the causal mask, and so is
+    a ``window``: query t sees the keys t - window < s <= t.
     ``return_lse``: also each row's log-sum-exp of its scaled, masked
     scores, (B, H, T) float32, as a constant (``flash_attention``'s)."""
     hd = q.shape[-1]
@@ -1040,6 +1162,8 @@ def dense_attention(q, k, v, causal: bool, selection=None,
     if causal:
         t = q.shape[1]
         mask = jnp.tril(jnp.ones((t, t), bool))
+        if window is not None:
+            mask = jnp.logical_and(mask, jnp.triu(mask, 1 - window))
         s = jnp.where(mask[None, None], s, jnp.float32(-1e30))
     if selection is not None:
         s = jnp.where((selection != 0)[:, None], s, jnp.float32(-1e30))
@@ -1087,6 +1211,20 @@ def resolve_blocks(t: int, itemsize: int, block_q: Optional[int] = None,
     return bq, bk
 
 
+def walked_pairs(t: int, heads: int, head_dim: int, itemsize: int,
+                 window: Optional[int]) -> int:
+    """(query, key) pairs of the sub-tiles ``flash_attention`` computes for
+    one head of one sequence of ``t`` rows under causality and this
+    ``window``, at the blocks and the layout it resolves to: the area the
+    kernels' walk covers, to set beside the pairs the window holds."""
+    bq, bk = resolve_blocks(t, itemsize)
+    lanes = (_LANES if _packed_supported(heads, head_dim)
+             else _lane_pad(head_dim))
+    computed = subtile_counts(t, bq, bk, True, lanes,
+                              window if window and window < t else None)[1]
+    return computed * _subtile(bq, lanes) * _subtile(bk, lanes)
+
+
 def selection_blocks(selection, block_q: int, block_k: int):
     """``(flags, total)`` of a causal selection (B, T, T) under these grid
     blocks: ``flags`` (B, nq, nk) int32, 1 where the block holds a selected
@@ -1107,6 +1245,7 @@ def flash_attention(
     q, k, v, *, causal: bool = False,
     block_q: Optional[int] = None, block_k: int = 1024,
     selection=None, selection_flags=None, return_lse: bool = False,
+    window: Optional[int] = None,
 ):
     """softmax(Q K^T / sqrt(d)) V without materializing the (T, T) scores.
 
@@ -1134,6 +1273,14 @@ def flash_attention(
     probabilities are recomputed from, exp(score - lse), by whoever needs
     them after the call.
 
+    ``window`` (causal, no selection): query t sees the keys t - window < s
+    <= t (sliding-window attention). The kernels, then named
+    ``dtpu_flash_*_swa``, skip the sub-tiles left of the band as they skip
+    those above the diagonal and mask the ones its edge crosses, and the
+    grids hold a block's band alone: a kv block left of it is neither
+    stepped over nor fetched (``_band_steps``, ``kv_block_fetches``). A
+    window that holds the whole sequence is the plain causal call.
+
     ``block_q`` / ``block_k`` are the DMA blocks: what one grid step holds
     in VMEM. ``block_q=None`` (default) resolves to the swept 1024, scoped-
     VMEM-clamped to 512 for float32 inputs (any length) and for bf16 above
@@ -1147,6 +1294,13 @@ def flash_attention(
     """
     b, t, h, d = q.shape
     bq, bk = resolve_blocks(t, jnp.dtype(q.dtype).itemsize, block_q, block_k)
+    if window is not None:
+        if not causal or selection is not None or window < 1:
+            raise ValueError(
+                "a window is a positive number of keys under causal "
+                f"attention with no selection; got window={window}, "
+                f"causal={causal}")
+        window = int(window) if window < t else None
     dv = v.shape[-1]
     packed = dv == d and _packed_supported(h, d)
     group = h // k.shape[2]
@@ -1157,14 +1311,15 @@ def flash_attention(
     # published here, at trace time, as counts.
     gauge = default_registry().gauge
     for name, n in zip(("square", "computed", "masked"), subtile_counts(
-            t, bq, bk, causal, _LANES if packed else _lane_pad(d))):
+            t, bq, bk, causal, _LANES if packed else _lane_pad(d), window)):
         gauge(f"flash.subtiles_{name}", n)
     # So is how often the kernels fetch a K/V block, beside once a head.
     t_pad = _round_up(t, max(bq, bk))
     rows, head_blocks = (b, h * d // _LANES) if packed else (b * h, 1)
     share = _heads_a_fetch(group, bq, bk, jnp.dtype(q.dtype).itemsize)
     for name, n in zip(("", "_a_head"), kv_block_fetches(
-            rows, head_blocks, share, t_pad // bq, t_pad // bk)):
+            rows, head_blocks, share, t_pad // bq, t_pad // bk,
+            window and (bq, bk, window))):
         gauge(f"flash.kv_block_fetches{name}", n)
     if selection is not None:
         if not (packed and d == _LANES and causal):
@@ -1186,12 +1341,13 @@ def flash_attention(
     if packed:
         # Lane-packed path: kernels read heads straight from the (B, T,
         # H*D) projection layout — the reshape is free, no transposes.
-        flash = _flash_cached(h, _LANES // d, "_packed", causal, bq, bk,
-                              group)
+        flash = _flash_cached(h, _LANES // d, "_swa" if window else "_packed",
+                              causal, bq, bk, group, False, window)
         return flash(
             q.reshape(b, t, h * d), k.reshape(b, t, -1),
             v.reshape(b, t, -1),
         ).reshape(b, t, h, d)
     fold = lambda x: jnp.moveaxis(x, 2, 1).reshape(b * h, t, x.shape[-1])
-    out = _flash_cached(1, 1, "", causal, bq, bk)(fold(q), fold(k), fold(v))
+    out = _flash_cached(1, 1, "_swa" if window else "", causal, bq, bk, 1,
+                        False, window)(fold(q), fold(k), fold(v))
     return jnp.moveaxis(out.reshape(b, h, t, dv), 1, 2)

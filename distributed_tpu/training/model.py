@@ -1808,6 +1808,10 @@ class Model:
         select_counters = attention_lib.select_counters(self.state)
         if select_counters:
             report["select"] = select_counters
+        # And those that see a window of their keys.
+        window_counters = attention_lib.window_counters(self.state)
+        if window_counters:
+            report["window"] = window_counters
         # The legacy dict is a VIEW stored in the metrics registry
         # (key-for-key identical — pinned by the obs parity test): one
         # telemetry surface, backward-compatible reader.

@@ -1,13 +1,16 @@
 #!/usr/bin/env python3
 """What the first-step limits of an expert-layer family's cell
-(``benchmarks/families/deepseek_v3.py``, ``keye_vl2.py``, ``lfm2_moe.py``)
+(``benchmarks/families/deepseek_v3.py``, ``keye_vl2.py``, ``lfm2_moe.py``,
+``laguna.py``)
 catch. The plain reference computes a wrong model on purpose (DeepSeek-V3:
 no shared expert, gates left unnormalised, pairs over a capacity dropped,
 the experts' matmuls or every weight matmul in int8; Keye-VL-2.0: every
 weight matmul in int8, the learned selection of keys ignored, half the keys
 selected; LFM2-MoE: every weight matmul in int8, the short convolutions'
-taps without their look-back, a head that hands the table no gradient), at the
-cell's own size, weights and first batch for ``--seed``, and stands in for
+taps without their look-back, a head that hands the table no gradient;
+Laguna: every weight matmul in int8, full causal attention in the sliding
+layers, no gate, all 128 dimensions rotated unscaled in the full layers), at
+the cell's own size, weights and first batch for ``--seed``, and stands in for
 the program in the driver's own comparison (``reference.compare`` and
 ``family.first_step_checks``, as ``drivers/train_family.py`` calls them):
 its loss, its gradient as ``system_grads``, and the reference held to the
@@ -16,8 +19,9 @@ a check. ``int8`` is a cell's control, the precision below the
 configuration's bfloat16; ``no_selection`` is the Keye cell's second, and
 ``half_selection`` its third: a selection that keeps too few keys agrees
 with the reference held to it in everything but the keys it missed;
-``no_lookback`` and ``untied_head`` are the LFM2 cell's second and third.
-Run them on the chip beside the cell's own runs. Run by hand; PERF.md keeps
+``no_lookback`` and ``untied_head`` are the LFM2 cell's second and third;
+``full_causal``, ``no_gate`` and ``plain_rope`` the Laguna cell's second to
+fourth. Run them on the chip beside the cell's own runs. Run by hand; PERF.md keeps
 the readings.
 
     chiprun --chips 1 -- python3 scripts/moe_wrong_models.py --variants int8
@@ -25,6 +29,8 @@ the readings.
         --cell keye-vl2-30b.train.dsa8k
     chiprun --chips 1 -- python3 scripts/moe_wrong_models.py \
         --cell lfm2-8b-a1b.train.ep4share
+    chiprun --chips 1 -- python3 scripts/moe_wrong_models.py \
+        --cell laguna-xs2.train.swa8k
     JAX_PLATFORMS=cpu python3 scripts/moe_wrong_models.py [--seed N]
 
 (float32 at "highest" on either backend; on the CPU some minutes a model
@@ -49,6 +55,7 @@ VARIANTS = {
                     "int8_experts", "int8"),
     "keye_vl2": ("int8", "no_selection", "half_selection"),
     "lfm2_moe": ("int8", "no_lookback", "untied_head"),
+    "laguna": ("int8", "full_causal", "no_gate", "plain_rope"),
 }
 
 
@@ -58,7 +65,7 @@ def held_to(own):
     if isinstance(own, tuple):  # keye_vl2: (selections, experts, sum of L_I)
         keys, chosen, _ = own
         return {"experts": chosen, "keys": keys}
-    if isinstance(own, dict):   # lfm2_moe: its experts, a sequence's
+    if isinstance(own, dict):   # lfm2_moe, laguna: its experts, a sequence's
         return own["experts"]
     return [c[0] for c in own]
 

@@ -793,3 +793,191 @@ def test_calls_that_share_no_kv_head_in_place_trace_as_before(case):
         shape(h, d), shape(kv, d), shape(kv, dv), sel))
     text = re.sub(r"0x[0-9a-f]+", "0x", text)
     assert hashlib.sha256(text.encode()).hexdigest() == digest
+
+
+# ----------------------------------------------------------- a sliding window --
+def _windowed_case(window, t, group, blocks):
+    b, kv = (1, 1) if t > 256 else (2, 2)
+    q, k, v, w = _grouped_qkv(group, t, b=b, kv_heads=kv)
+    flash = lambda q, k, v: jnp.sum(w * flash_attention(
+        q, k, v, causal=True, window=window, **blocks))
+    return (q, k, v), w, flash
+
+
+@pytest.mark.parametrize("blocks", [dict(block_q=64, block_k=128),
+                                    dict(block_q=128, block_k=128)])
+@pytest.mark.parametrize("t,group", [
+    (256, 1), (256, 6), (256, 8), (200, 1), (200, 6), (200, 8), (1024, 1)])
+@pytest.mark.parametrize("window", [64, 128, 512, 4096])
+def test_windowed_kernels_match_dense_attention(window, t, group, blocks):
+    """Values, the row statistic and all three gradients of the windowed
+    kernels (128-wide heads: one K/V head a query head, and groups of six
+    and eight on one fetch) against ``dense_attention`` with the same
+    window, at a T the blocks divide, one they pad and one of eight kv
+    blocks, for windows under a sub-tile, of one, of several blocks and
+    wider than the sequence."""
+    from distributed_tpu.ops import flash_attention as fa
+
+    (q, k, v), w, flash = _windowed_case(window, t, group, blocks)
+    dense = lambda q, k, v: jnp.sum(w * fa.dense_attention(
+        q, k, v, True, window=window))
+    # the values as they are (the weighted sum of 10^5 terms cancels to a
+    # number whose rounding depends on the order of the sum)
+    _close(flash_attention(q, k, v, causal=True, window=window, **blocks),
+           fa.dense_attention(q, k, v, True, window=window))
+    _close(jax.grad(flash, (0, 1, 2))(q, k, v),
+           jax.grad(dense, (0, 1, 2))(q, k, v))
+    flat = lambda x: x.reshape(*x.shape[:2], -1)
+    lse = fa._fwd_pallas(
+        flat(q), flat(k), flat(v), heads=q.shape[2], hpb=1, suffix="_swa",
+        causal=True, group=group, window=window if window < t else None,
+        **blocks)[1][:, :, 0, :t]
+    _close(lse, fa.dense_attention(q, k, v, True, window=window,
+                                   return_lse=True)[1])
+
+
+@pytest.mark.parametrize("shape,value_dim", [
+    ((2, 200, 4, 64), None),     # two heads a lane block
+    ((1, 160, 2, 192), 128),     # folded, 256 lanes: one sub-tile a block
+    ((2, 100, 3, 32), None),     # folded, a ragged T
+])
+def test_windowed_kernels_in_the_other_layouts(shape, value_dim):
+    """The window is the walk's, not the layout's: lane-packed 64-wide
+    heads and the folded layout (latent attention's widths) take it too."""
+    from distributed_tpu.ops import flash_attention as fa
+
+    q, k, v = _qkv(shape, value_dim=value_dim)
+    w = jax.random.normal(jax.random.PRNGKey(9), v.shape)
+    for window in (24, 130):
+        flash = lambda q, k, v: jnp.sum(w * flash_attention(
+            q, k, v, causal=True, window=window, block_q=64, block_k=128))
+        dense = lambda q, k, v: jnp.sum(w * fa.dense_attention(
+            q, k, v, True, window=window))
+        _close(flash_attention(q, k, v, causal=True, window=window,
+                               block_q=64, block_k=128),
+               fa.dense_attention(q, k, v, True, window=window))
+        _close(jax.grad(flash, (0, 1, 2))(q, k, v),
+               jax.grad(dense, (0, 1, 2))(q, k, v))
+
+
+@pytest.mark.parametrize("group", [1, 8])
+def test_a_window_that_holds_the_sequence_is_the_causal_call(group):
+    """To the bit, values and gradients: the call is the plain one (the
+    same cached function, the plain kernels' names)."""
+    (q, k, v), w, windowed = _windowed_case(256, 256, group, GROUP_BLOCKS)
+    plain = lambda q, k, v: jnp.sum(w * flash_attention(
+        q, k, v, causal=True, **GROUP_BLOCKS))
+    got = jax.value_and_grad(windowed, (0, 1, 2))(q, k, v)
+    want = jax.value_and_grad(plain, (0, 1, 2))(q, k, v)
+    for a, b in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        np.testing.assert_array_equal(a, b)
+    text = str(jax.make_jaxpr(jax.grad(windowed, (0, 1, 2)))(q, k, v))
+    assert "_swa" not in text and "dtpu_flash_fwd_packed" in text
+    with pytest.raises(ValueError, match="window"):
+        flash_attention(q, k, v, causal=False, window=64)
+    with pytest.raises(ValueError, match="window"):
+        flash_attention(q, k, v, causal=True, window=64,
+                        selection=_spread_selection(256))
+
+
+@pytest.mark.parametrize("t,blocks,window,lanes", [
+    (8192, (512, 1024), 512, 128),   # laguna-xs2.train.swa8k
+    (8192, (512, 1024), 1536, 128), (4096, (512, 1024), 200, 128),
+    (1000, (128, 256), 64, 128), (4096, (512, 1024), 512, 256),
+    (256, (256, 256), 1, 128),
+])
+def test_walked_subtiles_against_a_count_from_the_shape(t, blocks, window,
+                                                        lanes):
+    """``subtile_counts`` with a window against a count written out from
+    the shape: a sub-tile is walked if some row of it sees some column of
+    it, and masked unless every row sees every column."""
+    from distributed_tpu.ops import flash_attention as fa
+
+    side_q, side_k = (fa._subtile(x, lanes) for x in blocks)
+    t_pad = -(-t // max(blocks)) * max(blocks)
+    computed = masked = 0
+    for r0 in range(0, t_pad, side_q):
+        for c0 in range(0, t_pad, side_k):
+            r1, c1 = r0 + side_q - 1, c0 + side_k - 1
+            # the last row reaches furthest right, the first furthest left
+            if c0 > r1 or c1 <= r0 - window:
+                continue
+            computed += 1
+            # every row sees every column: the first row's diagonal is at
+            # or past the last column, the last row's window holds the first
+            masked += not (c1 <= r0 and c0 > r1 - window)
+    square = (t_pad // side_q) * (t_pad // side_k)
+    assert fa.subtile_counts(t, *blocks, True, lanes, window) == (
+        square, computed, masked)
+    if (t, window, lanes) == (8192, 512, 128):
+        # five sub-tiles a row of them where the window holds four
+        assert (computed, masked) == (1 + 2 + 3 + 4 + 60 * 5, 64 + 60)
+        assert fa.walked_pairs(t, 64, 128, 2, window) == 310 * 128 * 128
+        assert fa.walked_pairs(t, 64, 128, 2, None) == 2080 * 128 * 128
+
+
+def test_a_windowed_grid_neither_steps_over_nor_fetches_blocks_left_of_the_band():
+    """laguna-xs2.train.swa8k's sliding layers, 64 query heads over 8 K/V
+    heads at T = 8192 and a window of 512: the forward's and dq's kv axis is
+    2 steps long where the plain call's is 8, dk/dv's q axis 3 x 8 heads
+    where it is 16 x 8; walking the grids in order every K and V block is
+    fetched once a K/V head, 8 times, where the plain call fetches 128; and
+    ``kv_block_fetches`` and its gauges say the same."""
+    from distributed_tpu import obs
+    from distributed_tpu.ops import flash_attention as fa
+
+    def specs(window):
+        shape = lambda h: jax.ShapeDtypeStruct((1, 8192, h, 128),
+                                               jnp.bfloat16)
+        loss = lambda q, k, v: jnp.sum(flash_attention(
+            q, k, v, causal=True, window=window).astype(jnp.float32))
+        jaxpr = jax.make_jaxpr(jax.grad(loss, (0, 1, 2)))(
+            shape(64), shape(8), shape(8))
+        out = {}
+        for eqn in _eqns(jaxpr.jaxpr):
+            if eqn.primitive.name != "pallas_call":
+                continue
+            mapping = eqn.params["grid_mapping"]
+            # one K/V head's walk: the first row and K/V head
+            steps = [g for g in np.ndindex(*mapping.grid) if g[1] == 0]
+            out[eqn.params["name"]] = (mapping.grid, [
+                [tuple(int(i) for i in jax.core.eval_jaxpr(
+                    bm.index_map_jaxpr.jaxpr, bm.index_map_jaxpr.consts, *g))
+                 for g in steps] for bm in mapping.block_mappings])
+        return out
+
+    banded, plain = specs(512), specs(None)
+    changes = lambda seen: 1 + sum(a != b for a, b in zip(seen, seen[1:]))
+    for kernel in ("fwd", "dq"):
+        grid, maps = banded[f"dtpu_flash_{kernel}_swa"]
+        assert grid == (1, 8, 16, 2, 8)
+        assert plain[f"dtpu_flash_{kernel}_packed"][0] == (1, 8, 16, 8, 8)
+        k_blocks = [at[1] for at in maps[1]]
+        assert changes(k_blocks) == changes([at[1] for at in maps[2]]) == 8
+        assert sorted(set(k_blocks)) == list(range(8))
+        # q block qi sees kv blocks (qi - 1) // 2 .. qi // 2 and no other
+        seen = {}
+        for (g, at) in zip([g for g in np.ndindex(*grid) if g[1] == 0],
+                           maps[1]):
+            seen.setdefault(g[2], set()).add(at[1])
+        assert seen == {qi: {max(qi - 1, 0) // 2, qi // 2}
+                        for qi in range(16)}
+        assert changes([at[1] for at in plain[
+            f"dtpu_flash_{kernel}_packed"][1][1]]) == 128
+    grid, maps = banded["dtpu_flash_dkv_swa"]
+    assert grid == (1, 8, 8, 3 * 8)
+    assert plain["dtpu_flash_dkv_packed"][0] == (1, 8, 8, 16 * 8)
+    q_blocks = {}
+    for g, at in zip([g for g in np.ndindex(*grid) if g[1] == 0], maps[0]):
+        q_blocks.setdefault(g[2], set()).add(at[1])
+    assert q_blocks == {ki: {min(2 * ki + j, 15) for j in range(3)}
+                        for ki in range(8)}
+    assert fa.kv_block_fetches(1, 64, 8, 16, 8, (512, 1024, 512)) == (
+        8 * 8, 64 * 8)
+    assert fa.kv_block_fetches(1, 64, 8, 16, 8) == (8 * 128, 64 * 128)
+    reg = obs.default_registry()
+    assert reg.gauge_value("flash.kv_block_fetches") == 1024.0  # plain, last
+    specs(512)
+    assert reg.gauge_value("flash.kv_block_fetches") == 64.0
+    assert reg.gauge_value("flash.subtiles_computed") == 310.0

@@ -120,21 +120,47 @@ def test_grouped_query_flash_grad_compiles_for_v5e(t, kv_heads, dtype,
     and the forward and dq as many of them on one fetch of K, V and the
     selection as the 16 MB a kernel may use hold of their q-side blocks and
     scratch (``_heads_a_fetch``: all eight in the cell)."""
+    _grouped_query_grad_compiles(t, 32, kv_heads, dtype, selecting, None,
+                                 one_chip)
+
+
+def _grouped_query_grad_compiles(t, heads, kv_heads, dtype, selecting,
+                                 window, one_chip):
     spec = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
                                                      sharding=one_chip)
-    q = spec((1, t, 32, 128), dtype)
+    q = spec((1, t, heads, 128), dtype)
     kv = spec((1, t, kv_heads, 128), dtype)
     sel = spec((1, t, t), jnp.int8) if selecting else None
 
     def loss(q, k, v, sel):
-        out = fa.flash_attention(q, k, v, causal=True, selection=sel)
+        out = fa.flash_attention(q, k, v, causal=True, selection=sel,
+                                 window=window)
         return jnp.sum(out.astype(jnp.float32))
 
     text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
         q, kv, kv, sel).compile().as_text()
-    suffix = "_sel" if selecting else "_packed"
+    suffix = "_sel" if selecting else "_swa" if window else "_packed"
     for name in ("dtpu_flash_fwd", "dtpu_flash_dq", "dtpu_flash_dkv"):
         assert name + suffix in text
+
+
+@pytest.mark.parametrize("t,heads,dtype,window", [
+    (8192, 64, jnp.bfloat16, 512),   # the cell's three sliding layers
+    (8192, 48, jnp.bfloat16, None),  # its two full layers: groups of six
+    # Off the cell's shape: block_q 1024 at T <= 2048 (two heads a fetch),
+    # float32 inputs, a window no multiple of the sub-tile, and one wider
+    # than a kv block.
+    (2048, 64, jnp.bfloat16, 512), (8192, 64, jnp.float32, 512),
+    (4096, 48, jnp.bfloat16, 200), (8192, 64, jnp.bfloat16, 1536),
+])
+def test_windowed_grouped_query_flash_grad_compiles_for_v5e(
+        t, heads, dtype, window, one_chip, mosaic):
+    """laguna-xs2.train.swa8k: (1, 8192, 64) query heads over 8 K/V heads of
+    128 with a window of 512 keys (``dtpu_flash_*_swa``: the band's index
+    maps, ``_band_block``, and its masks lower to Mosaic; a q block's band
+    is 2 of the 8 kv blocks), and 48 over 8 with none (groups of six on the
+    plain grouped kernels)."""
+    _grouped_query_grad_compiles(t, heads, 8, dtype, False, window, one_chip)
 
 
 @pytest.mark.parametrize("pairs,groups,hidden", [
