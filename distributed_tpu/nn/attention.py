@@ -946,6 +946,19 @@ class GroupedQueryAttention(Layer):
     sliding layers; ``rotary_dim`` and ``rope_scaling`` in the full ones;
     ``gate`` in both).
 
+    Where the flash path is on and a head is 128 wide, exactly one lane
+    tile, the norm and the rotation of q and of k are one kernel pass each
+    on the projection's own (B, T, H x 128) layout
+    (``ops/head_norm_rope.py``: ``dtpu_head_norm_rope`` and its backward,
+    which keeps the projection alone), under the scopes ``q_norm`` and
+    ``k_norm``, which then hold the rotation too: on a (B, T, H, 128) view
+    XLA:TPU relayouts the projection in float32 around both (root PERF.md,
+    PR 39). Any other shape, the dense path and the indexer run ``_rms`` and
+    ``rope_half`` / ``rope_rotary``, the definition the kernels are held to
+    (rounded to the input's dtype after the norm and after the rotation,
+    both ways). The trace-time counters ``attn.qk_prep_fused`` and
+    ``attn.qk_prep_xla`` count the calls of either path, q and k each.
+
     ``window``: query t sees the keys t - window < s <= t (the flash
     kernels' ``window``; ``dtpu_flash_*_swa``). ``rotary_dim``: the first
     that many of a head's dimensions are rotated and the rest passed
@@ -1073,6 +1086,16 @@ class GroupedQueryAttention(Layer):
             return rope_half(x, self.rope_theta)
         return rope_rotary(x, *self.rotation)
 
+    def _rotation(self):
+        """``_rope``'s frequencies and factor, for ``head_norm_rope``:
+        ``rope_half``'s own, computed as it computes them, where the layer
+        has no others."""
+        if self.rotation is not None:
+            return self.rotation
+        d = self.head_dim
+        return 1.0 / (self.rope_theta ** (
+            jnp.arange(0, d, 2, dtype=jnp.float32) / d)), 1.0
+
     def init(self, key, input_shape: Shape):
         t, d = input_shape[-2], input_shape[-1]
         h, g, hd = self.num_heads, self.num_kv_heads, self.head_dim
@@ -1143,14 +1166,33 @@ class GroupedQueryAttention(Layer):
         h, g, hd = self.num_heads, self.num_kv_heads, self.head_dim
         proj = lambda a, w: jnp.dot(
             a, maybe_dequantize(params[w]).astype(a.dtype))
-        q = proj(x, "wq").reshape(b, t, h, hd)
-        k = proj(x, "wk").reshape(b, t, g, hd)
-        v = proj(x, "wv").reshape(b, t, g, hd)
-        with child_scope("q_norm"):
-            q = _rms(q, params["q_norm"]["scale"], self.epsilon)
-        with child_scope("k_norm"):
-            k = _rms(k, params["k_norm"]["scale"], self.epsilon)
-        q, k = self._rope(q), self._rope(k)
+        flash = self._use_flash(t) and ambient_mesh()[0] is None
+        # A head is one lane tile: what takes a selection in Mosaic, and
+        # what norms and rotates q and k on the projections' own layout.
+        kernels = flash and hd == 128
+        count = default_registry().counter
+        if kernels:
+            from ..ops.head_norm_rope import head_norm_rope
+
+            heads = lambda a: a.reshape(b, t, -1, hd)
+            q, k, v = proj(x, "wq"), proj(x, "wk"), heads(proj(x, "wv"))
+            prep = functools.partial(head_norm_rope, epsilon=self.epsilon)
+            rotation = self._rotation()
+            with child_scope("q_norm"):
+                q = heads(prep(q, params["q_norm"]["scale"], rotation))
+            with child_scope("k_norm"):
+                k = heads(prep(k, params["k_norm"]["scale"], rotation))
+            count("attn.qk_prep_fused", 2)
+        else:
+            q = proj(x, "wq").reshape(b, t, h, hd)
+            k = proj(x, "wk").reshape(b, t, g, hd)
+            v = proj(x, "wv").reshape(b, t, g, hd)
+            with child_scope("q_norm"):
+                q = _rms(q, params["q_norm"]["scale"], self.epsilon)
+            with child_scope("k_norm"):
+                k = _rms(k, params["k_norm"]["scale"], self.epsilon)
+            q, k = self._rope(q), self._rope(k)
+            count("attn.qk_prep_xla", 2)
         selection = None
         blocks = (jnp.float32(0.0), 0)
         if self.index_topk:
@@ -1158,8 +1200,6 @@ class GroupedQueryAttention(Layer):
                 index = self._indexer(params["indexer"], x)
                 selection = select_keys(*index, topk=self.index_topk,
                                         block=INDEX_BLOCK)
-        flash = self._use_flash(t) and ambient_mesh()[0] is None
-        kernels = flash and hd == 128  # what takes a selection in Mosaic
         walked = 0  # pairs of the sub-tiles the windowed kernels compute
         if self.window:
             if flash:

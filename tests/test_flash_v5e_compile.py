@@ -3,7 +3,8 @@ compiler for a described (not attached) v5e: lane-packed at the GPT-2
 cells' shapes and at the scoped-VMEM clamp shape; and, for the
 latent-attention cell, folded at 192-wide keys and 128-wide values; and the
 grouped-matmul kernels at the held experts' shapes; and the index scores'
-backward kernel at the selecting cell's. Nothing runs: this guards the
+backward kernel at the selecting cell's; and the per-head norm and rotation
+at the two 128-wide-head cells' head counts. Nothing runs: this guards the
 16 MB scoped-VMEM limit and the lane / sublane alignment of the in-kernel
 sub-tile slices, which interpret mode cannot see, at no chip time
 (on-chip-measurement guide, third rehearsal; the whole step programs are
@@ -22,6 +23,7 @@ from jax.sharding import SingleDeviceSharding
 
 from distributed_tpu.ops import flash_attention as fa
 from distributed_tpu.ops import grouped_matmul as gm
+from distributed_tpu.ops import head_norm_rope as hn
 from distributed_tpu.ops import index_scores as ix
 
 
@@ -46,7 +48,10 @@ def mosaic(monkeypatch):
     monkeypatch.setattr(fa, "_interpret", lambda: False)
     monkeypatch.setattr(gm, "_interpret", lambda: False)
     monkeypatch.setattr(ix, "_interpret", lambda: False)
+    monkeypatch.setattr(hn, "_interpret", lambda: False)
     fa._flash_cached.cache_clear()
+    hn._forward.clear_cache()
+    hn._backward.clear_cache()
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
@@ -54,6 +59,8 @@ def mosaic(monkeypatch):
     jax.config.update("jax_enable_compilation_cache", was)
     compilation_cache.reset_cache()
     fa._flash_cached.cache_clear()
+    hn._forward.clear_cache()
+    hn._backward.clear_cache()
 
 
 @pytest.mark.parametrize("shape,causal", [
@@ -210,3 +217,35 @@ def test_index_scores_gradient_compiles_for_v5e(dtype, one_chip, mosaic):
         spec((), jnp.int32)).compile().as_text()
     assert "dtpu_index_scores_bwd" in text
     assert not re.search(r"(?:f32|bf16|pred)\[512,16,8192\]", text)
+
+
+@pytest.mark.parametrize("rotation", ["theta", "yarn64"])
+@pytest.mark.parametrize("heads", [64, 48, 32, 8, 4])
+def test_head_norm_rope_compiles_for_v5e(heads, rotation, one_chip, mosaic):
+    """laguna-xs2.train.swa8k (64 and 48 query heads, 8 K/V heads; all 128
+    dimensions at theta 1e4, or YaRN over 64 of them) and
+    keye-vl2-30b.train.dsa8k (32 over 4) at 8,192 rows: both kernels' blocks
+    and a row block's tables fit the 16 MB a kernel may use, the lane rolls
+    lower, and no (T, H, 128) view is left."""
+    from distributed_tpu.nn.attention import yarn_inv_freq
+
+    spec = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                     sharding=one_chip)
+
+    def both(x, scale, g):
+        rot = (yarn_inv_freq(64, 500000.0, factor=64.0,
+                             original_max_position=4096, beta_fast=64.0),
+               1.4159) if rotation == "yarn64" else (
+            1.0 / (1e4 ** (jnp.arange(0, 128, 2, dtype=jnp.float32) / 128)),
+            1.0)
+        out, vjp = jax.vjp(lambda x, s: hn.head_norm_rope(
+            x, s, rot, epsilon=1e-6), x, scale)
+        return out, vjp(g)
+
+    wide = spec((1, 8192, heads * 128), jnp.bfloat16)
+    text = jax.jit(both).lower(
+        wide, spec((128,), jnp.float32), wide).compile().as_text()
+    calls = re.findall(r'custom_call_target="tpu_custom_call".*?'
+                       r'op_name="[^"]*/(dtpu_head\w*)/pallas_call"', text)
+    assert sorted(calls) == ["dtpu_head_norm_rope", "dtpu_head_norm_rope_bwd"]
+    assert not re.search(rf"(?:f32|bf16)\[1,8192,{heads},128\]", text)

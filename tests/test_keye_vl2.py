@@ -185,6 +185,27 @@ def reference_block(params):
                         "k_norm_bias": ix["k_norm"]["bias"]}}
 
 
+def test_a_selecting_128_wide_layer_norms_and_rotates_in_the_kernels():
+    """On the flash path at 128-wide heads the selecting layer's gradient
+    calls ``dtpu_head_norm_rope`` for q and for k and its backward for each
+    (the indexer keeps ``rope_half`` on its own heads), and nothing under
+    the layer's ``q_norm`` or ``k_norm`` makes a float32 (B, T, H, 128)
+    view; the dense path and the tiny model's heads keep the plain lines."""
+    from qk_prep import float32_head_views, gradient_jaxpr, kernel_calls
+
+    mk = lambda **kw: attention_layer(dtype="bfloat16", **kw)
+    jaxpr, counted = gradient_jaxpr(mk(flash=True, head_dim=128), 64, D, 2)
+    prep = [c for c in kernel_calls(jaxpr) if "head_norm" in c]
+    assert prep == ["dtpu_head_norm_rope"] * 2 + [
+        "dtpu_head_norm_rope_bwd"] * 2
+    assert float32_head_views(jaxpr) == []
+    assert counted == (2, 0)
+    for plain in (mk(head_dim=128), mk(flash=True), mk()):
+        jaxpr, counted = gradient_jaxpr(plain, 64, D, 2)
+        assert [c for c in kernel_calls(jaxpr) if "head_norm" in c] == []
+        assert counted == (0, 2)
+
+
 def test_grouped_query_attention_without_an_indexer_matches_the_reference():
     """The selection off: no indexer, no state, every key before a query
     seen: the reference told to ignore its own selection."""

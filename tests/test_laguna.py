@@ -200,6 +200,52 @@ def test_layer_on_the_kernels_matches_its_dense_path(heads, window):
     assert layer.apply(params, state, x, train=False)[1] == {}
 
 
+@pytest.mark.parametrize("heads", [12, 16])
+def test_full_layer_on_the_kernels_matches_its_dense_path(heads):
+    """128-wide heads in groups of six and eight with YaRN over 64 of the
+    128 dimensions: norm and rotation in ``dtpu_head_norm_rope`` and the
+    plain grouped flash kernels (``flash=True``: the interpreter) against
+    the same layer's dense path, which norms and rotates in plain lines."""
+    t = 256
+    mk = lambda flash: attention_layer(heads, False, head_dim=128,
+                                       flash=flash)
+    layer, dense = mk(True), mk(False)
+    params, state, _ = layer.init(jax.random.PRNGKey(1), (t, D))
+    params["q_norm"]["scale"] = 1.0 + 0.1 * jax.random.normal(
+        jax.random.PRNGKey(4), (128,))
+    x = jax.random.normal(jax.random.PRNGKey(2), (2, t, D))
+    w = jax.random.normal(jax.random.PRNGKey(3), x.shape)
+    loss = lambda lay: lambda p, x: jnp.sum(
+        w * lay.apply(p, state, x, train=True)[0])
+    assert_trees_close(jax.value_and_grad(loss(layer), (0, 1))(params, x),
+                       jax.value_and_grad(loss(dense), (0, 1))(params, x),
+                       rel=2e-4)
+
+
+@pytest.mark.parametrize("sliding", [True, False])
+def test_a_128_wide_layer_norms_and_rotates_in_the_kernels(sliding):
+    """On the flash path at 128-wide heads the gradient's jaxpr calls
+    ``dtpu_head_norm_rope`` for q and for k and its backward for each, and
+    nothing under ``q_norm`` or ``k_norm`` makes a float32 (B, T, H, 128)
+    view (the plain lines' ``_rms`` does: XLA:TPU relayouts it, PERF.md
+    section 6, PR 39); the dense path and the tiny models' 16-wide heads
+    stay on the plain lines. The trace-time counters say which."""
+    from qk_prep import float32_head_views, gradient_jaxpr, kernel_calls
+
+    mk = lambda **kw: attention_layer(16, sliding, dtype="bfloat16", **kw)
+    jaxpr, counted = gradient_jaxpr(mk(head_dim=128, flash=True), 256, D, 2)
+    prep = [c for c in kernel_calls(jaxpr) if "head_norm" in c]
+    assert prep == ["dtpu_head_norm_rope"] * 2 + [
+        "dtpu_head_norm_rope_bwd"] * 2
+    assert float32_head_views(jaxpr) == []
+    assert counted == (2, 0)
+    for plain in (mk(head_dim=128, flash=False), mk(flash=True), mk()):
+        jaxpr, counted = gradient_jaxpr(plain, 256, D, 2)
+        assert [c for c in kernel_calls(jaxpr) if "head_norm" in c] == []
+        assert len(float32_head_views(jaxpr, plain.head_dim)) >= 2
+        assert counted == (0, 2)
+
+
 def test_a_layer_without_the_new_arguments_is_the_layer_it_was():
     """Keye's and LFM2's cells: the same leaves, the same scope, no state
     and ``rope_half`` itself; the new arguments are refused where they make

@@ -139,6 +139,34 @@ def test_short_conv_does_not_decode():
         nn.ShortConv(0)
 
 
+# ------------------------------------- the attention layer's 64-wide heads --
+# The gradient's jaxpr of a layer of 64-wide heads with the flash path on, as
+# the commit before PR 39 traced it (PR 39 norms and rotates 128-wide heads
+# in a kernel of their own; these take the plain lines, to the letter).
+PARENT_JAX = "0.9.0"
+PARENT_JAXPR = "e9ce5480df1025bebd39abd6e248249db4281e226a205262a5049dced8adbbbb"
+
+
+@pytest.mark.parametrize("head_dim,flash", [(64, True), (64, False),
+                                            (16, True)])
+def test_heads_narrower_than_a_lane_tile_keep_the_plain_norm_and_rotation(
+        head_dim, flash):
+    from qk_prep import digest, gradient_jaxpr, kernel_calls
+
+    layer = nn.GroupedQueryAttention(
+        8, 2, head_dim, rope_theta=1e6, epsilon=1e-5, dtype="bfloat16",
+        flash=flash)
+    layer.name = layer.default_name()
+    jaxpr, counted = gradient_jaxpr(layer, 256, D)
+    assert counted == (0, 2)  # fused, plain: q and k each
+    assert [c for c in kernel_calls(jaxpr) if "head_norm" in c] == []
+    if (head_dim, flash) == (64, True):
+        if jax.__version__ != PARENT_JAX:
+            pytest.skip(
+                f"the parent's digest was taken under JAX {PARENT_JAX}")
+        assert digest(jaxpr) == PARENT_JAXPR
+
+
 # ------------------------------------------------------------- tied head --
 def lm(tie, layer_types=("conv", "full_attention"), dense=1, **kw):
     return dtpu.models.lfm2_moe_lm(
