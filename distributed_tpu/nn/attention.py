@@ -957,7 +957,13 @@ class GroupedQueryAttention(Layer):
     ``rope_half`` / ``rope_rotary``, the definition the kernels are held to
     (rounded to the input's dtype after the norm and after the rotation,
     both ways). The trace-time counters ``attn.qk_prep_fused`` and
-    ``attn.qk_prep_xla`` count the calls of either path, q and k each.
+    ``attn.qk_prep_xla`` count the calls of either path, q and k each. On
+    the same condition the gate is one kernel pass each way on the (B, T, H
+    x 128) layout the flash kernels return and ``Wo`` reads
+    (``ops/head_gate.py``: ``dtpu_head_gate`` and its backward, under the
+    scope ``gate``); elsewhere it is the plain lines below, which the
+    kernels are held to. ``attn.gate_fused`` and ``attn.gate_xla`` count a
+    gated layer's calls of either path.
 
     ``window``: query t sees the keys t - window < s <= t (the flash
     kernels' ``window``; ``dtpu_flash_*_swa``). ``rotary_dim``: the first
@@ -1167,8 +1173,9 @@ class GroupedQueryAttention(Layer):
         proj = lambda a, w: jnp.dot(
             a, maybe_dequantize(params[w]).astype(a.dtype))
         flash = self._use_flash(t) and ambient_mesh()[0] is None
-        # A head is one lane tile: what takes a selection in Mosaic, and
-        # what norms and rotates q and k on the projections' own layout.
+        # A head is one lane tile: what takes a selection in Mosaic, what
+        # norms and rotates q and k on the projections' own layout, and what
+        # gates the heads' outputs on the flash kernels' own.
         kernels = flash and hd == 128
         count = default_registry().counter
         if kernels:
@@ -1224,9 +1231,17 @@ class GroupedQueryAttention(Layer):
                                           return_lse=True)
         if self.gate:
             with child_scope("gate"):
-                g = jax.nn.sigmoid(proj(x, "wg").astype(jnp.float32))
-                ctx = (ctx.astype(jnp.float32) * g[..., None]).astype(
-                    ctx.dtype)
+                z = proj(x, "wg")
+                if kernels:
+                    from ..ops.head_gate import head_gate
+
+                    ctx = head_gate(ctx.reshape(b, t, h * hd), z)
+                    count("attn.gate_fused")
+                else:
+                    g = jax.nn.sigmoid(z.astype(jnp.float32))
+                    ctx = (ctx.astype(jnp.float32) * g[..., None]).astype(
+                        ctx.dtype)
+                    count("attn.gate_xla")
         out = proj(ctx.reshape(b, t, h * hd), "wo")
         if train and self.window:
             w = min(self.window, t)

@@ -1,6 +1,7 @@
 """What a ``GroupedQueryAttention``'s gradient does to q and k between their
-projections and the attention: a helper of the layer guards in
-``test_laguna.py``, ``test_keye_vl2.py`` and ``test_lfm2_moe.py``."""
+projections and the attention, and to the heads' outputs under its gate: a
+helper of the layer guards in ``test_laguna.py``, ``test_keye_vl2.py``,
+``test_lfm2_moe.py`` and ``test_head_gate.py``."""
 
 import hashlib
 import re
@@ -24,16 +25,17 @@ def _eqns(jaxpr):
                     yield from _eqns(sub)
 
 
-def gradient_jaxpr(layer, t, d, batch=1):
+def gradient_jaxpr(layer, t, d, batch=1, counters=COUNTERS):
     """The jaxpr of the gradient of a train-mode call's summed output in the
     layer's leaves and its (batch, t, d) float32 input, traced on shapes
-    alone, and what the trace added to the two ``attn.qk_prep_*`` counters."""
+    alone, and what the trace added to ``counters`` (the two
+    ``attn.qk_prep_*``)."""
     params, state, _ = jax.eval_shape(
         lambda k: layer.init(k, (t, d)), jax.random.PRNGKey(0))
     x = jax.ShapeDtypeStruct((batch, t, d), jnp.float32)
     loss = lambda p, x, state: jnp.sum(
         layer.apply(p, state, x, train=True)[0].astype(jnp.float32))
-    read = lambda: [default_registry().counter_value(c) for c in COUNTERS]
+    read = lambda: [default_registry().counter_value(c) for c in counters]
     before = read()
     jaxpr = jax.make_jaxpr(jax.grad(loss, (0, 1)))(params, x, state)
     return jaxpr, tuple(int(b - a) for a, b in zip(before, read()))
@@ -43,6 +45,12 @@ def kernel_calls(jaxpr):
     """Names of the Pallas kernels the jaxpr calls, sorted, one a call."""
     return sorted(e.params["name"] for e in _eqns(jaxpr.jaxpr)
                   if e.primitive.name == "pallas_call")
+
+
+def kernel_grids(jaxpr):
+    """Name and grid of every Pallas kernel call of the jaxpr, in order."""
+    return [(e.params["name"], e.params["grid_mapping"].grid)
+            for e in _eqns(jaxpr.jaxpr) if e.primitive.name == "pallas_call"]
 
 
 def float32_head_views(jaxpr, head_dim=128, scopes=("q_norm", "k_norm")):

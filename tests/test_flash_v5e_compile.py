@@ -4,7 +4,8 @@ cells' shapes and at the scoped-VMEM clamp shape; and, for the
 latent-attention cell, folded at 192-wide keys and 128-wide values; and the
 grouped-matmul kernels at the held experts' shapes; and the index scores'
 backward kernel at the selecting cell's; and the per-head norm and rotation
-at the two 128-wide-head cells' head counts. Nothing runs: this guards the
+at the two 128-wide-head cells' head counts; and the head-wise gate at the
+Laguna cell's. Nothing runs: this guards the
 16 MB scoped-VMEM limit and the lane / sublane alignment of the in-kernel
 sub-tile slices, which interpret mode cannot see, at no chip time
 (on-chip-measurement guide, third rehearsal; the whole step programs are
@@ -23,6 +24,7 @@ from jax.sharding import SingleDeviceSharding
 
 from distributed_tpu.ops import flash_attention as fa
 from distributed_tpu.ops import grouped_matmul as gm
+from distributed_tpu.ops import head_gate as hg
 from distributed_tpu.ops import head_norm_rope as hn
 from distributed_tpu.ops import index_scores as ix
 
@@ -49,9 +51,10 @@ def mosaic(monkeypatch):
     monkeypatch.setattr(gm, "_interpret", lambda: False)
     monkeypatch.setattr(ix, "_interpret", lambda: False)
     monkeypatch.setattr(hn, "_interpret", lambda: False)
+    monkeypatch.setattr(hg, "_interpret", lambda: False)
     fa._flash_cached.cache_clear()
-    hn._forward.clear_cache()
-    hn._backward.clear_cache()
+    for jitted in (hn._forward, hn._backward, hg._forward, hg._backward):
+        jitted.clear_cache()
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
@@ -59,8 +62,8 @@ def mosaic(monkeypatch):
     jax.config.update("jax_enable_compilation_cache", was)
     compilation_cache.reset_cache()
     fa._flash_cached.cache_clear()
-    hn._forward.clear_cache()
-    hn._backward.clear_cache()
+    for jitted in (hn._forward, hn._backward, hg._forward, hg._backward):
+        jitted.clear_cache()
 
 
 @pytest.mark.parametrize("shape,causal", [
@@ -248,4 +251,27 @@ def test_head_norm_rope_compiles_for_v5e(heads, rotation, one_chip, mosaic):
     calls = re.findall(r'custom_call_target="tpu_custom_call".*?'
                        r'op_name="[^"]*/(dtpu_head\w*)/pallas_call"', text)
     assert sorted(calls) == ["dtpu_head_norm_rope", "dtpu_head_norm_rope_bwd"]
+    assert not re.search(rf"(?:f32|bf16)\[1,8192,{heads},128\]", text)
+
+
+@pytest.mark.parametrize("heads,dtype", [
+    (64, jnp.bfloat16), (48, jnp.bfloat16),  # the cell's two head counts
+    (64, jnp.float32), (8, jnp.bfloat16)])
+def test_head_gate_compiles_for_v5e(heads, dtype, one_chip, mosaic):
+    """laguna-xs2.train.swa8k's gate at 8,192 rows of 64 heads (sliding
+    layers) and 48 (full ones): both kernels' blocks, all of a row block's
+    heads, fit the 16 MB a kernel may use, the gate's one-lane columns lower,
+    and no (T, H, 128) view is left."""
+    spec = lambda shape: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def both(ctx, z, g):
+        out, vjp = jax.vjp(hg.head_gate, ctx, z)
+        return out, vjp(g)
+
+    wide = spec((1, 8192, heads * 128))
+    text = jax.jit(both).lower(wide, spec((1, 8192, heads)),
+                               wide).compile().as_text()
+    calls = re.findall(r'custom_call_target="tpu_custom_call".*?'
+                       r'op_name="[^"]*/(dtpu_head\w*)/pallas_call"', text)
+    assert sorted(calls) == ["dtpu_head_gate", "dtpu_head_gate_bwd"]
     assert not re.search(rf"(?:f32|bf16)\[1,8192,{heads},128\]", text)
