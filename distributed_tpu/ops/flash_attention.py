@@ -1050,13 +1050,18 @@ def _bwd_pallas(res, g, *, heads, hpb, suffix, causal, block_q, block_k,
                                              nh * wv)
     selection = _pad_selection(selection, t_pad)
 
-    # delta_i = sum_j dO_ij O_ij per row and head, laid out like lse: XLA
-    # reduces over a 64-wide minor dimension by making T minor first, so
-    # this layout is the one it reaches with no copy after the reduce.
-    by_head = (b, t, nh, hpb, out.shape[-1] // heads)
-    gf = g.astype(jnp.float32).reshape(by_head)
-    of = out.astype(jnp.float32).reshape(by_head)
-    delta = jnp.transpose(jnp.sum(gf * of, axis=-1), (0, 2, 3, 1))
+    # delta_i = sum_j dO_ij O_ij per row and head, laid out like lse. The
+    # product stays on the (b, T, heads x D) layout the kernels read and a
+    # 0/1 (heads x D, heads) matrix sums each head's lanes: XLA:TPU fuses
+    # the product into the contraction's operand, reading dO and O once,
+    # where a sum over a (b, T, H, D) view makes it write that view in f32
+    # and relayout it, five times the bytes (scripts/flash_delta_bytes.py).
+    # The product is f32 (out is promoted), exact for bf16 inputs; HIGHEST
+    # has the MXU take it whole, where a lower precision rounds it to bf16.
+    lanes = jnp.arange(out.shape[-1]) // (out.shape[-1] // heads)
+    delta = jnp.einsum("btw,wh->bht", g.astype(jnp.float32) * out, (
+        lanes[:, None] == jnp.arange(heads)).astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST).reshape(b, nh, hpb, t)
     delta = jnp.pad(delta, ((0, 0), (0, 0), (0, 0), (0, t_pad - t)))
 
     grid, (q_spec, do_spec, k_spec, v_spec, stat, sel_spec) = specs(q_axis=2)

@@ -412,6 +412,24 @@ def _dot_operand_dtypes(jaxpr):
             for eqn in _eqns(jaxpr) if eqn.primitive.name == "dot_general"}
 
 
+def _dots_outside_kernels(jaxpr):
+    """(operand dtypes, precision) of each matrix product of the jaxpr that
+    no ``pallas_call`` holds."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "dot_general":
+            found.append((tuple(str(v.aval.dtype) for v in eqn.invars),
+                          eqn.params["precision"]))
+        if eqn.primitive.name == "pallas_call":
+            continue
+        for param in eqn.params.values():
+            for sub in param if isinstance(param, (list, tuple)) else [param]:
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    found += _dots_outside_kernels(sub)
+    return found
+
+
 @pytest.mark.parametrize("heads,head_dim,value_dim", [
     (2, 64, 64), (1, 128, 128),  # packed
     (3, 64, 64), (2, 192, 128),  # folded: odd heads; latent attention
@@ -420,7 +438,8 @@ def test_kernels_feed_the_mxu_bf16(heads, head_dim, value_dim):
     """Every matrix product of the three kernels takes bf16 operands from
     bf16 inputs: the scale folded into q (head_dim 64: a power of two) must
     not promote it (a NumPy scalar is no weak type), and head_dim 128 and
-    192 keep their f32 multiply on the scores."""
+    192 keep their f32 multiply on the scores. The one product outside them
+    is the backward's delta = sum(dO O), f32 products summed at HIGHEST."""
     q = jax.ShapeDtypeStruct((1, 512, heads, head_dim), jnp.bfloat16)
     v = jax.ShapeDtypeStruct((1, 512, heads, value_dim), jnp.bfloat16)
 
@@ -429,7 +448,14 @@ def test_kernels_feed_the_mxu_bf16(heads, head_dim, value_dim):
                        .astype(jnp.float32))
 
     jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, q, v)
-    assert _dot_operand_dtypes(jaxpr.jaxpr) == {("bfloat16", "bfloat16")}
+    kernels = [e.params["jaxpr"] for e in _eqns(jaxpr.jaxpr)
+               if e.primitive.name == "pallas_call"]
+    assert len(kernels) == 3
+    assert set().union(*map(_dot_operand_dtypes, kernels)) == {
+        ("bfloat16", "bfloat16")}
+    highest = (jax.lax.Precision.HIGHEST,) * 2
+    assert _dots_outside_kernels(jaxpr.jaxpr) == [
+        (("float32", "float32"), highest)]
 
 
 @pytest.mark.parametrize("causal", [False, True])
@@ -468,6 +494,75 @@ def test_residual_statistic(shape, value_dim, blocks, side, causal, subtile):
     np.testing.assert_allclose(
         np.asarray(lse[..., :t]).reshape(b, h, t), np.asarray(want),
         rtol=2e-5, atol=2e-5)
+
+
+# (B, H, K/V heads, D, Dv, window, a selection) of each layout the backward
+# forms its delta in: packed at 128-wide heads (grouped, with a selection,
+# with a window), packed at 64, folded at 192 / 128.
+DELTA_LAYOUTS = {
+    "grouped128": (2, 4, 2, 128, 128, None, False),
+    "selecting128": (2, 4, 2, 128, 128, None, True),
+    "window128": (2, 4, 2, 128, 128, 64, False),
+    "packed64": (2, 4, 4, 64, 64, None, False),
+    "folded": (2, 2, 2, 192, 128, None, False),
+}
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+@pytest.mark.parametrize("layout", list(DELTA_LAYOUTS))
+def test_backward_delta_is_the_sum_of_products(layout, dtype, monkeypatch):
+    """delta_i = sum_j dO_ij O_ij, a row and head, as the dq and dk/dv
+    kernels receive it ((b, head blocks, heads a block, t_pad), lane-major):
+    the float32 products' sum to within 1e-6 of sum_j |dO_ij O_ij| on every
+    real row, and 0 on the rows that pad T = 200 to the blocks' 256."""
+    from distributed_tpu.ops import flash_attention as fa
+
+    b, h, kv, d, dv, window, selecting = DELTA_LAYOUTS[layout]
+    t, blocks = 200, dict(block_q=64, block_k=128)
+    rng = np.random.default_rng(3)
+    arrays = [jnp.asarray(rng.standard_normal((b, t, n, w)), dtype)
+              for n, w in ((h, d), (kv, d), (kv, dv))]
+    if dv == d and fa._packed_supported(h, d):
+        rows, heads, hpb, group = b, h, 128 // d, h // kv
+        lay = lambda x: x.reshape(b, t, -1)
+    else:
+        rows, heads, hpb, group = b * h, 1, 1, 1
+        lay = lambda x: jnp.moveaxis(x, 2, 1).reshape(b * h, t, -1)
+    q, k, v = map(lay, arrays)
+    selection = None
+    if selecting:
+        sel = _spread_selection(t)
+        selection = (fa.selection_blocks(sel, **blocks)[0].reshape(-1), sel)
+    static = dict(heads=heads, hpb=hpb, causal=True, group=group,
+                  window=window, suffix="_swa" if window else "", **blocks)
+    out, lse = fa._fwd_pallas(q, k, v, selection, **static)
+    g = jnp.asarray(rng.standard_normal(out.shape), dtype)
+
+    seen = {}
+    call = fa._call
+
+    def spy(*args):  # a kernel's call, its name and last operand recorded
+        run = call(*args)
+
+        def record(*operands):
+            seen[args[7]] = operands[-1]
+            return run(*operands)
+        return record
+
+    monkeypatch.setattr(fa, "_call", spy)
+    fa._bwd_pallas((q, k, v, out, lse, selection), g, **static)
+    deltas = [np.asarray(x) for name, x in seen.items()
+              if name.startswith(("dtpu_flash_dq", "dtpu_flash_dkv"))]
+    assert len(deltas) == 2 and np.array_equal(*deltas)
+    delta = deltas[0]
+    assert delta.shape == (rows, heads // hpb, hpb, 256)
+    assert delta.dtype == np.float32
+    products = np.asarray(g.astype(jnp.float32) * out.astype(jnp.float32),
+                          np.float64).reshape(rows, t, heads, -1)
+    want, scale = products.sum(-1), np.abs(products).sum(-1)
+    got = delta[..., :t].reshape(rows, heads, t).transpose(0, 2, 1)
+    assert np.all(np.abs(got - want) <= 1e-6 * scale)
+    assert not np.any(delta[..., t:])
 
 
 # ---------------------------------------------------- grouped query heads --
@@ -756,21 +851,26 @@ def test_kv_block_fetches_at_the_selecting_cells_shape():
 # grids and index maps of the calls that share no K/V head in place, at the
 # four cells that make them (both GPT-2 cells' layout, kanana's folded
 # widths, LFM2's repeated 64-wide heads) and at 128-wide heads with one K/V
-# head a query head, with a selection and without.
+# head a query head, with a selection and without. Re-pinned where the
+# backward's delta = sum(dO O) became a contraction on the kernels' (b, T,
+# H x D) layout: against commit 7c33136's text the equations of delta alone
+# differ (a reshape to (b, T, H, D) of each operand, their product, its sum
+# and a transpose, for an iota, the product on (b, T, H x D), a 0/1 matrix,
+# a dot_general and a reshape), equation by equation, names aside.
 PARENT_JAX = "0.9.0"
 PARENT_JAXPRS = {
     "packed64": ((8, 1024, 16, 16, 64, 64, False),
-                 "c89acd0a5c7f8381d2fd77fb489042fab9071119fab6d844b3d53fc603d4ba4e"),
+                 "9227552482bc9d98d9e94f41c67d444489fb6d3feecda581379760cac9a9fc42"),
     "packed64_long": ((1, 4096, 16, 16, 64, 64, False),
-                      "b4e7ff96060b941408c9a895bf954b8c11b0bfde1b6b72157b508d12677723d6"),
+                      "b009002aef760ccd28eb7140772f618d47150e6197f1d9105d931f858e971d6e"),
     "folded": ((1, 4096, 32, 32, 192, 128, False),
-               "420a22c491223d568f3d523f2ff850254d3bebbcd9d5d5d87cca426cc27531a7"),
+               "5baf7d308998946400a72dbb3364b054343b9f07ba52396a1bb21c44960d4d4d"),
     "repeated64": ((1, 8192, 32, 8, 64, 64, False),
-                   "43955745a889a1527e994b1120367fc807ef2de499980228a029653821a0458c"),
+                   "aad5e3f3e289f3d1e3967ca87572d366abdb15d8acbf8f1df5de13d875b95cbc"),
     "sel_128": ((1, 2048, 4, 4, 128, 128, True),
-                "8840d978165b0f0e5d827c8b8395cff36854862e719188f44e53f89591379f79"),
+                "2eabc9e41a52d2744e161d5b03535f5d059e95d62d25b996ce7f097425f41c80"),
     "packed_128": ((1, 2048, 4, 4, 128, 128, False),
-                   "1398f70fe554d646d20f8ea87e07fa5231a5daab181f5458836ec54cd8ed6328"),
+                   "94804f86569479d6ad3b0f048f0df307acfb21170f333e69deacd931b20b0740"),
 }
 
 

@@ -5,7 +5,8 @@ latent-attention cell, folded at 192-wide keys and 128-wide values; and the
 grouped-matmul kernels at the held experts' shapes; and the index scores'
 backward kernel at the selecting cell's; and the per-head norm and rotation
 at the two 128-wide-head cells' head counts; and the head-wise gate at the
-Laguna cell's. Nothing runs: this guards the
+Laguna cell's; and the flash backward at five cells' layer shapes, for a
+float32 view of the heads outside its kernels. Nothing runs: this guards the
 16 MB scoped-VMEM limit and the lane / sublane alignment of the in-kernel
 sub-tile slices, which interpret mode cannot see, at no chip time
 (on-chip-measurement guide, third rehearsal; the whole step programs are
@@ -14,6 +15,7 @@ sub-tile slices, which interpret mode cannot see, at no chip time
 Kept in ONE file: the worker that runs it loads libtpu and keeps its lock.
 """
 
+import math
 import os
 import re
 
@@ -275,3 +277,50 @@ def test_head_gate_compiles_for_v5e(heads, dtype, one_chip, mosaic):
                        r'op_name="[^"]*/(dtpu_head\w*)/pallas_call"', text)
     assert sorted(calls) == ["dtpu_head_gate", "dtpu_head_gate_bwd"]
     assert not re.search(rf"(?:f32|bf16)\[1,8192,{heads},128\]", text)
+
+
+# ``%name = type opcode(`` of an instruction of the HLO text.
+_INSTRUCTION = re.compile(r"^\s*(?:ROOT )?%?[\w.\-]+ = (.+?) ([\w\-]+)\(")
+
+
+@pytest.mark.parametrize("shape,kv_heads,value_dim,window,selecting", [
+    ((1, 8192, 64, 128), 8, 128, 512, False),   # Laguna's sliding layers
+    ((1, 8192, 48, 128), 8, 128, None, False),  # its full layers
+    ((1, 8192, 32, 128), 4, 128, None, True),   # Keye
+    ((8, 1024, 16, 64), 16, 64, None, False),   # gpt2-medium
+    ((1, 4096, 32, 192), 32, 128, None, False),  # kanana, folded
+])
+def test_flash_backward_makes_no_float32_head_view_for_v5e(
+        shape, kv_heads, value_dim, window, selecting, one_chip, mosaic):
+    """The backward alone (``jax.vjp``, its cotangent bf16, so no loss adds
+    a float32 array): outside the ``dtpu_flash_*`` calls the entry
+    computation makes no float32 array of T x H x D entries or more. The
+    row statistic delta = sum(dO O) is contracted on the kernels' (b, T,
+    H x D) layout: a sum over a float32 (b, T, H, D) view makes XLA:TPU
+    write that view and relayout it."""
+    b, t, h, d = shape
+    spec = lambda shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(
+        shape, dtype, sharding=one_chip)
+    sel = spec((b, t, t), jnp.int8) if selecting else None
+
+    def backward(q, k, v, sel, g):
+        out, pull = jax.vjp(lambda q, k, v: fa.flash_attention(
+            q, k, v, causal=True, selection=sel, window=window), q, k, v)
+        return pull(g)
+
+    text = jax.jit(backward).lower(
+        spec(shape), spec((b, t, kv_heads, d)),
+        spec((b, t, kv_heads, value_dim)), sel,
+        spec((b, t, h, value_dim))).compile().as_text()
+    entry = text[text.index("\nENTRY"):]
+    entry = entry[:entry.index("\n}")].splitlines()[2:]
+    assert any("dtpu_flash_dq" in line for line in entry)
+    views = []
+    for line in entry:
+        m = _INSTRUCTION.match(line)
+        if m and not (m.group(2) == "custom-call" and "dtpu_flash" in line):
+            views += [dims for dims in re.findall(r"f32\[([\d,]*)\]",
+                                                  m.group(1))
+                      if math.prod(int(n) for n in dims.split(",") if n)
+                      >= t * h * value_dim]
+    assert views == []

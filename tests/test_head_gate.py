@@ -261,11 +261,14 @@ def test_a_128_wide_gated_layer_gates_in_the_kernels(sliding):
 
 # The gradient's jaxprs of the two ungated cells' layers, with the flash path
 # on, as the tree before the gate's kernels traced them: Keye's 128-wide
-# selecting heads and LFM2's 64-wide ones.
+# selecting heads and LFM2's 64-wide ones. Re-pinned where the flash
+# backward's delta became a contraction on the (b, T, H x D) layout: against
+# commit 7c33136's text only delta's equations differ
+# (``test_flash_attention.py:PARENT_JAXPRS``).
 PARENT_JAX = "0.9.0"
 PARENT_JAXPRS = {
-    "keye": "0a1a977e11f4a53109bbccfe835d71a5d3edd089e7a658c606a323a75d582845",
-    "lfm2": "5f56e0747b2ff571ce1072f012e173237078e2f6feeaacd6c120a4124f38c8a3",
+    "keye": "cb0fea852f6084ea15aca0951861321c8161f04ba12513e7b5b36a492f0bcfd4",
+    "lfm2": "57bdb0dc4bae6eabf6ad4ae58af864bd6b79e5e144674b6e029fc98fc48dab7c",
 }
 
 
