@@ -26,7 +26,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from . import initializers
-from .core import Layer, Shape, child_scope, read_counters
+from .core import Layer, Shape, child_scope, counts
 from ..obs.registry import default_registry
 from ..ops.index_scores import (
     block_index_scores, index_scores, kernel_fits, tile_counts)
@@ -921,10 +921,10 @@ def _index_loss_bwd(block, kernel, res, ct):
 index_loss.defvjp(_index_loss_fwd, _index_loss_bwd)
 
 # Names of a selecting GroupedQueryAttention's counters in its state.
-_SELECT_COUNTERS = ("steps", "queries", "causal_pairs", "selected_pairs",
-                    "blocks_total", "blocks_computed")
-_WINDOW_COUNTERS = ("steps", "queries", "causal_pairs", "window_pairs",
-                    "walked_pairs")
+_SELECT_COUNTERS = counts("select", ("steps", "queries", "causal_pairs",
+                          "selected_pairs", "blocks_total", "blocks_computed"))
+_WINDOW_COUNTERS = counts("window", ("steps", "queries", "causal_pairs",
+                                     "window_pairs", "walked_pairs"))
 
 
 class GroupedQueryAttention(Layer):
@@ -1004,12 +1004,12 @@ class GroupedQueryAttention(Layer):
     ``L_I`` and its gradient) and, inside it, ``select``; ``gate`` (the
     gate's projection, its sigmoid and its product with the heads'
     outputs). A windowed layer counts in its state, cumulative over train
-    steps (``window_counters``): ``steps``, ``queries``, ``causal_pairs``
-    (pairs s <= t), ``window_pairs`` (those inside the window) and
-    ``walked_pairs``, the pairs of the sub-tiles the windowed kernels
-    computed (``flash_attention.subtile_counts``; 0 on the dense path).
-    A selecting layer's counters, cumulative over
-    train steps, in the layer's state (``select_counters``): ``steps``,
+    steps (``core.counters``' "window"): ``steps``, ``queries``,
+    ``causal_pairs`` (pairs s <= t), ``window_pairs`` (those inside the
+    window) and ``walked_pairs``, the pairs of the sub-tiles the windowed
+    kernels computed (``flash_attention.subtile_counts``; 0 on the dense
+    path). A selecting layer's counters, cumulative over train steps, in
+    the layer's state (``core.counters``' "select"): ``steps``,
     ``queries``, ``causal_pairs`` (pairs s <= t), ``selected_pairs``, and the
     flash kernels' grid blocks at or below the diagonal, ``blocks_total``,
     with those that held a selected pair and were walked,
@@ -1272,20 +1272,6 @@ class GroupedQueryAttention(Layer):
                 new_state["selection"] = jnp.packbits(
                     selection[0].astype(bool), axis=-1)
         return out, new_state
-
-
-def window_counters(state) -> dict:
-    """``{layer path: {counter: value}}`` of every windowed
-    ``GroupedQueryAttention`` in a model's ``state`` tree
-    (``core.read_counters``)."""
-    return read_counters(state, _WINDOW_COUNTERS)
-
-
-def select_counters(state) -> dict:
-    """``{layer path: {counter: value}}`` of every selecting
-    ``GroupedQueryAttention`` in a model's ``state`` tree
-    (``core.read_counters``)."""
-    return read_counters(state, _SELECT_COUNTERS)
 
 
 class PositionalEmbedding(Layer):

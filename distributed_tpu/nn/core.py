@@ -194,10 +194,20 @@ class NameScope:
         return name
 
 
-_AMBIENT_WEIGHTS = threading.local()
+# Trace-time ambient values, each set for a block by ``_ambient``.
+_AMBIENT = threading.local()
 
 
 @contextlib.contextmanager
+def _ambient(slot: str, value):
+    prev = getattr(_AMBIENT, slot, None)
+    setattr(_AMBIENT, slot, value)
+    try:
+        yield
+    finally:
+        setattr(_AMBIENT, slot, prev)
+
+
 def eval_sample_weights(weights):
     """Trace-time ambient per-EXAMPLE validity weights (shape (B,)).
 
@@ -208,16 +218,11 @@ def eval_sample_weights(weights):
     ``current_sample_weights()`` during tracing — the weights are a traced
     array, so they become a real input of the compiled step. Training
     never sets this (fit never pads), so the train graph is unchanged."""
-    prev = getattr(_AMBIENT_WEIGHTS, "value", None)
-    _AMBIENT_WEIGHTS.value = weights
-    try:
-        yield
-    finally:
-        _AMBIENT_WEIGHTS.value = prev
+    return _ambient("weights", weights)
 
 
 def current_sample_weights():
-    return getattr(_AMBIENT_WEIGHTS, "value", None)
+    return getattr(_AMBIENT, "weights", None)
 
 
 def child_scope(key: str):
@@ -239,24 +244,78 @@ def child_scope(key: str):
     return jax.named_scope(key)
 
 
-def read_counters(state, names) -> dict:
-    """``{layer path: {counter: value}}`` of every layer in a model's
-    ``state`` tree whose state holds all of ``names`` (a layer that counts in
-    its own state, inside the step program), fetched from the device: call
-    it outside a step loop (``Model.fit`` does, once, when a fit ends)."""
-    out = {}
+# What layers put into ``Model.last_fit_telemetry``, each under a kind of
+# its own: counters in their state (``counts``), or records handed to the
+# program being traced (``trace_record``) and read by ``reports``.
+_COUNTED: Dict[str, tuple] = {}  # kind -> (counter names, gauges)
+_REPORTS: Dict[str, Any] = {}  # kind -> report(record, strategy)
+
+
+def counts(kind: str, names, gauges=lambda layers: {}) -> tuple:
+    """A layer keeps the float counters ``names`` (returned) in its state,
+    reported under ``kind``; ``gauges``, given the kind's ``{layer path:
+    {counter: value}}``, returns ``{gauge: value}`` to set when a fit ends."""
+    _COUNTED[kind] = (tuple(names), gauges)
+    return _COUNTED[kind][0]
+
+
+def reports(kind: str):
+    """Register ``report(record, strategy)``: ``kind``'s entry (None for
+    none) from the record handed ``trace_record(kind, ...)`` while the step
+    program traced (None if none). It also emits the kind's events."""
+    def register(report):
+        _REPORTS[kind] = report
+        return report
+    return register
+
+
+def trace_record(kind: str, record) -> None:
+    """Hand ``record`` to the program being traced (``recording``)."""
+    records = getattr(_AMBIENT, "records", None)
+    if records is not None:
+        records[kind] = record
+
+
+def recording(records: dict):
+    """Collect into ``records`` what ``trace_record`` is handed in the block:
+    around a jitted function's call, what its trace handed."""
+    return _ambient("records", records)
+
+
+def counters(state) -> dict:
+    """``{kind: {layer path: {counter: value}}}`` of the layers in a model's
+    ``state`` tree that keep a ``counts`` kind's counters, fetched from the
+    device: call it outside a step loop (``Model.fit`` does, at its end)."""
+    out: Dict[str, dict] = {}
 
     def walk(tree, path):
         if not isinstance(tree, dict):
             return
-        if all(c in tree for c in names):
-            values = jax.device_get({c: tree[c] for c in names})
-            out["/".join(path)] = {c: float(v) for c, v in values.items()}
-            return
+        for kind, (names, _) in _COUNTED.items():
+            if all(c in tree for c in names):
+                values = jax.device_get({c: tree[c] for c in names})
+                out.setdefault(kind, {})["/".join(path)] = {
+                    c: float(v) for c, v in values.items()}
+                return
         for key, sub in tree.items():
             walk(sub, path + (key,))
 
     walk(state, ())
+    return out
+
+
+def layer_report(state, records: dict, strategy, registry) -> dict:
+    """The layers' entries of a fit's report: each ``reports`` kind's from
+    ``records``, each ``counts`` kind's with its gauges set in ``registry``."""
+    out = {}
+    for kind, report in sorted(_REPORTS.items()):
+        entry = report(records.get(kind), strategy)
+        if entry is not None:
+            out[kind] = entry
+    for kind, layers in sorted(counters(state).items()):
+        out[kind] = layers
+        for name, value in _COUNTED[kind][1](layers).items():
+            registry.gauge(name, value)
     return out
 
 

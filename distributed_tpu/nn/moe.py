@@ -35,7 +35,7 @@ import jax
 import jax.numpy as jnp
 
 from . import core, initializers
-from .core import Layer, Shape, child_scope, read_counters
+from .core import Layer, Shape, child_scope
 from .layers import GatedMLP
 from ..ops import grouped_matmul as gmm, moe_rows
 from ..quant import maybe_dequantize
@@ -338,7 +338,7 @@ def _combine_bwd(res, dy):
 
 _combine.defvjp(_combine_fwd, _combine_bwd)
 
-# Names of a DroplessMoE's counters in its state (``counters``).
+# Names of a DroplessMoE's counters in its state (kind "moe").
 _COUNTERS = ("steps", "pairs", "held_rows", "load_max_sum", "tiles_used",
              "buffer_tiles")
 
@@ -401,8 +401,8 @@ class DroplessMoE(Layer):
     sort, the row walks both ways), ``experts`` (the weights' casts and the
     nine grouped matmuls) and ``shared``. Counters, cumulative over
     train steps, in the layer's state and so read with no device sync
-    inside a step loop (``counters``): ``steps``, ``pairs`` (tokens x
-    ``top_k``), ``held_rows`` (pairs whose expert is held here),
+    inside a step loop (``core.counters``' "moe"): ``steps``, ``pairs``
+    (tokens x ``top_k``), ``held_rows`` (pairs whose expert is held here),
     ``load_max_sum`` (the busiest of all ``num_experts`` experts' pairs,
     summed over steps), ``tiles_used`` (tiles of the buffer the step went
     over) and ``buffer_tiles`` (tiles of its static worst case: their ratio
@@ -562,7 +562,13 @@ class DroplessMoE(Layer):
         return y, new_state
 
 
-def counters(state) -> dict:
-    """``{layer path: {counter: value}}`` of every ``DroplessMoE`` in a
-    model's ``state`` tree (``core.read_counters``)."""
-    return read_counters(state, _COUNTERS)
+def _gauges(layers):
+    # The layers' sums, and the tiles the row walks and the grouped matmuls
+    # went over, of the tiles of the buffers' static worst case.
+    total = lambda name: sum(c[name] for c in layers.values())
+    return {"moe.pairs": total("pairs"), "moe.held_rows": total("held_rows"),
+            "moe.buffer_used_pct": 100.0 * total("tiles_used") / max(
+                total("buffer_tiles"), 1.0)}
+
+
+core.counts("moe", _COUNTERS, _gauges)

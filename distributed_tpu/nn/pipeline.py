@@ -36,7 +36,6 @@ of S inlined copies, which keeps compile time flat in depth.
 
 from __future__ import annotations
 
-import threading
 from typing import Callable, Optional
 
 import jax
@@ -45,23 +44,39 @@ import numpy as np
 from jax import lax, shard_map
 from jax.sharding import PartitionSpec
 
-from .core import Layer, Shape
+from ..obs import registry as obs_registry
+from ..utils import event_schema as evs, events as events_lib
+from .core import Layer, Shape, reports, trace_record
 
 
-# Trace-time record of the most recent pipelined apply on this thread:
-# which schedule ran, over how many stages/microbatches/ticks, and the
-# resulting bubble fraction. Model.fit's telemetry exit reads it
-# (training/model.py) the same way it reads scan.last_overlap_trace —
-# best-effort by design, like the threadlocal strategy scope it mirrors.
-_pipeline_trace = threading.local()
-
-
-def last_pipeline_trace() -> Optional[dict]:
-    """``{"schedule", "interleave", "num_stages", "num_microbatches",
-    "ticks", "bubble_fraction"}`` from the most recent pipelined apply
-    traced on this thread, or None before any (including the sequential
-    single-device path, which has no schedule to report)."""
-    return getattr(_pipeline_trace, "record", None)
+@reports("pipeline")
+def _pipeline_report(record, strategy):
+    """Pipeline-schedule attribution (PipelinedBlocks x schedule), from the
+    record the step program's pipelined apply handed at trace time: which
+    schedule ran, its static tick count, and the analytic bubble fraction
+    (n-1)/ticks (docs/API.md "Pipeline round 2"). None without one (the
+    sequential single-device path has no schedule to report)."""
+    if record is None:
+        return None
+    if obs_registry.enabled() and events_lib.default_log() is not None:
+        events_lib.emit(
+            evs.PIPELINE_SCHEDULE_SELECTED,
+            schedule=record["schedule"],
+            interleave=record["interleave"],
+            num_stages=record["num_stages"],
+            num_microbatches=record["num_microbatches"],
+            strategy=type(strategy).__name__,
+        )
+        events_lib.emit(
+            evs.BUBBLE_REPORT,
+            bubble_fraction=record["bubble_fraction"],
+            ticks=record["ticks"],
+            schedule=record["schedule"],
+            interleave=record["interleave"],
+            num_stages=record["num_stages"],
+            num_microbatches=record["num_microbatches"],
+        )
+    return dict(record)
 
 
 def _live_pipe_mesh(strategy):
@@ -245,14 +260,14 @@ class PipelinedBlocks(Layer):
         b_local = b_global // n_data
         mb = b_local // m
         ticks = v * m + n - 1
-        _pipeline_trace.record = {
+        trace_record("pipeline", {
             "schedule": self.schedule,
             "interleave": v,
             "num_stages": n,
             "num_microbatches": m,
             "ticks": ticks,
             "bubble_fraction": round((n - 1) / ticks, 6),
-        }
+        })
         feat_none = (None,) * (x.ndim - 1)
         rows = row_axes if len(row_axes) > 1 else row_axes[0]
         x_spec = PartitionSpec(rows, *feat_none)

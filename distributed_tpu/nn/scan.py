@@ -20,27 +20,38 @@ and threaded through the scan as per-iteration inputs/outputs.
 
 from __future__ import annotations
 
-import threading
 from typing import Callable, Optional
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 
-from .core import Layer, Shape, child_scope
-
-# Trace-time record of the most recent ScannedBlocks.apply on this thread:
-# whether the gather overlap engaged and over how many layers. Model.fit's
-# telemetry exit reads it (training/model.py) to attribute exposed
-# communication without a layer-tree traversal protocol — best-effort by
-# design, like the threadlocal strategy scope it mirrors.
-_overlap_trace = threading.local()
+from ..obs import registry as obs_registry
+from ..utils import event_schema as evs, events as events_lib
+from .core import Layer, Shape, child_scope, reports, trace_record
 
 
-def last_overlap_trace() -> Optional[dict]:
-    """``{"layers": int, "active": bool}`` from the most recent scanned
-    apply traced on this thread, or None before any."""
-    return getattr(_overlap_trace, "record", None)
+@reports("overlap")
+def _overlap_report(record, strategy):
+    """Gather-overlap attribution (ScannedBlocks x Strategy.overlap_spec),
+    from the record the step program's scanned apply handed at trace time:
+    whether the double-buffered gather engaged, over how many layers, and
+    ``exposed_comm_fraction``, the analytic share of per-layer gather
+    traffic left serial with compute: all L gathers without overlap, only
+    layer 0's warm-up gather with it. Exposed collective time on the chip
+    is the benchmark's trace metric; this rides with every fit so telemetry
+    names the lever (docs/API.md "Overlap round 2")."""
+    entry = dict(record or {"overlap": False, "exposed_comm_fraction": 1.0,
+                            "layers": 0})
+    if obs_registry.enabled() and events_lib.default_log() is not None:
+        events_lib.emit(
+            evs.OVERLAP_REPORT,
+            overlap=entry["overlap"],
+            exposed_comm_fraction=entry["exposed_comm_fraction"],
+            layers=entry["layers"],
+            strategy=type(strategy).__name__,
+        )
+    return entry
 
 
 def init_stacked_blocks(
@@ -376,9 +387,12 @@ class ScannedBlocks(Layer):
             jax.random.split(rng, self.num_blocks) if rng is not None else None
         )
         gather = self._overlap_gather()
-        _overlap_trace.record = {
-            "layers": self.num_blocks, "active": gather is not None,
-        }
+        trace_record("overlap", {
+            "overlap": gather is not None,
+            "exposed_comm_fraction": (
+                1.0 if gather is None else round(1.0 / self.num_blocks, 6)),
+            "layers": self.num_blocks,
+        })
         out, new_s = scan_stacked(
             self.block, params["blocks"], state.get("blocks", {}), x,
             train=train, rngs=rngs, overlap_gather=gather,

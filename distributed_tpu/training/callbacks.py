@@ -18,8 +18,6 @@ import math
 import os
 import time
 from pathlib import Path
-from typing import Optional
-
 import jax
 import numpy as np
 
@@ -49,6 +47,11 @@ class Callback:
 
     def on_train_end(self, model, history):
         pass
+
+    def telemetry(self, model) -> dict:
+        """Entries for ``model.last_fit_telemetry``, asked after every
+        callback's ``on_train_end``."""
+        return {}
 
 
 class ModelCheckpoint(Callback):
@@ -140,8 +143,7 @@ class ModelCheckpoint(Callback):
         blocked wall time to the active fit's checkpoint_wait bucket —
         through the obs span tracer, so checkpoint attribution shares the
         train/serve code path (registry counter + XProf annotation)."""
-        timer = getattr(model, "_stall_timer", None)
-        with obs_spans.span("checkpoint_wait", timer=timer):
+        with obs_spans.span("checkpoint_wait", timer=model.stall_timer):
             return fn()
 
     def _select_tier(self):
@@ -279,9 +281,14 @@ class ModelCheckpoint(Callback):
         self._timed(model, self.ckpt.wait)
         if self._buddy is not None:
             self._timed(model, self._buddy.wait)
-            # The (1+1/N)x pricing rides the fit telemetry (fit assembles
-            # last_fit_telemetry right after on_train_end).
-            model._redundancy_report = self._buddy.report(model)
+
+    def telemetry(self, model):
+        # Buddy-redundancy pricing: the measured (1+1/N)x of holding a
+        # peer's shard mirror in host RAM, next to the state bytes it
+        # insures (docs/RESILIENCE.md "Recovery tiers").
+        if self._buddy is None:
+            return {}
+        return {"redundancy": self._buddy.report(model)}
 
 
 def _metric_mode(monitor: str) -> str:

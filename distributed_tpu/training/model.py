@@ -40,9 +40,9 @@ from ..nn.core import (
     child_scope as _child_scope,
     apply_layers as _apply_layers,
     eval_sample_weights as _eval_sample_weights,
+    layer_report as _layer_report,
+    recording as _recording,
 )
-from ..nn import moe as moe_lib
-from ..nn import attention as attention_lib
 from ..ops import losses as losses_lib
 from ..ops import metrics as metrics_lib
 from ..parallel.strategy import SingleDevice, Strategy, current_strategy
@@ -190,7 +190,7 @@ class Model:
         self._dtype_hints = {}  # per-layer dtype= overrides, set by build()
         self.stop_training = False  # callbacks (EarlyStopping) set this
         self._resumed_step = None  # set by a restoring ModelCheckpoint
-        self._stall_timer = None  # live StepTimer of the fit in progress
+        self.stall_timer = None  # StepTimer of the fit in progress
         self.last_fit_telemetry = None  # stall_report() of the last fit
         self.last_plan = None  # auto_shard.Plan of compile(strategy="auto")
         self._auto_shard = None  # planner config, set by compile()
@@ -1011,17 +1011,20 @@ class Model:
         on first call, and trace-time code — MultiHeadAttention's
         ring-attention detection reads current_strategy(), layer dtype
         resolution reads precision.current_policy(). Per-call cost is a
-        thread-local set/reset."""
+        thread-local set/reset. The returned function's ``records`` keep what
+        layers handed ``nn.core.trace_record`` while it traced."""
         strategy = self.strategy
         policy = self.precision
+        records = {}
 
         def call(*args):
-            with strategy.scope():
+            with strategy.scope(), _recording(records):
                 if policy is None:
                     return jitted(*args)
                 with policy.scope():
                     return jitted(*args)
 
+        call.records = records
         return call
 
     def _get_eval_step(self):
@@ -1312,19 +1315,10 @@ class Model:
 
         # Stall accounting for this fit: input_wait / dispatch /
         # checkpoint_wait (callbacks attribute the latter through
-        # model._stall_timer). Summarized into last_fit_telemetry at exit.
+        # model.stall_timer). Summarized into last_fit_telemetry at exit.
         timer = StepTimer(warmup=0)
-        self._stall_timer = timer
+        self.stall_timer = timer
         in_timeline = obs_spans.loop_gate()
-        # Reset the thread's scanned-overlap trace record so this fit's
-        # telemetry can only see a record ITS OWN tracing wrote (a warm
-        # jit cache writes none — the report then under-claims rather
-        # than inherit another model's record).
-        from ..nn import scan as _nn_scan
-        _nn_scan._overlap_trace.record = None
-        # Same reset for the pipeline-schedule trace record (nn/pipeline.py).
-        from ..nn import pipeline as _nn_pipeline
-        _nn_pipeline._pipeline_trace.record = None
         # Observability runtime (docs/OBSERVABILITY.md): per-dispatch
         # flight records + step-seconds ring, and a periodic cross-rank
         # metrics_snapshot flush over the supervisor's event-log
@@ -1683,6 +1677,20 @@ class Model:
             # train-end wait() (flushing a background writer) attributes
             # its blocked time to checkpoint_wait and must be counted.
             cb.on_train_end(self, history)
+        self.last_fit_telemetry = self._fit_report(
+            timer, callbacks, x if y is None else None,
+            step_fn or self._get_multi_step_train_step(accum_m),
+            fit_steps_done, lambda: _flush_obs_window(force=True))
+        self.stall_timer = None
+        teardown_phase.end()
+        return history
+
+    def _fit_report(self, timer, callbacks, source, program, steps, flush):
+        """The report of the fit that ends, stored as the registry's
+        ``model.fit`` view: the stall report, memory, precision, traffic
+        estimate and plan, what callbacks (``Callback.telemetry``) and layers
+        (``nn.core.layer_report``) hand in, and the ``fit/*`` gauges."""
+        obs_reg = obs_registry.default_registry()
         report = timer.stall_report()
         # Device-memory telemetry: the allocator's peak/current bytes when
         # the backend exposes them (HBM backends do; XLA:CPU reports None)
@@ -1694,13 +1702,8 @@ class Model:
         report["model_state_bytes_per_device"] = tree_bytes_per_device(
             self.params, self.state, self.opt_state
         )["max_bytes_per_device"]
-        # Buddy-redundancy pricing (set by ModelCheckpoint(buddy=...) at
-        # train end): the measured (1+1/N)x of holding a peer's shard
-        # mirror in host RAM, next to the state bytes it insures
-        # (docs/RESILIENCE.md "Recovery tiers").
-        red = getattr(self, "_redundancy_report", None)
-        if red is not None:
-            report["redundancy"] = red
+        for cb in callbacks:
+            report.update(cb.telemetry(self))
         # Collective-traffic estimate at the dtype the bytes move in: a
         # mixed policy halves FSDP's gathered-param bytes (bf16 vs f32) —
         # the number tests/test_precision.py compares across policies.
@@ -1710,7 +1713,7 @@ class Model:
         # Streaming-input telemetry: the decode-parallelism setting rides
         # next to the stall fractions it exists to shrink, so a stall
         # report names the knob to turn (docs/API.md "Streaming input").
-        if y is None and getattr(source, "decode_workers", None) is not None:
+        if getattr(source, "decode_workers", None) is not None:
             report["input_decode_workers"] = int(source.decode_workers)
         report["comm_bytes_estimate"] = self.strategy.comm_bytes_estimate(
             self.params,
@@ -1720,104 +1723,16 @@ class Model:
             ),
             hints=self._param_hints,
         )
-        # Gather-overlap attribution (ScannedBlocks x Strategy.overlap_spec):
-        # the trace-time record of the most recent scanned apply on this
-        # thread says whether the double-buffered gather engaged.
-        # exposed_comm_fraction is the analytic share of per-layer gather
-        # traffic left serial with compute: all L gathers without overlap,
-        # only layer 0's warm-up gather with it. Exposed collective time
-        # on the chip is the benchmark's trace metric; this rides with every
-        # fit so telemetry names the lever (docs/API.md "Overlap round 2").
-        from ..nn.scan import last_overlap_trace
-        _otrace = last_overlap_trace()
-        if _otrace is None:
-            # Warm jit cache = nothing traced this fit; this model's own
-            # previous fit (if any) already recorded the program's shape.
-            _otrace = getattr(self, "_overlap_record", None)
-        else:
-            self._overlap_record = _otrace
-        _olayers = int(_otrace["layers"]) if _otrace else 0
-        _oactive = bool(_otrace and _otrace["active"])
-        report["overlap"] = {
-            "overlap": _oactive,
-            "exposed_comm_fraction": (
-                round(1.0 / _olayers, 6) if (_oactive and _olayers) else 1.0
-            ),
-            "layers": _olayers,
-        }
-        if obs_registry.enabled() and events_lib.default_log() is not None:
-            events_lib.emit(
-                evs.OVERLAP_REPORT,
-                overlap=report["overlap"]["overlap"],
-                exposed_comm_fraction=report["overlap"][
-                    "exposed_comm_fraction"],
-                layers=report["overlap"]["layers"],
-                strategy=type(self.strategy).__name__,
-            )
-        # Pipeline-schedule attribution (PipelinedBlocks x schedule): the
-        # trace-time record of the most recent pipelined apply on this
-        # thread — which schedule ran, its static tick count, and the
-        # analytic bubble fraction (n-1)/ticks. Same warm-cache fallback
-        # discipline as the overlap record above (docs/API.md "Pipeline
-        # round 2").
-        from ..nn.pipeline import last_pipeline_trace
-        _ptrace = last_pipeline_trace()
-        if _ptrace is None:
-            _ptrace = getattr(self, "_pipeline_record", None)
-        else:
-            self._pipeline_record = _ptrace
-        if _ptrace is not None:
-            report["pipeline"] = dict(_ptrace)
-            if obs_registry.enabled() and events_lib.default_log() is not None:
-                events_lib.emit(
-                    evs.PIPELINE_SCHEDULE_SELECTED,
-                    schedule=_ptrace["schedule"],
-                    interleave=_ptrace["interleave"],
-                    num_stages=_ptrace["num_stages"],
-                    num_microbatches=_ptrace["num_microbatches"],
-                    strategy=type(self.strategy).__name__,
-                )
-                events_lib.emit(
-                    evs.BUBBLE_REPORT,
-                    bubble_fraction=_ptrace["bubble_fraction"],
-                    ticks=_ptrace["ticks"],
-                    schedule=_ptrace["schedule"],
-                    interleave=_ptrace["interleave"],
-                    num_stages=_ptrace["num_stages"],
-                    num_microbatches=_ptrace["num_microbatches"],
-                )
         # The auto-shard decision record rides with every fit it governed:
         # chosen config, predicted bytes/traffic, and the pruned
         # candidates' rationale (docs/API.md "Autotuned sharding").
         if self.last_plan is not None:
             report["plan"] = self.last_plan.summary()
-        # Dropless expert layers count in their state (no sync in the step
-        # loop); read once here: per layer in the report, the sums as gauges.
-        moe_counters = moe_lib.counters(self.state)
-        if moe_counters:
-            report["moe"] = moe_counters
-            total = lambda name: sum(
-                c[name] for c in moe_counters.values())
-            for name in ("pairs", "held_rows"):
-                obs_reg.gauge(f"moe.{name}", total(name))
-            # Tiles the row walks and the grouped matmuls went over, of the
-            # tiles of the buffers' static worst case.
-            obs_reg.gauge("moe.buffer_used_pct", 100.0 * total(
-                "tiles_used") / max(total("buffer_tiles"), 1.0))
-        # Attention layers that select their keys count the same way.
-        select_counters = attention_lib.select_counters(self.state)
-        if select_counters:
-            report["select"] = select_counters
-        # And those that see a window of their keys.
-        window_counters = attention_lib.window_counters(self.state)
-        if window_counters:
-            report["window"] = window_counters
-        # The legacy dict is a VIEW stored in the metrics registry
-        # (key-for-key identical — pinned by the obs parity test): one
-        # telemetry surface, backward-compatible reader.
-        _flush_obs_window(force=True)
+        report.update(_layer_report(
+            self.state, program.records, self.strategy, obs_reg))
+        flush()
         obs_reg.gauge("fit/steps_per_sec", round(
-            fit_steps_done / report["total_seconds"], 3))
+            steps / report["total_seconds"], 3))
         obs_reg.gauge("fit/input_stall_fraction",
                       report["input_stall_fraction"])
         obs_reg.gauge("fit/model_state_bytes_per_device",
@@ -1826,10 +1741,7 @@ class Model:
         if dm:
             for key, val in dm.items():
                 obs_reg.gauge(f"fit/device_memory/{key}", val)
-        self.last_fit_telemetry = obs_reg.set_report("model.fit", report)
-        self._stall_timer = None
-        teardown_phase.end()
-        return history
+        return obs_reg.set_report("model.fit", report)
 
     # --------------------------------------------------------------- evaluate
     def evaluate(self, x, y=None, batch_size: int = 32, verbose: int = 1,

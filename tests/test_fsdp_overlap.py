@@ -27,7 +27,6 @@ import pytest
 from jax.sharding import NamedSharding, PartitionSpec
 
 import distributed_tpu as dtpu
-from distributed_tpu.nn import scan as nn_scan
 
 
 def _data(vocab=64, batch=8, seq=16, seed=0):
@@ -147,8 +146,9 @@ def test_scanned_blocks_validates_overlap_mode():
 
 
 def test_overlap_trace_records_activation(devices):
-    """The threadlocal trace the fit loop reads: set by the scanned apply
-    at trace time, layers + active flag."""
+    """The record the scanned apply hands its program at trace time, as the
+    fit's report reads it: layers + active flag."""
     _, model = _fit_losses(dtpu.FullyShardedDataParallel(), "auto", steps=1)
-    rec = nn_scan.last_overlap_trace()
-    assert rec == {"layers": 2, "active": True}
+    rec = model.last_fit_telemetry["overlap"]
+    assert rec == {"overlap": True, "exposed_comm_fraction": 0.5,
+                   "layers": 2}

@@ -461,7 +461,7 @@ def test_fit_counts_the_windowed_and_the_expert_layers_and_learns(tiny):
     # no selection bias: the buffer stays at zeros, whatever the loads
     bias = model.state["residual_3"]["main"]["moe"]["router_bias"]
     assert not np.any(np.asarray(bias))
-    assert attention_lib.window_counters(model.state).keys() == fit[
+    assert nn.core.counters(model.state)["window"].keys() == fit[
         "window"].keys()
 
 

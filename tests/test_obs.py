@@ -628,6 +628,65 @@ class TestDerivedViewParity:
         assert snaps[0]["rank"] == 0 and snaps[0]["world"] == 1
 
 
+class Probe(dtpu.nn.Layer):
+    """A layer the package does not know: counts its train calls and rows
+    in its state, and how often its apply was traced."""
+
+    traces = 0
+
+    def init(self, key, input_shape):
+        return {}, {"probe_calls": np.float32(0), "probe_rows": np.float32(0)
+                    }, tuple(input_shape)
+
+    def apply(self, params, state, x, *, train=False, rng=None):
+        Probe.traces += 1
+        return x, {"probe_calls": state["probe_calls"] + 1.0,
+                   "probe_rows": state["probe_rows"] + float(x.shape[0])}
+
+
+def test_a_layer_reports_its_own_kind_with_no_trainer_edit(
+        devices, tmp_path, monkeypatch):
+    """A kind declared where the layer lives reaches ``last_fit_telemetry``
+    with its gauges; a warm second fit traces nothing and still carries the
+    scanned stack's ``overlap`` entry, which the trace handed its
+    program."""
+    from distributed_tpu.nn import core
+
+    monkeypatch.setattr(core, "_COUNTED", dict(core._COUNTED))
+    core.counts("probe", ("probe_calls", "probe_rows"), lambda layers: {
+        "probe.rows": sum(c["probe_rows"] for c in layers.values())})
+    monkeypatch.setenv("DTPU_EVENT_LOG", str(tmp_path / "ev.jsonl"))
+    with dtpu.FullyShardedDataParallel().scope():
+        m = dtpu.Model(dtpu.nn.Sequential([
+            dtpu.nn.Dense(16),
+            dtpu.nn.ScannedBlocks(lambda: dtpu.nn.Dense(16), 2),
+            Probe(),
+            dtpu.nn.Dense(4),
+        ]))
+        m.compile(optimizer=dtpu.optim.SGD(0.05),
+                  loss="sparse_categorical_crossentropy", metrics=())
+    rng = np.random.default_rng(0)
+    x = rng.normal(size=(16, 16)).astype(np.float32)
+    y = rng.integers(0, 4, 16).astype(np.int32)
+    overlap = {"overlap": True, "exposed_comm_fraction": 0.5, "layers": 2}
+    m.fit(x, y, batch_size=8, epochs=1, steps_per_epoch=2, verbose=0)
+    t = m.last_fit_telemetry
+    assert t["probe"] == {"probe": {"probe_calls": 2.0, "probe_rows": 16.0}}
+    assert obs.default_registry().gauge_value("probe.rows") == 16.0
+    assert t["overlap"] == overlap
+    traced = Probe.traces
+    m.fit(x, y, batch_size=8, epochs=1, steps_per_epoch=2, verbose=0)
+    assert Probe.traces == traced  # warm: the step was not traced again
+    t = m.last_fit_telemetry
+    assert t["probe"] == {"probe": {"probe_calls": 4.0, "probe_rows": 32.0}}
+    assert t["overlap"] == overlap
+    reports = [e for e in read_events(tmp_path / "ev.jsonl")
+               if e["event"] == "overlap_report"]
+    assert [dict(e, ts=0, pid=0) for e in reports] == 2 * [dict(
+        overlap, event="overlap_report", ts=0, pid=0,
+        strategy="FullyShardedDataParallel")]
+
+
 # -------------------------------------------------------------- logging ----
 class TestLoggingRanks:
     def test_rank_world_defaults(self):

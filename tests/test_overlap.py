@@ -207,7 +207,7 @@ class TestFitPrefetchParity:
         assert t["dispatch"] > 0  # donated dispatches wait on the device
         assert 0.0 <= t["input_stall_fraction"] <= 1.0
         assert t["total_seconds"] >= t["input_wait"]
-        assert m._stall_timer is None  # detached at fit end
+        assert m.stall_timer is None  # detached at fit end
 
 
 # -------------------------------------------------- async checkpointing ----
